@@ -11,7 +11,7 @@ WORKDIR /app
 # jax pinned to the version the framework is tested against; everything
 # here is CPU-only so the image stays pullable anywhere
 RUN pip install --no-cache-dir \
-    "jax>=0.4.30" "numpy>=1.26" "pandas>=2.1" "pyarrow>=14" \
+    "jax==0.9.0" "jaxlib==0.9.0" "numpy==2.0.2" "pandas==3.0.3" "pyarrow>=14" \
     "pyyaml>=6" "optax>=0.2" "scipy>=1.11" "sympy>=1.12" "statsmodels>=0.14"
 
 COPY anovos_tpu/ /app/anovos_tpu/

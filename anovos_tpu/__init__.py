@@ -1,7 +1,7 @@
 """anovos_tpu — a TPU-native feature-engineering-at-scale framework.
 
-A ground-up JAX/XLA re-design of the Anovos workflow (reference:
-/root/reference, src/main/anovos): the Spark DataFrame engine is replaced by a
+A ground-up JAX/XLA re-design of the Anovos workflow (reference: upstream
+anovos, src/main/anovos): the Spark DataFrame engine is replaced by a
 device-sharded columnar Table, Spark SQL aggregations by batched XLA
 reductions with ICI collectives, and driver-side sklearn/TF models by
 JAX-native models trained on TPU.

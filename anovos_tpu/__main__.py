@@ -7,8 +7,7 @@ import sys
 
 if __name__ == "__main__":
     # --resume re-runs a killed config, restoring crash-committed node
-    # results from the cache store (anovos_tpu.cache); it needs a cache
-    # root, defaulted before the workflow import wires the runtime
+    # results from the cache store (anovos_tpu.cache); it needs a cache root
     resume = "--resume" in sys.argv
     if resume:
         sys.argv = [a for a in sys.argv if a != "--resume"]

@@ -20,10 +20,9 @@ stdlib-only pieces:
   record that lets ``--resume`` pick up a killed run's committed
   frontier.
 
-Opt-in via ``ANOVOS_TPU_CACHE=<dir>``; the same root also hosts JAX's
-persistent XLA compilation cache (``<dir>/xla``, wired by
-``init_runtime``) so cold compile wall is paid once per (program,
-jaxlib), not per process.
+Opt-in via ``ANOVOS_TPU_CACHE=<dir>``.  JAX's persistent XLA compilation
+cache is separate and on by default (``init_runtime``:
+``JAX_COMPILATION_CACHE_DIR``, else ``<checkout>/.jax_cache``).
 """
 
 from __future__ import annotations

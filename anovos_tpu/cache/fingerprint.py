@@ -162,11 +162,9 @@ EXEMPT_ENV_KNOBS = {
         "selects WHERE artifacts persist (store backend override), never "
         "their bytes — restore parity is store-agnostic by the "
         "ArtifactStore contract",
-    "ANOVOS_COMPILE_CACHE":
-        "XLA compile-cache directory — compile time only; compiled "
-        "programs produce identical outputs",
-    "ANOVOS_COMPILE_CACHE_MIN_SECS":
-        "compile-cache admission threshold — compile time only",
+    "JAX_COMPILATION_CACHE_DIR":
+        "JAX's own variable: where compiled programs persist — compile "
+        "time only; compiled programs produce identical outputs",
     "ANOVOS_DBSCAN_BATCH_MAX":
         "memory bound splitting the min_samples sweep into independent "
         "fits; per-fit results are unchanged and stacked in input order",

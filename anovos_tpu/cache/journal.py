@@ -60,7 +60,7 @@ alert appended to ``obs/continuum_alerts.jsonl`` with flight-recorder
 context).
 Sibling machine-readable contract (round 15): the perf-doctor
 **diagnosis** document — the ranked run-diff a gate failure attaches to
-its ``PERF_LEDGER.jsonl`` entry under ``diagnosis``, the same schema
+its ``BENCH_LEDGER.jsonl`` entry under ``diagnosis``, the same schema
 ``tools/perf_doctor`` prints and the HTML "Run Diff" tab renders.  Its
 full JSON schema (``diagnosis_version`` / ``kind`` / ``baseline`` /
 ``candidate`` / ``nodes`` / ``programs`` / ``cache`` / ``env`` /

@@ -5,7 +5,6 @@ Layout under the cache root (``ANOVOS_TPU_CACHE=<dir>``)::
     objects/<aa>/<sha256>   # file contents, content-addressed (deduped)
     nodes/<fingerprint>.json  # node manifest — the COMMIT POINT
     payloads/<fingerprint>/   # opaque per-node payload (df checkpoints)
-    xla/                      # jax persistent compilation cache (runtime)
 
 Commit protocol (crash-safe by ordering): objects land first (tmp +
 rename, so a torn write can never be addressed), then the payload dir
@@ -19,7 +18,7 @@ that rewrite a restored file in place via ``open("w")`` — truncating a
 linked file would corrupt the shared object for every future restore —
 so it is opt-in for read-only artifact trees.
 
-Eviction is LRU over node entries and xla cache files: ``lookup`` touches
+Eviction is LRU over node entries: ``lookup`` touches
 the manifest's mtime, ``gc(max_bytes)`` drops the least-recently-used
 units (freeing objects once unreferenced) until the store fits.
 ``tools/cache_gc.py`` is the CLI; ``ANOVOS_TPU_CACHE_MAX_BYTES`` makes
@@ -76,7 +75,6 @@ class CacheStore:
         self.objects_dir = os.path.join(self.root, "objects")
         self.nodes_dir = os.path.join(self.root, "nodes")
         self.payloads_dir = os.path.join(self.root, "payloads")
-        self.xla_dir = os.path.join(self.root, "xla")
         for d in (self.objects_dir, self.nodes_dir, self.payloads_dir):
             os.makedirs(d, exist_ok=True)
 
@@ -234,7 +232,7 @@ class CacheStore:
         return out
 
     def gc(self, max_bytes: int, dry_run: bool = False) -> dict:
-        """Evict least-recently-used node entries and xla cache files until
+        """Evict least-recently-used node entries until
         the store fits ``max_bytes``.  Also sweeps tmp debris and objects no
         remaining manifest references.  Returns an accounting dict."""
         before = self.total_bytes()
@@ -259,40 +257,21 @@ class CacheStore:
         for m in manifests:
             for e in m.get("files", ()):
                 refs[e["sha256"]] = refs.get(e["sha256"], 0) + 1
-        # LRU units: (mtime, kind, identity)
+        # LRU units: (mtime, fingerprint)
         units: List[tuple] = []
         for m in manifests:
             mpath = self._manifest_path(m["fingerprint"])
             try:
-                units.append((os.path.getmtime(mpath), "node", m["fingerprint"]))
+                units.append((os.path.getmtime(mpath), m["fingerprint"]))
             except OSError:
                 continue
-        if os.path.isdir(self.xla_dir):
-            for dirpath, _dirs, files in os.walk(self.xla_dir):
-                for f in files:
-                    p = os.path.join(dirpath, f)
-                    try:
-                        units.append((os.path.getmtime(p), "xla", p))
-                    except OSError:
-                        pass
         units.sort()
         by_fp = {m["fingerprint"]: m for m in manifests}
         evicted_nodes: List[str] = []
-        evicted_xla = 0
         total = self.total_bytes() if not dry_run else before
-        for _mtime, kind, ident in units:
+        for _mtime, ident in units:
             if total <= max_bytes:
                 break
-            if kind == "xla":
-                try:
-                    size = os.path.getsize(ident)
-                    if not dry_run:
-                        os.remove(ident)
-                    total -= size
-                    evicted_xla += 1
-                except OSError:
-                    pass
-                continue
             m = by_fp[ident]
             freed = 0
             mpath = self._manifest_path(ident)
@@ -340,7 +319,6 @@ class CacheStore:
             "after_bytes": after,
             "max_bytes": max_bytes,
             "evicted_nodes": evicted_nodes,
-            "evicted_xla_files": evicted_xla,
             "swept_tmp": swept_tmp,
             "swept_orphan_objects": swept_objects,
             "dry_run": dry_run,

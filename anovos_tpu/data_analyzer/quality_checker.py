@@ -717,8 +717,12 @@ def _is_invalid_values_bulk(
 
 def _unique_compact(data: jax.Array, mask: jax.Array):
     """Sorted distinct values scattered to a prefix buffer, on device.
-    Returns (buffer (rows+1,), nu) — callers slice buffer[:nu] so only the
-    distinct values transfer to host.  Integer columns stay integer: an f32
+    Returns (buffer (rows,), nu) — callers slice buffer[:nu] so only the
+    distinct values transfer to host.  The buffer is exactly ``rows`` long,
+    a length every mesh divides: as (rows+1,) it was the one program shape
+    of configs_full that four chips do not divide, and the TPU's SPMD
+    partitioner overflowed its stack padding it for a reshard (PR 22,
+    four-chip run).  Integer columns stay integer: an f32
     cast would collapse distinct ints above 2^24 (the exact failure this
     codebase documents for 1e9-range ids)."""
     from anovos_tpu.shared.runtime import wants_column_parallel
@@ -749,8 +753,9 @@ def _unique_compact_jit(data: jax.Array, mask: jax.Array, cp: bool = False):
     n_valid = mask.sum()
     trans = jnp.concatenate([jnp.ones(1, bool), Xs[1:] != Xs[:-1]])
     uniq_here = trans & (jnp.arange(rows) < n_valid)
+    # non-distinct entries aim past the end and are dropped
     tgt = jnp.where(uniq_here, jnp.cumsum(uniq_here) - 1, rows)
-    buf = jnp.zeros(rows + 1, dt).at[tgt].set(Xs)
+    buf = jnp.zeros(rows, dt).at[tgt].set(Xs, mode="drop")
     return buf, uniq_here.sum()
 
 

@@ -483,7 +483,8 @@ def reconcile_frames(frames: Sequence[Tuple[str, pd.DataFrame]],
         if widened:
             _count("widened", len(widened))  # pd.concat promotes int→float
         for c in ref_cols:
-            if ref_isnum[c] and df[c].dtype == object:
+            # string-typed: object, or pandas 3's StringDtype
+            if ref_isnum[c] and not pd.api.types.is_numeric_dtype(df[c]):
                 coerced = pd.to_numeric(df[c], errors="coerce")
                 bad = int((coerced.isna() & df[c].notna()).sum())
                 if bad:

@@ -167,19 +167,15 @@ def neighbor_counts(X: np.ndarray, eps: float, tile: int = 4096) -> np.ndarray:
     dbscan_fit uses; public so a hyperparameter grid can compute it once per
     eps and share it across every min_samples.
 
-    ``ANOVOS_USE_PALLAS=1`` (TPU-only, EXPERIMENTAL — ops/pallas_kernels)
-    swaps in the hand-scheduled kernel that streams the query rows through
-    VMEM with the (tile, n) distance block kept on-chip; the XLA tile loop
-    below materializes each block in HBM.  The backend choice happens
-    OUTSIDE jit so the env var is honored per call."""
-    from anovos_tpu.ops.pallas_kernels import neighbor_counts_pallas, use_pallas
+    The Pallas twin (ops/pallas_kernels.neighbor_counts_pallas) is not
+    accepted by the chip's compiler at these sizes, so
+    ``ANOVOS_USE_PALLAS=1`` is refused here with that reason."""
+    from anovos_tpu.ops.pallas_kernels import use_pallas
 
     X = np.asarray(X, np.float32)
     Xd = jnp.asarray(X - X.mean(axis=0, keepdims=True), jnp.float32)  # magnitude → spread
     eps2 = jnp.asarray(eps * eps, jnp.float32)
-    if use_pallas():
-        # early-return branch: nothing dispatches after this materialization
-        return np.asarray(neighbor_counts_pallas(Xd, eps2))  # graftcheck: disable=GC001
+    use_pallas("neighbor_counts")  # raises under ANOVOS_USE_PALLAS=1
     # dispatch every tile before fetching any: the per-tile programs queue
     # asynchronously on the device stream and the transfers drain afterwards
     # (a fetch inside the dispatch loop serialized tile k+1 behind tile k's

@@ -17,10 +17,7 @@ from typing import Dict
 import jax
 import jax.numpy as jnp
 
-try:  # jax ≥ 0.8 promotes shard_map out of experimental
-    from jax import shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from anovos_tpu.ops.reductions import finalize_moments

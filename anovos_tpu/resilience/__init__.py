@@ -15,8 +15,7 @@ Four cooperating, stdlib-only pieces:
 * **policy** — :class:`ErrorPolicy` / ``on_error="retry:N[:degrade]"``
   parsing, deterministic-jitter backoff, and the degradation registry
   the manifest + report placeholder banner read.
-* **failover** — bounded in-run health probe (reusing
-  ``backend_probe``'s dispatch check) and the one-shot CPU flip.
+* **failover** — bounded in-run health probe and the one-shot CPU flip.
 * the scheduler integration lives in ``parallel/scheduler.py`` (retry
   loop, partial-artifact discard via the PR 5 capture recorder, watchdog
   escalation) and ``workflow.py`` (per-class policy defaults, manifest

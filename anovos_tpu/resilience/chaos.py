@@ -2,9 +2,8 @@
 
 Every recovery path in this package (retry, timeout escalation, backend
 failover, graceful degradation) must be EXERCISED in tier-1 tests, not
-just believed — the upfront backend probe is explicitly "necessary but
-not sufficient" (``shared/backend_probe.py``), and a recovery path that
-only runs during a real outage is a recovery path that has never run.
+just believed — a recovery path that only runs during a real outage is
+a recovery path that has never run.
 
 ``ANOVOS_TPU_CHAOS`` holds a spec of semicolon-separated directives:
 

@@ -61,9 +61,6 @@ def _cmd_export(ns) -> int:
 def _cmd_smoke(ns) -> int:
     workdir = ns.workdir or tempfile.mkdtemp(prefix="anovos_serve_smoke_")
     cache = ns.cache or os.path.join(workdir, "cache")
-    # the CAS store doubles as the persistent XLA compile-cache root
-    # (<cache>/xla) — set BEFORE the runtime initializes so warm-up
-    # compiles land in (and on re-runs, come from) the persistent cache
     os.environ.setdefault("ANOVOS_TPU_CACHE", cache)
 
     from anovos_tpu.serving.bundle import load_bundle

@@ -16,7 +16,7 @@ shape-bucket discipline:
 * **warm()** — at server start, drive the full apply path once per
   bucket on schema-synthesized rows: every ``jax.jit`` in the chain
   lowers and compiles HERE, against the persistent XLA compile cache
-  (``ANOVOS_COMPILE_CACHE`` / ``ANOVOS_TPU_CACHE/xla``) so a warm
+  (``JAX_COMPILATION_CACHE_DIR``, else ``<checkout>/.jax_cache``) so a warm
   process boots in bounded time and a cold one pays each program once
   per (program, jaxlib) ever.  The measured wall and per-bucket compile
   counts are the server's cold-start record; after warm, a request-time

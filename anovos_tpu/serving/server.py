@@ -36,7 +36,6 @@ Request lifecycle:
 from __future__ import annotations
 
 import logging
-import math
 import os
 import queue
 import threading
@@ -157,13 +156,14 @@ def frame_to_payload(df: pd.DataFrame) -> Dict[str, list]:
     out: Dict[str, list] = {}
     for name in df.columns:
         s = df[name]
-        if np.issubdtype(s.dtype, np.datetime64):
+        # pandas' own predicates: pandas 3 string columns carry StringDtype,
+        # which numpy's issubdtype cannot interpret
+        if pd.api.types.is_datetime64_any_dtype(s.dtype):
             out[name] = [None if pd.isna(v) else pd.Timestamp(v).isoformat()
                          for v in s]
-        elif s.dtype == object:
-            out[name] = [None if v is None or (isinstance(v, float) and math.isnan(v))
-                         else str(v) for v in s]
-        elif np.issubdtype(s.dtype, np.integer):
+        elif s.dtype == object or pd.api.types.is_string_dtype(s.dtype):
+            out[name] = [None if pd.isna(v) else str(v) for v in s]
+        elif pd.api.types.is_integer_dtype(s.dtype):
             out[name] = [int(v) for v in s]
         else:
             out[name] = [None if not np.isfinite(v) else float(v) for v in s]

@@ -165,20 +165,13 @@ class Runtime:
         return b
 
 
-def compile_cache_dir() -> str:
-    """Resolve the persistent XLA compilation cache directory ('' = off).
-
-    ``ANOVOS_COMPILE_CACHE`` wins when set explicitly; otherwise the
-    incremental-recompute root (``ANOVOS_TPU_CACHE``, anovos_tpu.cache)
-    hosts the compile cache too at ``<root>/xla`` — one knob makes BOTH
-    the node results and the compiled programs persistent, so a cold
-    process pays compilation once per (program, jaxlib) instead of per
-    run.  The xla/ subtree is LRU-swept with the rest of the store
-    (``tools/cache_gc.py``)."""
-    cache_dir = os.environ.get("ANOVOS_COMPILE_CACHE", "")
-    if not cache_dir and os.environ.get("ANOVOS_TPU_CACHE", ""):
-        cache_dir = os.path.join(os.environ["ANOVOS_TPU_CACHE"], "xla")
-    return cache_dir
+# JAX's persistent compilation cache, when ``JAX_COMPILATION_CACHE_DIR`` does
+# not place it: one fixed directory of the checkout, resolved from this
+# file and never from cwd, a temp name, a pid or the time — the directory is
+# part of the cache key, so a cache that moves never hits.
+DEFAULT_COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    ".jax_cache")
 
 
 @contextmanager
@@ -383,19 +376,15 @@ def init_runtime(
     jax.config.update(
         "jax_default_matmul_precision", os.environ.get("ANOVOS_MATMUL_PRECISION", "highest")
     )
-    cache_dir = compile_cache_dir()
-    if cache_dir:
-        # persistent XLA compilation cache: pipeline stages produce many
-        # distinct table shapes, and compilation dominates cold-run wall
-        # time.  The pipeline is ~200 SMALL programs, so the threshold must
-        # sit well below jax's 1s default — at 0.02s a second process's
-        # configs_full "cold" run drops 34 → 15 s on one CPU core (~1.5 MB
-        # of cache).  First run pays ~15% cache-write overhead.
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update(
-            "jax_persistent_cache_min_compile_time_secs",
-            float(os.environ.get("ANOVOS_COMPILE_CACHE_MIN_SECS", 0.02)),
-        )
+    # persistent XLA compilation cache, on by default: pipeline stages
+    # produce many distinct table shapes, and compilation dominates
+    # cold-run wall time.  Where JAX_COMPILATION_CACHE_DIR is set JAX has
+    # already read it and no directory is set in code.
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", DEFAULT_COMPILE_CACHE_DIR)
+    # The pipeline is ~200 SMALL programs, so the threshold must sit well
+    # below jax's 1s default.
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.02)
     if distributed and jax.process_count() == 1 and "JAX_COORDINATOR_ADDRESS" in os.environ:
         jax.distributed.initialize()
     devs = list(devices if devices is not None else jax.devices())

@@ -1386,11 +1386,10 @@ def main(
 
                     try:  # capacity bound: same LRU sweep as tools/cache_gc.py
                         stats = cache_store.gc(parse_bytes(max_bytes))
-                        if stats["evicted_nodes"] or stats["evicted_xla_files"]:
+                        if stats["evicted_nodes"]:
                             logger.info(
-                                "cache gc: %d node entr(ies) + %d xla file(s) "
-                                "evicted (%d -> %d bytes)",
-                                len(stats["evicted_nodes"]), stats["evicted_xla_files"],
+                                "cache gc: %d node entr(ies) evicted (%d -> %d bytes)",
+                                len(stats["evicted_nodes"]),
                                 stats["before_bytes"], stats["after_bytes"])
                     except Exception:
                         logger.exception("cache gc failed; store left as-is")

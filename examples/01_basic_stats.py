@@ -12,16 +12,13 @@ from pathlib import Path
 
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
-from examples._data import supervised_entry, load_income  # noqa: E402
-
-supervised_entry()
-
+from anovos_tpu.data_ingest.synthetic import load_income  # noqa: E402
 from anovos_tpu.data_analyzer import stats_generator as sg  # noqa: E402
 from anovos_tpu.shared import Table  # noqa: E402
 
 
 def main() -> None:
-    df = load_income()
+    df = load_income().drop(columns=["dt_1", "dt_2", "empty", "logfnl"])
     t = Table.from_pandas(df)
     print(f"loaded {t.nrows} rows × {len(t.col_names)} cols\n")
 

@@ -16,16 +16,13 @@ import numpy as np
 
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
-from examples._data import supervised_entry, load_income  # noqa: E402
-
-supervised_entry()
-
+from anovos_tpu.data_ingest.synthetic import load_income  # noqa: E402
 from anovos_tpu.drift_stability import drift_detector, stability  # noqa: E402
 from anovos_tpu.shared import Table  # noqa: E402
 
 
 def main() -> None:
-    df = load_income()
+    df = load_income().drop(columns=["dt_1", "dt_2", "empty", "logfnl"])
     n = len(df)
     source = df.iloc[: n // 2].reset_index(drop=True)
     target = df.iloc[n // 2 :].reset_index(drop=True).copy()

@@ -2,10 +2,8 @@
 
 This is exactly what `python main.py config/configs_basic.yaml local` does —
 the reference's demo flow (demo/run_anovos_demo.sh) — run in-process so you
-can step through it.  When the config's dataset paths don't exist on this
-host (e.g. inside the demo container), a synthesized income-schema dataset
-is materialized first and the config is patched to read it, so the script
-runs anywhere.
+can step through it.  The seeded income dataset is generated under
+``data/income_dataset`` on first use.
 
     python examples/03_full_report.py [output_dir]
 """
@@ -19,11 +17,8 @@ import yaml
 REPO = Path(__file__).resolve().parent.parent
 sys.path.insert(0, str(REPO))
 
-from examples._data import supervised_entry, materialize_income_parquet  # noqa: E402
-
-supervised_entry()
-
 from anovos_tpu import workflow  # noqa: E402
+from anovos_tpu.data_ingest.synthetic import generate, rebase_config  # noqa: E402
 
 
 def main() -> None:
@@ -31,17 +26,8 @@ def main() -> None:
     out.mkdir(parents=True, exist_ok=True)
 
     with open(REPO / "config" / "configs_basic.yaml") as f:
-        cfg = yaml.safe_load(f)
-
-    src = cfg["input_dataset"]["read_dataset"]["file_path"]
-    if not os.path.isdir(src):
-        print(f"dataset not found at {src}; materializing a synthesized copy")
-        main_dir, join_dir = materialize_income_parquet(out / "data")
-        cfg["input_dataset"]["read_dataset"]["file_path"] = main_dir
-        join_block = cfg.get("join_dataset")
-        if join_block:
-            join_block["dataset1"]["read_dataset"]["file_path"] = join_dir
-            join_block["dataset1"]["read_dataset"]["file_type"] = "parquet"
+        cfg = rebase_config(yaml.safe_load(f))  # data/... → absolute: we chdir below
+    generate()
 
     os.chdir(out)
     workflow.main(cfg, "local")

@@ -39,7 +39,9 @@ import numpy as np
 import pandas as pd
 
 HERE = os.path.dirname(os.path.abspath(__file__))
-DATA = "/root/reference/examples/data/income_dataset/parquet/*.parquet"
+# the seeded 32,561-row set: `python -m anovos_tpu.data_ingest.synthetic`
+DATA = os.path.join(os.path.dirname(os.path.dirname(HERE)),
+                    "data", "income_dataset", "parquet", "*.parquet")
 
 NUM_COLS = [
     "age", "fnlwgt", "logfnl", "education-num", "capital-gain",

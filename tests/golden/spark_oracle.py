@@ -41,9 +41,11 @@ import pandas as pd
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 REFERENCE_SRC = os.environ.get("ANOVOS_REFERENCE_SRC", "/root/reference/src/main")
+# the seeded set the committed goldens were generated from
+# (python -m anovos_tpu.data_ingest.synthetic)
 DATA = os.environ.get(
     "ANOVOS_GOLDEN_DATA",
-    "/root/reference/examples/data/income_dataset/parquet",
+    os.path.join(os.path.dirname(os.path.dirname(HERE)), "data", "income_dataset", "parquet"),
 )
 
 NUM_COLS = [

@@ -155,16 +155,96 @@ def test_node_fingerprint_folds_slice_writes_and_deps():
     assert a != node_fingerprint("base2", "n", {"k": 1}, ("w",), ("dep1",))
 
 
-def test_xla_compile_cache_rides_the_cache_root(monkeypatch):
-    from anovos_tpu.shared.runtime import compile_cache_dir
+# ------------------------------------------------------ compile cache ----
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-    monkeypatch.delenv("ANOVOS_COMPILE_CACHE", raising=False)
-    monkeypatch.delenv("ANOVOS_TPU_CACHE", raising=False)
-    assert compile_cache_dir() == ""
-    monkeypatch.setenv("ANOVOS_TPU_CACHE", "/c/root")
-    assert compile_cache_dir() == os.path.join("/c/root", "xla")
-    monkeypatch.setenv("ANOVOS_COMPILE_CACHE", "/explicit")
-    assert compile_cache_dir() == "/explicit"  # explicit knob wins
+
+def test_compile_cache_env_var_means_no_directory_set_in_code(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR set: JAX has read it; init_runtime must
+    not touch jax_compilation_cache_dir at all."""
+    import jax
+
+    from anovos_tpu.shared.runtime import init_runtime
+
+    before = jax.config.jax_compilation_cache_dir
+    jax.config.update("jax_compilation_cache_dir", "/sentinel/not/ours")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/placed/from/outside")
+    try:
+        init_runtime()
+        assert jax.config.jax_compilation_cache_dir == "/sentinel/not/ours"
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+
+
+def test_compile_cache_default_is_one_fixed_path_in_the_checkout(monkeypatch, tmp_path):
+    import jax
+
+    from anovos_tpu.shared import runtime
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    monkeypatch.chdir(tmp_path)  # not resolved from cwd
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        runtime.init_runtime()
+        assert jax.config.jax_compilation_cache_dir == os.path.join(_CHECKOUT, ".jax_cache")
+        assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.02
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+    assert runtime.DEFAULT_COMPILE_CACHE_DIR == os.path.join(_CHECKOUT, ".jax_cache")
+
+
+_CACHE_PROBE = """
+import json, sys
+import jax, jax.numpy as jnp
+hits = []
+jax.monitoring.register_event_listener(
+    lambda name, **kw: hits.append(name) if name.endswith("/cache_hits") else None)
+sys.path.insert(0, sys.argv[1])
+from anovos_tpu.shared.runtime import init_runtime
+init_runtime()
+f = jax.jit(lambda a: jnp.cumsum(jnp.sort(a * float(sys.argv[2]), axis=0), axis=0).sum())
+f(jnp.ones((4096, 8))).block_until_ready()
+print(json.dumps({"dir": jax.config.jax_compilation_cache_dir, "hits": len(hits)}))
+"""
+
+
+def _probe(cwd, salt, env_dir=None):
+    import json
+    import subprocess
+    import sys
+
+    env = {k: v for k, v in os.environ.items()
+           if k not in ("JAX_COMPILATION_CACHE_DIR", "XLA_FLAGS")}
+    env["JAX_PLATFORMS"] = "cpu"
+    if env_dir:
+        env["JAX_COMPILATION_CACHE_DIR"] = env_dir
+    out = subprocess.run([sys.executable, "-c", _CACHE_PROBE, _CHECKOUT, salt], cwd=cwd,
+                         env=env, capture_output=True, text=True, timeout=300, check=True)
+    return json.loads(out.stdout.strip().splitlines()[-1])
+
+
+def test_compile_cache_same_directory_from_two_cwds(tmp_path):
+    """Unset: two processes started in different working directories share
+    <checkout>/.jax_cache, and the second compiles nothing the first cached."""
+    import random
+
+    (tmp_path / "a").mkdir()
+    (tmp_path / "b").mkdir()
+    salt = repr(random.random())  # a constant no earlier run has compiled
+    first = _probe(str(tmp_path / "a"), salt)
+    second = _probe(str(tmp_path / "b"), salt)
+    assert first["dir"] == second["dir"] == os.path.join(_CHECKOUT, ".jax_cache")
+    # helper programs (jnp.ones) may already be cached; the salted one is new
+    assert second["hits"] > first["hits"]
+    assert not list((tmp_path / "a").iterdir()) and not list((tmp_path / "b").iterdir())
+
+
+def test_compile_cache_placed_by_env_var_gets_the_entries(tmp_path):
+    placed = tmp_path / "placed"
+    out = _probe(str(tmp_path), "3.5", env_dir=str(placed))
+    assert out["dir"] == str(placed) and out["hits"] == 0
+    assert any(placed.iterdir())
+    assert _probe(str(tmp_path), "3.5", env_dir=str(placed))["hits"] >= 1
 
 
 # -------------------------------------------------------------- store ----

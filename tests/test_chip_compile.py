@@ -1,0 +1,128 @@
+"""Compile for the chip without the chip: the TPU compiler is installed and
+compiles for a described, unattached ``v5e:2x2`` topology.  Kept here: the
+two Pallas kernels that Mosaic accepts and the main path's large XLA programs
+at 4 M rows x 16 columns on one described chip, each read against 16 GB.
+
+One file, one worker: only one process may load the TPU library.  The
+topology is described inside a fixture (never at import or collection), and
+JAX's persistent compilation cache is off around these compiles — such an
+entry is written but cannot be read back without a chip.
+"""
+
+import os
+
+import pytest
+
+ROWS = 1 << 22  # Runtime.pad_rows(4_000_000)
+K = 16
+HBM_BYTES = 16e9
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import jax
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else the compiler logs under /tmp
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        desc = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", enabled)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def shapes(topo):
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    return {"X": spec((ROWS, K), jnp.float32), "M": spec((ROWS, K), jnp.bool_),
+            "cuts": spec((K, 9), jnp.float32)}
+
+
+def _compile(fn, *args, **kw):
+    compiled = fn.lower(*args, **kw).compile()
+    ma = compiled.memory_analysis()
+    total = ma.temp_size_in_bytes + ma.argument_size_in_bytes + ma.output_size_in_bytes
+    assert total < HBM_BYTES, f"{total / 1e9:.1f} GB does not fit one v5e chip"
+    return compiled
+
+
+def test_moments_pallas_compiles(shapes):
+    from anovos_tpu.ops.pallas_kernels import moments_pallas
+
+    assert "tpu_custom_call" in _compile(moments_pallas, shapes["X"], shapes["M"]).as_text()
+
+
+def test_binned_histograms_pallas_compiles(shapes):
+    """Refused before PR 22 (infer-vector-layout: i1 shape cast); the kernel
+    now keeps every intermediate 2-D."""
+    from anovos_tpu.ops.pallas_kernels import binned_histograms_pallas
+
+    c = _compile(binned_histograms_pallas, shapes["X"], shapes["M"], shapes["cuts"], nbins=10)
+    assert "tpu_custom_call" in c.as_text()
+
+
+def test_masked_moments_compiles(shapes):
+    from anovos_tpu.ops.reductions import _masked_moments_xla
+
+    _compile(_masked_moments_xla, shapes["X"], shapes["M"])
+
+
+def test_describe_numeric_compiles(shapes):
+    """The fused describe (moments + the sort behind percentiles, mode and
+    distinct count): the main path's largest program, ~3.2 GB of temp."""
+    from anovos_tpu.ops.describe import _describe_numeric
+
+    _compile(_describe_numeric, shapes["X"], shapes["M"])
+
+
+def test_dense_binned_histograms_compiles(shapes, monkeypatch):
+    """_flat_counts with the TPU-only dense budget (1 << 30): at 4 M x 16 x
+    10 the compare-and-reduce branch is taken, which no CPU test reaches."""
+    from anovos_tpu.ops import drift_kernels
+
+    monkeypatch.setattr(drift_kernels, "_dense_budget", lambda: 1 << 30)
+    assert ROWS * K * 10 <= 1 << 30
+    c = _compile(drift_kernels._binned_histograms_xla, shapes["X"], shapes["M"],
+                 shapes["cuts"], nbins=10)
+    assert "scatter" not in c.as_text()
+
+
+def test_masked_corr_compiles(shapes):
+    from anovos_tpu.ops.correlation import _masked_corr
+
+    _compile(_masked_corr, shapes["X"], shapes["M"])
+
+
+def test_row_sharded_describe_and_histograms_compile_for_four_chips(topo):
+    """The four-chip phase of chip_smoke.py: the same programs with rows
+    sharded over a 4-device mesh built from the described topology."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from anovos_tpu.ops.drift_kernels import _binned_histograms_xla
+    from anovos_tpu.ops.reductions import _masked_moments_xla
+
+    mesh = Mesh(np.array(topo.devices).reshape(4, 1), ("data", "model"))
+    rows, rep = NamedSharding(mesh, P("data")), NamedSharding(mesh, P())
+    X = jax.ShapeDtypeStruct((ROWS, K), jnp.float32, sharding=rows)
+    M = jax.ShapeDtypeStruct((ROWS, K), jnp.bool_, sharding=rows)
+    cuts = jax.ShapeDtypeStruct((K, 9), jnp.float32, sharding=rep)
+    text = _compile(_masked_moments_xla, X, M).as_text()
+    assert "all-reduce" in text  # per-shard partials meet in a psum
+    _compile(_binned_histograms_xla, X, M, cuts, nbins=10)

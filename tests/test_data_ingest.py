@@ -22,8 +22,10 @@ from anovos_tpu.data_ingest import (
 )
 from anovos_tpu.shared.table import Table
 
-INCOME_PARQUET = "/root/reference/examples/data/income_dataset/parquet"
-INCOME_AVRO = "/root/reference/examples/data/income_dataset/join"
+from anovos_tpu.data_ingest.synthetic import DEFAULT_DIR
+
+INCOME_PARQUET = str(DEFAULT_DIR / "parquet")
+INCOME_AVRO = str(DEFAULT_DIR / "join")
 
 
 def test_read_parquet_dir():
@@ -32,12 +34,12 @@ def test_read_parquet_dir():
     assert "age" in t and "workclass" in t
 
 
-def test_read_avro_snappy():
+def test_read_avro(income_df):
     t = read_dataset(INCOME_AVRO, "avro")
-    assert t.nrows > 0
+    assert t.nrows == 32561
     assert set(t.col_names) == {"ifa", "age", "workclass"}
     df = t.to_pandas()
-    assert df["workclass"].iloc[0] == "Self-emp-not-inc"
+    assert df["workclass"].head(100).tolist() == income_df["workclass"].head(100).tolist()
 
 
 def test_write_roundtrip(tmp_path):

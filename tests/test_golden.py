@@ -5,7 +5,6 @@ here as a diff against a committed artifact, not against an in-test
 reimplementation (VERDICT r2 weak #7).
 """
 
-import glob
 import os
 import tempfile
 
@@ -34,9 +33,9 @@ def _golden(name: str) -> pd.DataFrame:
 
 @pytest.fixture(scope="module")
 def income():
-    files = sorted(glob.glob("/root/reference/examples/data/income_dataset/parquet/*.parquet"))
-    df = pd.concat([pd.read_parquet(f) for f in files], ignore_index=True)[ALL_COLS]
-    return df
+    from anovos_tpu.data_ingest.synthetic import load_income
+
+    return load_income()[ALL_COLS]
 
 
 @pytest.fixture(scope="module")

@@ -235,7 +235,7 @@ def test_string_vs_numeric_drift_stringifies():
     out = guard.reconcile_frames([("p0", ref), ("p1", drifted)])
     merged = pd.concat(out, ignore_index=True)
     assert merged["code"].tolist() == ["00501", "00502", "501", "502"]
-    assert merged["code"].dtype == object
+    assert pd.api.types.is_string_dtype(merged["code"])  # object, or pandas 3's str
     assert get_metrics().counter("ingest_schema_drift_total").value(kind="retyped") == 1
 
 

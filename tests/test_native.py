@@ -8,10 +8,9 @@ from anovos_tpu.data_ingest import avro_io
 from anovos_tpu.shared import native as nat
 from anovos_tpu.shared.table import Table
 
-REF_AVRO = (
-    "/root/reference/examples/data/income_dataset/join/"
-    "part-00000-d500b201-de80-47c8-ad2c-88b0915a2d17-c000.avro"
-)
+from anovos_tpu.data_ingest.synthetic import DEFAULT_DIR
+
+REF_AVRO = str(DEFAULT_DIR / "join" / "part-00000.avro")
 
 
 @pytest.fixture(scope="module")
@@ -31,7 +30,7 @@ def _python_decode(path):
         nat._LIB, nat._TRIED = saved_lib, saved_tried
 
 
-def test_native_avro_parity_snappy(lib):
+def test_native_avro_parity_income_join(lib):
     out_n = avro_io.read_avro(REF_AVRO)
     out_p = _python_decode(REF_AVRO)
     assert set(out_n) == set(out_p)
@@ -66,12 +65,12 @@ def test_native_avro_parity_deflate(lib, tmp_path):
     np.testing.assert_allclose(np.nan_to_num(np.asarray(out["x"], float), nan=-1), np.nan_to_num(df["x"].to_numpy(), nan=-1))
 
 
-def test_native_encoded_strings_into_table(lib):
+def test_native_encoded_strings_into_table(lib, income_df):
     out = avro_io.read_avro(REF_AVRO)
     t = Table.from_numpy(out, nrows=len(out["ifa"]))
     assert t["workclass"].kind == "cat"
     df = t.to_pandas()
-    assert df["workclass"].iloc[0] == "Self-emp-not-inc"
+    assert df["workclass"].iloc[0] == income_df["workclass"].iloc[0]
     # vocab is sorted (canonical convention shared with np.unique encoding)
     vocab = t["workclass"].vocab
     assert list(vocab) == sorted(vocab)

@@ -8,10 +8,8 @@ import jax.numpy as jnp
 
 def test_pallas_histogram_parity():
     from anovos_tpu.ops.drift_kernels import binned_histograms
-    from anovos_tpu.ops.pallas_kernels import _PALLAS_OK, binned_histograms_pallas
+    from anovos_tpu.ops.pallas_kernels import binned_histograms_pallas
 
-    if not _PALLAS_OK:
-        pytest.skip("pallas unavailable")
     g = np.random.default_rng(0)
     rows, k, nbins = 5000, 6, 10
     X = jnp.asarray(g.normal(50, 20, (rows, k)), jnp.float32)
@@ -28,10 +26,8 @@ def test_pallas_neighbor_counts_parity():
     mode, across non-tile-multiple row counts and eps scales (incl. the
     all-isolated and the everything-connected regimes)."""
     from anovos_tpu.ops.cluster import neighbor_counts
-    from anovos_tpu.ops.pallas_kernels import _PALLAS_OK, neighbor_counts_pallas
+    from anovos_tpu.ops.pallas_kernels import neighbor_counts_pallas
 
-    if not _PALLAS_OK:
-        pytest.skip("pallas unavailable")
     import jax
 
     g = np.random.default_rng(3)
@@ -67,3 +63,18 @@ def test_moments_pallas_matches_xla_interpret():
         np.testing.assert_allclose(np.asarray(got[k]), np.asarray(exp[k]), rtol=5e-3, atol=1e-3)
     for k in ("skewness", "kurtosis"):  # f32 sampling noise scale for shape stats
         np.testing.assert_allclose(np.asarray(got[k]), np.asarray(exp[k]), rtol=2e-2, atol=2e-2)
+
+
+def test_use_pallas_raises_off_tpu_and_refuses_neighbor_kernel(monkeypatch):
+    """ANOVOS_USE_PALLAS=1 on a backend other than TPU is an error, not a
+    warning and a quiet XLA run; the DBSCAN neighbor kernel, which the
+    chip's compiler does not accept at pipeline sizes, is refused by name."""
+    from anovos_tpu.ops.pallas_kernels import use_pallas
+
+    monkeypatch.delenv("ANOVOS_USE_PALLAS", raising=False)
+    assert use_pallas() is False and use_pallas("neighbor_counts") is False
+    monkeypatch.setenv("ANOVOS_USE_PALLAS", "1")
+    with pytest.raises(RuntimeError, match="TPU-only"):
+        use_pallas()
+    with pytest.raises(RuntimeError, match="compiler"):
+        use_pallas("neighbor_counts")

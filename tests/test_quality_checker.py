@@ -126,3 +126,20 @@ def test_invalid_entries_manual():
         t, detection_type="manual", invalid_entries=["forbidden"], treatment=False
     )
     assert stats["invalid_count"][0] == 1
+
+
+def test_unique_compact_buffer_is_exactly_rows_long():
+    """The compaction buffer keeps the padded row count as its length (a
+    length every mesh divides — as rows+1 it crashed the TPU's SPMD
+    partitioner on four chips), and still holds every distinct value."""
+    import jax.numpy as jnp
+
+    from anovos_tpu.data_analyzer.quality_checker import _unique_compact
+
+    data = jnp.asarray([3.0, 1.0, 3.0, 2.0, 9.0, 1.0, 7.0, 7.0])
+    for mask in ([True] * 8, [True, True, True, True, False, True, False, False]):
+        m = jnp.asarray(mask)
+        buf, nu = _unique_compact(data, m)
+        assert buf.shape == data.shape
+        want = sorted(set(float(v) for v, k in zip(data, mask) if k))
+        assert [float(v) for v in buf[: int(nu)]] == want

@@ -275,7 +275,7 @@ def test_truly_stuck_raise_node_still_raises_nodetimeout():
 
 # --------------------------------------------- failover / health ----
 def test_probe_in_process_healthy_on_cpu():
-    from anovos_tpu.shared.backend_probe import probe_in_process
+    from anovos_tpu.resilience.failover import probe_in_process
 
     assert probe_in_process(60.0) is True
 

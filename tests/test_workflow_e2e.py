@@ -9,11 +9,14 @@ import pytest
 import yaml
 
 from anovos_tpu import workflow
+from anovos_tpu.data_ingest.synthetic import DEFAULT_DIR
+
+INCOME_PARQUET = str(DEFAULT_DIR / "parquet")
 
 CFG = {
     "input_dataset": {
         "read_dataset": {
-            "file_path": "/root/reference/examples/data/income_dataset/parquet",
+            "file_path": INCOME_PARQUET,
             "file_type": "parquet",
         },
         "delete_column": ["logfnl", "empty", "dt_1", "dt_2"],
@@ -56,7 +59,7 @@ CFG = {
             },
             "source_dataset": {
                 "read_dataset": {
-                    "file_path": "/root/reference/examples/data/income_dataset/parquet",
+                    "file_path": INCOME_PARQUET,
                     "file_type": "parquet",
                 },
                 "delete_column": ["logfnl", "empty", "dt_1", "dt_2"],
@@ -206,7 +209,7 @@ def test_ts_geo_failures_do_not_kill_pipeline(tmp_path, monkeypatch):
     cfg = {
         "input_dataset": {
             "read_dataset": {
-                "file_path": "/root/reference/examples/data/income_dataset/parquet",
+                "file_path": INCOME_PARQUET,
                 "file_type": "parquet",
             },
             "delete_column": ["logfnl", "empty", "dt_2"],

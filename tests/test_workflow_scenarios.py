@@ -9,13 +9,14 @@ import pytest
 import yaml
 
 from anovos_tpu import workflow
+from anovos_tpu.data_ingest.synthetic import CHECKOUT, rebase_config
 
-CONFIG_DIR = "/root/repo/config"
+CONFIG_DIR = str(CHECKOUT / "config")
 
 
 def _run(cfg_name, tmp_path, monkeypatch, mutate=None):
     with open(os.path.join(CONFIG_DIR, cfg_name)) as f:
-        cfg = yaml.safe_load(f)
+        cfg = rebase_config(yaml.safe_load(f))
     if mutate:
         mutate(cfg)
     monkeypatch.chdir(tmp_path)
@@ -67,7 +68,7 @@ def test_configs_concat_join_stages(tmp_path, monkeypatch):
     config/configs.yaml) drive the ETL helper + ingest ops end to end:
     concat doubles the rows, the avro join attaches the dupl_* columns."""
     with open(os.path.join(CONFIG_DIR, "configs.yaml")) as f:
-        cfg = yaml.safe_load(f)
+        cfg = rebase_config(yaml.safe_load(f))
     monkeypatch.chdir(tmp_path)
     from anovos_tpu.data_ingest import data_ingest
 

@@ -15,7 +15,6 @@ The contract under test (anovos_tpu/parallel/scheduler.py):
 """
 
 import hashlib
-import importlib.util
 import os
 import threading
 import time
@@ -292,13 +291,9 @@ def test_block_times_thread_safe_accumulation():
 # ---------------------------------------------------------------------------
 
 def _synthesize_income(n=6000):
-    spec = importlib.util.spec_from_file_location(
-        "_example_data",
-        os.path.join(os.path.dirname(__file__), "..", "examples", "_data.py"),
-    )
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.synthesize(n)
+    from anovos_tpu.data_ingest.synthetic import synthesize
+
+    return synthesize(n)
 
 
 def _demo_cfg(pq: str) -> dict:

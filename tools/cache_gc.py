@@ -8,7 +8,7 @@ Usage::
 ``--root`` defaults to ``$ANOVOS_TPU_CACHE``.  ``--max-bytes`` accepts
 plain bytes or a K/M/G suffix (``--max-bytes 500M``).  Evicts the
 least-recently-used node entries (manifest + payload + newly-unreferenced
-objects) and persistent-XLA-cache files until the store fits, sweeps tmp
+objects) until the store fits, sweeps tmp
 debris from crashed commits and orphaned objects, and prints an
 accounting summary.
 
@@ -57,8 +57,7 @@ def main(argv=None) -> int:
         print(f"cache_gc: {stats['before_bytes']} -> {stats['after_bytes']} bytes "
               f"(cap {stats['max_bytes']}); {verb} "
               f"{len(stats['evicted_nodes'])} node entr"
-              f"{'y' if len(stats['evicted_nodes']) == 1 else 'ies'} + "
-              f"{stats['evicted_xla_files']} xla file(s); swept "
+              f"{'y' if len(stats['evicted_nodes']) == 1 else 'ies'}; swept "
               f"{stats['swept_tmp']} tmp + {stats['swept_orphan_objects']} orphan object(s)")
     return 0 if stats["fits"] else 1
 

@@ -12,7 +12,7 @@ executes the flagship kernels sharded, and — since round 8 — runs the
 collective-aware concurrent-executor pass: the synthetic pipeline once per
 executor mode, asserting byte-identical artifacts, >= 2 nodes concurrently
 in flight, and concurrent wall <= sequential wall on the same box.  The
-executor record is appended to PERF_LEDGER.jsonl (``e2e_multidev_overlap``
+executor record is appended to BENCH_LEDGER.jsonl (``e2e_multidev_overlap``
 / ``e2e_multidev_wall_s`` join the regression trajectory).
 
 Must run in a FRESH process (the virtual-device count is latched at

@@ -148,7 +148,7 @@ def main(argv: Optional[List[str]] = None) -> int:
     ap.add_argument("--baseline", help="baseline manifest file or run dir")
     ap.add_argument("--candidate", help="candidate manifest file or run dir")
     ap.add_argument("--ledger", help="perf ledger file for --entry-* mode "
-                                     "(default: the committed PERF_LEDGER.jsonl)")
+                                     "(default: the committed BENCH_LEDGER.jsonl)")
     ap.add_argument("--entry-baseline", help="ledger entry: source/round/id/index")
     ap.add_argument("--entry-candidate", help="ledger entry: source/round/id/index")
     ap.add_argument("--self-check", action="store_true",
