@@ -102,6 +102,15 @@ def shard_files_for_process(files: List[str]) -> List[str]:
     return files[_jax.process_index() :: _jax.process_count()]
 
 
+def _file_bytes(f: str) -> int:
+    """Size of a part file for its decode span; 0 where it cannot be read
+    (the guarded read that follows reports that fault)."""
+    try:
+        return os.path.getsize(f)
+    except OSError:
+        return 0
+
+
 def _coerce_numeric_strings(decoded: dict) -> dict:
     """Schema-inference parity for the decoded-Table path: a string column
     whose every value parses numeric becomes numeric (the pandas route's
@@ -134,8 +143,9 @@ def read_dataset(file_path: str, file_type: str, file_configs: Optional[dict] = 
     from anovos_tpu.obs import get_metrics, get_tracer
 
     cfg = dict(file_configs or {})
-    with get_tracer().span("io:read_dataset", cat="io", path=str(file_path),
-                           file_type=file_type):
+    tracer = get_tracer()
+    with tracer.phase("io:read_dataset", cat="io", path=str(file_path),
+                      file_type=file_type):
         if jax.process_count() > 1:
             # multi-host runtime: each host reads its file slice and columns
             # are assembled into global arrays (distributed_ingest module)
@@ -157,16 +167,19 @@ def read_dataset(file_path: str, file_type: str, file_configs: Optional[dict] = 
                 tables = []
                 bad = set()
                 for f in files:
-                    decoded = guard.guarded_part_read(
-                        f, lambda f=f: avro_io.read_avro(f),
-                        file_type="avro", policy=pol)
-                    if decoded is None:
-                        bad.add(f)
-                        continue
-                    if not decoded:
-                        tables = None
-                        break
-                    n = len(next(iter(decoded.values())))
+                    with tracer.phase("ingest/decode", cat="io",
+                                      bytes=_file_bytes(f)) as sp:
+                        decoded = guard.guarded_part_read(
+                            f, lambda f=f: avro_io.read_avro(f),
+                            file_type="avro", policy=pol)
+                        if decoded is None:
+                            bad.add(f)
+                            continue
+                        if not decoded:
+                            tables = None
+                            break
+                        n = len(next(iter(decoded.values())))
+                        sp.add(rows=n)
                     tables.append(Table.from_numpy(_coerce_numeric_strings(decoded), nrows=n))
                 if tables is not None and not tables:
                     raise guard.IngestError(
@@ -176,8 +189,9 @@ def read_dataset(file_path: str, file_type: str, file_configs: Optional[dict] = 
                 # the parts the guard already set aside
                 files = [f for f in files if f not in bad]
                 if tables:
-                    out = tables[0] if len(tables) == 1 else concatenate_dataset(
-                        *tables, method_type="name")
+                    with tracer.phase("ingest/assemble", cat="io"):
+                        out = tables[0] if len(tables) == 1 else concatenate_dataset(
+                            *tables, method_type="name")
             if out is None:
                 df = read_host_frame(files, file_type, cfg)
                 out = Table.from_pandas(df)
@@ -242,19 +256,31 @@ def read_host_frame(files: List[str], file_type: str, cfg: dict) -> pd.DataFrame
     sanitized at this boundary (anovos_tpu.data_ingest.guard)."""
     if file_type not in ("csv", "parquet", "avro", "json"):
         raise ValueError(f"unsupported file_type: {file_type}")
+    from anovos_tpu.obs import get_tracer
+
+    tracer = get_tracer()
     pol = guard.policy_from_env()
     frames: List = []
     for f in files:
-        df = guard.guarded_part_read(
-            f, lambda f=f: _read_one_part(f, file_type, cfg),
-            file_type=file_type, policy=pol)
-        if df is not None:
-            frames.append((f, df))
+        with tracer.phase("ingest/decode", cat="io", bytes=_file_bytes(f)) as sp:
+            df = guard.guarded_part_read(
+                f, lambda f=f: _read_one_part(f, file_type, cfg),
+                file_type=file_type, policy=pol)
+            if df is not None:
+                sp.add(rows=len(df))
+                frames.append((f, df))
     if not frames:
         raise guard.IngestError(
             f"every {file_type} part was quarantined ({len(files)} file(s), "
             f"first: {files[0] if files else '<none>'}) — no schema left to "
             "build a frame")
+    with tracer.phase("ingest/assemble", cat="io"):
+        return _assemble_frames(frames, cfg, pol)
+
+
+def _assemble_frames(frames: List, cfg: dict, pol) -> pd.DataFrame:
+    """One frame from the decoded parts: schemas reconciled, parts
+    concatenated, ``inferSchema`` re-coercion, hostile values sanitized."""
     aligned = guard.reconcile_frames(frames, pol)
     df = aligned[0] if len(aligned) == 1 else pd.concat(aligned, ignore_index=True)
     if str(cfg.get("inferSchema", True)).lower() in ("true", "1", "none"):

@@ -7,7 +7,11 @@ Three cooperating, stdlib-only pieces:
   (open the JSON in Perfetto / ``chrome://tracing``).  The DAG scheduler
   emits a span per node (worker lane, queue wait, deps), the hot ops emit
   compile-vs-execute spans via :func:`timed`, and the async artifact
-  writer spans its writes and drain barrier.
+  writer spans its writes and drain barrier.  One ``workflow.run`` is one
+  ``Tracer.run_pass()``: its phase spans (``config``, ``ingest`` and its
+  decode / encode / h2d parts, ``register``, ``dag``, ...) land in the
+  manifest as ``phases`` and, under ``ANOVOS_PROFILE``, with the node
+  spans in the profiler trace as ``TraceAnnotation`` events.
 * **Metrics** (``obs.metrics``): a process-wide :class:`MetricsRegistry`
   of counters/gauges/histograms — node wall time, queue wait, rows
   ingested, bytes written, device-memory high-water mark, compile-cache
@@ -71,7 +75,6 @@ from anovos_tpu.obs.tracing import (
     get_tracer,
     maybe_rotator,
     rotation_spec,
-    span,
     trace_destination,
     write_chrome_trace,
 )
@@ -104,7 +107,6 @@ __all__ = [
     "get_tracer",
     "maybe_rotator",
     "rotation_spec",
-    "span",
     "trace_destination",
     "write_chrome_trace",
 ]

@@ -50,9 +50,9 @@ by ``stable_view`` — pure telemetry), (b) ``devprof_*`` metric families,
 (c) a ``devprof:<node>`` tracer instant next to the node span, and (d)
 ``bench.py``'s ``e2e_device_time_s`` / ``e2e_transfer_bytes`` fields.
 ``ANOVOS_TPU_DEVPROF=0`` disables the brackets (one dict lookup per
-site remains); when ``ANOVOS_PROFILE`` is set the node bracket
-additionally opens a ``jax.profiler.TraceAnnotation`` so xprof device
-traces attribute kernels to pipeline nodes.
+site remains).  The node names in a profiler trace are not this module's:
+the tracer annotates node and phase spans (``obs.tracing.annotate_with``),
+whatever this switch says.
 """
 
 from __future__ import annotations
@@ -323,23 +323,9 @@ def node_bracket(name: str, drain: Optional[bool] = None,
     _TL.frame = frame
     with _LOCK:
         _ACTIVE[name] = frame
-    profile_ctx = None
-    if os.environ.get("ANOVOS_PROFILE", ""):
-        jax = sys.modules.get("jax")
-        try:  # xprof device traces then attribute kernels to this node
-            profile_ctx = jax.profiler.TraceAnnotation(name) if jax else None
-        except Exception:
-            profile_ctx = None
-    if profile_ctx is not None:
-        profile_ctx.__enter__()
     try:
         yield frame
     finally:
-        if profile_ctx is not None:
-            try:
-                profile_ctx.__exit__(None, None, None)
-            except Exception:
-                pass
         _TL.frame = prev
         try:
             out = frame.finish(drain=drain)
@@ -425,10 +411,17 @@ def record_transfer(direction: str, nbytes: int, seconds: float,
     ).inc(nbytes)
     frame = getattr(_TL, "frame", None)
     if frame is None:
-        # a writer-pool thread materializing a queued artifact still
-        # belongs to the node that submitted it — but without plumbing the
-        # submitting node through the queue, attribute to the global
-        # counters only (the per-node split stays a lower bound)
+        # outside every node: ingest books its bytes and its enqueue seconds
+        # (device_put is async) on the ``ingest/h2d`` span open on this
+        # thread.  A writer-pool thread materializing a queued artifact has
+        # neither frame nor span: without plumbing the submitting node
+        # through the queue it feeds the global counters only (the per-node
+        # split stays a lower bound)
+        from anovos_tpu.obs.tracing import get_tracer
+
+        sp = get_tracer().current()
+        if sp is not None and sp.name == "ingest/h2d":
+            sp.add(bytes=nbytes, enqueue_s=seconds)
         return
     frame.add_transfer(direction, nbytes, seconds, label or direction)
 

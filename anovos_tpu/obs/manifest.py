@@ -21,7 +21,8 @@ import json
 import os
 from typing import Optional
 
-MANIFEST_VERSION = 1
+# 2: ``phases`` and ``clock``
+MANIFEST_VERSION = 2
 
 __all__ = [
     "MANIFEST_VERSION",
@@ -136,6 +137,15 @@ def build_manifest(
         # diff material): audited env-knob VALUES, code version, env and
         # dataset fingerprints — see anovos_tpu.obs.diffing
         "env": _env_section(all_configs),
+        # the pass's phase tree (obs.tracing): ``[{name, parent, start_s,
+        # end_s, thread, counts}]``, seconds from the start of the root span
+        # ``run``, and ``clock``: the pass's ``run_id`` and
+        # ``scheduler_origin_s``, what ``scheduler.nodes[*].start_s/end_s``
+        # count from, on the same origin (one addition places a node among
+        # the phases).  The root ends after this dict is built, so
+        # ``workflow`` fills both in just before it writes the file
+        "phases": None,
+        "clock": None,
         "trace_path": trace_path,
         "backend": backend,
         "generated_unix": round(
@@ -206,6 +216,9 @@ _VOLATILE_TOP_FIELDS = (
     # chaos directives and spill-dir temp paths, and the dataset signature
     # embeds mtimes — run-comparison telemetry, never run identity
     "env",
+    # seconds, thread names and a per-pass id
+    "phases",
+    "clock",
 )
 
 

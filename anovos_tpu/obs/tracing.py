@@ -17,6 +17,15 @@ Span events use the Trace Event Format "complete" phase (``ph: "X"``) with
 microsecond ``ts``/``dur``; worker threads appear as separate lanes via
 ``tid`` plus ``thread_name`` metadata events, so per-lane span sums can be
 checked against the scheduler's reported wall time.
+
+One pass of ``workflow.run`` is one ``run_pass()``: a fresh timeline, a
+``run_id`` that every span of the pass carries, and the root span ``run``.
+``phase()`` spans opened under it (``config``, ``ingest``, ``dag``, ...) form
+the pass's phase tree.  The tree has two sinks besides the Chrome trace: the
+run manifest's ``phases`` (``Tracer.phases()``), and, while a profiler
+session is on (``annotate_with``), a ``jax.profiler.TraceAnnotation`` per
+phase and per scheduler node, which puts the program's spans into the
+``.xplane.pb`` on the clock of the device's operations.
 """
 
 from __future__ import annotations
@@ -26,6 +35,7 @@ import logging
 import os
 import threading
 import time
+import uuid
 from collections import deque
 from contextlib import contextmanager
 from typing import Dict, Iterable, List, Optional, Tuple
@@ -36,10 +46,10 @@ __all__ = [
     "Span",
     "TraceRotator",
     "Tracer",
+    "annotate_with",
     "get_tracer",
     "maybe_rotator",
     "rotation_spec",
-    "span",
     "trace_destination",
     "write_chrome_trace",
 ]
@@ -49,14 +59,34 @@ __all__ = [
 # loops (a long-lived service calling traced ops forever)
 _DEFAULT_BUFFER = 200_000
 
+# the root span of a pass, and the categories that are also written into a
+# profiler session as TraceAnnotations
+ROOT_PHASE = "run"
+_ANNOTATED_CATS = ("phase", "node")
+
+# ``jax.profiler.TraceAnnotation`` while a profiler session is on, else None:
+# the one check ``span()`` makes.  ``workflow.run`` sets it around the
+# session; this module never imports jax.
+_ANNOTATION = None
+
+
+def annotate_with(factory) -> None:
+    """Open ``factory(name)`` around every phase and node span from now on
+    (``None`` stops it).  ``workflow.run`` passes
+    ``jax.profiler.TraceAnnotation`` for the length of its profiler session."""
+    global _ANNOTATION
+    _ANNOTATION = factory
+
 
 class Span:
     """One finished span: wall-clock interval + attributes, immutable."""
 
-    __slots__ = ("name", "cat", "start_ns", "dur_ns", "thread", "tid", "args")
+    __slots__ = ("name", "cat", "start_ns", "dur_ns", "thread", "tid", "args",
+                 "run_id")
 
     def __init__(self, name: str, cat: str, start_ns: int, dur_ns: int,
-                 thread: str, tid: int, args: Optional[dict] = None):
+                 thread: str, tid: int, args: Optional[dict] = None,
+                 run_id: Optional[str] = None):
         self.name = name
         self.cat = cat
         self.start_ns = start_ns
@@ -64,10 +94,29 @@ class Span:
         self.thread = thread
         self.tid = tid
         self.args = args or {}
+        self.run_id = run_id
 
     def __repr__(self) -> str:  # pragma: no cover - debug aid
         return (f"Span({self.name!r}, cat={self.cat!r}, "
                 f"dur={self.dur_ns / 1e6:.3f}ms, thread={self.thread!r})")
+
+
+class OpenSpan:
+    """A span that has not ended: what ``span()`` yields and
+    ``Tracer.current()`` returns, so that counts can be put on the span at
+    the boundary where the work happens."""
+
+    __slots__ = ("name", "cat", "attrs")
+
+    def __init__(self, name: str, cat: str, attrs: dict):
+        self.name = name
+        self.cat = cat
+        self.attrs = attrs
+
+    def add(self, **counts) -> None:
+        """Add each count to the span's attribute of that name."""
+        for k, v in counts.items():
+            self.attrs[k] = self.attrs.get(k, 0) + v
 
 
 class Tracer:
@@ -101,44 +150,93 @@ class Tracer:
         # one epoch per tracer: chrome ts fields are offsets from it, so a
         # clear() between runs re-bases the timeline at ~0
         self._epoch_ns = time.perf_counter_ns()
+        # the same instant on time.monotonic(), the scheduler's clock, so
+        # that its stamps convert to this timeline (seconds_at)
+        self._epoch_monotonic = time.monotonic()
+        # the pass in progress: its id, its finished phase spans (kept apart
+        # from the ring, which rotation drains and a long service wraps) and
+        # its root once that has ended
+        self.run_id: Optional[str] = None
+        self._phases: List[Span] = []
+        self._root: Optional[Span] = None
 
     # -- recording -------------------------------------------------------
-    def _stack(self) -> List[str]:
+    def _stack(self) -> List[OpenSpan]:
         st = getattr(self._local, "stack", None)
         if st is None:
             st = self._local.stack = []
         return st
 
+    def current(self) -> Optional[OpenSpan]:
+        """The innermost span open on THIS thread, or None."""
+        stack = self._stack()
+        return stack[-1] if stack else None
+
     @contextmanager
     def span(self, name: str, cat: str = "anovos", **attrs):
-        """Record ``name`` spanning the ``with`` body.  Exceptions propagate
-        (the span still lands, flagged ``error``)."""
+        """Record ``name`` spanning the ``with`` body; yields the open span.
+        Exceptions propagate (the span still lands, flagged ``error``)."""
         stack = self._stack()
         if stack:
-            attrs.setdefault("parent", stack[-1])
-        stack.append(name)
+            attrs.setdefault("parent", stack[-1].name)
+        stack.append(OpenSpan(name, cat, attrs))
+        note = None
+        if _ANNOTATION is not None and cat in _ANNOTATED_CATS:
+            note = _ANNOTATION(name)
+            note.__enter__()
         t0 = time.perf_counter_ns()
         try:
-            yield self
+            yield stack[-1]
         except BaseException as e:
             attrs["error"] = type(e).__name__
             raise
         finally:
             dur = time.perf_counter_ns() - t0
+            if note is not None:
+                note.__exit__(None, None, None)
             stack.pop()
             th = threading.current_thread()
             self._record(Span(name, cat, t0 - self._epoch_ns, dur,
-                              th.name, th.ident or 0, attrs))
+                              th.name, th.ident or 0, attrs, self.run_id))
+
+    def phase(self, name: str, cat: str = "anovos", **attrs):
+        """A span of the pass's phase tree: ``cat="phase"`` where the span
+        that encloses it on this thread is itself a phase (the root is
+        ``run_pass()``'s), so every phase's parent is a phase.  Anywhere else
+        (inside a scheduler node, on a writer thread, outside any pass) the
+        same work is an ordinary span of category ``cat``."""
+        stack = self._stack()
+        if stack and stack[-1].cat == "phase":
+            cat = "phase"
+        return self.span(name, cat=cat, **attrs)
+
+    def in_pass(self) -> bool:
+        """Whether THIS thread is inside a ``run_pass()``."""
+        stack = self._stack()
+        return bool(stack) and stack[0].cat == "phase"
+
+    @contextmanager
+    def run_pass(self):
+        """One pass: a fresh timeline, a new ``run_id`` and the root span
+        (``workflow.run`` opens one per call)."""
+        self.clear()
+        self.run_id = uuid.uuid4().hex[:12]
+        with self.span(ROOT_PHASE, cat="phase") as root:
+            yield root
 
     def instant(self, name: str, cat: str = "anovos", **attrs) -> None:
         """A zero-duration marker event."""
         th = threading.current_thread()
         self._record(Span(name, cat, time.perf_counter_ns() - self._epoch_ns,
-                          0, th.name, th.ident or 0, attrs))
+                          0, th.name, th.ident or 0, attrs, self.run_id))
 
     def _record(self, sp: Span) -> None:
         dropped = warn = False
         with self._lock:
+            if sp.cat == "phase":
+                self._phases.append(sp)
+                if "parent" not in sp.args:
+                    self._root = sp
             if len(self._spans) == self._spans.maxlen:
                 self._dropped += 1
                 dropped = True
@@ -180,12 +278,50 @@ class Tracer:
             return self._dropped
 
     def clear(self) -> None:
-        """Start a fresh timeline (workflow.main calls this per run)."""
+        """Start a fresh timeline (``run_pass`` calls this per pass)."""
         with self._lock:
             self._spans.clear()
             self._dropped = 0
             self._warned_wrap = False
             self._epoch_ns = time.perf_counter_ns()
+            self._epoch_monotonic = time.monotonic()
+            self.run_id = None
+            self._phases = []
+            self._root = None
+
+    def phases(self) -> List[dict]:
+        """The finished phase spans of the pass as the manifest holds them:
+        ``{name, parent, start_s, end_s, thread, counts}``, seconds from the
+        root span's start, in order of start.  ``counts`` are the span's
+        numeric attributes.  Empty until the root has ended."""
+        with self._lock:
+            spans, root = list(self._phases), self._root
+        if root is None:
+            return []
+        rows = []
+        for sp in spans:
+            start = sp.start_ns - root.start_ns
+            rows.append({
+                "name": sp.name,
+                "parent": sp.args.get("parent"),
+                "start_s": round(start / 1e9, 6),
+                "end_s": round((start + sp.dur_ns) / 1e9, 6),
+                "thread": sp.thread,
+                "counts": {k: round(v, 6) if isinstance(v, float) else v
+                           for k, v in sp.args.items()
+                           if isinstance(v, (int, float)) and not isinstance(v, bool)},
+            })
+        rows.sort(key=lambda r: (r["start_s"], -r["end_s"]))
+        return rows
+
+    def seconds_at(self, monotonic: float) -> Optional[float]:
+        """A ``time.monotonic()`` reading (the scheduler's stamps) in seconds
+        from the root span's start, as ``phases()`` counts them."""
+        with self._lock:
+            root = self._root
+            if root is None:
+                return None
+            return round(monotonic - self._epoch_monotonic - root.start_ns / 1e9, 6)
 
     def drain(self) -> List[Span]:
         """Atomically copy-and-clear the ring WITHOUT re-basing the epoch
@@ -250,6 +386,8 @@ class Tracer:
                 ev["s"] = "t"  # instant scope: thread
             if sp.args:
                 ev["args"] = {k: _jsonable(v) for k, v in sp.args.items()}
+            if sp.run_id is not None:
+                ev.setdefault("args", {})["run_id"] = sp.run_id
             events.append(ev)
         meta = [
             {"name": "thread_name", "ph": "M", "pid": pid, "tid": tid,
@@ -285,11 +423,6 @@ _TRACER = Tracer()
 def get_tracer() -> Tracer:
     """The process-wide tracer (scheduler, writer, and ops all share it)."""
     return _TRACER
-
-
-def span(name: str, cat: str = "anovos", **attrs):
-    """Shortcut: a span on the process-wide tracer."""
-    return _TRACER.span(name, cat=cat, **attrs)
 
 
 def trace_destination(default_dir: str = ".") -> Optional[str]:

@@ -249,6 +249,10 @@ class DagScheduler:
         # by stable id into the new device set (see _lease_devices)
         self._lanes = None
         self._lanes_gen = -1
+        # set by run(): the node spans' parent, and what the summary's
+        # start_s/end_s count from (time.monotonic() of the first node)
+        self._span_parent: dict = {}
+        self.origin_monotonic: Optional[float] = None
 
     # -- registration ----------------------------------------------------
     def add(
@@ -351,6 +355,12 @@ class DagScheduler:
         if node_timeout is None:
             node_timeout = float(os.environ.get("ANOVOS_TPU_NODE_TIMEOUT", "900"))
         t0 = time.monotonic()
+        # the span this run happens under (``dag`` in a workflow pass): the
+        # node spans' parent, which a worker thread's own stack cannot give
+        from anovos_tpu.obs import get_tracer
+
+        caller = get_tracer().current()
+        self._span_parent = {"parent": caller.name} if caller is not None else {}
         # devprof boundary drain probes are device syncs: fine when nodes
         # run one at a time, but with concurrent nodes sharing a device
         # queue they would serialize the async overlap — so concurrent
@@ -455,6 +465,7 @@ class DagScheduler:
                 queue_wait_s=round(node.queue_wait, 4),
                 lane=node.placement.describe(),
                 scheduler=self.name,
+                **self._span_parent,
             ), devprof.node_bracket(node.name,
                                     drain=getattr(self, "_devprof_drain", True),
                                     lane=node.placement.describe(),
@@ -1125,6 +1136,9 @@ class DagScheduler:
     def _summary(self, wall_s: float, mode: str, workers: int) -> dict:
         executed = [n for n in self._nodes if n.end > 0.0]
         origin = min((n.start for n in executed), default=0.0)
+        # what the nodes' start_s/end_s count from, on time.monotonic():
+        # the manifest's ``clock`` places it on the tracer's timeline
+        self.origin_monotonic = origin if executed else None
         durs = {n.name: n.end - n.start for n in executed}
         serial = sum(durs.values())
         # longest dependency chain by measured duration; registration order

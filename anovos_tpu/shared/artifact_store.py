@@ -282,7 +282,7 @@ class AsyncArtifactWriter:
             futs = [f for fl in self._pending.values() for f in fl]
         from anovos_tpu.obs import get_tracer
 
-        with get_tracer().span("artifact:drain", cat="artifact", pending=len(futs)):
+        with get_tracer().phase("artifact:drain", cat="artifact", pending=len(futs)):
             for f in futs:
                 f.result()
         with self._lock:  # all landed: forget completed tickets

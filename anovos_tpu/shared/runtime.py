@@ -30,6 +30,7 @@ from __future__ import annotations
 import dataclasses
 import os
 import threading
+import time
 from contextlib import contextmanager
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -172,6 +173,31 @@ class Runtime:
 DEFAULT_COMPILE_CACHE_DIR = os.path.join(
     os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
     ".jax_cache")
+
+
+def _stamp_unstamped_cache_entries() -> None:
+    """Where the compile cache has a size limit (``jax_compilation_cache_max_size``;
+    the chip machine sets ``JAX_COMPILATION_CACHE_MAX_SIZE``) JAX keeps an
+    ``-atime`` file beside every ``-cache`` entry and reads them all before
+    each write.  An entry without one, left by a process that used the
+    directory without the limit, makes every write fail, and every later
+    process compiles everything again (PERF.md, PR 24 and PR 25).  Such
+    entries get the stamp of now and age out like the rest."""
+    cache_dir = jax.config.jax_compilation_cache_dir
+    if not cache_dir or "://" in cache_dir or jax.config.jax_compilation_cache_max_size == -1:
+        return
+    try:
+        names = set(os.listdir(cache_dir))
+    except OSError:
+        return  # not there yet: JAX makes it
+    stamp = time.time_ns().to_bytes(8, "little")
+    for name in names:
+        if name.endswith("-cache") and name[: -len("cache")] + "atime" not in names:
+            try:
+                with open(os.path.join(cache_dir, name[: -len("cache")] + "atime"), "wb") as f:
+                    f.write(stamp)
+            except OSError:
+                pass
 
 
 @contextmanager
@@ -385,6 +411,7 @@ def init_runtime(
     # The pipeline is ~200 SMALL programs, so the threshold must sit well
     # below jax's 1s default.
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.02)
+    _stamp_unstamped_cache_entries()
     if distributed and jax.process_count() == 1 and "JAX_COORDINATOR_ADDRESS" in os.environ:
         jax.distributed.initialize()
     devs = list(devices if devices is not None else jax.devices())

@@ -618,27 +618,51 @@ def _gather_program(datas, masks, idx, valid):
 
 
 def _host_to_column(arr: np.ndarray, n: int, npad: int, rt) -> Column:
-    """Convert one host array to a device Column (pad + shard)."""
+    """Convert one host array to a device Column (pad + shard).
+
+    Two spans, phases of the pass where ingest calls this: ``ingest/encode``
+    around the dictionary-encoding of a string column (counts: rows, distinct
+    values) and ``ingest/h2d`` around the conversion to the device dtype, the
+    padding and the ``device_put`` calls.  ``Runtime.shard_rows``'s transfer
+    bracket puts ``bytes`` and ``enqueue_s`` on the latter: ``device_put`` is
+    async, so those seconds are the time to enqueue, not to move."""
+    from anovos_tpu.obs.tracing import get_tracer
+
+    tracer = get_tracer()
+    if not _is_encoded(arr) and (arr.dtype == object or arr.dtype.kind in ("U", "S")):
+        # categorical: dictionary-encode on host, codes on device
+        with tracer.phase("ingest/encode", cat="io", rows=n) as sp:
+            vals = arr[:n]
+            isnull = pd.isna(vals)
+            nn_strs = np.array([str(v) for v in vals[~isnull]], dtype=object)
+            vocab, codes = np.unique(nn_strs, return_inverse=True)
+            code_arr = np.full(n, -1, dtype=np.int32)
+            code_arr[~isnull] = codes.astype(np.int32)
+            sp.add(distinct=len(vocab))
+        with tracer.phase("ingest/h2d", cat="io"):
+            data = rt.shard_rows(_pad_to(code_arr, npad, -1))
+            mask = rt.shard_rows(_pad_to(~isnull, npad, False))
+        return Column("cat", data, mask, vocab=vocab.astype(object), dtype_name="string")
+    with tracer.phase("ingest/h2d", cat="io"):
+        return _plain_to_column(arr, n, npad, rt)
+
+
+def _is_encoded(arr) -> bool:
     from anovos_tpu.shared.native import NativeEncodedStrings
 
-    if isinstance(arr, NativeEncodedStrings):
+    return isinstance(arr, NativeEncodedStrings)
+
+
+def _plain_to_column(arr: np.ndarray, n: int, npad: int, rt) -> Column:
+    """A column that needs no dictionary-encoding: codes the native decoder
+    made, timestamps, booleans, numbers."""
+    if _is_encoded(arr):
         # already dictionary-encoded by the native decoder (codes + vocab,
         # strings never became Python objects)
         code_arr = arr.codes[:n]
         data = rt.shard_rows(_pad_to(code_arr, npad, -1))
         mask = rt.shard_rows(_pad_to(code_arr >= 0, npad, False))
         return Column("cat", data, mask, vocab=arr.vocab, dtype_name="string")
-    if arr.dtype == object or arr.dtype.kind in ("U", "S"):
-        # categorical: dictionary-encode on host, codes on device
-        vals = arr[:n]
-        isnull = pd.isna(vals)
-        nn_strs = np.array([str(v) for v in vals[~isnull]], dtype=object)
-        vocab, codes = np.unique(nn_strs, return_inverse=True)
-        code_arr = np.full(n, -1, dtype=np.int32)
-        code_arr[~isnull] = codes.astype(np.int32)
-        data = rt.shard_rows(_pad_to(code_arr, npad, -1))
-        mask = rt.shard_rows(_pad_to(~isnull, npad, False))
-        return Column("cat", data, mask, vocab=vocab.astype(object), dtype_name="string")
     if arr.dtype.kind == "M":
         # timestamps → epoch seconds int32
         vals = arr[:n].astype("datetime64[s]")

@@ -25,6 +25,21 @@ import numpy as np  # noqa: E402
 import pytest  # noqa: E402
 
 
+@pytest.fixture(autouse=True)
+def _compile_cache_dir_stays_the_tests_own():
+    """``benchmark/run.py``'s ``main`` names its compile cache directory in
+    ``os.environ``.  A test that calls it in-process must not hand that name to
+    the subprocesses of every later test of its worker: their CPU programs
+    landed in ``.jax_cache/benchmark/`` without the stamps a size-limited cache
+    needs, and on the chip every write there failed (PERF.md, PR 25)."""
+    before = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    yield
+    if before is None:
+        os.environ.pop("JAX_COMPILATION_CACHE_DIR", None)
+    else:
+        os.environ["JAX_COMPILATION_CACHE_DIR"] = before
+
+
 @pytest.fixture(scope="session", autouse=True)
 def runtime():
     """Module-scoped runtime over the 8-device virtual mesh (the analogue of

@@ -208,7 +208,7 @@ print(json.dumps({"dir": jax.config.jax_compilation_cache_dir, "hits": len(hits)
 """
 
 
-def _probe(cwd, salt, env_dir=None):
+def _probe(cwd, salt, env_dir=None, extra_env=None):
     import json
     import subprocess
     import sys
@@ -218,6 +218,7 @@ def _probe(cwd, salt, env_dir=None):
     env["JAX_PLATFORMS"] = "cpu"
     if env_dir:
         env["JAX_COMPILATION_CACHE_DIR"] = env_dir
+    env.update(extra_env or {})
     out = subprocess.run([sys.executable, "-c", _CACHE_PROBE, _CHECKOUT, salt], cwd=cwd,
                          env=env, capture_output=True, text=True, timeout=300, check=True)
     return json.loads(out.stdout.strip().splitlines()[-1])
@@ -245,6 +246,23 @@ def test_compile_cache_placed_by_env_var_gets_the_entries(tmp_path):
     assert out["dir"] == str(placed) and out["hits"] == 0
     assert any(placed.iterdir())
     assert _probe(str(tmp_path), "3.5", env_dir=str(placed))["hits"] >= 1
+
+
+@pytest.mark.parametrize("stamped", [False, True])
+def test_size_limited_compile_cache_takes_an_entry_without_a_stamp(tmp_path, stamped):
+    """With a size limit JAX reads an ``-atime`` file beside every entry before
+    each write.  An entry a process without the limit left has none: without
+    init_runtime's stamp every write fails and the second process finds nothing."""
+    placed = tmp_path / "placed"
+    placed.mkdir()
+    (placed / "jit_left_by_a_process_without_the_limit-0f0f-cache").write_bytes(b"x" * 64)
+    if stamped:
+        (placed / "jit_left_by_a_process_without_the_limit-0f0f-atime").write_bytes(b"\x01" * 8)
+    limit = {"JAX_COMPILATION_CACHE_MAX_SIZE": str(1 << 28)}
+    assert _probe(str(tmp_path), "4.25", env_dir=str(placed), extra_env=limit)["hits"] == 0
+    assert _probe(str(tmp_path), "4.25", env_dir=str(placed), extra_env=limit)["hits"] >= 1
+    stamp = (placed / "jit_left_by_a_process_without_the_limit-0f0f-atime").read_bytes()
+    assert len(stamp) == 8 and (stamp == b"\x01" * 8) == stamped  # one that is there is left alone
 
 
 # -------------------------------------------------------------- store ----
