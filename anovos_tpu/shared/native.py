@@ -19,9 +19,11 @@ _TRIED = False
 
 
 class NativeEncodedStrings:
-    """A string column already dictionary-encoded in C++: int32 codes
-    (−1 null) + sorted vocab.  Table construction consumes this directly,
-    so string payloads never materialize as Python objects."""
+    """A string column already dictionary-encoded: int32 codes (−1 null) +
+    sorted vocab, from the native avro decoder or from
+    ``shared.table.encode_strings`` (csv / parquet / json columns, object
+    arrays).  Table construction consumes this directly, so string payloads
+    never materialize as a Python object per row."""
 
     dtype = np.dtype(object)  # duck-type for callers checking .dtype
 

@@ -31,8 +31,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pandas as pd
+import pyarrow as pa
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from anovos_tpu.shared.native import NativeEncodedStrings
 from anovos_tpu.shared.runtime import get_runtime
 
 # Spark-style dtype names kept for report parity (global_summary prints them).
@@ -191,8 +193,6 @@ class Table:
             return Table(cols, 0)
         n = nrows if nrows is not None else len(next(iter(data.values())))
         npad = rt.pad_rows(max(n, 1))
-        from anovos_tpu.shared.native import NativeEncodedStrings
-
         for name, arr in data.items():
             if not isinstance(arr, NativeEncodedStrings):
                 arr = np.asarray(arr)
@@ -201,10 +201,19 @@ class Table:
 
     @staticmethod
     def from_pandas(df) -> "Table":
+        """Build from a pandas frame.  A column of a string dtype (pandas 3's
+        ``str``, ``string``; Arrow- or python-backed) or of dtype ``category``
+        is dictionary-encoded from the Series by :func:`encode_strings` and
+        never becomes an object array; an ``object`` column goes to
+        :meth:`from_numpy` as objects and is encoded there, by the same
+        function; every other dtype goes as its numpy array.  The vocab of a
+        cat column is in ``np.unique``'s order over Python ``str``."""
         data = {}
         for name in df.columns:
             s = df[name]
-            if s.dtype == object or str(s.dtype) in ("string", "category"):
+            if isinstance(s.dtype, (pd.StringDtype, pd.CategoricalDtype)):
+                data[name] = encode_strings(s)
+            elif s.dtype == object:
                 data[name] = s.to_numpy(dtype=object)
             else:
                 data[name] = s.to_numpy()
@@ -617,48 +626,108 @@ def _gather_program(datas, masks, idx, valid):
     return gd, gm
 
 
+def _sorted_vocab_codes(first: np.ndarray, uniques) -> NativeEncodedStrings:
+    """Codes into ``uniques`` (−1 null; every entry in use) → int32 codes into
+    the sorted vocab of their ``str()``.  The order is ``np.unique``'s over
+    an object array of Python ``str`` (code points), and two entries that
+    ``str()`` to one string become one code: the Python work is over the
+    distinct values, the rows pay one int32 gather."""
+    strs = np.empty(len(uniques), dtype=object)
+    strs[:] = [str(u) for u in uniques]
+    vocab, inverse = np.unique(strs, return_inverse=True)
+    remap = np.append(inverse, -1).astype(np.int32)  # first == −1 reads the −1
+    return NativeEncodedStrings(remap[first], vocab)
+
+
+def _hash_encode(values) -> Optional[NativeEncodedStrings]:
+    """The encoding by one hash pass in C over the rows, with no Python
+    object per row; None for an input whose hash is another function than
+    its ``str()``, or that Arrow cannot hold.  A categorical was hashed when
+    it was made: its codes are taken, its unused categories left out."""
+    if isinstance(values.dtype, pd.CategoricalDtype):
+        cats = values.cat.categories.to_numpy(dtype=object)
+        first = values.cat.codes.to_numpy()
+        used = np.flatnonzero(np.bincount(first[first >= 0], minlength=len(cats)))
+        lut = np.full(len(cats) + 1, -1)
+        lut[used] = np.arange(len(used))
+        return _sorted_vocab_codes(lut[first], cats[used])
+    if not isinstance(values.dtype, pd.StringDtype) and (
+            pd.api.types.infer_dtype(values, skipna=True) != "string"):
+        return None
+    try:
+        strings = pa.array(values, type=pa.large_string(), from_pandas=True)
+    except UnicodeEncodeError:  # a lone surrogate: a str, and not UTF-8
+        return None
+    if isinstance(strings, pa.ChunkedArray):  # a pd.concat of part files
+        strings = strings.combine_chunks()
+    enc = strings.dictionary_encode()
+    first = enc.indices.fill_null(-1).to_numpy(zero_copy_only=False)
+    return _sorted_vocab_codes(first, enc.dictionary.to_numpy(zero_copy_only=False))
+
+
+def _loop_encode(vals: np.ndarray) -> NativeEncodedStrings:
+    """The plain encoding, a ``str()`` per row and a sort of all of them:
+    what the hash path must equal, and the path of the inputs it leaves
+    (``1``, ``1.0`` and ``True`` are one key to a hash table and three
+    strings; ``b"x"`` is ``"b'x'"``)."""
+    isnull = pd.isna(vals)
+    nn_strs = np.array([str(v) for v in vals[~isnull]], dtype=object)
+    vocab, codes = np.unique(nn_strs, return_inverse=True)
+    code_arr = np.full(len(vals), -1, dtype=np.int32)
+    code_arr[~isnull] = codes.astype(np.int32)
+    return NativeEncodedStrings(code_arr, vocab.astype(object))
+
+
+def encode_strings(values) -> NativeEncodedStrings:
+    """Dictionary-encode one string column (a Series or an array) on the
+    host: int32 codes (−1 null) into a vocab of Python ``str`` sorted as
+    ``np.unique`` sorts them.  Nulls are what ``pd.isna`` says (None, NaN,
+    ``pd.NA``, NaT); ``""`` is a value.  Which input takes which path:
+
+    - a Series of a string dtype or of dtype ``category``, and an object or
+      ``U`` array or Series whose non-null values are all ``str``
+      (``infer_dtype`` says "string"): hashed;
+    - anything else (mixed objects, bytes, an all-null or empty object
+      array, a lone surrogate): the per-value loop.
+
+    One ``ingest/encode`` span per call (a phase of the pass where ingest
+    calls this) with the counts ``rows``, ``distinct`` and ``hashed``."""
+    from anovos_tpu.obs.tracing import get_tracer
+
+    with get_tracer().phase("ingest/encode", cat="io", rows=len(values)) as sp:
+        enc = _hash_encode(values)
+        hashed = enc is not None
+        if not hashed:
+            enc = _loop_encode(np.asarray(values, dtype=object))
+        sp.add(distinct=len(enc.vocab), hashed=int(hashed))
+    return enc
+
+
 def _host_to_column(arr: np.ndarray, n: int, npad: int, rt) -> Column:
     """Convert one host array to a device Column (pad + shard).
 
-    Two spans, phases of the pass where ingest calls this: ``ingest/encode``
-    around the dictionary-encoding of a string column (counts: rows, distinct
-    values) and ``ingest/h2d`` around the conversion to the device dtype, the
-    padding and the ``device_put`` calls.  ``Runtime.shard_rows``'s transfer
-    bracket puts ``bytes`` and ``enqueue_s`` on the latter: ``device_put`` is
-    async, so those seconds are the time to enqueue, not to move."""
+    An object / ``U`` / ``S`` array is a string column: :func:`encode_strings`
+    dictionary-encodes it under an ``ingest/encode`` span (hashed when its
+    non-null values are all ``str``, the per-value loop otherwise; the vocab
+    in ``np.unique``'s order either way).  Codes that arrive encoded (avro's
+    decoder, ``Table.from_pandas``) skip that.  Then ``ingest/h2d`` around
+    the conversion to the device dtype, the padding and the ``device_put``
+    calls.  ``Runtime.shard_rows``'s transfer bracket puts ``bytes`` and
+    ``enqueue_s`` on the latter: ``device_put`` is async, so those seconds
+    are the time to enqueue, not to move."""
     from anovos_tpu.obs.tracing import get_tracer
 
-    tracer = get_tracer()
-    if not _is_encoded(arr) and (arr.dtype == object or arr.dtype.kind in ("U", "S")):
-        # categorical: dictionary-encode on host, codes on device
-        with tracer.phase("ingest/encode", cat="io", rows=n) as sp:
-            vals = arr[:n]
-            isnull = pd.isna(vals)
-            nn_strs = np.array([str(v) for v in vals[~isnull]], dtype=object)
-            vocab, codes = np.unique(nn_strs, return_inverse=True)
-            code_arr = np.full(n, -1, dtype=np.int32)
-            code_arr[~isnull] = codes.astype(np.int32)
-            sp.add(distinct=len(vocab))
-        with tracer.phase("ingest/h2d", cat="io"):
-            data = rt.shard_rows(_pad_to(code_arr, npad, -1))
-            mask = rt.shard_rows(_pad_to(~isnull, npad, False))
-        return Column("cat", data, mask, vocab=vocab.astype(object), dtype_name="string")
-    with tracer.phase("ingest/h2d", cat="io"):
+    if not isinstance(arr, NativeEncodedStrings) and arr.dtype.kind in "OUS":
+        arr = encode_strings(arr[:n])
+    with get_tracer().phase("ingest/h2d", cat="io"):
         return _plain_to_column(arr, n, npad, rt)
-
-
-def _is_encoded(arr) -> bool:
-    from anovos_tpu.shared.native import NativeEncodedStrings
-
-    return isinstance(arr, NativeEncodedStrings)
 
 
 def _plain_to_column(arr: np.ndarray, n: int, npad: int, rt) -> Column:
     """A column that needs no dictionary-encoding: codes the native decoder
     made, timestamps, booleans, numbers."""
-    if _is_encoded(arr):
-        # already dictionary-encoded by the native decoder (codes + vocab,
-        # strings never became Python objects)
+    if isinstance(arr, NativeEncodedStrings):
+        # already dictionary-encoded (avro's decoder, encode_strings)
         code_arr = arr.codes[:n]
         data = rt.shard_rows(_pad_to(code_arr, npad, -1))
         mask = rt.shard_rows(_pad_to(code_arr >= 0, npad, False))

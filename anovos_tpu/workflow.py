@@ -1522,6 +1522,13 @@ def _main(all_configs: dict, run_type: str, auth_key_val: Optional[dict],
                 path = os.path.join(write_main["file_path"], "final_dataset", "part*")
                 files = _glob.glob(path)
                 feast_exporter.generate_feature_description(df.dtypes(), write_feast, files[0] if files else "")
+        with tracer.phase("release"):
+            # the pass is over: its nodes' closures and the registrar hold
+            # each other, so without this the last table would keep its
+            # device memory until the cyclic collector next runs, some
+            # passes later
+            pipe._versions.clear()
+            df = None
     logger.info(f"execution time w/o report (in sec) = {round(time.monotonic() - start_main, 4)}")
 
 

@@ -3,11 +3,13 @@ two sinks: the run manifest's ``phases`` and ``clock``, read from a
 2,000-row ``stats`` pass, and the profiler annotations of phase and node
 spans, read from a real profiler session's ``.xplane.pb`` on the CPU."""
 
+import gc
 import glob
 import os
 import threading
 import time
 
+import jax
 import pytest
 import yaml
 
@@ -18,7 +20,7 @@ from anovos_tpu.parallel.scheduler import DagScheduler
 
 ROWS = 2000
 TOP = ["config", "reset", "ingest", "register", "dag", "artifact:drain", "manifest", "close",
-       "write_main"]
+       "write_main", "release"]
 UNDER_INGEST = ["io:read_dataset", "ingest/decode", "ingest/assemble", "ingest/encode",
                 "ingest/h2d", "ingest/delete_column", "ingest/rename_column",
                 "ingest/recast_column"]
@@ -150,12 +152,50 @@ def test_ingest_spans_carry_their_counts(stats_pass, work):
     strings = [c for c, t in synthetic.load_income(ROWS, 7, work / "income_dataset").dtypes.items()
                if str(t) in ("object", "str", "string")]
     assert len(encode) == len(strings) >= 10
-    assert all(r["counts"]["rows"] == ROWS for r in encode)
+    assert all(r["counts"]["rows"] == ROWS and r["counts"]["hashed"] == 1 for r in encode)
+    assert all(r["parent"] == "io:read_dataset" for r in encode)
     assert max(r["counts"]["distinct"] for r in encode) == ROWS  # the id column
     # every byte handed to device_put before the scheduler starts is on an ingest/h2d span
     h2d = [r for r in inside if r["name"] == "ingest/h2d"]
     assert sum(r["counts"]["bytes"] for r in h2d) == stats_pass["h2d_before_dag"] > 0
     assert all(0.0 < r["counts"]["enqueue_s"] <= r["end_s"] - r["start_s"] + 1e-6 for r in h2d)
+
+
+def test_one_encode_span_a_string_column_and_the_looped_one_says_so(tmp_path):
+    """A parquet table read inside a pass: one ``ingest/encode`` row per
+    string column inside ``ingest``, each with ``rows``, ``distinct`` and
+    ``hashed``; a binary column decodes to ``bytes`` objects, which the hash
+    cannot take (``str(b"x")`` is ``"b'x'"``), and reports ``hashed`` 0."""
+    import pyarrow as pa
+    import pyarrow.parquet as pq
+
+    from anovos_tpu.data_ingest.data_ingest import read_dataset
+
+    n = 60
+    os.makedirs(tmp_path / "t")
+    for part in range(2):
+        pq.write_table(pa.table({
+            "word": pa.array([["x", "y", None][i % 3] for i in range(n)], pa.string()),
+            "key": pa.array([f"k{part}_{i}" for i in range(n)], pa.string()),
+            "raw": pa.array([[b"x", b"y", None, b"z"][i % 4] for i in range(n)], pa.binary()),
+            "value": pa.array([float(i) for i in range(n)], pa.float64()),
+        }), tmp_path / "t" / f"part-{part:05d}.parquet")
+    tr = obs.get_tracer()
+    with tr.run_pass():
+        with tr.phase("ingest"):
+            tbl = read_dataset(str(tmp_path / "t"), "parquet")
+    rows = tr.phases()
+    (ingest,) = [r for r in rows if r["name"] == "ingest"]
+    encode = [r for r in rows if r["name"] == "ingest/encode"]
+    assert [r["counts"] for r in encode] == [
+        {"rows": 2 * n, "distinct": 2, "hashed": 1},
+        {"rows": 2 * n, "distinct": 2 * n, "hashed": 1},
+        {"rows": 2 * n, "distinct": 3, "hashed": 0},
+    ]
+    assert all(r["parent"] == "io:read_dataset" and ingest["start_s"] <= r["start_s"]
+               and r["end_s"] <= ingest["end_s"] for r in encode)
+    assert list(tbl["raw"].vocab) == ["b'x'", "b'y'", "b'z'"] and tbl["value"].kind == "num"
+    assert len([r for r in rows if r["name"] == "ingest/h2d"]) == 4
 
 
 def test_clock_places_the_scheduler_nodes_among_the_phases(stats_pass):
@@ -190,6 +230,25 @@ def test_main_called_directly_is_a_pass_of_its_own(stats_pass, config_path, work
     assert top == [n for n in TOP if n != "config"]
     assert man["clock"]["run_id"] != stats_pass["manifest"]["clock"]["run_id"]
     assert not obs.get_tracer().in_pass()
+
+
+def test_a_finished_pass_keeps_no_device_array(stats_pass, config_path, work):
+    """The registrar and its nodes' closures hold each other; the pass lets
+    go of its last table itself, so its device memory does not wait for the
+    cyclic collector (which the next pass's ingest no longer wakes: it
+    makes no Python object per row)."""
+    cwd = os.getcwd()
+    os.chdir(work)
+    gc.collect()
+    gc.disable()
+    try:
+        before = {id(a) for a in jax.live_arrays()}
+        workflow.run(config_path, "local")
+        left = [a for a in jax.live_arrays() if id(a) not in before and a.size >= ROWS]
+    finally:
+        gc.enable()
+        os.chdir(cwd)
+    assert not left, [(a.shape, a.dtype) for a in left]
 
 
 # ---------------------------------------------------------------- tracer ----

@@ -1,0 +1,206 @@
+"""``shared.table.encode_strings`` against the plain encoding it replaced: a
+``str()`` per row and ``np.unique`` over all of them, kept here as the
+reference.  Codes, vocab (values, order and type) and mask must be equal
+for every input, whichever path the column took; ``hashed`` on the
+``ingest/encode`` span says which."""
+
+import numpy as np
+import pandas as pd
+import pytest
+
+from anovos_tpu import obs
+from anovos_tpu.data_ingest import data_ingest
+from anovos_tpu.shared.table import Table, encode_strings
+
+
+def reference(vals: np.ndarray):
+    """The two lines ``_host_to_column`` had (PR 25's ``table.py:637-638``)
+    and what it made of them."""
+    isnull = pd.isna(vals)
+    nn_strs = np.array([str(v) for v in vals[~isnull]], dtype=object)
+    vocab, codes = np.unique(nn_strs, return_inverse=True)
+    code_arr = np.full(len(vals), -1, dtype=np.int32)
+    code_arr[~isnull] = codes.astype(np.int32)
+    return code_arr, vocab.astype(object), ~isnull
+
+
+def encode(values):
+    """``(encoded, counts of its span)``."""
+    enc = encode_strings(values)
+    span = obs.get_tracer().snapshot()[-1]
+    assert span.name == "ingest/encode"
+    return enc, span.args
+
+
+def assert_same(enc, vals: np.ndarray):
+    codes, vocab, mask = reference(vals)
+    assert enc.codes.dtype == np.int32 and np.array_equal(enc.codes, codes)
+    assert np.array_equal(enc.codes >= 0, mask)
+    assert enc.vocab.dtype == object and len(enc.vocab) == len(vocab)
+    assert list(enc.vocab) == list(vocab)
+    assert all(type(v) is str for v in enc.vocab)
+
+
+def _obj(values) -> np.ndarray:
+    out = np.empty(len(values), dtype=object)
+    out[:] = values
+    return out
+
+
+def _shuffled_ids(n: int) -> np.ndarray:
+    return _obj([f"id{i:07d}" for i in np.random.default_rng(3).permutation(n)])
+
+
+def _few_of_many(n: int) -> np.ndarray:
+    rng = np.random.default_rng(5)
+    vals = _obj([f"level {i}" for i in range(40)])[rng.integers(0, 40, n)]
+    vals[rng.random(n) < 0.07] = None
+    vals[rng.random(n) < 0.02] = np.nan
+    return vals
+
+
+# (values, hashed): what the array's own content makes the helper do
+ARRAYS = {
+    "all_str": (_obj(["b", "a", "c", "a"]), 1),
+    "with_none": (_obj(["b", None, "a", None]), 1),
+    "with_nan": (_obj(["b", np.nan, "a", float("nan")]), 1),
+    "with_pd_na": (_obj(["b", pd.NA, "a"]), 1),
+    "every_null_at_once": (_obj([None, "x", np.nan, pd.NA, "x", "w"]), 1),
+    "empty_string_is_a_value": (_obj(["", None, "a", "", " "]), 1),
+    "non_ascii": (_obj(["zebra", "éclair", "Zürich", "ß", "ss", "çedille", "z"]), 1),
+    # code-point order is not UTF-16 order: U+FFFF sorts before U+10000
+    "non_bmp": (_obj(["\U0001F600", "\uffff", "\U00010000", "a", "\ud7ff", "\U0001F600"]), 1),
+    "case_only": (_obj(["a", "A", "b", "B", "aa", "Aa", "aA", "AA"]), 1),
+    "one_distinct": (_obj(["same"] * 50), 1),
+    "all_distinct_1e5": (_shuffled_ids(120_000), 1),
+    "few_of_many_rows": (_few_of_many(50_000), 1),
+    # pandas' own string hash table reads C strings: "b\0" and "b" are one key there
+    "embedded_nul": (_obj(["b\0", "b", "b\0c", "b"]), 1),
+    "numpy_str_scalars": (_obj([np.str_("x"), "x", np.str_("y"), None]), 1),
+    "u_dtype": (np.array(["pear", "apple", "fig", "apple"]), 1),
+    "all_null": (_obj([None, np.nan, pd.NA]), 0),
+    "zero_rows": (np.empty(0, dtype=object), 0),
+    "mixed_objects": (_obj([1, 1.0, True, "1", None]), 0),
+    "bytes_objects": (_obj([b"x", "x", b"y", None]), 0),
+    "s_dtype": (np.array([b"pear", b"apple", b"pear"]), 0),
+    "str_and_nat": (_obj(["a", pd.NaT, "b"]), 0),
+    "lone_surrogate": (_obj(["\ud800", "a", "\ud800", None]), 0),
+}
+
+
+@pytest.mark.parametrize("case", sorted(ARRAYS))
+def test_array_encodes_as_the_plain_loop_does(case):
+    vals, hashed = ARRAYS[case]
+    enc, counts = encode(vals)
+    assert_same(enc, vals)
+    assert counts["rows"] == len(vals) and counts["distinct"] == len(enc.vocab)
+    assert counts["hashed"] == hashed
+
+
+def _chunked_arrow_str() -> pd.Series:
+    parts = [pd.Series(["x", "y", None], dtype="str"), pd.Series(["z", "x", "é"], dtype="str"),
+             pd.Series([], dtype="str"), pd.Series([None, "a"], dtype="str")]
+    s = pd.concat(parts, ignore_index=True)
+    assert s.array._pa_array.num_chunks > 1  # what read_host_frame's pd.concat leaves
+    return s
+
+
+SERIES = {
+    "str_arrow_chunked": _chunked_arrow_str,
+    "str_arrow_all_null": lambda: pd.Series([None, None], dtype="str"),
+    "str_python_backed_nul": lambda: pd.Series(
+        ["b\0", "b", "b\0", "a"], dtype=pd.StringDtype("python", na_value=np.nan)),
+    "string_python": lambda: pd.Series(["q", pd.NA, "p", "q", ""], dtype="string[python]"),
+    "string_pyarrow": lambda: pd.Series(["q", pd.NA, "p", "q", ""], dtype="string[pyarrow]"),
+    "object_strings": lambda: pd.Series(_obj(["q", None, "p", np.nan])),
+    "object_mixed": lambda: pd.Series(_obj([1, 1.0, True, "1", None])),
+    "category_unused": lambda: pd.Series(
+        pd.Categorical(["b", "a", None, "b"], categories=["z", "b", "never", "a"])),
+    "category_all_null": lambda: pd.Series(pd.Categorical([None, None], categories=["a"])),
+    # two categories that str() to one string are one value
+    "category_colliding": lambda: pd.Series(
+        pd.Categorical([1, "1", "0", None, 1], categories=["0", 1, "1", 2.5])),
+}
+SERIES_LOOPED = {"object_mixed"}
+
+
+@pytest.mark.parametrize("case", sorted(SERIES))
+def test_series_encodes_as_its_object_array_does(case):
+    s = SERIES[case]()
+    enc, counts = encode(s)
+    assert_same(enc, s.to_numpy(dtype=object))
+    assert counts["rows"] == len(s) and counts["hashed"] == (case not in SERIES_LOOPED)
+    if case == "category_unused":
+        assert list(enc.vocab) == ["a", "b"]
+    if case == "category_colliding":
+        assert list(enc.vocab) == ["0", "1"] and list(enc.codes) == [1, 1, 0, -1, 1]
+
+
+def _frame(n: int = 700) -> pd.DataFrame:
+    rng = np.random.default_rng(11)
+
+    def pick(cats, null):
+        return pd.Series(np.where(rng.random(n) < null, None,
+                                  _obj(cats)[rng.integers(0, len(cats), n)]), dtype=object)
+
+    return pd.DataFrame({
+        "id": [f"r{i:05d}" for i in rng.permutation(n)],
+        "city": pick(["Zürich", "zagreb", "Århus", "Aachen", "İzmir", "\U0001F600"], 0.1),
+        "grade": pick(["a", "A", "b", "B"], 0.0),
+        "note": pick(["x y", "x,y", 'say "hi"', "-"], 0.3),
+        "amount": rng.normal(size=n),
+    })
+
+
+@pytest.mark.parametrize("file_type", ["csv", "parquet", "json"])
+def test_read_dataset_keeps_vocab_and_device_codes(file_type, tmp_path):
+    """Through the reader: what ``read_dataset`` puts on the device for each
+    string column is what the plain loop makes of the frame the same files
+    decode to (three part files, so Arrow-backed columns arrive chunked)."""
+    df = _frame()
+    path = str(tmp_path / file_type)
+    data_ingest.write_dataset(Table.from_pandas(df), path, file_type,
+                              {"repartition": 3, "mode": "overwrite"})
+    files = data_ingest._resolve_files(path, file_type)
+    assert len(files) == 3
+    host = data_ingest.read_host_frame(files, file_type, {})
+    before = len(obs.get_tracer().snapshot())
+    tbl = data_ingest.read_dataset(path, file_type)
+    spans = [sp for sp in obs.get_tracer().snapshot()[before:] if sp.name == "ingest/encode"]
+    strings = ["id", "city", "grade", "note"]
+    assert tbl.nrows == len(df) and len(spans) == len(strings)
+    assert all(sp.args["hashed"] == 1 and sp.args["rows"] == len(df) for sp in spans)
+    for name in strings:
+        codes, vocab, mask = reference(host[name].to_numpy(dtype=object))
+        col = tbl[name]
+        assert col.kind == "cat" and col.dtype_name == "string"
+        assert col.vocab.dtype == object and list(col.vocab) == list(vocab)
+        assert np.array_equal(np.asarray(col.data)[: tbl.nrows], codes)
+        assert np.array_equal(np.asarray(col.mask)[: tbl.nrows], mask)
+        assert not np.asarray(col.mask)[tbl.nrows:].any()
+        assert (np.asarray(col.data)[tbl.nrows:] == -1).all()
+    assert tbl["amount"].kind == "num"
+
+
+def test_from_pandas_names_the_dtypes_it_means():
+    """pandas 3 spells the string dtype ``str``: such a column, a ``string``
+    one and a categorical are encoded from the Series, an object column from
+    its array, and all four give one answer."""
+    words = ["b", None, "a", "b"]
+    df = pd.DataFrame({
+        "as_str": pd.Series(words, dtype="str"),
+        "as_string": pd.Series(words, dtype="string"),
+        "as_object": pd.Series(_obj(words)),
+        "as_category": pd.Series(words, dtype="category"),
+        "number": [1.0, 2.0, 3.0, 4.0],
+    })
+    before = len(obs.get_tracer().snapshot())
+    tbl = Table.from_pandas(df)
+    spans = [sp for sp in obs.get_tracer().snapshot()[before:] if sp.name == "ingest/encode"]
+    assert len(spans) == 4 and all(sp.args["hashed"] == 1 for sp in spans)
+    for name in ("as_str", "as_string", "as_object", "as_category"):
+        col = tbl[name]
+        assert col.kind == "cat" and list(col.vocab) == ["a", "b"]
+        assert list(np.asarray(col.data)[:4]) == [1, -1, 0, 1]
+        assert list(np.asarray(col.mask)[:4]) == [True, False, True, True]
+    assert tbl["number"].kind == "num"
