@@ -99,7 +99,7 @@ def _fill_counts_light(idf: Table, cols: List[str]) -> np.ndarray:
     if cache:
         # a cache entry may cover only a subset of columns — positions must
         # come from ITS key, not from the table's full column lists
-        for (knum, kcat, *_mode), (num_out, cat_out) in cache.items():
+        for (knum, kcat, *_mode), (num_out, cat_out) in list(cache.items()):
             ni = {c: i for i, c in enumerate(knum)}
             ci = {c: i for i, c in enumerate(kcat)}
             if all(c in ni or c in ci for c in cols):

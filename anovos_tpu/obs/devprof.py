@@ -392,8 +392,10 @@ def dispatch_bracket(label: str, phase: str = "execute"):
 
 
 def record_transfer(direction: str, nbytes: int, seconds: float,
-                    label: str = "") -> None:
+                    label: str = "", shards: int = 0) -> None:
     """Book one host↔device movement (``direction`` ∈ {"h2d", "d2h"}).
+    ``shards``: into how many device shards the bytes were split (the open
+    ``ingest/h2d`` span counts them; the frames and counters do not).
 
     Honors the off switch like every bracket — direct callers
     (``data_ingest._concat_columns``) must go quiet under
@@ -421,13 +423,13 @@ def record_transfer(direction: str, nbytes: int, seconds: float,
 
         sp = get_tracer().current()
         if sp is not None and sp.name == "ingest/h2d":
-            sp.add(bytes=nbytes, enqueue_s=seconds)
+            sp.add(bytes=nbytes, enqueue_s=seconds, shards=shards)
         return
     frame.add_transfer(direction, nbytes, seconds, label or direction)
 
 
 @contextmanager
-def transfer_bracket(direction: str, nbytes: int, label: str = ""):
+def transfer_bracket(direction: str, nbytes: int, label: str = "", shards: int = 0):
     """Time + book one materialization boundary."""
     if not enabled():
         yield
@@ -438,7 +440,7 @@ def transfer_bracket(direction: str, nbytes: int, label: str = ""):
     finally:
         try:
             record_transfer(direction, int(nbytes),
-                            time.perf_counter() - t0, label)
+                            time.perf_counter() - t0, label, shards)
         except Exception:
             logger.exception("devprof transfer record failed")
 

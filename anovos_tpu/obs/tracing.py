@@ -21,7 +21,10 @@ checked against the scheduler's reported wall time.
 One pass of ``workflow.run`` is one ``run_pass()``: a fresh timeline, a
 ``run_id`` that every span of the pass carries, and the root span ``run``.
 ``phase()`` spans opened under it (``config``, ``ingest``, ``dag``, ...) form
-the pass's phase tree.  The tree has two sinks besides the Chrome trace: the
+the pass's phase tree; the scheduler's node spans of the pass are rows of it
+too (under ``dag``, each on its worker's thread), and so is a ``phase()``
+opened inside a node (``place/d2d``).  The tree has two sinks besides the
+Chrome trace: the
 run manifest's ``phases`` (``Tracer.phases()``), and, while a profiler
 session is on (``annotate_with``), a ``jax.profiler.TraceAnnotation`` per
 phase and per scheduler node, which puts the program's spans into the
@@ -106,12 +109,13 @@ class OpenSpan:
     ``Tracer.current()`` returns, so that counts can be put on the span at
     the boundary where the work happens."""
 
-    __slots__ = ("name", "cat", "attrs")
+    __slots__ = ("name", "cat", "attrs", "tree")
 
-    def __init__(self, name: str, cat: str, attrs: dict):
+    def __init__(self, name: str, cat: str, attrs: dict, tree: bool = False):
         self.name = name
         self.cat = cat
         self.attrs = attrs
+        self.tree = tree  # a row of the pass's phase tree (Tracer.phases)
 
     def add(self, **counts) -> None:
         """Add each count to the span's attribute of that name."""
@@ -157,6 +161,7 @@ class Tracer:
         # from the ring, which rotation drains and a long service wraps) and
         # its root once that has ended
         self.run_id: Optional[str] = None
+        self._pass_open = False
         self._phases: List[Span] = []
         self._root: Optional[Span] = None
 
@@ -172,6 +177,11 @@ class Tracer:
         stack = self._stack()
         return stack[-1] if stack else None
 
+    def enclosing(self, cat: str) -> Optional[OpenSpan]:
+        """The innermost span of category ``cat`` open on THIS thread (the
+        scheduler node a library call runs under), or None."""
+        return next((sp for sp in reversed(self._stack()) if sp.cat == cat), None)
+
     @contextmanager
     def span(self, name: str, cat: str = "anovos", **attrs):
         """Record ``name`` spanning the ``with`` body; yields the open span.
@@ -179,7 +189,11 @@ class Tracer:
         stack = self._stack()
         if stack:
             attrs.setdefault("parent", stack[-1].name)
-        stack.append(OpenSpan(name, cat, attrs))
+        # of the pass's tree: a phase, or a scheduler node of an open pass
+        # (its worker's stack starts at the node; the scheduler names the
+        # phase it ran under as the node's parent)
+        tree = cat == "phase" or (cat == "node" and self._pass_open)
+        stack.append(OpenSpan(name, cat, attrs, tree))
         note = None
         if _ANNOTATION is not None and cat in _ANNOTATED_CATS:
             note = _ANNOTATION(name)
@@ -197,16 +211,17 @@ class Tracer:
             stack.pop()
             th = threading.current_thread()
             self._record(Span(name, cat, t0 - self._epoch_ns, dur,
-                              th.name, th.ident or 0, attrs, self.run_id))
+                              th.name, th.ident or 0, attrs, self.run_id), tree)
 
     def phase(self, name: str, cat: str = "anovos", **attrs):
         """A span of the pass's phase tree: ``cat="phase"`` where the span
-        that encloses it on this thread is itself a phase (the root is
-        ``run_pass()``'s), so every phase's parent is a phase.  Anywhere else
-        (inside a scheduler node, on a writer thread, outside any pass) the
-        same work is an ordinary span of category ``cat``."""
+        that encloses it on this thread is itself of the tree (a phase, the
+        root being ``run_pass()``'s, or a scheduler node of the pass), so
+        every row's parent is a row.  Anywhere else (on a writer thread,
+        outside any pass) the same work is an ordinary span of category
+        ``cat``."""
         stack = self._stack()
-        if stack and stack[-1].cat == "phase":
+        if stack and stack[-1].tree:
             cat = "phase"
         return self.span(name, cat=cat, **attrs)
 
@@ -221,8 +236,12 @@ class Tracer:
         (``workflow.run`` opens one per call)."""
         self.clear()
         self.run_id = uuid.uuid4().hex[:12]
-        with self.span(ROOT_PHASE, cat="phase") as root:
-            yield root
+        self._pass_open = True
+        try:
+            with self.span(ROOT_PHASE, cat="phase") as root:
+                yield root
+        finally:
+            self._pass_open = False
 
     def instant(self, name: str, cat: str = "anovos", **attrs) -> None:
         """A zero-duration marker event."""
@@ -230,12 +249,12 @@ class Tracer:
         self._record(Span(name, cat, time.perf_counter_ns() - self._epoch_ns,
                           0, th.name, th.ident or 0, attrs, self.run_id))
 
-    def _record(self, sp: Span) -> None:
+    def _record(self, sp: Span, tree: bool = False) -> None:
         dropped = warn = False
         with self._lock:
-            if sp.cat == "phase":
+            if tree:
                 self._phases.append(sp)
-                if "parent" not in sp.args:
+                if sp.cat == "phase" and "parent" not in sp.args:
                     self._root = sp
             if len(self._spans) == self._spans.maxlen:
                 self._dropped += 1
@@ -290,10 +309,11 @@ class Tracer:
             self._root = None
 
     def phases(self) -> List[dict]:
-        """The finished phase spans of the pass as the manifest holds them:
-        ``{name, parent, start_s, end_s, thread, counts}``, seconds from the
-        root span's start, in order of start.  ``counts`` are the span's
-        numeric attributes.  Empty until the root has ended."""
+        """The finished spans of the pass's tree (phases and scheduler nodes)
+        as the manifest holds them: ``{name, parent, start_s, end_s, thread,
+        counts}``, seconds from the root span's start, in order of start.
+        ``counts`` are the span's numeric attributes.  Empty until the root
+        has ended."""
         with self._lock:
             spans, root = list(self._phases), self._root
         if root is None:

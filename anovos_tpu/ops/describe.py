@@ -18,6 +18,7 @@ from __future__ import annotations
 
 import functools
 import os
+import threading
 from typing import Dict, List, Tuple
 
 import jax
@@ -26,7 +27,7 @@ import numpy as np
 
 from anovos_tpu.shared.runtime import column_parallel, wants_column_parallel
 from anovos_tpu.shared.table import Table
-from anovos_tpu.obs import timed
+from anovos_tpu.obs import get_tracer, timed
 
 # the percentile grid every consumer shares (measures_of_percentiles order)
 PCTL_QS = (0.0, 0.01, 0.05, 0.10, 0.25, 0.50, 0.75, 0.90, 0.95, 0.99, 1.0)
@@ -280,20 +281,35 @@ def table_describe(idf: Table, num_cols: List[str], cat_cols: List[str]) -> Tupl
     with per-column count/nunique/mode_code/mode_count).
 
     The cache lives on the Table instance — any transformation produces a
-    NEW Table, so staleness is impossible by construction.
+    NEW Table, so staleness is impossible by construction.  The table's
+    lock makes the memo single-flight: of the scheduler nodes that ask for
+    the same table at once, one computes and the others wait for its
+    result (without it six of a stats pass's seven nodes each dispatched
+    the whole describe).  The scheduler node a call runs under counts the
+    outcome on its span: ``describe_computed`` 1 for the compute, 0 for a
+    memo hit.
     """
-    cache = getattr(idf, "_describe_cache", None)
-    if cache is None:
-        cache = {}
-        idf._describe_cache = cache
-    # the compensated mode is a cache INPUT: toggling the env var mid-process
-    # must not serve the other mode's moments.  The threshold compares the
-    # LOGICAL row count — shape-bucket padding inflates the device length
-    # and must not flip the mode for tables just under the cutoff.
-    compensated = bool(num_cols) and _compensated_enabled(idf.nrows)
-    key = (tuple(num_cols), tuple(cat_cols), compensated)
-    if key in cache:
-        return cache[key]
+    lock = idf.__dict__.setdefault("_describe_lock", threading.Lock())
+    with lock:
+        cache = idf.__dict__.setdefault("_describe_cache", {})
+        # the compensated mode is a cache INPUT: toggling the env var mid-process
+        # must not serve the other mode's moments.  The threshold compares the
+        # LOGICAL row count — shape-bucket padding inflates the device length
+        # and must not flip the mode for tables just under the cutoff.
+        compensated = bool(num_cols) and _compensated_enabled(idf.nrows)
+        key = (tuple(num_cols), tuple(cat_cols), compensated)
+        computed = key not in cache
+        if computed:
+            cache[key] = _table_describe(idf, num_cols, cat_cols, compensated)
+    node = get_tracer().enclosing("node")
+    if node is not None:
+        node.add(describe_computed=int(computed))
+    return cache[key]
+
+
+def _table_describe(idf: Table, num_cols: List[str], cat_cols: List[str],
+                    compensated: bool) -> Tuple[dict, dict]:
+    """The unmemoized body of :func:`table_describe`."""
     num_out: dict = {}
     if num_cols:
         X, M = idf.numeric_block(num_cols)
@@ -407,5 +423,4 @@ def table_describe(idf: Table, num_cols: List[str], cat_cols: List[str]) -> Tupl
                 mv = float(lg["mode_value"][j])
                 cat_out["mode_code"][i] = int(mv) if mv == mv else -1
                 cat_out["mode_count"][i] = float(lg["mode_count"][j])
-    cache[key] = (num_out, cat_out)
     return num_out, cat_out

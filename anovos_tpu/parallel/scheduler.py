@@ -98,7 +98,10 @@ Design properties:
   (their tables re-placed onto the leased chip, uncommitted dispatch
   pinned via ``jax.default_device``) and fan out freely — single-device
   programs carry no rendezvous, so any number may overlap each other
-  and the collective in flight.  ``host`` nodes never touch a device
+  and the collective in flight.  ``mesh`` nodes registered under one
+  ``lane_group`` (the workflow's ``stats_generator`` readers of one table
+  version) hold the lane as one claim and are in flight together; their
+  registrant orders their device work.  ``host`` nodes never touch a device
   and need no lease.  On single-device runtimes (or without a runtime)
   the lane machinery is inert and behavior is exactly the PR 1
   scheduler.  Leases are released when a node finishes, degrades, or is
@@ -172,7 +175,7 @@ class Node:
         "pending", "state", "start", "end", "ready", "thread", "error",
         "cache", "fingerprint", "cached",
         # lane state (collective-aware multi-device execution)
-        "placement", "lease", "devices",
+        "placement", "lane_group", "lease", "devices",
         # resilience state (anovos_tpu.resilience)
         "policy", "attempts", "attempt_start", "interrupt",
         "timeout_retried", "failover_retried", "failover_granted",
@@ -181,12 +184,14 @@ class Node:
 
     def __init__(self, name: str, fn: Callable[[], None], reads, writes,
                  on_error: Union[str, ErrorPolicy],
-                 placement: Union[None, str, Placement] = None):
+                 placement: Union[None, str, Placement] = None,
+                 lane_group: Optional[str] = None):
         self.name = name
         self.fn = fn
         self.reads = tuple(reads)
         self.writes = tuple(writes)
         self.placement = parse_placement(placement)  # raises on unknown kind
+        self.lane_group = lane_group  # shared mesh claim (DeviceLeaseRegistry)
         self.lease = None           # DeviceLease while claimed/running
         self.devices: List[str] = []  # leased device labels (telemetry)
         self.policy = parse_policy(on_error)   # raises on an unknown mode
@@ -264,6 +269,7 @@ class DagScheduler:
         on_error: Union[str, ErrorPolicy] = "raise",
         cache=None,
         placement: Union[None, str, Placement] = None,
+        lane_group: Optional[str] = None,
     ) -> Node:
         """Register ``fn`` as node ``name``.
 
@@ -292,10 +298,17 @@ class DagScheduler:
         to ``host`` — a node that dispatches device programs on a multi-
         device mesh MUST declare itself (graftcheck GC011 audits the
         workflow's declarations).
+
+        ``lane_group``: ``mesh``-placed nodes of one group hold the
+        rendezvous lane as ONE claim and may be in flight together; the
+        registrant answers for their device work being ordered among
+        themselves (``_PipelineRun.fanout(share_lane=True)`` runs each
+        body under the table version's lock).
         """
         if name in self._by_name:
             raise ValueError(f"duplicate node name {name!r}")
-        node = Node(name, fn, reads, writes, on_error, placement=placement)
+        node = Node(name, fn, reads, writes, on_error, placement=placement,
+                    lane_group=lane_group)
         node.cache = cache
         deps: "dict[int, Node]" = {}  # id -> Node, insertion-ordered, deduped
         raw_deps: "dict[int, Node]" = {}  # the content-carrying subset
@@ -851,7 +864,8 @@ class DagScheduler:
             node.ready = time.monotonic()  # no pool: ready == start
             if lanes is not None:
                 node.lease = lanes.try_lease(node.name, node.placement.kind,
-                                             node.placement.n_devices)
+                                             node.placement.n_devices,
+                                             node.lane_group)
             self._running[node.name] = node
             try:
                 self._execute(node)
@@ -887,7 +901,7 @@ class DagScheduler:
                     del ready[i]
                     return n
                 lease = lanes.try_lease(n.name, n.placement.kind,
-                                        n.placement.n_devices)
+                                        n.placement.n_devices, n.lane_group)
                 if lease is not None:
                     n.lease = lease
                     del ready[i]

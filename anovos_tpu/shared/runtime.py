@@ -111,7 +111,7 @@ class Runtime:
 
         spec = P(*((self.data_axis,) + (None,) * (arr.ndim - 1)))
         with devprof.transfer_bracket("h2d", getattr(arr, "nbytes", 0),
-                                      label="runtime.shard_rows"):
+                                      label="runtime.shard_rows", shards=self.n_data):
             return jax.device_put(arr, NamedSharding(self.mesh, spec))
 
     def pad_rows(self, n: int) -> int:
@@ -284,7 +284,11 @@ class DeviceLeaseRegistry:
     * at most ONE collective claim may cover any given device — the
       rendezvous lane.  A ``mesh`` claim covers every device, so it is
       exclusive against all collective claims; two ``submesh`` claims
-      may coexist only on disjoint device sets.
+      may coexist only on disjoint device sets.  ``mesh`` claims of one
+      ``group`` count as one claim and may coexist: the group's members
+      order their own device work (the workflow's readers of one table
+      version run it under that version's lock), so the lane still sees
+      one program at a time.
     * ``device`` claims never block (single-device programs carry no
       rendezvous, so sharing a chip with anything merely timeshares it).
       Chip choice is STICKY by holder name — XLA executables are keyed on
@@ -303,6 +307,7 @@ class DeviceLeaseRegistry:
         self._devices = tuple(devices)
         self._lock = threading.Lock()
         self._collective: Dict[str, Tuple[jax.Device, ...]] = {}
+        self._group: Dict[str, Optional[str]] = {}  # holder -> group of its mesh claim
         self._single_load: Dict[int, int] = {d.id: 0 for d in self._devices}
 
     @property
@@ -315,11 +320,11 @@ class DeviceLeaseRegistry:
             out.update(d.id for d in devs)
         return out
 
-    def try_lease(self, holder: str, kind: str, n_devices: int = 0
-                  ) -> Optional[DeviceLease]:
+    def try_lease(self, holder: str, kind: str, n_devices: int = 0,
+                  group: Optional[str] = None) -> Optional[DeviceLease]:
         """A lease for ``holder`` under placement ``kind``, or None when
         the lane is busy (collective kinds only — device/host always
-        succeed)."""
+        succeed).  ``group`` (``mesh`` only): the shared claim to join."""
         with self._lock:
             if kind == "host":
                 return DeviceLease(holder, "host")
@@ -342,9 +347,11 @@ class DeviceLeaseRegistry:
                 self._single_load[dev.id] += 1
                 return DeviceLease(holder, "device", (dev,))
             if kind == "mesh":
-                if self._collective:
+                if any(group is None or self._group.get(h) != group
+                       for h in self._collective):
                     return None
                 self._collective[holder] = self._devices
+                self._group[holder] = group
                 return DeviceLease(holder, "mesh", self._devices)
             if kind == "submesh":
                 covered = self._collective_covered()
@@ -362,6 +369,7 @@ class DeviceLeaseRegistry:
         with self._lock:
             if lease.kind in ("mesh", "submesh"):
                 self._collective.pop(lease.holder, None)
+                self._group.pop(lease.holder, None)
             elif lease.kind == "device":
                 for d in lease.devices:
                     if self._single_load.get(d.id, 0) > 0:

@@ -363,8 +363,11 @@ class Table:
         every program the node dispatches is local to its lane.  A table
         already on that layout round-trips through ``device_put`` as a
         cheap no-op; the cross-layout copy is booked as a ``d2d``
-        transfer."""
+        transfer and lies under a ``place/d2d`` span (``bytes`` copied,
+        ``chips`` copied to), a row of the pass's phase tree where a
+        scheduler node makes the copy."""
         from anovos_tpu.obs import devprof
+        from anovos_tpu.obs.tracing import get_tracer
 
         def put(a):
             spec = P(*((rt.data_axis,) + (None,) * (a.ndim - 1)))
@@ -375,7 +378,8 @@ class Table:
             + (c.wide_hi.nbytes + c.wide_lo.nbytes if c.wide_hi is not None else 0)
             for c in self.columns.values()
         ) + (self.valid_rows.nbytes if self.valid_rows is not None else 0)
-        with devprof.transfer_bracket("d2d", nbytes, label="table.with_runtime"):
+        with get_tracer().phase("place/d2d", cat="place", bytes=nbytes, chips=rt.mesh.size), \
+                devprof.transfer_bracket("d2d", nbytes, label="table.with_runtime"):
             cols: "OrderedDict[str, Column]" = OrderedDict()
             for name, c in self.columns.items():
                 cols[name] = Column(

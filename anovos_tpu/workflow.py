@@ -450,6 +450,7 @@ class _PipelineRun:
         self.cache_base = cache_base
         self.spine_policy, self.fanout_policy = _node_policies()
         self._versions = {0: df0}
+        self._version_locks: dict = {}  # df version -> lock of its share_lane readers
         self._planned_readers: dict = {}
         self._ver = 0
         self._lock = threading.Lock()
@@ -583,8 +584,15 @@ class _PipelineRun:
         self._track(writes)
 
     def fanout(self, name, fn, reads=(), writes=(), timed=None, cache_slice=None,
-               on_error=None, placement="mesh") -> None:
+               on_error=None, placement="mesh", share_lane=False) -> None:
         """``fn(df)`` only reads the table: pinned to the current version.
+
+        ``share_lane`` (with ``placement="mesh"``): the readers of one
+        table version hold the rendezvous lane as one claim, are in flight
+        together and run ``fn`` one at a time under the version's lock — for
+        a family whose members all want one memoized result of the
+        mesh-resident table (``stats_generator``: the first computes the
+        partitioned describe, the others wait for it and read the memo).
 
         ``placement="device"`` fans the node out onto one leased chip: the
         executor's placement scope re-places the pinned df version onto a
@@ -596,12 +604,15 @@ class _PipelineRun:
         self._claim(v)
         reads = tuple(reads)
         placement = self._effective_placement(placement)
+        turn = (self._version_locks.setdefault(v, threading.Lock())
+                if share_lane else contextlib.nullcontext())
 
         def body():
             self.writer.wait(reads)
             df_in = self._resolve(v).to_active_placement()
             t0 = time.monotonic()
-            fn(df_in)
+            with turn:
+                fn(df_in)
             if timed:
                 _log_block_time(timed, t0)
             self._release(v)
@@ -609,6 +620,7 @@ class _PipelineRun:
         self.sched.add(name, body, reads=(f"df:{v}",) + reads, writes=tuple(writes),
                        on_error=on_error if on_error is not None else self.fanout_policy,
                        placement=placement,
+                       lane_group=f"df:{v}" if share_lane else None,
                        cache=self._policy(name, cache_slice, writes,
                                           placement=placement,
                                           on_hit=lambda _pdir, v=v: self._release(v)))
@@ -940,9 +952,16 @@ def _main(all_configs: dict, run_type: str, auth_key_val: Optional[dict],
                             else:
                                 save(df_stats, write_stats, "data_analyzer/stats_generator/" + m,
                                      reread=True, writer=writer, key=f"stats:{m}")
+                        # mesh-placed on one shared claim: the seven nodes read ONE Table
+                        # instance and with it one memoized describe, which on a multi-chip
+                        # runtime is the partitioned program (column-parallel sort, psum'd
+                        # moments).  Device-placed, each node got its own replica of the
+                        # whole table on its leased chip, and with the replica a memo of its
+                        # own: six unpartitioned describes and seven table copies a pass
+                        # (PERF.md §6, PR 27).
                         pipe.fanout(f"stats_generator/{m}", _stat,
                                     writes=(f"stats:{m}",), timed=f"stats_generator, {m}",
-                                    placement="device",
+                                    placement="mesh", share_lane=True,
                                     cache_slice={"metric": m, "metric_args": args["metric_args"]})
 
                 if key == "quality_checker" and args is not None:

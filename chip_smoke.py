@@ -13,7 +13,10 @@ process (cold, then warm), require a clean run (no degraded section, no
 failover, no retry, backend ``tpu``, every artifact present) and compare the
 reported statistics with a float64 pandas computation on the same frame.
 ``--chips 4``: data, a placement check of the row-sharded table, ONE cold
-run on the four-chip mesh and the same checks — nothing else.
+run of the whole pipeline on the four-chip mesh and the same checks — nothing
+else.  What four chips do warm, pass after pass, is the benchmark's to say:
+the cell ``income_1m_x4.stats`` (``chiprun --chips 4 -- python3
+benchmark/run.py --workload income_1m_x4.stats ...``, PERF.md).
 
 The last line of stdout is
 ``{"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}``;

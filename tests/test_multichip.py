@@ -143,3 +143,98 @@ def test_column_sharded_describe_matches_row_sharded():
             np.testing.assert_allclose(mr[k], mc[k], rtol=1e-5, err_msg=k)
     finally:
         init_runtime()  # restore the default 8-device data mesh
+
+
+# ------------------------------------------ a stats pass on 1 and 4 devices --
+
+_STATS_ROWS = 2000
+_STATS_METRICS = ["global_summary", "measures_of_counts", "measures_of_centralTendency",
+                  "measures_of_cardinality", "measures_of_percentiles", "measures_of_dispersion",
+                  "measures_of_shape"]
+
+
+def _stats_pass(work, n_devices, tag):
+    """One ``workflow.run`` of ``input_dataset`` + ``stats_generator`` (all
+    seven measures) + ``write_stats`` at 2,000 rows with the runtime on the
+    first ``n_devices`` of the suite's eight: its manifest, its seven tables
+    and the bytes it booked as copied from chip to chip."""
+    import os
+
+    import pandas as pd
+    import yaml
+
+    from anovos_tpu import obs, workflow
+    from anovos_tpu.data_ingest import synthetic
+    from anovos_tpu.shared.runtime import init_runtime
+
+    data = synthetic.generate(_STATS_ROWS, 7, dest=work / "income_dataset")
+    out = work / tag
+    out.mkdir()
+    cfg = {
+        "input_dataset": {
+            "read_dataset": {"file_path": os.path.join(data, "parquet"), "file_type": "parquet"},
+            "delete_column": ["logfnl", "empty", "dt_2"],
+        },
+        "stats_generator": {"metric": _STATS_METRICS,
+                            "metric_args": {"list_of_cols": "all", "drop_cols": ["ifa"]}},
+        "write_stats": {"file_path": "stats", "file_type": "parquet", "file_configs": {"mode": "overwrite"}},
+    }
+    (out / "pipeline.yaml").write_text(yaml.safe_dump(cfg, sort_keys=False))
+
+    def d2d():
+        return sum(v for _, v in obs.get_metrics().counter("transfer_d2d_bytes_total").items())
+
+    cwd = os.getcwd()
+    os.chdir(out)
+    init_runtime(devices=jax.devices()[:n_devices])
+    try:
+        before = d2d()
+        workflow.run(str(out / "pipeline.yaml"), "local")
+        copied = d2d() - before
+    finally:
+        init_runtime()  # the suite's 8-device mesh again
+        os.chdir(cwd)
+    tables = {m: pd.read_parquet(out / "stats" / "data_analyzer" / "stats_generator" / m) for m in _STATS_METRICS}
+    return {"manifest": obs.load_manifest(workflow.LAST_MANIFEST_PATH), "tables": tables, "d2d_bytes": copied}
+
+
+@pytest.fixture(scope="module")
+def one_device_stats_pass(tmp_path_factory):
+    work = tmp_path_factory.mktemp("stats_pass")
+    return work, _stats_pass(work, 1, "reference")
+
+
+@pytest.mark.parametrize("n_devices", [1, 4])
+def test_stats_pass_computes_one_describe_copies_nothing_and_agrees_with_one_device(
+        one_device_stats_pass, n_devices):
+    """The kept path of PR 27 whatever the device count: the seven nodes read
+    one Table instance, one of them computes the (on four devices partitioned)
+    describe and five read its memo, no table is copied from chip to chip, and
+    the seven tables are the 1-device pass's — counts, distinct counts and
+    labels exactly, the float statistics within the tolerances the benchmark's
+    configurations hold a pass to."""
+    import pandas as pd
+
+    work, ref = one_device_stats_pass
+    got = _stats_pass(work, n_devices, f"devices{n_devices}")
+    sched = got["manifest"]["scheduler"]
+    assert sched.get("n_devices", 1) == n_devices
+    nodes = [r for r in got["manifest"]["phases"] if r["parent"] == "dag"]
+    assert {r["name"] for r in nodes} == {f"stats_generator/{m}" for m in _STATS_METRICS}
+    assert sorted(r["counts"].get("describe_computed", -1) for r in nodes) == [-1, 0, 0, 0, 0, 0, 1]
+    assert len({r["thread"] for r in nodes}) > 1  # in flight together, not one after the other
+    assert got["d2d_bytes"] == 0 and not [r for r in got["manifest"]["phases"] if r["name"] == "place/d2d"]
+    if n_devices > 1:
+        assert {n["lane"] for n in sched["nodes"].values()} == {"mesh"}
+    for m in _STATS_METRICS:
+        a = got["tables"][m].set_index(got["tables"][m].columns[0]).sort_index()
+        b = ref["tables"][m].set_index(ref["tables"][m].columns[0]).sort_index()
+        assert list(a.index) == list(b.index) and list(a.columns) == list(b.columns), m
+        for c in a.columns:
+            x, y = pd.to_numeric(a[c], errors="coerce"), pd.to_numeric(b[c], errors="coerce")
+            floats = pd.api.types.is_float_dtype(a[c]) and not c.endswith(("_count", "_rows", "_values"))
+            if floats and n_devices > 1:  # another partial-sum tree: the last ulps may differ
+                np.testing.assert_allclose(x.to_numpy(float), y.to_numpy(float), rtol=1e-4, atol=5.1e-5,
+                                           err_msg=f"{m}.{c}")
+            else:
+                pd.testing.assert_series_equal(a[c], b[c], check_names=False, obj=f"{m}.{c}")

@@ -72,6 +72,26 @@ def test_mesh_lease_is_exclusive_against_collectives():
     reg.release(dev)
 
 
+def test_mesh_claims_of_one_group_are_one_claim():
+    """Readers of one table version share the rendezvous lane (they order
+    their own device work); every other collective claim waits for all of
+    them, and they for it."""
+    reg, _ = _registry()
+    a = reg.try_lease("a", "mesh", group="df:1")
+    b = reg.try_lease("b", "mesh", group="df:1")
+    assert a is not None and b is not None and len(b.devices) == reg.n_devices
+    assert reg.try_lease("c", "mesh") is None
+    assert reg.try_lease("d", "mesh", group="df:2") is None
+    assert reg.try_lease("e", "submesh", 2) is None
+    reg.release(a)
+    assert reg.try_lease("c", "mesh") is None and reg.collective_holders() == ["b"]
+    reg.release(b)
+    c = reg.try_lease("c", "mesh")
+    assert c is not None
+    assert reg.try_lease("a", "mesh", group="df:1") is None  # an ungrouped claim admits nobody
+    reg.release(c)
+
+
 def test_submesh_carves_are_disjoint():
     reg, _ = _registry()
     a = reg.try_lease("a", "submesh", 4)
@@ -180,6 +200,30 @@ def test_collective_nodes_serialize_device_nodes_overlap():
     # device nodes record which chip they leased; mesh nodes the full set
     assert len(summary["nodes"]["dev0"]["devices"]) == 1
     assert len(summary["nodes"]["coll0"]["devices"]) == 8
+
+
+def test_mesh_nodes_of_one_lane_group_overlap_each_other_and_no_other_collective():
+    lock = threading.Lock()
+    live, seen = set(), []
+
+    def body(name):
+        def f():
+            with lock:
+                live.add(name)
+                seen.append(frozenset(live))
+            time.sleep(0.15)
+            with lock:
+                live.discard(name)
+        return f
+
+    s = DagScheduler()
+    for i in range(3):
+        s.add(f"reader{i}", body(f"reader{i}"), placement="mesh", lane_group="df:0")
+    s.add("spine", body("spine"), placement="mesh")
+    summary = s.run(mode="concurrent", max_workers=8, node_timeout=30)
+    assert all(n["state"] == "done" for n in summary["nodes"].values())
+    assert max(len(x) for x in seen) == 3, "the group's members never overlapped"
+    assert not [x for x in seen if "spine" in x and len(x) > 1], "a collective overlapped the group"
 
 
 def test_submesh_nodes_with_disjoint_carves_overlap():

@@ -103,7 +103,14 @@ def test_manifest_holds_every_phase_of_the_pass(stats_pass):
     assert "input_dataset/ETL" not in {sp.name for sp in stats_pass["spans"]}
     for r in man["phases"]:
         assert set(r) == {"name", "parent", "start_s", "end_s", "thread", "counts"}
-        assert r["thread"] == "MainThread" and 0.0 <= r["start_s"] <= r["end_s"]
+        assert 0.0 <= r["start_s"] <= r["end_s"]
+        # the scheduler's nodes are rows too, each on its worker's thread under ``dag``
+        assert (r["thread"] == "MainThread") != (r["parent"] == "dag"), r
+    nodes = {r["name"]: r for r in man["phases"] if r["parent"] == "dag"}
+    assert set(nodes) == set(man["scheduler"]["nodes"])
+    # one describe a pass: of the two nodes that need it one computes it and one reads the memo
+    # (global_summary needs none), on the suite's 8-device mesh as on one device
+    assert sorted(r["counts"].get("describe_computed", -1) for r in nodes.values()) == [-1, 0, 1]
     assert [r["name"] for r in man["phases"] if r["parent"] == "run" and r["name"] in TOP] == TOP
     assert len(man["clock"]["run_id"]) == 12
     assert {sp.run_id for sp in stats_pass["spans"]} == {man["clock"]["run_id"]}
@@ -263,20 +270,25 @@ def test_a_phase_is_a_phase_only_under_a_phase():
             sp.add(rows=3)
             with tr.phase("ingest/decode", cat="io"):
                 pass
-        with tr.span("a_node", cat="node"):
-            with tr.phase("ingest/encode", cat="io"):  # inside a node: not of the tree
-                pass
+        with tr.span("a_node", cat="node") as node:  # a scheduler node of the pass: a row
+            with tr.phase("place/d2d", cat="place", bytes=8):  # and so is a phase inside it
+                assert tr.enclosing("node") is node and tr.enclosing("op") is None
         seen = []
         worker = threading.Thread(target=lambda: seen.append(tr.in_pass()))  # another thread's stack
         worker.start()
         worker.join()
         assert seen == [False]
+    with tr.span("a_node", cat="node"):  # no pass open: an ordinary span, and what it holds too
+        with tr.phase("ingest/encode", cat="io"):
+            pass
     cats = {sp.name: sp.cat for sp in tr.snapshot()}
-    assert cats == {"ingest/decode": "phase", "ingest": "phase", "ingest/encode": "io",
+    assert cats == {"ingest/decode": "phase", "ingest": "phase", "ingest/encode": "io", "place/d2d": "phase",
                     "a_node": "node", "run": "phase"}  # run_pass cleared what came before
     rows = tr.phases()
     assert [(r["name"], r["parent"]) for r in rows] == [
-        ("run", None), ("ingest", "run"), ("ingest/decode", "ingest")]
+        ("run", None), ("ingest", "run"), ("ingest/decode", "ingest"), ("a_node", "run"),
+        ("place/d2d", "a_node")]
+    assert rows[4]["counts"] == {"bytes": 8}
     assert rows[1]["counts"] == {"rows": 5}
     assert not tr.in_pass() and tr.current() is None
     assert tr.seconds_at(time.monotonic()) == pytest.approx(rows[0]["end_s"], abs=0.05)
