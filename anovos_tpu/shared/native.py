@@ -20,7 +20,9 @@ _TRIED = False
 
 class NativeEncodedStrings:
     """A string column already dictionary-encoded: int32 codes (−1 null) +
-    sorted vocab, from the native avro decoder or from
+    a vocab in code-point order (``np.unique``'s over Python ``str``; Arrow
+    computes it over UTF-8 bytes where the distinct values are an Arrow
+    string array), from the native avro decoder or from
     ``shared.table.encode_strings`` (csv / parquet / json columns, object
     arrays).  Table construction consumes this directly, so string payloads
     never materialize as a Python object per row."""

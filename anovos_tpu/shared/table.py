@@ -24,6 +24,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import time
 from collections import OrderedDict
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple, Union
 
@@ -32,6 +33,7 @@ import jax.numpy as jnp
 import numpy as np
 import pandas as pd
 import pyarrow as pa
+import pyarrow.compute as pc
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from anovos_tpu.shared.native import NativeEncodedStrings
@@ -207,7 +209,9 @@ class Table:
         never becomes an object array; an ``object`` column goes to
         :meth:`from_numpy` as objects and is encoded there, by the same
         function; every other dtype goes as its numpy array.  The vocab of a
-        cat column is in ``np.unique``'s order over Python ``str``."""
+        cat column is in code-point order, which is ``np.unique``'s over
+        Python ``str``; Arrow computes it over the UTF-8 bytes where the
+        distinct values are an Arrow string array."""
         data = {}
         for name in df.columns:
             s = df[name]
@@ -631,11 +635,12 @@ def _gather_program(datas, masks, idx, valid):
 
 
 def _sorted_vocab_codes(first: np.ndarray, uniques) -> NativeEncodedStrings:
-    """Codes into ``uniques`` (−1 null; every entry in use) → int32 codes into
-    the sorted vocab of their ``str()``.  The order is ``np.unique``'s over
-    an object array of Python ``str`` (code points), and two entries that
-    ``str()`` to one string become one code: the Python work is over the
-    distinct values, the rows pay one int32 gather."""
+    """Codes into ``uniques`` (−1 null; every entry in use), which may be any
+    objects (a categorical's categories) → int32 codes into the sorted vocab
+    of their ``str()``.  The order is ``np.unique``'s over an object array of
+    Python ``str``, which is code-point order, and two entries that ``str()``
+    to one string become one code: the Python work is over the distinct
+    values, the rows pay one int32 gather."""
     strs = np.empty(len(uniques), dtype=object)
     strs[:] = [str(u) for u in uniques]
     vocab, inverse = np.unique(strs, return_inverse=True)
@@ -643,18 +648,38 @@ def _sorted_vocab_codes(first: np.ndarray, uniques) -> NativeEncodedStrings:
     return NativeEncodedStrings(remap[first], vocab)
 
 
-def _hash_encode(values) -> Optional[NativeEncodedStrings]:
+def _arrow_sorted_vocab_codes(first: np.ndarray, dictionary: pa.Array) -> NativeEncodedStrings:
+    """The same for distinct values that are an Arrow string array, the
+    ``dictionary`` of a ``dictionary_encode``: non-null, valid UTF-8 and no
+    two equal, so there is nothing to merge.  Arrow orders them by their
+    UTF-8 bytes, whose order is code-point order (``np.unique``'s over Python
+    ``str``), and Python neither compares them nor makes a ``str`` of one
+    more than once: the vocab is built from the dictionary already in order."""
+    order = pc.array_sort_indices(dictionary)
+    rank = np.empty(len(order) + 1, dtype=np.int32)
+    rank[order.to_numpy()] = np.arange(len(order), dtype=np.int32)
+    rank[-1] = -1  # first == −1 reads the −1
+    vocab = dictionary.take(order).to_numpy(zero_copy_only=False)
+    return NativeEncodedStrings(rank[first], vocab)
+
+
+def _hash_encode(values) -> Optional[Tuple[NativeEncodedStrings, Dict[str, float]]]:
     """The encoding by one hash pass in C over the rows, with no Python
-    object per row; None for an input whose hash is another function than
-    its ``str()``, or that Arrow cannot hold.  A categorical was hashed when
-    it was made: its codes are taken, its unused categories left out."""
+    object per row, and what the span says of it: ``hashed`` 1,
+    ``native_sort`` (1 where Arrow ordered the vocab, 0 where Python did)
+    and, where Arrow did both, ``hash_s`` (seconds in ``dictionary_encode``)
+    and ``sort_s`` (seconds ordering the distinct values, building the vocab
+    and gathering the rows' codes).  None for an input whose hash is another function than its
+    ``str()``, or that Arrow cannot hold.  A categorical was hashed when it
+    was made: its codes are taken, its unused categories left out, and its
+    categories, which may be any objects, are ordered in Python."""
     if isinstance(values.dtype, pd.CategoricalDtype):
         cats = values.cat.categories.to_numpy(dtype=object)
         first = values.cat.codes.to_numpy()
         used = np.flatnonzero(np.bincount(first[first >= 0], minlength=len(cats)))
         lut = np.full(len(cats) + 1, -1)
         lut[used] = np.arange(len(used))
-        return _sorted_vocab_codes(lut[first], cats[used])
+        return _sorted_vocab_codes(lut[first], cats[used]), {"hashed": 1, "native_sort": 0}
     if not isinstance(values.dtype, pd.StringDtype) and (
             pd.api.types.infer_dtype(values, skipna=True) != "string"):
         return None
@@ -664,9 +689,13 @@ def _hash_encode(values) -> Optional[NativeEncodedStrings]:
         return None
     if isinstance(strings, pa.ChunkedArray):  # a pd.concat of part files
         strings = strings.combine_chunks()
+    t0 = time.perf_counter()
     enc = strings.dictionary_encode()
+    t1 = time.perf_counter()
     first = enc.indices.fill_null(-1).to_numpy(zero_copy_only=False)
-    return _sorted_vocab_codes(first, enc.dictionary.to_numpy(zero_copy_only=False))
+    encoded = _arrow_sorted_vocab_codes(first, enc.dictionary)
+    return encoded, {"hashed": 1, "native_sort": 1, "hash_s": t1 - t0,
+                     "sort_s": time.perf_counter() - t1}
 
 
 def _loop_encode(vals: np.ndarray) -> NativeEncodedStrings:
@@ -684,26 +713,32 @@ def _loop_encode(vals: np.ndarray) -> NativeEncodedStrings:
 
 def encode_strings(values) -> NativeEncodedStrings:
     """Dictionary-encode one string column (a Series or an array) on the
-    host: int32 codes (−1 null) into a vocab of Python ``str`` sorted as
-    ``np.unique`` sorts them.  Nulls are what ``pd.isna`` says (None, NaN,
-    ``pd.NA``, NaT); ``""`` is a value.  Which input takes which path:
+    host: int32 codes (−1 null) into a vocab of Python ``str`` in code-point
+    order, which is how ``np.unique`` sorts them.  Nulls are what ``pd.isna``
+    says (None, NaN, ``pd.NA``, NaT); ``""`` is a value.  Which input takes
+    which path:
 
-    - a Series of a string dtype or of dtype ``category``, and an object or
-      ``U`` array or Series whose non-null values are all ``str``
-      (``infer_dtype`` says "string"): hashed;
+    - a Series of a string dtype, and an object or ``U`` array or Series
+      whose non-null values are all ``str`` (``infer_dtype`` says "string"):
+      hashed by Arrow, and the distinct values, an Arrow string array,
+      ordered by Arrow over their UTF-8 bytes;
+    - a Series of dtype ``category``: its own codes taken, its categories
+      ordered by ``np.unique`` over their ``str()``;
     - anything else (mixed objects, bytes, an all-null or empty object
       array, a lone surrogate): the per-value loop.
 
     One ``ingest/encode`` span per call (a phase of the pass where ingest
-    calls this) with the counts ``rows``, ``distinct`` and ``hashed``."""
+    calls this) with the counts ``rows``, ``distinct``, ``hashed`` and
+    ``native_sort`` (1 where Arrow ordered the vocab, 0 where Python did),
+    and where Arrow did ``hash_s`` and ``sort_s``: the seconds in its
+    ``dictionary_encode`` over the rows, and those ordering the distinct
+    values and building the vocab."""
     from anovos_tpu.obs.tracing import get_tracer
 
     with get_tracer().phase("ingest/encode", cat="io", rows=len(values)) as sp:
-        enc = _hash_encode(values)
-        hashed = enc is not None
-        if not hashed:
-            enc = _loop_encode(np.asarray(values, dtype=object))
-        sp.add(distinct=len(enc.vocab), hashed=int(hashed))
+        enc, counts = _hash_encode(values) or (
+            _loop_encode(np.asarray(values, dtype=object)), {"hashed": 0, "native_sort": 0})
+        sp.add(distinct=len(enc.vocab), **counts)
     return enc
 
 
@@ -713,8 +748,9 @@ def _host_to_column(arr: np.ndarray, n: int, npad: int, rt) -> Column:
     An object / ``U`` / ``S`` array is a string column: :func:`encode_strings`
     dictionary-encodes it under an ``ingest/encode`` span (hashed when its
     non-null values are all ``str``, the per-value loop otherwise; the vocab
-    in ``np.unique``'s order either way).  Codes that arrive encoded (avro's
-    decoder, ``Table.from_pandas``) skip that.  Then ``ingest/h2d`` around
+    in code-point order either way, which is ``np.unique``'s: computed by
+    Arrow over UTF-8 bytes in the first case, by ``np.unique`` in the other).
+    Codes that arrive encoded (avro's decoder, ``Table.from_pandas``) skip that.  Then ``ingest/h2d`` around
     the conversion to the device dtype, the padding and the ``device_put``
     calls.  ``Runtime.shard_rows``'s transfer bracket puts ``bytes`` and
     ``enqueue_s`` on the latter: ``device_put`` is async, so those seconds

@@ -159,7 +159,8 @@ def test_ingest_spans_carry_their_counts(stats_pass, work):
     strings = [c for c, t in synthetic.load_income(ROWS, 7, work / "income_dataset").dtypes.items()
                if str(t) in ("object", "str", "string")]
     assert len(encode) == len(strings) >= 10
-    assert all(r["counts"]["rows"] == ROWS and r["counts"]["hashed"] == 1 for r in encode)
+    assert all(r["counts"]["rows"] == ROWS and r["counts"]["hashed"] == 1
+               and r["counts"]["native_sort"] == 1 for r in encode)
     assert all(r["parent"] == "io:read_dataset" for r in encode)
     assert max(r["counts"]["distinct"] for r in encode) == ROWS  # the id column
     # every byte handed to device_put before the scheduler starts is on an ingest/h2d span
@@ -170,9 +171,10 @@ def test_ingest_spans_carry_their_counts(stats_pass, work):
 
 def test_one_encode_span_a_string_column_and_the_looped_one_says_so(tmp_path):
     """A parquet table read inside a pass: one ``ingest/encode`` row per
-    string column inside ``ingest``, each with ``rows``, ``distinct`` and
-    ``hashed``; a binary column decodes to ``bytes`` objects, which the hash
-    cannot take (``str(b"x")`` is ``"b'x'"``), and reports ``hashed`` 0."""
+    string column inside ``ingest``, each with ``rows``, ``distinct``,
+    ``hashed`` and ``native_sort`` (and ``hash_s``, ``sort_s`` where Arrow
+    did both); a binary column decodes to ``bytes`` objects, which the hash
+    cannot take (``str(b"x")`` is ``"b'x'"``), and reports both 0."""
     import pyarrow as pa
     import pyarrow.parquet as pq
 
@@ -194,11 +196,17 @@ def test_one_encode_span_a_string_column_and_the_looped_one_says_so(tmp_path):
     rows = tr.phases()
     (ingest,) = [r for r in rows if r["name"] == "ingest"]
     encode = [r for r in rows if r["name"] == "ingest/encode"]
+    timings = [{k: r["counts"].pop(k) for k in ("hash_s", "sort_s") if k in r["counts"]} for r in encode]
     assert [r["counts"] for r in encode] == [
-        {"rows": 2 * n, "distinct": 2, "hashed": 1},
-        {"rows": 2 * n, "distinct": 2 * n, "hashed": 1},
-        {"rows": 2 * n, "distinct": 3, "hashed": 0},
+        {"rows": 2 * n, "distinct": 2, "hashed": 1, "native_sort": 1},
+        {"rows": 2 * n, "distinct": 2 * n, "hashed": 1, "native_sort": 1},
+        {"rows": 2 * n, "distinct": 3, "hashed": 0, "native_sort": 0},
     ]
+    # where Arrow hashed the rows and ordered the vocab, where the span's time went; the loop is one
+    assert [sorted(t) for t in timings] == [["hash_s", "sort_s"], ["hash_s", "sort_s"], []]
+    assert all(0.0 <= t["hash_s"] and 0.0 <= t["sort_s"]
+               and t["hash_s"] + t["sort_s"] <= r["end_s"] - r["start_s"] + 1e-6
+               for t, r in zip(timings, encode) if t)
     assert all(r["parent"] == "io:read_dataset" and ingest["start_s"] <= r["start_s"]
                and r["end_s"] <= ingest["end_s"] for r in encode)
     assert list(tbl["raw"].vocab) == ["b'x'", "b'y'", "b'z'"] and tbl["value"].kind == "num"

@@ -1,8 +1,9 @@
 """``shared.table.encode_strings`` against the plain encoding it replaced: a
 ``str()`` per row and ``np.unique`` over all of them, kept here as the
 reference.  Codes, vocab (values, order and type) and mask must be equal
-for every input, whichever path the column took; ``hashed`` on the
-``ingest/encode`` span says which."""
+for every input, whichever path the column took; ``hashed`` and
+``native_sort`` on the ``ingest/encode`` span say which, and who ordered the
+vocab (Arrow over UTF-8 bytes, or ``np.unique`` over Python ``str``)."""
 
 import numpy as np
 import pandas as pd
@@ -25,11 +26,21 @@ def reference(vals: np.ndarray):
 
 
 def encode(values):
-    """``(encoded, counts of its span)``."""
+    """``(encoded, counts of its span)``, the counts held to what every
+    span owes: Arrow orders the vocab of every column it hashed but a
+    categorical's, and only there says where the span's time went."""
     enc = encode_strings(values)
     span = obs.get_tracer().snapshot()[-1]
     assert span.name == "ingest/encode"
-    return enc, span.args
+    counts = span.args
+    categorical = isinstance(getattr(values, "dtype", None), pd.CategoricalDtype)
+    assert counts["native_sort"] == (counts["hashed"] and not categorical)
+    if counts["native_sort"]:
+        assert counts["hash_s"] >= 0 and counts["sort_s"] >= 0
+        assert counts["hash_s"] + counts["sort_s"] <= span.dur_ns / 1e9
+    else:
+        assert "hash_s" not in counts and "sort_s" not in counts
+    return enc, counts
 
 
 def assert_same(enc, vals: np.ndarray):
@@ -47,8 +58,34 @@ def _obj(values) -> np.ndarray:
     return out
 
 
+def _ids(n: int, order=None) -> np.ndarray:
+    """The id column as ``benchmark/datasets/income.py`` makes it: distinct
+    9-character keys, in order unless another is given."""
+    return _obj([f"id{i:07d}" for i in (range(n) if order is None else order)])
+
+
 def _shuffled_ids(n: int) -> np.ndarray:
-    return _obj([f"id{i:07d}" for i in np.random.default_rng(3).permutation(n)])
+    return _ids(n, np.random.default_rng(3).permutation(n))
+
+
+def _every_utf8_length(n: int = 24_000) -> np.ndarray:
+    """Distinct random strings over 1-, 2-, 3- and 4-byte code points (NUL
+    among them, the surrogates not), every prefix of some of them, ``""``,
+    and the neighbours across the lengths' borders: the order of UTF-8 bytes
+    against the order of code points."""
+    rng = np.random.default_rng(28)
+    ranges = [(0x00, 0x80), (0x80, 0x800), (0x800, 0xD800), (0xE000, 0x10000), (0x10000, 0x110000)]
+    words = {"", "\0", "\0\0", "a\0", "a\0b", "a", "\x7f", "\x80", "\u07ff", "\u0800", "\ud7ff",
+             "\ue000", "\uffff", "\U00010000", "\U0010ffff", "\uffffz", "\U00010000z"}
+    while len(words) < n:
+        lo_hi = [ranges[i] for i in rng.integers(0, len(ranges), rng.integers(1, 9))]
+        word = "".join(chr(rng.integers(lo, hi)) for lo, hi in lo_hi)
+        words.add(word)
+        if len(words) % 7 == 0:
+            words.update(word[:k] for k in range(1, len(word)))
+    vals = _obj(sorted(words))[rng.permutation(len(words))]
+    vals = np.concatenate([vals, vals[: n // 3], _obj([None] * 50)])
+    return vals[rng.permutation(len(vals))]
 
 
 def _few_of_many(n: int) -> np.ndarray:
@@ -73,6 +110,11 @@ ARRAYS = {
     "case_only": (_obj(["a", "A", "b", "B", "aa", "Aa", "aA", "AA"]), 1),
     "one_distinct": (_obj(["same"] * 50), 1),
     "all_distinct_1e5": (_shuffled_ids(120_000), 1),
+    # the scaled cell's id column, as it arrives and as it might
+    "ids_sorted_4e5": (_ids(400_000), 1),
+    "ids_reversed_4e5": (_ids(400_000, range(399_999, -1, -1)), 1),
+    "ids_shuffled_4e5": (_shuffled_ids(400_000), 1),
+    "every_utf8_length": (_every_utf8_length(), 1),
     "few_of_many_rows": (_few_of_many(50_000), 1),
     # pandas' own string hash table reads C strings: "b\0" and "b" are one key there
     "embedded_nul": (_obj(["b\0", "b", "b\0c", "b"]), 1),
@@ -94,7 +136,10 @@ def test_array_encodes_as_the_plain_loop_does(case):
     enc, counts = encode(vals)
     assert_same(enc, vals)
     assert counts["rows"] == len(vals) and counts["distinct"] == len(enc.vocab)
-    assert counts["hashed"] == hashed
+    assert counts["hashed"] == hashed == counts["native_sort"]
+    if case == "every_utf8_length":
+        assert counts["distinct"] >= 20_000
+        assert {len(v.encode()) for v in enc.vocab if len(v) == 1} == {1, 2, 3, 4}
 
 
 def _chunked_arrow_str() -> pd.Series:
@@ -105,8 +150,22 @@ def _chunked_arrow_str() -> pd.Series:
     return s
 
 
+def _concat_of_parts() -> pd.Series:
+    """What ``read_host_frame`` hands over: the part frames' ``str`` columns
+    under one ``pd.concat``, a chunked Arrow array, the parts' values
+    interleaved in the order."""
+    rng = np.random.default_rng(6)
+    words = _obj([f"k{i:05d}é" for i in rng.permutation(9_000)] + [None] * 300)
+    parts = [pd.DataFrame({"key": pd.Series(part, dtype="str")})
+             for part in np.array_split(words[rng.permutation(len(words))], 4)]
+    s = pd.concat(parts, ignore_index=True)["key"]
+    assert s.array._pa_array.num_chunks == 4
+    return s
+
+
 SERIES = {
     "str_arrow_chunked": _chunked_arrow_str,
+    "str_arrow_concat_of_parts": _concat_of_parts,
     "str_arrow_all_null": lambda: pd.Series([None, None], dtype="str"),
     "str_python_backed_nul": lambda: pd.Series(
         ["b\0", "b", "b\0", "a"], dtype=pd.StringDtype("python", na_value=np.nan)),
@@ -120,6 +179,10 @@ SERIES = {
     # two categories that str() to one string are one value
     "category_colliding": lambda: pd.Series(
         pd.Categorical([1, "1", "0", None, 1], categories=["0", 1, "1", 2.5])),
+    # 1 and "1" are one string, 1.5 and "1.5" another (pandas takes 1 and 1.0 for one
+    # category): Python must order, and merge, what may be any object
+    "category_int_str_float": lambda: pd.Series(
+        pd.Categorical([1.5, "1", None, 1, "1.5", 1.5], categories=["1.5", 1, "1", 1.5, "unused"])),
 }
 SERIES_LOOPED = {"object_mixed"}
 
@@ -134,6 +197,8 @@ def test_series_encodes_as_its_object_array_does(case):
         assert list(enc.vocab) == ["a", "b"]
     if case == "category_colliding":
         assert list(enc.vocab) == ["0", "1"] and list(enc.codes) == [1, 1, 0, -1, 1]
+    if case == "category_int_str_float":
+        assert list(enc.vocab) == ["1", "1.5"] and list(enc.codes) == [1, 0, -1, 0, 1, 1]
 
 
 def _frame(n: int = 700) -> pd.DataFrame:
@@ -170,6 +235,7 @@ def test_read_dataset_keeps_vocab_and_device_codes(file_type, tmp_path):
     strings = ["id", "city", "grade", "note"]
     assert tbl.nrows == len(df) and len(spans) == len(strings)
     assert all(sp.args["hashed"] == 1 and sp.args["rows"] == len(df) for sp in spans)
+    assert all(sp.args["native_sort"] == 1 for sp in spans)
     for name in strings:
         codes, vocab, mask = reference(host[name].to_numpy(dtype=object))
         col = tbl[name]
