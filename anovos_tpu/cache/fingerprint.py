@@ -59,10 +59,7 @@ __all__ = [
 # ANOVOS_SHAPE_BUCKETS is on it defensively: bucketed-vs-exact parity is
 # tested byte-identical, but the knob exists precisely to flip compiled
 # program shapes, and a false invalidation is cheap while a false hit is
-# not.  ANOVOS_FUSE_BLOCKS follows the same policy (fused-vs-eager parity
-# is byte-tested, tests/test_fuse_blocks.py, but the knob flips program
-# structure wholesale).  graftcheck GC008 audits node bodies against this
-# list.
+# not.  graftcheck GC008 audits node bodies against this list.
 KNOWN_ENV_KNOBS = (
     # continuum feed knobs (anovos_tpu/continuum): the alert gate changes
     # what the arrival loop EMITS (obs/continuum_alerts.jsonl + journal
@@ -74,8 +71,6 @@ KNOWN_ENV_KNOBS = (
     # never cost a recompute in practice.
     "ANOVOS_CONTINUUM_ALERTS",
     "ANOVOS_CONTINUUM_POLL_S",
-    # whole-block fusion (ops/fuse.py): =0 restores the eager glue chains
-    "ANOVOS_FUSE_BLOCKS",
     # hardened-ingest policy knobs (data_ingest/guard.py): what happens to
     # a corrupt part (quarantine drops its rows vs raise), a schema-
     # drifted part (reconcile null-fills/widens vs strict crash) and a

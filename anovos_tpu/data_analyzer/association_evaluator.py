@@ -26,9 +26,8 @@ import numpy as np
 import pandas as pd
 
 from anovos_tpu.data_analyzer.association_eval_varclus import VarClusJax
-from anovos_tpu.ops.correlation import masked_corr
-from anovos_tpu.ops.fuse import fuse_enabled
-from anovos_tpu.ops.segment import code_counts, code_label_counts, masked_nunique
+from anovos_tpu.ops.correlation import masked_corr_cc
+from anovos_tpu.ops.segment import cat_valid_mask, code_counts, masked_nunique
 from anovos_tpu.shared.table import Table
 from anovos_tpu.shared.utils import parse_cols
 
@@ -60,14 +59,8 @@ def correlation_matrix(
     # (dead lanes mask=False), so `M.all(axis=1)` would veto every row.
     # The live count rides in as a device scalar, keeping the program
     # keyed on the bucketed shape rather than recompiling per width.
-    if fuse_enabled():
-        # the row-count/compare/combine glue fused into the corr program
-        from anovos_tpu.ops.correlation import masked_corr_cc
-
-        C = np.asarray(masked_corr_cc(X, M, len(cols)))[: len(cols), : len(cols)]
-    else:
-        row_ok = (M.sum(axis=1) == jnp.asarray(np.int32(len(cols))))[:, None]
-        C = np.asarray(masked_corr(X, M & row_ok))[: len(cols), : len(cols)]
+    # the row-count/compare/combine glue is fused into the corr program
+    C = np.asarray(masked_corr_cc(X, M, len(cols)))[: len(cols), : len(cols)]
     odf = pd.DataFrame(C, columns=cols, index=cols)
     odf["attribute"] = odf.index
     ordered = sorted(cols)
@@ -79,9 +72,8 @@ def correlation_matrix(
 
 def _label_group_counts_fused(data, mask, y, ym, nrows, vsize: int):
     """ONE program per column for the IV/IG group sweep: valid-mask
-    combine, both label segment-sums, and the two null-group reductions —
-    the eager chain here dispatched ~8 tiny programs per column (and two
-    of them were blocking host syncs mid-loop).  ``mask=None`` when the
+    combine, both label segment-sums, and the two null-group reductions,
+    with no host sync mid-loop.  ``mask=None`` when the
     null semantics already live in the codes (−1 = invalid).  Returns host
     (tot, ev, null_tot, null_ev); tot/ev padded to the segment class."""
     from anovos_tpu.ops.segment import _bucket_segments
@@ -123,7 +115,7 @@ def _label_group_program_nomask(data, y, ym, nrows, vsize: int):
 @jax.jit
 def _masked_sum_program(y, ym):
     """sum(where(ym, y, 0)) — the IV/IG total-event reduction as one
-    program (the eager where+sum pair compiled two)."""
+    program."""
     return jnp.sum(jnp.where(ym, y, 0.0))
 
 
@@ -135,17 +127,9 @@ def _grouped_label_counts(idf: Table, col: str, y, ym, nbins_cap: int = 0):
     c = idf.columns[col]
     if c.kind == "cat":
         vsize = max(len(c.vocab), 1)
-        if fuse_enabled():
-            tot, ev, null_tot, null_ev = _label_group_counts_fused(
-                c.data, c.mask, y, ym, idf.nrows, vsize)
-            tot, ev = tot[:vsize], ev[:vsize]
-        else:
-            m_eff = c.mask & ym & (c.data >= 0)
-            tot = np.asarray(code_label_counts(c.data, m_eff, jnp.ones_like(y), vsize))[:vsize]
-            ev = np.asarray(code_label_counts(c.data, m_eff, y, vsize))[:vsize]
-            null_m = ym & ~(c.mask & (c.data >= 0))
-            null_tot = float(jnp.sum(null_m & (jnp.arange(c.padded_len) < idf.nrows)))
-            null_ev = float(jnp.sum(jnp.where(null_m, y, 0.0)))
+        tot, ev, null_tot, null_ev = _label_group_counts_fused(
+            c.data, c.mask, y, ym, idf.nrows, vsize)
+        tot, ev = tot[:vsize], ev[:vsize]
     else:
         # integer-binned or raw discrete numeric: group by exact value via codes
         vals = np.asarray(c.data)[: idf.nrows]
@@ -159,19 +143,11 @@ def _grouped_label_counts(idf: Table, col: str, y, ym, nbins_cap: int = 0):
         rt = get_runtime()
         pad = idf.padded_rows - idf.nrows
         codes_d = rt.shard_rows(np.concatenate([code_arr, np.full(pad, -1, np.int32)]))
-        if fuse_enabled():
-            # null codes carry the mask (-1 = invalid), so the fused
-            # program runs maskless (mask_none)
-            tot, ev, null_tot, null_ev = _label_group_counts_fused(
-                codes_d, None, y, ym, idf.nrows, vsize)
-            tot, ev = tot[:vsize], ev[:vsize]
-        else:
-            m_eff = (codes_d >= 0) & ym
-            tot = np.asarray(code_label_counts(codes_d, m_eff, jnp.ones_like(y), vsize))[:vsize]
-            ev = np.asarray(code_label_counts(codes_d, m_eff, y, vsize))[:vsize]
-            null_m = ym & (codes_d < 0) & (jnp.arange(c.padded_len) < idf.nrows)
-            null_tot = float(jnp.sum(null_m))
-            null_ev = float(jnp.sum(jnp.where(null_m, y, 0.0)))
+        # null codes carry the mask (-1 = invalid), so the fused
+        # program runs maskless (mask_none)
+        tot, ev, null_tot, null_ev = _label_group_counts_fused(
+            codes_d, None, y, ym, idf.nrows, vsize)
+        tot, ev = tot[:vsize], ev[:vsize]
     tot = np.append(tot, null_tot)
     ev = np.append(ev, null_ev)
     keep = tot > 0
@@ -265,8 +241,7 @@ def IG_calculation(
     if not cols:
         raise TypeError("Invalid input for Column(s)")
     y, ym = _event_vector(idf, label_col, event_label)
-    total_event = float(_masked_sum_program(y, ym) if fuse_enabled()
-                        else jnp.sum(jnp.where(ym, y, 0.0))) / max(idf.nrows, 1)
+    total_event = float(_masked_sum_program(y, ym)) / max(idf.nrows, 1)
     if total_event in (0.0, 1.0):
         warnings.warn("IG undefined: label has a single class")
         return pd.DataFrame({"attribute": cols, "ig": [np.nan] * len(cols)})
@@ -321,19 +296,11 @@ def variable_clustering(
     # nunique readback is sliced to the live k)
     from anovos_tpu.shared.table import stack_padded
 
-    if fuse_enabled():
-        from anovos_tpu.ops.segment import cat_valid_mask
-
-        vc_masks = [
-            cat_valid_mask(sub.columns[c].data, sub.columns[c].mask)
-            if sub.columns[c].kind == "cat" else sub.columns[c].mask
-            for c in cols
-        ]
-    else:
-        vc_masks = [
-            sub.columns[c].mask & ((sub.columns[c].data >= 0) if sub.columns[c].kind == "cat" else True)
-            for c in cols
-        ]
+    vc_masks = [
+        cat_valid_mask(sub.columns[c].data, sub.columns[c].mask)
+        if sub.columns[c].kind == "cat" else sub.columns[c].mask
+        for c in cols
+    ]
     X, M = stack_padded([sub.columns[c].data for c in cols], vc_masks)
     nu = np.asarray(masked_nunique(X, M))[: len(cols)]
     cols = [c for c, u in zip(cols, nu) if u >= 2]
@@ -345,14 +312,8 @@ def variable_clustering(
     Xn, Mn = sub.numeric_block(cols)
     # complete-case over live lanes (see correlation_matrix): dead bucketed
     # lanes are mask=False and must not veto rows
-    if fuse_enabled():
-        from anovos_tpu.ops.correlation import masked_corr_cc
-
-        C = np.asarray(masked_corr_cc(Xn, Mn, len(cols)),
-                       dtype=np.float64)[: len(cols), : len(cols)]
-    else:
-        row_ok = (Mn.sum(axis=1) == jnp.asarray(np.int32(len(cols))))[:, None]
-        C = np.asarray(masked_corr(Xn, Mn & row_ok), dtype=np.float64)[: len(cols), : len(cols)]
+    C = np.asarray(masked_corr_cc(Xn, Mn, len(cols)),
+                   dtype=np.float64)[: len(cols), : len(cols)]
     # harden for eigendecomposition: f32 device numerics can leave NaNs for
     # near-constant columns (zero-variance denominators) and tiny asymmetry;
     # either makes eigh fail to converge.  masked_corr pins the diagonal to

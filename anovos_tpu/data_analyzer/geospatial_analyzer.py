@@ -432,23 +432,12 @@ def cluster_analysis(
     # host.  ANOVOS_DBSCAN_HOST_CC_MAX bounds the host memory (n² f32 +
     # transient edge lists); samples above it — a grid cap RAISED beyond the
     # 4096 default — use the tiled on-device propagation path instead.
-    from anovos_tpu.ops.fuse import fuse_enabled
-
     eps_values = [float(e) for e in np.arange(e0, e1 + 1e-9, estep)]
     D2 = None
-    D_full = None
-    sil_squared = False
     if eps_values and len(sub) <= int(os.environ.get("ANOVOS_DBSCAN_HOST_CC_MAX", 6144)):
         Xc = np.asarray(sub, np.float32)
         Xc = Xc - Xc.mean(axis=0, keepdims=True)  # f32 bits follow the spread
         D2 = np.asarray(jax.device_get(pairwise_d2(jnp.asarray(Xc))))
-        # distances reused by every combo's silhouette sample
-        if fuse_enabled():
-            # the silhouette path sqrt's AFTER sampling (bit-identical,
-            # ~1/64 the elementwise work) — hand it the squared matrix
-            D_full, sil_squared = D2, True
-        else:
-            D_full = np.sqrt(np.maximum(D2, 0.0))
         all_labels = dbscan_host_grid_multi(D2, eps_values, ms_eff)
     combos = []  # (eps, min_samples, labels)
     for a, e in enumerate(eps_values):
@@ -460,9 +449,12 @@ def cluster_analysis(
             counts = neighbor_counts(sub, float(e))
             labels_b = dbscan_grid(sub, float(e), ms_eff, counts=counts)
         combos.extend((e, m, labels) for m, labels in zip(ms_values, labels_b))
-    if D_full is not None:
-        scores = _silhouettes_batched(D_full, [lab for _, _, lab in combos],
-                                      squared=sil_squared)
+    if D2 is not None:
+        # distances reused by every combo's silhouette sample; the
+        # silhouette path sqrt's AFTER sampling (bit-identical, ~1/64 the
+        # elementwise work), so it is handed the squared matrix
+        scores = _silhouettes_batched(D2, [lab for _, _, lab in combos],
+                                      squared=True)
     else:
         # _silhouette itself returns -1.0 for <2 clusters / <10 valid points
         scores = [_silhouette(sub, lab) for _, _, lab in combos]

@@ -25,7 +25,6 @@ import pandas as pd
 
 from anovos_tpu.data_analyzer import stats_generator as sg
 from anovos_tpu.obs import timed
-from anovos_tpu.ops.fuse import fuse_enabled
 from anovos_tpu.ops.quantiles import masked_quantiles
 from anovos_tpu.ops.reductions import masked_moments
 from anovos_tpu.ops.segment import row_signature
@@ -38,10 +37,9 @@ _R = lambda v: round(float(v), 4)
 
 
 # ---------------------------------------------------------------------------
-# fused glue programs (ops/fuse.py): the eager chains between this module's
-# big kernels — float-bit canonicalization for row hashing, the per-row
-# null-count reduction, invalid-mask combines — each lowered as ONE shared
-# program.  ANOVOS_FUSE_BLOCKS=0 restores the eager chain at the call site.
+# fused glue programs: the chains between this module's big kernels —
+# float-bit canonicalization for row hashing, the per-row null-count
+# reduction, invalid-mask combines — each lowered as ONE shared program.
 # ---------------------------------------------------------------------------
 @jax.jit
 def _float_bits_program(data):
@@ -104,7 +102,7 @@ def _outlier_flags(X, M, lo, hi):
 def _outlier_value_replace_program(X, M, lo, hi):
     """Whole-block value-replacement treatment: per-column clip + null
     zero-fill in one program (bounds carry ±inf where a detection side is
-    open, so the clip matches the per-column scalar-bound chain)."""
+    open)."""
     return jnp.where(M, jnp.clip(X, lo[None, :], hi[None, :]), 0.0)
 
 
@@ -124,7 +122,6 @@ def duplicate_detection(
     cols = _discrete_cols(idf, list_of_cols, drop_cols)
     treatment = _check_bool(treatment)
     sub = idf.select(cols)
-    fused = fuse_enabled()
 
     def _hashable(c):
         col = sub.columns[c]
@@ -133,11 +130,9 @@ def duplicate_detection(
         if col.kind == "cat" or col.data.dtype != jnp.float32:
             if col.data.dtype == jnp.int32:
                 return [col.data]  # already the exact bit pattern
-            return [_as_int32_program(col.data) if fused
-                    else col.data.astype(jnp.int32)]
+            return [_as_int32_program(col.data)]
         # +0.0 canonicalizes -0.0 → +0.0 so equal floats hash equally
-        return [_float_bits_program(col.data) if fused
-                else (col.data + 0.0).view(jnp.int32)]
+        return [_float_bits_program(col.data)]
 
     hash_arrays, hash_masks = [], []
     for c in cols:
@@ -196,14 +191,9 @@ def nullRows_detection(
     from anovos_tpu.shared.table import stack_masks_padded
 
     M = stack_masks_padded([idf.columns[c].mask for c in cols])
-    if fuse_enabled():
-        null_cnt = np.asarray(
-            _null_count_program(M, np.int32(len(cols)))
-        )[: idf.nrows]
-    else:
-        null_cnt = np.asarray(
-            jnp.asarray(np.int32(len(cols))) - M.sum(axis=1, dtype=jnp.int32)
-        )[: idf.nrows]
+    null_cnt = np.asarray(
+        _null_count_program(M, np.int32(len(cols)))
+    )[: idf.nrows]
     if treatment_threshold == 1:
         flagged = null_cnt == len(cols)
     else:
@@ -479,22 +469,14 @@ def outlier_detection(
     X, M = idf.numeric_block(cols)
     # bounds padded to the bucketed lane count (dead lanes are mask=False,
     # so any pad value yields flag 0 there — including the row_removal
-    # `clean_row` reduction, which stays correct across padding).  One
-    # fused program replaces the eager compare/where/reduce chain that
-    # compiled per width (cold-compile census).
+    # `clean_row` reduction, which stays correct across padding).  The
+    # host f32 bound arrays ride through the jit boundary directly: a
+    # jnp.asarray cast would compile one convert program per width.
     from anovos_tpu.shared.table import pad_lane_params
 
-    fused = fuse_enabled()
     lo_p = pad_lane_params(lower, X.shape[1]).astype(np.float32)
     hi_p = pad_lane_params(upper, X.shape[1]).astype(np.float32)
-    if fused:
-        # host f32 bound arrays ride through the jit boundary directly —
-        # the eager jnp.asarray casts compiled one convert program per width
-        lo_d, hi_d = lo_p, hi_p
-    else:
-        lo_d = jnp.asarray(pad_lane_params(lower, X.shape[1]), jnp.float32)
-        hi_d = jnp.asarray(pad_lane_params(upper, X.shape[1]), jnp.float32)
-    flag, n_lo_d, n_hi_d, clean_row = _outlier_flags(X, M, lo_d, hi_d)
+    flag, n_lo_d, n_hi_d, clean_row = _outlier_flags(X, M, lo_p, hi_p)
     n_lo = np.asarray(n_lo_d)[: len(cols)]
     n_hi = np.asarray(n_hi_d)[: len(cols)]
     stats = pd.DataFrame(
@@ -511,41 +493,25 @@ def outlier_detection(
             from collections import OrderedDict
 
             new_cols = OrderedDict()
-            if fused:
-                # whole-block treatment program: clip/flag-null + zero-fill
-                # fused over (rows, k_pad) instead of a per-column eager
-                # clip/where chain (the non-finite detection-side bounds
-                # fold into the bound arrays as ±inf — same clip values)
-                lo_eff = pad_lane_params(
-                    np.where(np.isfinite(lower), lo_p[: len(cols)], -np.inf),
-                    X.shape[1], fill=-np.inf).astype(np.float32)
-                hi_eff = pad_lane_params(
-                    np.where(np.isfinite(upper), hi_p[: len(cols)], np.inf),
-                    X.shape[1], fill=np.inf).astype(np.float32)
-                if treatment_method == "value_replacement":
-                    T = _outlier_value_replace_program(X, M, lo_eff, hi_eff)
-                    for i, c in enumerate(cols):
-                        new_cols[c] = Column("num", T[:, i], idf.columns[c].mask,
-                                             dtype_name="double")
-                else:  # null_replacement
-                    T, OK = _outlier_null_replace_program(X, M, flag)
-                    for i, c in enumerate(cols):
-                        new_cols[c] = Column("num", T[:, i], OK[:, i],
-                                             dtype_name=idf.columns[c].dtype_name)
-            else:
+            # whole-block treatment program: clip/flag-null + zero-fill
+            # over (rows, k_pad); the non-finite detection-side bounds
+            # fold into the bound arrays as ±inf
+            lo_eff = pad_lane_params(
+                np.where(np.isfinite(lower), lo_p[: len(cols)], -np.inf),
+                X.shape[1], fill=-np.inf).astype(np.float32)
+            hi_eff = pad_lane_params(
+                np.where(np.isfinite(upper), hi_p[: len(cols)], np.inf),
+                X.shape[1], fill=np.inf).astype(np.float32)
+            if treatment_method == "value_replacement":
+                T = _outlier_value_replace_program(X, M, lo_eff, hi_eff)
                 for i, c in enumerate(cols):
-                    col = idf.columns[c]
-                    x = col.data.astype(jnp.float32)
-                    if treatment_method == "value_replacement":
-                        clipped = jnp.clip(
-                            x,
-                            lo_d[i] if np.isfinite(lower[i]) else -jnp.inf,
-                            hi_d[i] if np.isfinite(upper[i]) else jnp.inf,
-                        )
-                        new_cols[c] = Column("num", jnp.where(col.mask, clipped, 0.0), col.mask, dtype_name="double")
-                    else:  # null_replacement
-                        ok = col.mask & (flag[:, i] == 0)
-                        new_cols[c] = Column("num", jnp.where(ok, x, 0.0), ok, dtype_name=col.dtype_name)
+                    new_cols[c] = Column("num", T[:, i], idf.columns[c].mask,
+                                         dtype_name="double")
+            else:  # null_replacement
+                T, OK = _outlier_null_replace_program(X, M, flag)
+                for i, c in enumerate(cols):
+                    new_cols[c] = Column("num", T[:, i], OK[:, i],
+                                         dtype_name=idf.columns[c].dtype_name)
             for name, ncol in new_cols.items():
                 odf = odf.with_column(name if output_mode == "replace" else name + "_outliered", ncol)
     if print_impact:
@@ -886,8 +852,7 @@ def invalidEntries_detection(
             new_cols = OrderedDict()
             for c in target_cols:
                 col = idf.columns[c]
-                ok = (_mask_and_not_program(col.mask, invalid_masks[c])
-                      if fuse_enabled() else col.mask & ~invalid_masks[c])
+                ok = _mask_and_not_program(col.mask, invalid_masks[c])
                 new_cols[c] = dataclasses.replace(col, mask=ok)
             for name, ncol in new_cols.items():
                 odf = odf.with_column(name if output_mode == "replace" else name + "_invalid", ncol)

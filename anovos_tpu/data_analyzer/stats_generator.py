@@ -68,24 +68,14 @@ def _stacked_valid_mask(idf: Table, cols: List[str]) -> "jnp.ndarray":
     null rule, shared by every consumer so it lives in exactly one place.
     Column-bucketed (dead lanes False): per-column reductions slice back to
     the live ``len(cols)``."""
-    from anovos_tpu.ops.fuse import fuse_enabled
+    from anovos_tpu.ops.segment import cat_valid_mask
     from anovos_tpu.shared.table import stack_masks_padded
 
-    if fuse_enabled():
-        from anovos_tpu.ops.segment import cat_valid_mask
-
-        # numeric/ts lanes pass their mask through untouched (the old
-        # ``mask & True`` spelled an eager and-program per column)
-        return stack_masks_padded(
-            [
-                cat_valid_mask(idf.columns[c].data, idf.columns[c].mask)
-                if idf.columns[c].kind == "cat" else idf.columns[c].mask
-                for c in cols
-            ]
-        )
+    # numeric/ts lanes pass their mask through untouched
     return stack_masks_padded(
         [
-            idf.columns[c].mask & ((idf.columns[c].data >= 0) if idf.columns[c].kind == "cat" else True)
+            cat_valid_mask(idf.columns[c].data, idf.columns[c].mask)
+            if idf.columns[c].kind == "cat" else idf.columns[c].mask
             for c in cols
         ]
     )

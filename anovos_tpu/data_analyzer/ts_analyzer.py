@@ -50,8 +50,6 @@ def daypart_cat(hour: pd.Series) -> pd.Series:
 
 def ts_processed_feats(idf: Table, col: str) -> pd.DataFrame:
     """Per-row calendar features for one ts column (reference :87-158)."""
-    from anovos_tpu.ops.fuse import fuse_enabled
-
     ts = _ts_frame(idf, col)
     out = pd.DataFrame({col: ts})
     out["date"] = ts.dt.date
@@ -60,17 +58,12 @@ def ts_processed_feats(idf: Table, col: str) -> pd.DataFrame:
     out["is_weekend"] = ts.dt.dayofweek >= 5
     out["daypart"] = daypart_cat(ts.dt.hour)
     out["month"] = ts.dt.month
-    if fuse_enabled():
-        # vectorized day formatting: datetime64[D] → str is the same
-        # ISO "%Y-%m-%d" rendering as strftime at ~10× the speed; NaT rows
-        # render differently ('NaT' vs NaN) but every consumer drops them
-        # via dropna(subset=[col]) first, so the frames agree where read
-        days = ts.to_numpy().astype("datetime64[D]")
-        ymd = days.astype(str).astype(object)
-        ymd[pd.isna(ts).to_numpy()] = np.nan
-        out["yyyymmdd_col"] = ymd
-    else:
-        out["yyyymmdd_col"] = ts.dt.strftime("%Y-%m-%d")
+    # vectorized day formatting: datetime64[D] → str is the same ISO
+    # "%Y-%m-%d" rendering as strftime at ~10× the speed
+    days = ts.to_numpy().astype("datetime64[D]")
+    ymd = days.astype(str).astype(object)
+    ymd[pd.isna(ts).to_numpy()] = np.nan
+    out["yyyymmdd_col"] = ymd
     return out
 
 
@@ -103,8 +96,7 @@ _DOW_NAMES = ["Mon", "Tue", "Wed", "Thu", "Fri", "Sat", "Sun"]
 
 @functools.partial(jax.jit, static_argnames=("grain",))
 def _grain_ids(tdata, grain: str):
-    """One fused program per grain: the eager clip/gather/shift chain here
-    compiled ~7 programs per run (cold-compile census)."""
+    """One fused program per grain (clip, LUT gather, shift)."""
     from anovos_tpu.ops import datetime_kernels as dk
 
     if grain == "hourly":
@@ -222,18 +214,12 @@ def _cat_viz(idf: Table, ts_col: str, cat_cols: List[str], n_cat: int = 10) -> p
     and ONE stacked day×category combo program — two device dispatches
     total instead of two per column."""
     from anovos_tpu.data_transformer.datetime import (
-        _bucket_ids, _bucket_ids_minmax, _bucket_start_secs, _col_min_max,
+        _bucket_ids_minmax, _bucket_start_secs,
     )
-    from anovos_tpu.ops.fuse import fuse_enabled
 
-    fused = fuse_enabled()
     tcol = idf.columns[ts_col]
-    if fused:
-        day_ids, lo_d, hi_d = _bucket_ids_minmax(tcol.data, tcol.mask, "day")
-        lo, hi = int(lo_d), int(hi_d)
-    else:
-        day_ids = _bucket_ids(tcol.data, "day")
-        lo, hi = _col_min_max(day_ids, tcol.mask)
+    day_ids, lo_d, hi_d = _bucket_ids_minmax(tcol.data, tcol.mask, "day")
+    lo, hi = int(lo_d), int(hi_d)
     if lo > hi or not cat_cols:
         return pd.DataFrame(columns=["date", "attribute", "category", "count"])
     ndays = hi - lo + 1
@@ -245,17 +231,12 @@ def _cat_viz(idf: Table, ts_col: str, cat_cols: List[str], n_cat: int = 10) -> p
     nv = max(max(len(idf.columns[c].vocab) for c in cat_cols), 1)
     nv_b = max(8, 1 << (nv - 1).bit_length())
     ndays_b = max(8, 1 << (int(ndays) - 1).bit_length())
-    if fused:
-        # stacks fold INTO the jitted programs (tuple args): the eager
-        # jnp.stack pair compiled broadcast+concat programs per arity
-        datas = tuple(idf.columns[c].data for c in cat_cols)
-        masks = tuple(idf.columns[c].mask for c in cat_cols)
-        cnts = np.asarray(jax.device_get(
-            _all_code_counts_cols(datas, masks, nv_b)))  # (k, nv_b)
-    else:
-        C = jnp.stack([idf.columns[c].data for c in cat_cols], axis=1)
-        Mc = jnp.stack([idf.columns[c].mask for c in cat_cols], axis=1)
-        cnts = np.asarray(jax.device_get(_all_code_counts(C, Mc, nv_b)))  # (k, nv_b)
+    # stacks fold INTO the jitted programs (tuple args): a jnp.stack pair
+    # out here would compile broadcast+concat programs per arity
+    datas = tuple(idf.columns[c].data for c in cat_cols)
+    masks = tuple(idf.columns[c].mask for c in cat_cols)
+    cnts = np.asarray(jax.device_get(
+        _all_code_counts_cols(datas, masks, nv_b)))  # (k, nv_b)
     # top-N per column (codes beyond a column's own vocab count zero)
     lut = np.full((k, nv_b), n_cat, np.int32)  # → Others
     tops = []
@@ -264,14 +245,9 @@ def _cat_viz(idf: Table, ts_col: str, cat_cols: List[str], n_cat: int = 10) -> p
         top = np.argsort(-cnts[j, :v])[:n_cat]
         lut[j, top] = np.arange(len(top), dtype=np.int32)
         tops.append(top)
-    if fused:
-        combo = np.asarray(jax.device_get(_combo_counts_all_cols(
-            datas, masks, tcol.mask, lut, day_ids, np.int32(lo), ndays_b, n_cat + 1
-        ))).reshape(k, ndays_b, n_cat + 1)[:, :ndays, :]
-    else:
-        combo = np.asarray(jax.device_get(_combo_counts_all(
-            C, Mc & tcol.mask[:, None], jnp.asarray(lut), day_ids - lo, ndays_b, n_cat + 1
-        ))).reshape(k, ndays_b, n_cat + 1)[:, :ndays, :]
+    combo = np.asarray(jax.device_get(_combo_counts_all_cols(
+        datas, masks, tcol.mask, lut, day_ids, np.int32(lo), ndays_b, n_cat + 1
+    ))).reshape(k, ndays_b, n_cat + 1)[:, :ndays, :]
     rows = []
     for j, c in enumerate(cat_cols):
         labels = [str(idf.columns[c].vocab[t]) for t in tops[j]] + ["Others"]
@@ -350,14 +326,12 @@ def ts_viz_data(
     daily = feats.groupby("yyyymmdd_col").size().reset_index(name="count")
     daily.to_csv(out + f"ts_daily_{col}.csv", index=False)
 
-    # numeric viz: all three grains in ONE fused dispatch under
-    # ANOVOS_FUSE_BLOCKS (_ts_num_viz_all); the per-grain path — daily via
-    # the device groupby-aggregator, small grains via one segment program
-    # each — is the fallback and the parity baseline
+    # numeric viz: all three grains in ONE fused dispatch
+    # (_ts_num_viz_all); the per-grain path — daily via the device
+    # groupby-aggregator, small grains via one segment program each — is
+    # taken where that returns None (all-null or degenerate span)
     if num_cols:
-        from anovos_tpu.ops.fuse import fuse_enabled
-
-        viz = _ts_num_viz_all(idf, col, num_cols) if fuse_enabled() else None
+        viz = _ts_num_viz_all(idf, col, num_cols)
         if viz is not None:
             dv, hourly_df, weekly_df = viz
         else:
@@ -549,27 +523,23 @@ def ts_analyzer(
     """Entry (reference :408-550): run eligibility + viz dumps for every
     timestamp column; write ``ts_stats.csv`` summary."""
     Path(output_path).mkdir(parents=True, exist_ok=True)
-    from anovos_tpu.ops.fuse import fuse_enabled
-
     ts_cols = [c for c in idf.col_names if idf.columns[c].kind == "ts"]
     rows = []
     eligible = []
     feats_map: dict = {}
-    share = fuse_enabled()
     for c in ts_cols:
         stats = ts_eligiblity_check(idf, c, id_col, max_days)
         rows.append(stats)
         if stats.get("eligible"):
             eligible.append(c)
-            if share:
-                # calendar feats computed ONCE per column — the viz dump
-                # and the landscape sweep used to pay the pandas pass twice
-                feats_map[c] = ts_processed_feats(idf, c)
+            # calendar feats computed ONCE per column, shared by the viz
+            # dump and the landscape sweep
+            feats_map[c] = ts_processed_feats(idf, c)
             ts_viz_data(idf, c, output_path, output_type,
-                        _feats=feats_map.get(c))
+                        _feats=feats_map[c])
     if eligible:
         ts_landscape(idf, eligible, id_col, output_path,
-                     _feats_map=feats_map if share else None)
+                     _feats_map=feats_map)
     # always emit the same headered schema — a headerless empty CSV breaks
     # readers and per-run schema drift breaks downstream joins
     pd.DataFrame(rows).reindex(columns=TS_STATS_COLUMNS).to_csv(

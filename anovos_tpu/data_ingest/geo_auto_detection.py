@@ -12,8 +12,8 @@ Detection mirrors the reference's two-stage logic:
    through the base-32 codec (>2 distinct, ref :272-292);
 4. a lat/lon count mismatch resets both (pairs must align, ref :294-296).
 
-All column statistics come from ONE fused device describe dispatch
-(ops/describe.table_describe) instead of the reference's four Spark jobs
+All column statistics come from ONE masked-moments dispatch
+(ops/reductions.masked_moments) instead of the reference's four Spark jobs
 per column.
 """
 
@@ -60,8 +60,6 @@ def _value_regex_hits(vals: np.ndarray, rx: re.Pattern, limit: int = 500) -> int
 
 def ll_gh_cols(idf: Table, max_records: int = 100000) -> Tuple[List[str], List[str], List[str]]:
     """Detect (lat_cols, lon_cols, geohash_cols) (reference :177-298)."""
-    from anovos_tpu.ops.describe import table_describe
-
     lat_cols, lon_cols, gh_cols = [], [], []
     num_cols = [
         c
@@ -70,36 +68,21 @@ def ll_gh_cols(idf: Table, max_records: int = 100000) -> Tuple[List[str], List[s
     ]
     stats = {}
     if num_cols:
-        from anovos_tpu.ops.fuse import fuse_enabled
+        # the gates below read only range/spread stats: one sort-free
+        # masked-moments pass, not the full describe with its device sort
+        # for percentiles/nunique
+        from anovos_tpu.ops.reductions import masked_moments
 
-        if fuse_enabled():
-            # the gates below read only range/spread stats: one sort-free
-            # masked-moments pass instead of the full fused describe (whose
-            # device sort for percentiles/nunique was ~3/4 of the geo
-            # block's detection cost; the describe's nunique was computed
-            # here and never read)
-            from anovos_tpu.ops.reductions import masked_moments
-
-            X, M = idf.numeric_block(num_cols)
-            mom = {k: np.asarray(v)[: len(num_cols)]
-                   for k, v in masked_moments(X, M).items()}
-            for i, c in enumerate(num_cols):
-                stats[c] = {
-                    "max": float(mom["max"][i]),
-                    "min": float(mom["min"][i]),
-                    "mean": float(mom["mean"][i]),
-                    "std": float(mom["stddev"][i]),
-                }
-        else:
-            num_out, _ = table_describe(idf, num_cols, [])
-            for i, c in enumerate(num_cols):
-                stats[c] = {
-                    "max": float(num_out["max"][i]),
-                    "min": float(num_out["min"][i]),
-                    "mean": float(num_out["mean"][i]),
-                    "std": float(num_out["stddev"][i]),
-                    "nunique": int(num_out["nunique"][i]),
-                }
+        X, M = idf.numeric_block(num_cols)
+        mom = {k: np.asarray(v)[: len(num_cols)]
+               for k, v in masked_moments(X, M).items()}
+        for i, c in enumerate(num_cols):
+            stats[c] = {
+                "max": float(mom["max"][i]),
+                "min": float(mom["min"][i]),
+                "mean": float(mom["mean"][i]),
+                "std": float(mom["stddev"][i]),
+            }
     for c in num_cols:
         s = stats[c]
         if not np.isfinite(s["max"]):

@@ -430,24 +430,15 @@ def charts_to_objects(
         vals = [float(cnts[j]) for j in order if cnts[j] > 0]
         _emit(_bar_fig(cats, vals, c), ends_with(master_path) + "freqDist_" + c)
         if y is not None:
-            from anovos_tpu.ops.fuse import fuse_enabled
-            from anovos_tpu.ops.segment import code_label_counts
+            # one fused program per column (shared with the IV/IG group
+            # sweep): mask combine + both label segment-sums
+            from anovos_tpu.data_analyzer.association_evaluator import (
+                _label_group_counts_fused,
+            )
 
-            if fuse_enabled():
-                # one fused program per column (shared with the IV/IG group
-                # sweep): mask combine + both label segment-sums — the
-                # eager chain dispatched ~5 programs per chart column
-                from anovos_tpu.data_analyzer.association_evaluator import (
-                    _label_group_counts_fused,
-                )
-
-                tot, evs, _, _ = _label_group_counts_fused(
-                    col.data, col.mask, y, ym, idf.nrows, vsize)
-                tot, evs = tot[:vsize], evs[:vsize]
-            else:
-                m_eff = col.mask & ym
-                tot = np.asarray(code_label_counts(col.data, m_eff, jnp.ones_like(y), vsize))[:vsize]
-                evs = np.asarray(code_label_counts(col.data, m_eff, y, vsize))[:vsize]
+            tot, evs, _, _ = _label_group_counts_fused(
+                col.data, col.mask, y, ym, idf.nrows, vsize)
+            tot, evs = tot[:vsize], evs[:vsize]
             with np.errstate(invalid="ignore", divide="ignore"):
                 rate = np.where(tot > 0, evs / np.maximum(tot, 1), 0.0)
             _emit(

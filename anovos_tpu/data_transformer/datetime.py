@@ -656,8 +656,7 @@ def _segment_aggregate(ids0: jax.Array, valid: jax.Array, V: jax.Array, Mv: jax.
         nseg = bucket_segments_pow2(nseg)
     cp = wants_column_parallel(ids0, valid, V, Mv, replicate=(ids0, valid))
     if off is not None:
-        # lo-offset subtraction fused into the aggregate program (the
-        # eager ``ids - lo`` spelled one subtract program + dispatch)
+        # lo-offset subtraction fused into the aggregate program
         return _segment_aggregate_jit_off(
             ids0, np.int32(off), valid, V, Mv, nseg, cp=cp)
     return _segment_aggregate_jit(ids0, valid, V, Mv, nseg, cp=cp)
@@ -726,32 +725,19 @@ def aggregator(
         )
         return _aggregator_host(idf, cols, aggs, time_col, granularity_format)
 
-    from anovos_tpu.ops.fuse import fuse_enabled
-
-    fused = fuse_enabled()
-    if fused:
-        # bucket ids + span min/max in ONE dispatch (the id program and
-        # the min/max program used to round-trip separately), and the
-        # lo-offset subtraction folds into the aggregate program below
-        ids, lo_d, hi_d = _bucket_ids_minmax(tcol.data, tcol.mask, grain)
-        lo, hi = int(lo_d), int(hi_d)
-    else:
-        ids = _bucket_ids(tcol.data, grain)
-        lo, hi = _col_min_max(ids, tcol.mask)
+    # bucket ids + span min/max in ONE dispatch, and the lo-offset
+    # subtraction folds into the aggregate program below
+    ids, lo_d, hi_d = _bucket_ids_minmax(tcol.data, tcol.mask, grain)
+    lo, hi = int(lo_d), int(hi_d)
     if lo > hi:  # all-null time column: empty result
         return pd.DataFrame(columns=[time_col] + [f"{c}_{a}" for c in cols for a in aggs])
     nseg = hi - lo + 1
     if nseg > 4_000_000:  # degenerate span: seconds-grain over decades
         return _aggregator_host(idf, cols, aggs, time_col, granularity_format)
     V, Mv = idf.numeric_block(cols)
-    if fused:
-        cnt, sm, sq, mn, mx, med = jax.device_get(
-            _segment_aggregate(ids, tcol.mask, V, Mv, int(nseg), off=lo)
-        )
-    else:
-        cnt, sm, sq, mn, mx, med = jax.device_get(
-            _segment_aggregate(ids - lo, tcol.mask, V, Mv, int(nseg))
-        )
+    cnt, sm, sq, mn, mx, med = jax.device_get(
+        _segment_aggregate(ids, tcol.mask, V, Mv, int(nseg), off=lo)
+    )
     return format_segment_aggregate(
         (cnt, sm, sq, mn, mx, med), cols, aggs, time_col, granularity_format,
         lo, grain)

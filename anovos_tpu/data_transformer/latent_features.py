@@ -25,7 +25,6 @@ import pandas as pd
 
 from anovos_tpu.data_transformer.model_io import load_model_df, save_model_df
 from anovos_tpu.models.autoencoder import AutoEncoder
-from anovos_tpu.ops.fuse import fuse_enabled
 from anovos_tpu.ops.mxu import bf16_sweep, mm
 from anovos_tpu.ops.reductions import masked_moments
 from anovos_tpu.shared.runtime import get_runtime
@@ -142,10 +141,9 @@ def _pca_center(X, nrows):
 @functools.partial(jax.jit, static_argnames=("bf16",))
 def _pca_cov_eig(X, nrows, bf16: bool = False):
     """Fused PCA spectrum: row-masked centering + covariance + eigh +
-    descending reorder in ONE program (the eager chain compiled ~14
-    single-primitive programs per run — cold-compile census).  The
-    covariance matmul is pre-centered, so it qualifies for the guarded
-    bf16 sweep (ops/mxu.py); eigh itself always runs f32."""
+    descending reorder in ONE program.  The covariance matmul is
+    pre-centered, so it qualifies for the guarded bf16 sweep (ops/mxu.py);
+    eigh itself always runs f32."""
     rowmask = (jnp.arange(X.shape[0]) < nrows)[:, None]
     Xc = jnp.where(rowmask, X - X.mean(axis=0, where=rowmask), 0.0)
     cov = mm(Xc.T, Xc, bf16) / jnp.maximum(nrows - 1, 1)
@@ -186,23 +184,16 @@ def PCA_latentFeatures(
         warnings.warn("No PCA Computation - need ≥2 numerical columns")
         return idf
     X, mean, std = _prep_block(idf, cols, standardization, imputation=True)
-    fused = fuse_enabled()
-    if fused:
-        if pre_existing_model:
-            # scoring path: the spectrum comes from the saved model — run
-            # the centering-only program, not the cov+eigh it would discard
-            Xc = _pca_center(X, np.int32(idf.nrows))
-        else:
-            # whole-chain program (ops/fuse.py): centering + covariance +
-            # eigh + descending reorder lowered as ONE compiled program —
-            # the eager chain here compiled ~14 single-primitive programs
-            # per run (cold-compile census).  Xc stays a device handle for
-            # projection.
-            Xc, eig_d, vec_d = _pca_cov_eig(
-                X, np.int32(idf.nrows), bf16=bf16_sweep())
+    if pre_existing_model:
+        # scoring path: the spectrum comes from the saved model — run
+        # the centering-only program, not the cov+eigh it would discard
+        Xc = _pca_center(X, np.int32(idf.nrows))
     else:
-        rowmask = (jnp.arange(idf.padded_rows) < idf.nrows)[:, None]
-        Xc = jnp.where(rowmask, X - X.mean(axis=0, where=rowmask), 0.0)
+        # whole-chain program: centering + covariance + eigh + descending
+        # reorder lowered as ONE compiled program.  Xc stays a device
+        # handle for projection.
+        Xc, eigval, eigvec = _pca_cov_eig(
+            X, np.int32(idf.nrows), bf16=bf16_sweep())
 
     if pre_existing_model:
         dfm = load_model_df(model_path, "PCA_latentFeatures")
@@ -211,16 +202,7 @@ def PCA_latentFeatures(
         k = comp.shape[0]
         V = jnp.asarray(comp.T)
     else:
-        if fused:
-            eigval, eigvec = eig_d, vec_d
-        else:
-            cov = (Xc.T @ Xc) / jnp.maximum(idf.nrows - 1, 1)
-            eigval, eigvec = jnp.linalg.eigh(cov)
-            order = jnp.argsort(eigval)[::-1]
-            eigval = eigval[order]
-            eigvec = eigvec[:, order]
-        # k selection on host from the (k,)-small spectrum — identical
-        # arithmetic in both modes so the chosen k can never differ
+        # k selection on host from the (k,)-small spectrum
         ev_h = np.asarray(eigval)
         ratio = np.cumsum(ev_h) / max(float(ev_h.sum()), 1e-30)
         k = int(np.searchsorted(ratio, explained_variance_cutoff) + 1)
@@ -237,15 +219,9 @@ def PCA_latentFeatures(
                 model_path,
                 "PCA_latentFeatures",
             )
-    if fused:
-        # one projection program; matmul columns are independent, so
-        # projecting against the k-sliced V matches slicing the full
-        # projection column-for-column bit-exactly
-        Z, in_range = _pca_project(Xc, V, np.int32(idf.nrows),
-                                   bf16=bf16_sweep())
-    else:
-        Z = Xc @ V  # (padded_rows, k)
-        in_range = jnp.arange(idf.padded_rows) < idf.nrows
+    # one projection program over the k-sliced V: (padded_rows, k)
+    Z, in_range = _pca_project(Xc, V, np.int32(idf.nrows),
+                               bf16=bf16_sweep())
     odf = idf
     for i in range(int(Z.shape[1])):
         odf = odf.with_column(

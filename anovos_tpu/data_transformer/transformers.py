@@ -25,7 +25,6 @@ import numpy as np
 import pandas as pd
 
 from anovos_tpu.data_transformer.model_io import load_model_df, save_model_df
-from anovos_tpu.ops.fuse import fuse_enabled
 from anovos_tpu.ops.histogram import digitize, masked_bincount
 from anovos_tpu.ops.quantiles import masked_quantiles
 from anovos_tpu.ops.reductions import masked_moments
@@ -38,11 +37,9 @@ logger = logging.getLogger(__name__)
 
 
 # ---------------------------------------------------------------------------
-# fused apply programs (ops/fuse.py): each transformer's eager glue chain —
-# digitize/cast, affine scale, elementwise math + finite-mask, per-column
-# impute fills — lowered as ONE program over the padded (rows, k_pad)
-# block.  ANOVOS_FUSE_BLOCKS=0 restores the eager chain at every call site;
-# the two paths are byte-identical (tests/test_fuse_blocks.py).
+# fused apply programs: each transformer's glue chain — digitize/cast,
+# affine scale, elementwise math + finite-mask, per-column impute fills —
+# lowered as ONE program over the padded (rows, k_pad) block.
 # ---------------------------------------------------------------------------
 @jax.jit
 def _bin_apply_program(X, edges):
@@ -254,18 +251,13 @@ def attribute_binning(
         [np.full((len(cols), 1), -np.inf), cutoffs, np.full((len(cols), 1), np.inf)], axis=1
     )
     edges_p = pad_lane_params(edges, X.shape[1]).astype(np.float32)
-    if fuse_enabled():
-        # digitize + 1-based cast in one program; the host edge array rides
-        # in through the jit boundary (no eager convert program)
-        bins0, bins1 = _bin_apply_program(X, edges_p)
-    else:
-        bins0 = digitize(X, jnp.asarray(edges_p))  # 0-indexed
-        bins1 = None
+    # digitize (0-indexed) + 1-based cast in one program; the host edge
+    # array rides in through the jit boundary (no convert program)
+    bins0, bins1 = _bin_apply_program(X, edges_p)
     new_cols: "OrderedDict[str, Column]" = OrderedDict()
     if bin_dtype == "numerical":
-        data = bins1 if bins1 is not None else (bins0 + 1).astype(jnp.int32)
         for i, c in enumerate(cols):
-            new_cols[c] = Column("num", data[:, i], idf.columns[c].mask, dtype_name="int")
+            new_cols[c] = Column("num", bins1[:, i], idf.columns[c].mask, dtype_name="int")
     else:
         bins_host = np.asarray(bins0)
         for i, c in enumerate(cols):
@@ -356,15 +348,9 @@ def _event_vector(idf: Table, label_col: str, event_label):
     if col.kind == "cat":
         hits = np.nonzero(col.vocab == str(event_label))[0]
         code = int(hits[0]) if len(hits) else -2
-        if fuse_enabled():
-            y = _event_vector_cat_program(col.data, np.int32(code))
-        else:
-            y = (col.data == code).astype(jnp.float32)
+        y = _event_vector_cat_program(col.data, np.int32(code))
     else:
-        if fuse_enabled():
-            y = _event_vector_num_program(col.data, np.float32(float(event_label)))
-        else:
-            y = (col.data.astype(jnp.float32) == float(event_label)).astype(jnp.float32)
+        y = _event_vector_num_program(col.data, np.float32(float(event_label)))
     return y, col.mask
 
 
@@ -460,9 +446,8 @@ def cat_to_num_unsupervised(
                 code_map[j] = mp[str(v)]
         from anovos_tpu.ops.segment import _bucket_segments, vocab_lookup
 
-        if fuse_enabled() and method_type == "label_encoding":
-            # LUT gather + null fold + validity in one program (the eager
-            # chain dispatched three programs per encoded column); the LUT
+        if method_type == "label_encoding":
+            # LUT gather + null fold + validity in one program; the LUT
             # is padded to its 2^k class so every vocab size shares one
             # compiled program per row shape (vocab_lookup discipline)
             p = _bucket_segments(len(code_map))
@@ -474,16 +459,13 @@ def cat_to_num_unsupervised(
             continue
         idx = jnp.where(col.data >= 0, vocab_lookup(code_map, col.data), -1)
         valid = col.mask & (idx >= 0)
-        if method_type == "label_encoding":
-            new_cols[c] = Column("num", jnp.where(valid, idx, 0).astype(jnp.int32), valid, dtype_name="int")
-        else:
-            k = len(mp)
-            oh = (idx[:, None] == jnp.arange(k)[None, :]).astype(jnp.int32)
-            for j in range(k):
-                name = f"{c}_{j}"
-                odf = odf.with_column(name, Column("num", oh[:, j], valid, dtype_name="int"))
-            if output_mode == "replace":
-                odf = odf.drop([c])
+        k = len(mp)
+        oh = (idx[:, None] == jnp.arange(k)[None, :]).astype(jnp.int32)
+        for j in range(k):
+            name = f"{c}_{j}"
+            odf = odf.with_column(name, Column("num", oh[:, j], valid, dtype_name="int"))
+        if output_mode == "replace":
+            odf = odf.drop([c])
     if method_type == "label_encoding":
         odf = _emit(idf, new_cols, output_mode, "_index")
     if print_impact:
@@ -652,11 +634,8 @@ def IQR_standardization(
     X, M = idf.numeric_block(cols)
     med_p = pad_lane_params(med, X.shape[1])
     iqr_p = pad_lane_params(iqr, X.shape[1], fill=1.0)
-    if fuse_enabled():
-        # one affine program; host params ride through the jit boundary
-        Z = _affine_scale_program(X, med_p.astype(np.float32), iqr_p.astype(np.float32))
-    else:
-        Z = (X - jnp.asarray(med_p)[None, :]) / jnp.asarray(iqr_p)[None, :]
+    # one affine program; host params ride through the jit boundary
+    Z = _affine_scale_program(X, med_p.astype(np.float32), iqr_p.astype(np.float32))
     new_cols = OrderedDict(
         (c, Column("num", Z[:, i].astype(jnp.float32), idf.columns[c].mask, dtype_name="double"))
         for i, c in enumerate(cols)
@@ -793,7 +772,6 @@ def imputation_MMM(
                 "imputation_MMM",
             )
 
-    fused = fuse_enabled()
     new_cols: "OrderedDict[str, Column]" = OrderedDict()
     for c in cols:
         if c not in fills:
@@ -804,21 +782,15 @@ def imputation_MMM(
             fv = float(v)
             if np.isnan(fv):
                 continue
-            if fused:
-                # fill + cast in one shared program per (shape, dtype)
-                if col.data.dtype == jnp.int32 and float(fv).is_integer():
-                    data = _impute_num_int_program(col.data, col.mask,
-                                                   np.float32(fv))
-                else:
-                    data = _impute_num_program(col.data, col.mask,
+            # fill + cast in one shared program per (shape, dtype)
+            if col.data.dtype == jnp.int32 and float(fv).is_integer():
+                data = _impute_num_int_program(col.data, col.mask,
                                                np.float32(fv))
-                rv = _row_valid_program(col.mask, np.int32(idf.nrows))
-                new_cols[c] = Column("num", data, rv, dtype_name=col.dtype_name)
             else:
-                data = jnp.where(col.mask, col.data.astype(jnp.float32), fv)
-                if col.data.dtype == jnp.int32 and float(fv).is_integer():
-                    data = data.astype(jnp.int32)
-                new_cols[c] = Column("num", data, jnp.ones_like(col.mask) & (jnp.arange(col.padded_len) < idf.nrows), dtype_name=col.dtype_name)
+                data = _impute_num_program(col.data, col.mask,
+                                           np.float32(fv))
+            rv = _row_valid_program(col.mask, np.int32(idf.nrows))
+            new_cols[c] = Column("num", data, rv, dtype_name=col.dtype_name)
         else:
             if v is None:
                 continue
@@ -828,18 +800,11 @@ def imputation_MMM(
                 code = len(vocab) - 1
             else:
                 vocab, code = col.vocab, int(hits[0])
-            if fused:
-                data, rv = _impute_cat_program(col.data, col.mask,
-                                               np.int32(code),
-                                               np.int32(idf.nrows))
-                new_cols[c] = Column("cat", data, rv, vocab=vocab,
-                                     dtype_name="string")
-            else:
-                valid = col.mask & (col.data >= 0)
-                data = jnp.where(valid, col.data, code).astype(jnp.int32)
-                new_cols[c] = Column(
-                    "cat", data, jnp.arange(col.padded_len) < idf.nrows, vocab=vocab, dtype_name="string"
-                )
+            data, rv = _impute_cat_program(col.data, col.mask,
+                                           np.int32(code),
+                                           np.int32(idf.nrows))
+            new_cols[c] = Column("cat", data, rv, vocab=vocab,
+                                 dtype_name="string")
     odf = _emit(idf, new_cols, output_mode, "_imputed")
     if print_impact:
         logger.info(f"imputed ({method_type}): {list(new_cols)}")
@@ -900,29 +865,19 @@ def feature_transformation(
     if method_type in _MATH_OPS_N:
         if N is None:
             raise TypeError(f"N required for method_type {method_type}")
-        fn = lambda x: _MATH_OPS_N[method_type](x, N)
         postfix = "_" + method_type[:-1] + str(N)
     elif method_type in _MATH_OPS:
-        fn = _MATH_OPS[method_type]
         postfix = "_" + method_type
     else:
         raise TypeError("Invalid input for method_type")
     X, M = idf.numeric_block(cols)
-    if fuse_enabled():
-        # math op + finite-mask + zero-fill in one program over the block
-        Yc, ok = _mathop_apply_program(
-            X, M, method_type, n=N if method_type in _MATH_OPS_N else None)
-        new_cols = OrderedDict(
-            (c, Column("num", Yc[:, i], ok[:, i], dtype_name="double"))
-            for i, c in enumerate(cols)
-        )
-    else:
-        Y = fn(X)
-        ok = M & jnp.isfinite(Y)
-        new_cols = OrderedDict(
-            (c, Column("num", jnp.where(ok[:, i], Y[:, i], 0.0).astype(jnp.float32), ok[:, i], dtype_name="double"))
-            for i, c in enumerate(cols)
-        )
+    # math op + finite-mask + zero-fill in one program over the block
+    Yc, ok = _mathop_apply_program(
+        X, M, method_type, n=N if method_type in _MATH_OPS_N else None)
+    new_cols = OrderedDict(
+        (c, Column("num", Yc[:, i], ok[:, i], dtype_name="double"))
+        for i, c in enumerate(cols)
+    )
     odf = idf
     for name, col in new_cols.items():
         odf = odf.with_column(name if output_mode == "replace" else name + postfix, col)

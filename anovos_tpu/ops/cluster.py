@@ -387,22 +387,18 @@ def dbscan_host_grid_multi(
     # (measured: distance-sorting the edges to make each eps a prefix slice
     # LOSES — the shuffled edge order is cache-hostile for the per-combo
     # bincount/remap gathers; the row-major order from nonzero wins)
-    from anovos_tpu.ops.fuse import fuse_enabled
-
-    fused = fuse_enabled()
     out = np.full((len(eps_list), len(min_samples_list), n), -1, np.int64)
     # T-nearest border-adoption prefix, built ONCE for the WHOLE grid over
     # the union border set (non-core at the smallest eps and largest ms ⊇
     # every combo's border set, since neighbor counts are monotone in eps):
     # each (eps, ms) then adopts via a (rows, T) core-gather + argmax
-    # instead of re-gathering a (rows, n) distance block — the per-combo
-    # gather/where/argmin was ~2/3 of the grid's host wall.  The prefix is
+    # instead of re-gathering a (rows, n) distance block.  The prefix is
     # the T nearest neighbors by RAW distance, sorted by (d², index), so
     # the first in-eps core in a row's prefix IS the exact argmin-with-
     # lowest-index owner whenever its distance beats the prefix max (ties
     # at the boundary, or a truncated prefix, fall back to the full row).
     nn_part = nn_d2 = nn_pmax = bi_pos = None
-    if fused and len(min_samples_list):
+    if len(min_samples_list):
         emin = min(eps_list)
         wmin = d2e <= emin * emin
         cmin = (np.bincount(ei[wmin], minlength=n)
@@ -463,7 +459,7 @@ def dbscan_host_grid_multi(
                 _, comp = connected_components(g, directed=True, connection="weak")
             out[a, b, ci] = comp
             bi = np.nonzero(~core)[0]
-            if len(bi) and nn_part is not None:
+            if len(bi):  # ⊆ the union border set, so the prefix exists
                 rows_u = bi_pos[bi]  # positions in the union border set
                 pref = nn_part[rows_u]  # (m, T) candidate indices
                 cand = core[pref] & (nn_d2[rows_u] <= eps * eps)
@@ -489,15 +485,6 @@ def dbscan_host_grid_multi(
                     j = np.argmin(Db, axis=1)
                     hit = np.isfinite(Db[np.arange(len(bif)), j])
                     out[a, b, bif[hit]] = comp[remap[j[hit]]]
-            elif len(bi):
-                # contiguous ROW gather + column mask beats the (bi, ci)
-                # double-fancy gather ~5×; ci is ascending so the argmin
-                # tie-winner is identical
-                D2b = D2[bi]
-                Db = np.where(core[None, :] & (D2b <= eps * eps), D2b, np.inf)
-                j = np.argmin(Db, axis=1)
-                hit = np.isfinite(Db[np.arange(len(bi)), j])
-                out[a, b, bi[hit]] = comp[remap[j[hit]]]
     return out
 
 

@@ -87,10 +87,9 @@ def _masked_cov(X: jax.Array, M: jax.Array, bf16: bool = False) -> jax.Array:
 @timed("ops.masked_corr_cc")
 def masked_corr_cc(X: jax.Array, M: jax.Array, k_live: int) -> jax.Array:
     """Complete-case Pearson correlation over the LIVE lanes of a
-    column-bucketed block, fused: the per-call eager chain at the
-    association_evaluator call site (live-lane row count, complete-case
-    scalar compare, mask combine) compiled three single-primitive programs
-    per run — here it folds into the correlation program itself.  The live
+    column-bucketed block, fused: the live-lane row count, complete-case
+    scalar compare and mask combine of the association_evaluator call
+    sites fold into the correlation program itself.  The live
     count rides in as a device scalar so the program stays keyed on the
     bucketed shape."""
     import numpy as np

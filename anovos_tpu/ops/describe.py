@@ -396,15 +396,10 @@ def _table_describe(idf: Table, num_cols: List[str], cat_cols: List[str],
         if large:
             # codes are just ints: the sort-based numeric kernel yields
             # count/nunique/mode directly, no per-vocab lanes
-            from anovos_tpu.ops.fuse import fuse_enabled
             from anovos_tpu.ops.segment import cat_valid_mask
 
-            if fuse_enabled():
-                lg_masks = [cat_valid_mask(idf.columns[c].data, idf.columns[c].mask)
-                            for c in large]
-            else:
-                lg_masks = [idf.columns[c].mask & (idf.columns[c].data >= 0)
-                            for c in large]
+            lg_masks = [cat_valid_mask(idf.columns[c].data, idf.columns[c].mask)
+                        for c in large]
             C, Mc = stack_padded(
                 [idf.columns[c].data for c in large],
                 lg_masks,

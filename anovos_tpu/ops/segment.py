@@ -74,9 +74,8 @@ def bucket_segments_pow2(n: int) -> int:
 @jax.jit
 def cat_valid_mask(codes: jax.Array, M: jax.Array) -> jax.Array:
     """mask & (code >= 0) — THE categorical null rule as one shared
-    program.  The eager per-column compare/and chain spelled one
-    greater_equal + one bitwise_and program at every stacking call site
-    (stats mask prep, varclus, large-cat describe) — cold-compile census."""
+    program for every stacking call site (stats mask prep, varclus,
+    large-cat describe)."""
     return M & (codes >= 0)
 
 

@@ -15,9 +15,8 @@ if not _ON_TPU:
 import jax  # noqa: E402
 
 # init_runtime turns JAX's persistent compilation cache on by default; the
-# tests that count backend compiles (test_compile_census, test_shape_buckets,
-# test_fuse_blocks) must see real compiles on every run, so the session runs
-# with it off.  Set before the first compile: JAX decides once per process
+# tests that count backend compiles (test_compile_census, test_shape_buckets)
+# must see real compiles on every run, so the session runs with it off.  Set before the first compile: JAX decides once per process
 # (the cache-placement tests reset that decision for themselves).
 jax.config.update("jax_enable_compilation_cache", False)
 

@@ -128,8 +128,9 @@ def test_cli_rejects_censusless_manifest(tmp_path):
 # with column+row bucketing AND whole-block fusion in place (fresh process;
 # in-suite runs reuse the session's jit cache and land lower).  A per-call
 # jit in any touched op adds one program per invocation and blows through
-# this fast.  Tightened 45 → 35 with the round-9 fusion layer (ops/fuse.py:
-# the eager glue chains that used to pad the budget are gone).
+# this fast.  35, not 45: the blocks' glue runs as fused programs (the
+# `_*_program` functions of data_analyzer/, data_transformer/ and
+# data_report/), so no single-primitive chain pads the budget.
 GATE_MAX_PROGRAMS = 35
 # total-compile ceiling (compiles ≈ programs on a fresh process; in-suite
 # reruns land near zero) — the second axis the census CLI gates: a warm-path

@@ -103,6 +103,30 @@ def test_pca_latentFeatures(latent_df):
     assert v.iloc[0] >= v.iloc[-1]  # components ordered by variance
 
 
+def test_pca_matches_float64_reference(latent_df):
+    """PCA_latentFeatures against float64 numpy (centre, covariance over
+    n-1, eigh, descending), compared free of each eigenvector's sign: the
+    chosen k exactly, the kept spectrum relatively, |latent column|
+    absolutely.  Read on this fixture: 1.5e-7 and 9.5e-7 (values up to 6.7,
+    kept eigenvalues 3.51 and 1.32, the next 0.0025); held to 1e-3."""
+    cutoff = 0.95
+    out = PCA_latentFeatures(Table.from_pandas(latent_df), explained_variance_cutoff=cutoff)
+    got = out.to_pandas()
+    latents = [c for c in got.columns if c.startswith("latent_")]
+
+    X = latent_df.to_numpy().astype(np.float32).astype(np.float64)
+    Xc = X - X.mean(axis=0)
+    w, v = np.linalg.eigh(Xc.T @ Xc / (len(X) - 1))
+    order = np.argsort(w)[::-1]
+    w, v = w[order], v[:, order]
+    k = int(np.searchsorted(np.cumsum(w) / w.sum(), cutoff) + 1)
+
+    assert latents == [f"latent_{i}" for i in range(k)]
+    Z = got[latents].to_numpy().astype(np.float64)
+    np.testing.assert_allclose(Z.var(axis=0, ddof=1), w[:k], rtol=1e-3)
+    np.testing.assert_allclose(np.abs(Z), np.abs(Xc @ v[:, :k]), atol=1e-3)
+
+
 def test_pca_model_roundtrip(latent_df, tmp_path):
     t = Table.from_pandas(latent_df)
     mp = str(tmp_path / "pca")

@@ -45,7 +45,6 @@ def test_knobs_registered_in_fingerprint():
     from anovos_tpu.cache.fingerprint import KNOWN_ENV_KNOBS
 
     assert "ANOVOS_TPU_BF16" in KNOWN_ENV_KNOBS
-    assert "ANOVOS_FUSE_BLOCKS" in KNOWN_ENV_KNOBS
 
 
 def test_corr_bf16_within_band(bf16_env):

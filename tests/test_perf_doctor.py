@@ -14,9 +14,9 @@ Covers the ISSUE-15 acceptance surface:
   committed BENCH_r04 -> r05 ledger entries);
 * the flight recorder's live doctor summary ("slow vs the last clean
   run" on /statusz);
-* the PR 9 fusion transition: a fused vs ``ANOVOS_FUSE_BLOCKS=0`` run of
-  the same config must name the fused program-set change and the
-  dispatch_s drop in its top-3 attributions, deterministically.
+* a program-set change that comes with a dispatch_s drop and a flipped
+  knob: the first two lead the top-3 attributions, the knob is the
+  informational tail, deterministically.
 """
 
 import json
@@ -194,8 +194,49 @@ def test_program_set_diff_names_nodes_and_wall():
     assert "nodes touched: a" in prog["detail"]
 
 
+def test_program_set_change_and_dispatch_drop_lead_the_top3():
+    """Glue chains folded into one program: the program-set change is
+    named, the dispatch_s drop is a NEGATIVE phase attribution beside it in
+    the top three, and the knob that differs is the informational tail."""
+    env_b = {"code_version": "1.0", "knobs": {"ANOVOS_SHAPE_BUCKETS": "0"},
+             "env_fingerprint": "e1", "dataset_fingerprint": "d1"}
+    env_c = {"code_version": "1.0", "knobs": {"ANOVOS_SHAPE_BUCKETS": "1"},
+             "env_fingerprint": "e2", "dataset_fingerprint": "d1"}
+    base = _man(
+        {"a": _node(2.0), "b": _node(1.0)},
+        devprof={"a": _dev(2.0, dispatch=1.2), "b": _dev(1.0, dispatch=0.4)},
+        census={"programs_distinct": 3, "programs": [
+            {"program": "jit(eager_and)", "count": 4, "seconds": 0.5, "nodes": ["a"]},
+            {"program": "jit(eager_cast)", "count": 4, "seconds": 0.5, "nodes": ["a", "b"]},
+            {"program": "jit(shared)", "count": 1, "seconds": 1.0, "nodes": ["a"]},
+        ]}, env=env_b)
+    cand = _man(
+        {"a": _node(1.1), "b": _node(0.8)},
+        devprof={"a": _dev(1.1, dispatch=0.3), "b": _dev(0.8, dispatch=0.2)},
+        census={"programs_distinct": 2, "programs": [
+            {"program": "jit(_glue_program)", "count": 1, "seconds": 0.6, "nodes": ["a", "b"]},
+            {"program": "jit(shared)", "count": 1, "seconds": 1.0, "nodes": ["a"]},
+        ]}, env=env_c)
+    d = diffing.diff_manifests(base, cand)
+    assert diffing.validate_diagnosis(d) == []
+    assert diffing.canonical(d) == diffing.canonical(diffing.diff_manifests(base, cand))
+    top3 = d["attributions"][:3]
+    assert ("programs", "program_set") in _kinds(d, 3), d["attributions"][:6]
+    prog = next(a for a in top3 if a["kind"] == "programs")
+    assert prog["detail"].startswith("program set moved"), prog
+    assert d["programs"]["new"] == ["jit(_glue_program)"]
+    assert d["programs"]["retired"] == ["jit(eager_and)", "jit(eager_cast)"]
+    disp = next((a for a in top3
+                 if a["kind"] == "phase" and a["subject"] == "dispatch_s"), None)
+    assert disp is not None, d["attributions"][:6]
+    assert disp["delta_s"] == pytest.approx(-1.1)
+    env_attrs = [a for a in d["attributions"] if a["kind"] == "env"]
+    assert [a["subject"] for a in env_attrs] == ["ANOVOS_SHAPE_BUCKETS"]
+    assert env_attrs[0]["severity"] == "info"
+
+
 def test_cache_hit_set_diff_names_moved_fingerprint_input():
-    env_b = {"code_version": "1.0", "knobs": {"ANOVOS_FUSE_BLOCKS": "1"},
+    env_b = {"code_version": "1.0", "knobs": {"ANOVOS_SHAPE_BUCKETS": "1"},
              "env_fingerprint": "e1", "dataset_fingerprint": "d1"}
     env_c = {"code_version": "1.0", "knobs": {},
              "env_fingerprint": "e2", "dataset_fingerprint": "d1"}
@@ -207,12 +248,12 @@ def test_cache_hit_set_diff_names_moved_fingerprint_input():
                 env=env_c)
     d = diffing.diff_manifests(base, cand)
     assert d["cache"]["re_executed"] == ["a"]
-    assert any("ANOVOS_FUSE_BLOCKS" in m for m in d["cache"]["moved_inputs"])
+    assert any("ANOVOS_SHAPE_BUCKETS" in m for m in d["cache"]["moved_inputs"])
     cache_attr = next(a for a in d["attributions"] if a["kind"] == "cache")
     assert "re-executed" in cache_attr["detail"]
-    assert "ANOVOS_FUSE_BLOCKS" in cache_attr["detail"]
+    assert "ANOVOS_SHAPE_BUCKETS" in cache_attr["detail"]
     env_attr = next(a for a in d["attributions"] if a["kind"] == "env")
-    assert env_attr["subject"] == "ANOVOS_FUSE_BLOCKS"
+    assert env_attr["subject"] == "ANOVOS_SHAPE_BUCKETS"
     assert env_attr["severity"] == "info"
 
 
@@ -412,165 +453,3 @@ def test_run_diff_tab_env_gated_and_renders_ranked_table(tmp_path, monkeypatch):
                    str(base_dir / "obs" / "run_manifest.json"))
     html3 = run_diff_gen(str(master))
     assert "per-node movement" in html3 and "renamed" in html3
-
-
-# -- the PR 9 fusion transition (acceptance) ------------------------------
-
-_FUSION_CHILD = r"""
-import json, os, pathlib, sys
-import numpy as np, pandas as pd, yaml
-os.environ["JAX_PLATFORMS"] = "cpu"
-# sequential on purpose (both legs): concurrent overlap books cross-node
-# device contention into dispatch walls, which can flip the fused
-# dispatch WIN into apparent noise — the pair must measure per-op cost,
-# not scheduling interference
-os.environ["ANOVOS_TPU_EXECUTOR"] = "sequential"
-import jax
-jax.config.update("jax_platforms", "cpu")
-import logging
-logging.basicConfig(level=logging.ERROR)
-
-data_dir = sys.argv[1]
-workdir = sys.argv[2]
-
-cfg = {
-    "input_dataset": {"read_dataset": {"file_path": data_dir, "file_type": "parquet"}},
-    "anovos_basic_report": {"basic_report": False},
-    "stats_generator": {
-        "metric": ["global_summary", "measures_of_counts",
-                   "measures_of_centralTendency", "measures_of_cardinality"],
-        "metric_args": {"list_of_cols": "all", "drop_cols": ["ifa"]}},
-    "quality_checker": {
-        "invalidEntries_detection": {"list_of_cols": "all", "drop_cols": ["ifa"],
-                                     "treatment": True, "output_mode": "replace"},
-        "outlier_detection": {"list_of_cols": "all", "drop_cols": ["ifa", "income"],
-                              "detection_side": "upper",
-                              "detection_configs": {"pctile_lower": 0.05, "pctile_upper": 0.9,
-                                                    "stdev_upper": 3.0, "IQR_upper": 1.5,
-                                                    "min_validation": 2},
-                              "treatment": True, "treatment_method": "value_replacement",
-                              "output_mode": "replace"},
-        "nullColumns_detection": {"list_of_cols": "all", "drop_cols": ["ifa", "income"],
-                                  "treatment": True, "treatment_method": "MMM",
-                                  "treatment_configs": {"method_type": "median",
-                                                        "output_mode": "replace"}},
-    },
-    "association_evaluator": {
-        "correlation_matrix": {"list_of_cols": "all", "drop_cols": ["ifa"]},
-        "IV_calculation": {"list_of_cols": "all", "drop_cols": "ifa", "label_col": "income",
-                           "event_label": ">50K",
-                           "encoding_configs": {"bin_method": "equal_frequency",
-                                                "bin_size": 10, "monotonicity_check": 0}},
-        "IG_calculation": {"list_of_cols": "all", "drop_cols": "ifa", "label_col": "income",
-                           "event_label": ">50K",
-                           "encoding_configs": {"bin_method": "equal_frequency",
-                                                "bin_size": 10, "monotonicity_check": 0}},
-    },
-    "drift_detector": {"drift_statistics": {
-        "configs": {"list_of_cols": "all", "drop_cols": ["ifa", "income"],
-                    "method_type": "all", "threshold": 0.1, "bin_method": "equal_range",
-                    "bin_size": 10},
-        "source_dataset": {"read_dataset": {"file_path": data_dir, "file_type": "parquet"}}}},
-    "transformers": {
-        "numerical_mathops": {"feature_transformation": {"list_of_cols": "all",
-                                                         "drop_cols": [], "method_type": "sqrt"}},
-        "numerical_binning": {"attribute_binning": {"list_of_cols": "all", "drop_cols": [],
-                                                    "method_type": "equal_frequency",
-                                                    "bin_size": 10, "bin_dtype": "numerical"}},
-        "numerical_rescaling": {"IQR_standardization": {"list_of_cols": "all"}},
-    },
-    "write_main": {"file_path": "output", "file_type": "parquet",
-                   "file_configs": {"mode": "overwrite"}},
-    "write_stats": {"file_path": "stats", "file_type": "parquet",
-                    "file_configs": {"mode": "overwrite"}},
-}
-os.makedirs(workdir, exist_ok=True)
-cfg_path = os.path.join(workdir, "cfg.yaml")
-with open(cfg_path, "w") as f:
-    yaml.safe_dump(cfg, f, sort_keys=False)
-from anovos_tpu import workflow
-os.chdir(workdir)
-workflow.run(cfg_path, "local")
-print("MANIFEST=" + workflow.LAST_MANIFEST_PATH)
-"""
-
-
-def _fusion_dataset(tmp_path):
-    """Large enough that the eager-vs-fused dispatch gap is SIGNAL, not
-    threshold noise: at ~3k rows the whole unfused dispatch wall is ~3 ms
-    and the fused delta hovers at the 1 ms noise floor; at 120k rows x 8
-    numeric columns the eager chains cost ~18 ms of dispatch vs ~4 ms of
-    transfer/drain-probe jitter (4x margin, measured), and the children
-    still run in ~10 s each."""
-    n = 120000
-    import numpy as np
-    import pandas as pd
-
-    g = np.random.default_rng(7)
-    df = pd.DataFrame({
-        "ifa": [f"id{i:06d}" for i in range(n)],
-        "age": g.normal(40, 12, n).round(0).clip(17, 90),
-        "fnlwgt": g.normal(1.9e5, 9e4, n).round(0).clip(1e4, 9e5),
-        "hours": g.normal(40, 10, n).round(0).clip(1, 99),
-        "gain": np.where(g.random(n) < 0.9, 0.0, g.exponential(9000, n).round(0)),
-        "loss": np.where(g.random(n) < 0.95, 0.0, g.exponential(1800, n).round(0)),
-        "score_a": g.normal(0, 1, n).round(4),
-        "score_b": g.lognormal(1.0, 0.6, n).round(4),
-        "tenure": g.integers(0, 400, n).astype(float),
-        "workclass": g.choice(["Private", "Gov", "Self"], n),
-        "education": g.choice(["HS", "College", "Masters", "PhD"], n),
-        "income": g.choice(["<=50K", ">50K"], n, p=[0.75, 0.25]),
-    })
-    for c in ("age", "hours", "score_a", "workclass"):
-        df.loc[g.random(n) < 0.03, c] = np.nan
-    data_dir = tmp_path / "data"
-    data_dir.mkdir()
-    df.to_parquet(data_dir / "part-00000.parquet", index=False)
-    return str(data_dir)
-
-
-def test_fusion_transition_named_in_top3(tmp_path):
-    """ISSUE-15 acceptance: doctoring an unfused (ANOVOS_FUSE_BLOCKS=0)
-    baseline against a fused candidate of the SAME config names the fused
-    program-set change AND the dispatch_s drop in its top-3 attributions,
-    deterministically (byte-identical diagnosis across repeated diffs)."""
-    data_dir = _fusion_dataset(tmp_path)
-    manifests = {}
-    for mode in ("0", "1"):
-        env = {**os.environ, "ANOVOS_FUSE_BLOCKS": mode, "JAX_PLATFORMS": "cpu"}
-        env.pop("XLA_FLAGS", None)
-        env.pop("ANOVOS_TPU_CACHE", None)
-        workdir = tmp_path / f"run_{mode}"
-        r = subprocess.run(
-            [sys.executable, "-c", _FUSION_CHILD, data_dir, str(workdir)],
-            capture_output=True, text=True, env=env, timeout=600, cwd=REPO)
-        assert r.returncode == 0, r.stderr[-4000:]
-        lines = [ln for ln in r.stdout.splitlines() if ln.startswith("MANIFEST=")]
-        assert lines, r.stdout[-2000:]
-        with open(lines[-1][len("MANIFEST="):]) as f:
-            manifests[mode] = json.load(f)
-
-    d = diffing.diff_manifests(manifests["0"], manifests["1"],
-                               baseline_label="unfused", candidate_label="fused")
-    assert diffing.validate_diagnosis(d) == []
-    # deterministic: diffing the same pair again is byte-identical
-    d2 = diffing.diff_manifests(manifests["0"], manifests["1"],
-                                baseline_label="unfused", candidate_label="fused")
-    assert diffing.canonical(d) == diffing.canonical(d2)
-
-    top3 = d["attributions"][:3]
-    kinds = [(a["kind"], a["subject"]) for a in top3]
-    # the fused program-set change is NAMED, not guessed
-    assert ("programs", "program_set") in kinds, d["attributions"][:6]
-    prog = next(a for a in top3 if a["kind"] == "programs")
-    assert prog["detail"].startswith("program set moved"), prog
-    assert d["programs"]["new"] and d["programs"]["retired"]
-    # ...and the dispatch_s drop is in the top-3, negative (fewer eager
-    # single-primitive dispatches between the big kernels)
-    disp = next((a for a in top3
-                 if a["kind"] == "phase" and a["subject"] == "dispatch_s"), None)
-    assert disp is not None, d["attributions"][:6]
-    assert disp["delta_s"] < 0, disp
-    # the flipped knob is named too (informational tail)
-    env_attrs = [a for a in d["attributions"] if a["kind"] == "env"]
-    assert any(a["subject"] == "ANOVOS_FUSE_BLOCKS" for a in env_attrs)

@@ -235,6 +235,20 @@ def test_shared_utils_reshapes():
     assert set(flat_tbl["key"]) == {"x"}
 
 
+def _canon(l):
+    """Cluster ids renumbered by first appearance; noise stays -1."""
+    out = np.full(len(l), -1)
+    seen, nxt = {}, 0
+    for i, v in enumerate(l):
+        if v < 0:
+            continue
+        if v not in seen:
+            seen[v] = nxt
+            nxt += 1
+        out[i] = seen[v]
+    return out
+
+
 def test_dbscan_grid_matches_per_combo_fit():
     from anovos_tpu.ops.cluster import dbscan_fit, dbscan_grid, neighbor_counts
 
@@ -247,23 +261,99 @@ def test_dbscan_grid_matches_per_combo_fit():
     counts = neighbor_counts(X, 0.3)
     grid = dbscan_grid(X, 0.3, [15, 40, 90], counts=counts)
 
-    def canon(l):
-        out = np.full(len(l), -1)
-        seen, nxt = {}, 0
-        for i, v in enumerate(l):
-            if v < 0:
-                continue
-            if v not in seen:
-                seen[v] = nxt
-                nxt += 1
-            out[i] = seen[v]
-        return out
-
     for b, ms in enumerate([15, 40, 90]):
         ref = dbscan_fit(X, 0.3, ms, counts=counts)
         assert ((ref < 0) == (grid[b] < 0)).all()
-        assert (canon(ref) == canon(grid[b])).all()
+        assert (_canon(ref) == _canon(grid[b])).all()
     assert len(set(grid[0][grid[0] >= 0])) == 2  # the two blobs separate
+
+
+def _dbscan_on_d2(D2, eps, ms):
+    """Plain numpy DBSCAN over a squared-distance matrix: core = within-eps
+    count (self included) >= ms, clusters = components of the core-core
+    graph by min-label propagation, a border point takes its nearest
+    within-eps core's cluster, ties to the lowest index; -1 is noise."""
+    n = len(D2)
+    within = D2 <= eps * eps
+    within[np.arange(n), np.arange(n)] = True
+    core = within.sum(axis=1) >= ms
+    lab = np.full(n, -1, np.int64)
+    ci = np.nonzero(core)[0]
+    if not len(ci):
+        return lab
+    A = within[np.ix_(ci, ci)]
+    comp = np.arange(len(ci))
+    while True:
+        new = np.where(A, comp[None, :], len(ci)).min(axis=1)
+        new = new[new]  # pointer jump: a label is the rank of a core point
+        if (new == comp).all():
+            break
+        comp = new
+    lab[ci] = comp
+    bi = np.nonzero(~core)[0]
+    Db = np.where(within[np.ix_(bi, ci)], D2[np.ix_(bi, ci)], np.inf)
+    j = Db.argmin(axis=1)
+    hit = np.isfinite(Db[np.arange(len(bi)), j])
+    lab[bi[hit]] = comp[j[hit]]
+    return lab
+
+
+def _blobs_in_noise(g):
+    return np.concatenate([
+        g.normal((0, 0), 0.2, (700, 2)),
+        g.normal((3, 3), 0.25, (700, 2)),
+        g.uniform(-6, 6, (600, 2)),
+    ])
+
+
+def _satellite(g):
+    # 100 tight points one eps away from a 400-point core: each sees only
+    # part of the core, so it is a border point whose nearest neighbours
+    # are all border points too
+    return np.concatenate([
+        g.normal((0, 0), 0.02, (400, 2)),
+        g.normal((1, 0), 0.02, (100, 2)),
+    ])
+
+
+@pytest.mark.parametrize(
+    "table, eps_l, ms_l",
+    [
+        (_blobs_in_noise, [0.3, 0.4, 0.5], [5, 15, 40]),
+        (_blobs_in_noise, [0.05], [2, 3]),
+        (_blobs_in_noise, [1.5], [300, 900]),
+        (_satellite, [1.0], [420]),
+    ],
+    ids=["blobs", "sparse-cores", "dense-eps", "prefix-without-core"],
+)
+def test_dbscan_host_grid_matches_numpy_dbscan(table, eps_l, ms_l):
+    """Every (eps, min_samples) of the host grid — shared edge list, native
+    union-find, nearest-neighbour border prefix — against an independent
+    DBSCAN on the same distances: from many small clusters over no core
+    point at all to border points whose prefix holds no core, which take
+    the full-row adoption."""
+    import jax
+    import jax.numpy as jnp
+
+    from anovos_tpu.ops.cluster import dbscan_host_grid_multi, pairwise_d2
+
+    pts = table(np.random.default_rng(23)).astype(np.float32)
+    Xc = pts - pts.mean(axis=0, keepdims=True)
+    D2 = np.asarray(jax.device_get(pairwise_d2(jnp.asarray(Xc))))
+    out = dbscan_host_grid_multi(D2, eps_l, ms_l)
+    assert out.shape == (len(eps_l), len(ms_l), len(pts))
+    for a, eps in enumerate(eps_l):
+        for b, ms in enumerate(ms_l):
+            ref = _dbscan_on_d2(D2, eps, ms)
+            np.testing.assert_array_equal(out[a, b] < 0, ref < 0)
+            np.testing.assert_array_equal(_canon(out[a, b]), _canon(ref))
+    if table is _satellite:
+        # the case is what it says: adopted points among whose 64 nearest
+        # (the prefix length) there is no core point
+        core = (D2 <= eps_l[0] ** 2).sum(axis=1) >= ms_l[0]
+        adopted = np.nonzero(~core & (ref >= 0))[0]
+        nearest = np.argsort(D2[adopted], axis=1, kind="stable")[:, :64]
+        assert len(adopted) and not core[nearest].any(axis=1).all()
 
 
 def test_kmeans_iters_budget():
