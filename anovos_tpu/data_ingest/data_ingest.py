@@ -31,7 +31,7 @@ import pyarrow.csv as pacsv
 from anovos_tpu.data_ingest import avro_io
 from anovos_tpu.data_ingest import guard
 from anovos_tpu.shared.runtime import get_runtime
-from anovos_tpu.shared.table import Column, Table, _host_to_column, _pad_to
+from anovos_tpu.shared.table import Column, Table, _host_to_column, _pad_to, host_table_frame
 from anovos_tpu.shared.utils import ends_with, pairwise_reduce, parse_cols
 
 logger = logging.getLogger(__name__)
@@ -309,7 +309,7 @@ def _assemble_frames(frames: List, cfg: dict, pol) -> pd.DataFrame:
 
 
 def write_dataset(
-    idf: Table,
+    idf: Union[Table, pd.DataFrame],
     file_path: str,
     file_type: str,
     file_configs: Optional[dict] = None,
@@ -320,19 +320,38 @@ def write_dataset(
     ``repartition`` in file_configs sets the number of part files; ``mode``
     ∈ {overwrite, append, error}.  Other keys (header/delimiter) map to the
     writers.
+
+    ``idf`` is on the device or on the host, and its type says which.  A
+    ``Table`` is fetched (``Table.to_pandas``: one ``device_get`` an array,
+    under a ``d2h`` transfer bracket).  A pandas frame (a stats table a node
+    computed on the host) is written from where it is:
+    ``host_table_frame`` gives the frame that ``Table.from_pandas`` +
+    ``to_pandas`` would, so the part files have the bytes they had when the
+    frame went through the device, and the write touches no device, books
+    no transfer and opens no ``ingest/*`` span; it puts ``host_frame=1`` on
+    the ``write:<key>`` span it runs under.
     """
     cfg = dict(file_configs or {})
     mode = cfg.pop("mode", "error")
     repartition = int(cfg.pop("repartition", 1) or 1)
+    on_host = isinstance(idf, pd.DataFrame)
     if column_order:
-        idf = idf.select(column_order)
+        idf = idf[list(column_order)] if on_host else idf.select(column_order)
     if os.path.exists(file_path):
         if mode == "overwrite":
             shutil.rmtree(file_path) if os.path.isdir(file_path) else os.remove(file_path)
         elif mode == "error":
             raise FileExistsError(f"{file_path} exists (mode=error)")
     os.makedirs(file_path, exist_ok=True)
-    df = idf.to_pandas()
+    if on_host:
+        from anovos_tpu.obs import get_tracer
+
+        df = host_table_frame(idf)
+        write_span = get_tracer().enclosing("artifact")
+        if write_span is not None:
+            write_span.add(host_frame=1)
+    else:
+        df = idf.to_pandas()
     parts = np.array_split(np.arange(len(df)), max(repartition, 1))
     written: List[str] = []  # THIS call's files (append mode must not re-book pre-existing parts)
     for i, part_idx in enumerate(parts):

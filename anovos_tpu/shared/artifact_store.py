@@ -206,6 +206,14 @@ class AsyncArtifactWriter:
     ``artifact_writes_total`` / ``artifact_write_seconds`` into the process
     metrics registry; ``wait``/``drain`` span the barrier time consumers
     actually blocked.
+
+    A write of a pandas frame (``write_dataset`` of a stats table) is host
+    and disk work only: no ``device_put``, no ``device_get``, no transfer
+    booked and no ``ingest/*`` span from the writer thread.  It marks its
+    ``write:<key>`` span ``host_frame=1``, and ``artifact:drain`` counts
+    those among its ``pending`` as ``host_frames``, so a manifest says how
+    many of a pass's writes never touched the device.  A write of a
+    ``Table`` fetches it on the writer thread, under ``d2h`` brackets.
     """
 
     def __init__(self, workers: int = 2, sync: bool = False):
@@ -233,22 +241,24 @@ class AsyncArtifactWriter:
         """Run one write inside its span + metrics booking (the writer
         thread's lane in the Chrome trace shows exactly what it wrote).
         ``recorder`` re-binds the SUBMITTING node's cache capture on this
-        writer thread, so queued writes stay attributed to their node."""
+        writer thread, so queued writes stay attributed to their node.
+        Returns the span's ``host_frame`` count (1 where the write was of a
+        frame on the host, else 0), which ``drain`` adds up."""
         from anovos_tpu.cache import capture
         from anovos_tpu.obs import get_metrics, get_tracer
 
         import time as _time
 
         t0 = _time.perf_counter()
-        with get_tracer().span(f"write:{key}", cat="artifact", key=key):
+        with get_tracer().span(f"write:{key}", cat="artifact", key=key) as sp:
             with capture.recording(recorder):
-                out = fn(*args, **kwargs)
+                fn(*args, **kwargs)
         reg = get_metrics()
         reg.counter("artifact_writes_total", "artifact writes queued+completed"
                     ).inc(key=key)
         reg.histogram("artifact_write_seconds", "one artifact write's wall time"
                       ).observe(_time.perf_counter() - t0, key=key)
-        return out
+        return sp.attrs.get("host_frame", 0)
 
     def submit(self, key: str, fn: Callable, *args, **kwargs) -> None:
         from anovos_tpu.cache import capture
@@ -282,9 +292,10 @@ class AsyncArtifactWriter:
             futs = [f for fl in self._pending.values() for f in fl]
         from anovos_tpu.obs import get_tracer
 
-        with get_tracer().phase("artifact:drain", cat="artifact", pending=len(futs)):
-            for f in futs:
-                f.result()
+        with get_tracer().phase("artifact:drain", cat="artifact", pending=len(futs)) as sp:
+            # result() re-raises a write's failure; its value is the write's
+            # host_frame count
+            sp.add(host_frames=sum(f.result() for f in futs))
         with self._lock:  # all landed: forget completed tickets
             for k in list(self._pending):
                 self._pending[k] = [f for f in self._pending[k] if not f.done()]

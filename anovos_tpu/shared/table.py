@@ -91,22 +91,59 @@ class Column:
 
     def exact_host(self, nrows: Optional[int] = None) -> np.ndarray:
         """Host values with exactness preserved (wide pair → int64/float64)."""
-        from anovos_tpu.obs import devprof
-
         n = self.data.shape[0] if nrows is None else nrows
         if self.wide_hi is not None:
-            with devprof.transfer_bracket(
-                    "d2h", self.wide_hi.nbytes + self.wide_lo.nbytes,
-                    label="column.exact_host"):
-                hi = np.asarray(jax.device_get(self.wide_hi))[:n].astype(np.int64)
-                lo = np.asarray(jax.device_get(self.wide_lo))[:n].astype(np.int64) + (1 << 31)
-            key = (hi << 32) + lo
-            if self.wide_kind == "float":
-                return float_from_order_key(key)
-            return key
-        with devprof.transfer_bracket("d2h", self.data.nbytes,
-                                      label="column.exact_host"):
-            return np.asarray(jax.device_get(self.data))[:n]
+            hi, lo = _fetch((self.wide_hi, self.wide_lo), n, "column.exact_host")
+            return _wide_exact(hi, lo, self.wide_kind)
+        return _fetch((self.data,), n, "column.exact_host")[0]
+
+    def to_host(self, nrows: int) -> "HostColumn":
+        """The fetch of :meth:`Table.to_pandas`: the column's first ``nrows``
+        entries on the host, the wide pair under ``exact_host``'s bracket."""
+        data, mask = _fetch((self.data, self.mask), nrows, "table.to_pandas")
+        hi = lo = None
+        if self.wide_hi is not None:
+            hi, lo = _fetch((self.wide_hi, self.wide_lo), nrows, "column.exact_host")
+        return HostColumn(self.kind, data, mask, self.vocab, self.dtype_name,
+                          hi, lo, self.wide_kind)
+
+
+@dataclasses.dataclass
+class HostColumn:
+    """A :class:`Column`'s arrays on the host, one entry a row and no
+    padding: what :func:`_plain_to_host` makes of an input array before the
+    device has it, and what :meth:`Column.to_host` fetches back.  Everything
+    between the two is a copy, so a frame that is only being written goes
+    from the one to :func:`_host_column_to_pandas` directly
+    (:func:`host_table_frame`)."""
+
+    kind: str
+    data: np.ndarray
+    mask: np.ndarray
+    vocab: Optional[np.ndarray] = None
+    dtype_name: str = "double"
+    wide_hi: Optional[np.ndarray] = None
+    wide_lo: Optional[np.ndarray] = None
+    wide_kind: str = "int"
+
+
+def _fetch(arrays, n: int, label: str) -> List[np.ndarray]:
+    """d2h materialization boundary: the first ``n`` entries of each device
+    array.  ``device_get`` blocks until the producing programs retire, so
+    the wall includes the device tail a fetch waits on (devprof books it as
+    transfer — "what the host was waiting ON", see obs.devprof)."""
+    from anovos_tpu.obs import devprof
+
+    with devprof.transfer_bracket("d2h", sum(a.nbytes for a in arrays), label=label):
+        return [np.asarray(jax.device_get(a))[:n] for a in arrays]
+
+
+def _wide_exact(hi: np.ndarray, lo: np.ndarray, wide_kind: str) -> np.ndarray:
+    """The exact int64 / float64 values of a host (hi, lo) pair."""
+    key = (hi.astype(np.int64) << 32) + (lo.astype(np.int64) + (1 << 31))
+    if wide_kind == "float":
+        return float_from_order_key(key)
+    return key
 
 
 def wide_int_parts(v64: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
@@ -212,16 +249,7 @@ class Table:
         cat column is in code-point order, which is ``np.unique``'s over
         Python ``str``; Arrow computes it over the UTF-8 bytes where the
         distinct values are an Arrow string array."""
-        data = {}
-        for name in df.columns:
-            s = df[name]
-            if isinstance(s.dtype, (pd.StringDtype, pd.CategoricalDtype)):
-                data[name] = encode_strings(s)
-            elif s.dtype == object:
-                data[name] = s.to_numpy(dtype=object)
-            else:
-                data[name] = s.to_numpy()
-        return Table.from_numpy(data, nrows=len(df))
+        return Table.from_numpy(_frame_arrays(df, encode_strings), nrows=len(df))
 
     # ------------------------------------------------------------------
     # basic introspection (the reference's utils.attributeType_segregation)
@@ -495,48 +523,9 @@ class Table:
     # host materialization
     # ------------------------------------------------------------------
     def to_pandas(self):
-        from anovos_tpu.obs import devprof
-
-        out = {}
         n = self.nrows
-        for name, c in self.columns.items():
-            # d2h materialization boundary: device_get blocks until the
-            # producing programs retire, so this wall includes the device
-            # tail a fetch waits on (devprof books it as transfer — "what
-            # the host was waiting ON", see obs.devprof)
-            with devprof.transfer_bracket("d2h", c.data.nbytes + c.mask.nbytes,
-                                          label="table.to_pandas"):
-                data = np.asarray(jax.device_get(c.data))[:n]
-                mask = np.asarray(jax.device_get(c.mask))[:n]
-            if c.kind == "cat":
-                vals = np.empty(n, dtype=object)
-                valid = mask & (data >= 0)
-                vals[valid] = c.vocab[data[valid]]
-                vals[~valid] = None
-                out[name] = vals
-            elif c.kind == "ts":
-                vals = data.astype("int64") * np.int64(1_000_000_000)
-                ts = vals.view("datetime64[ns]").copy()
-                s = pd.Series(ts)
-                s[~mask] = pd.NaT
-                out[name] = s
-            elif c.wide_hi is not None:
-                vals = c.exact_host(n)  # exact int64 / float64
-                if c.wide_kind == "float":
-                    vals = vals.copy()
-                    vals[~mask] = np.nan
-                    out[name] = vals
-                elif mask.all():
-                    out[name] = vals
-                else:  # nullable after outer joins: pandas Int64 keeps exactness
-                    out[name] = pd.arrays.IntegerArray(vals, ~mask)
-            else:
-                if np.issubdtype(data.dtype, np.integer) and mask.all():
-                    out[name] = data
-                else:
-                    vals = data.astype("float64")
-                    vals[~mask] = np.nan
-                    out[name] = vals
+        out = {name: _host_column_to_pandas(c.to_host(n))
+               for name, c in self.columns.items()}
         return pd.DataFrame(out, columns=list(self.columns.keys()))
 
     def head(self, k: int = 5):
@@ -736,10 +725,16 @@ def encode_strings(values) -> NativeEncodedStrings:
     from anovos_tpu.obs.tracing import get_tracer
 
     with get_tracer().phase("ingest/encode", cat="io", rows=len(values)) as sp:
-        enc, counts = _hash_encode(values) or (
-            _loop_encode(np.asarray(values, dtype=object)), {"hashed": 0, "native_sort": 0})
+        enc, counts = _encode_with_counts(values)
         sp.add(distinct=len(enc.vocab), **counts)
     return enc
+
+
+def _encode_with_counts(values) -> Tuple[NativeEncodedStrings, Dict[str, float]]:
+    """:func:`encode_strings` without its span: the encoding, and the counts
+    the span carries."""
+    return _hash_encode(values) or (
+        _loop_encode(np.asarray(values, dtype=object)), {"hashed": 0, "native_sort": 0})
 
 
 def _host_to_column(arr: np.ndarray, n: int, npad: int, rt) -> Column:
@@ -751,8 +746,9 @@ def _host_to_column(arr: np.ndarray, n: int, npad: int, rt) -> Column:
     in code-point order either way, which is ``np.unique``'s: computed by
     Arrow over UTF-8 bytes in the first case, by ``np.unique`` in the other).
     Codes that arrive encoded (avro's decoder, ``Table.from_pandas``) skip that.  Then ``ingest/h2d`` around
-    the conversion to the device dtype, the padding and the ``device_put``
-    calls.  ``Runtime.shard_rows``'s transfer bracket puts ``bytes`` and
+    the conversion to the device dtype (:func:`_plain_to_host`), the padding
+    and the ``device_put`` calls (:func:`_place_column`).
+    ``Runtime.shard_rows``'s transfer bracket puts ``bytes`` and
     ``enqueue_s`` on the latter: ``device_put`` is async, so those seconds
     are the time to enqueue, not to move."""
     from anovos_tpu.obs.tracing import get_tracer
@@ -760,55 +756,42 @@ def _host_to_column(arr: np.ndarray, n: int, npad: int, rt) -> Column:
     if not isinstance(arr, NativeEncodedStrings) and arr.dtype.kind in "OUS":
         arr = encode_strings(arr[:n])
     with get_tracer().phase("ingest/h2d", cat="io"):
-        return _plain_to_column(arr, n, npad, rt)
+        return _place_column(_plain_to_host(arr, n), npad, rt)
 
 
-def _plain_to_column(arr: np.ndarray, n: int, npad: int, rt) -> Column:
-    """A column that needs no dictionary-encoding: codes the native decoder
-    made, timestamps, booleans, numbers."""
+def _plain_to_host(arr: np.ndarray, n: int) -> HostColumn:
+    """The host half of a column that needs no dictionary-encoding (codes
+    the native decoder or :func:`encode_strings` made, timestamps, booleans,
+    numbers): its first ``n`` entries in the dtypes the device holds, numpy
+    throughout."""
     if isinstance(arr, NativeEncodedStrings):
-        # already dictionary-encoded (avro's decoder, encode_strings)
         code_arr = arr.codes[:n]
-        data = rt.shard_rows(_pad_to(code_arr, npad, -1))
-        mask = rt.shard_rows(_pad_to(code_arr >= 0, npad, False))
-        return Column("cat", data, mask, vocab=arr.vocab, dtype_name="string")
+        return HostColumn("cat", code_arr, code_arr >= 0, vocab=arr.vocab,
+                          dtype_name="string")
     if arr.dtype.kind == "M":
         # timestamps → epoch seconds int32
         vals = arr[:n].astype("datetime64[s]")
         isnull = np.isnat(vals)
         secs = vals.astype("int64")
         secs = np.where(isnull, 0, secs).astype(np.int32)
-        data = rt.shard_rows(_pad_to(secs, npad, 0))
-        mask = rt.shard_rows(_pad_to(~isnull, npad, False))
-        return Column("ts", data, mask, dtype_name="timestamp")
+        return HostColumn("ts", secs, ~isnull, dtype_name="timestamp")
     if arr.dtype.kind == "b":
-        vals = arr[:n].astype(np.int32)
-        data = rt.shard_rows(_pad_to(vals, npad, 0))
-        mask = rt.shard_rows(_pad_to(np.ones(n, bool), npad, False))
-        return Column("num", data, mask, dtype_name="boolean")
+        return HostColumn("num", arr[:n].astype(np.int32), np.ones(n, bool),
+                          dtype_name="boolean")
     # numeric
     dtn = _spark_dtype_name(arr.dtype)
     vals = arr[:n]
     if vals.dtype.kind == "f":
         isnull = np.isnan(vals)
         host = np.where(isnull, 0.0, vals).astype(np.float32)
-        fill = np.float32(0)
         if vals.dtype.itemsize > 4:
             v64 = np.where(isnull, 0.0, vals).astype(np.float64)
             if not np.array_equal(host.astype(np.float64), v64):
                 # values don't survive the f32 round-trip: keep the exact
                 # order-preserving (hi, lo) pair for distinct/mode/percentiles
                 whi, wlo = float_order_parts(v64)
-                mask = rt.shard_rows(_pad_to(~isnull, npad, False))
-                return Column(
-                    "num",
-                    rt.shard_rows(_pad_to(host, npad, fill)),
-                    mask,
-                    dtype_name=dtn,
-                    wide_hi=rt.shard_rows(_pad_to(whi, npad, np.int32(0))),
-                    wide_lo=rt.shard_rows(_pad_to(wlo, npad, np.int32(-(1 << 31)))),
-                    wide_kind="float",
-                )
+                return HostColumn("num", host, ~isnull, dtype_name=dtn,
+                                  wide_hi=whi, wide_lo=wlo, wide_kind="float")
     else:
         isnull = np.zeros(n, dtype=bool)
         if vals.dtype.itemsize > 4:
@@ -819,21 +802,104 @@ def _plain_to_column(arr: np.ndarray, n: int, npad: int, rt) -> Column:
                 # wide int64: f32 approximation for moment kernels + exact
                 # (hi, lo) int32 pair for distinct/mode/percentiles/joins
                 whi, wlo = wide_int_parts(vals)
-                mask = rt.shard_rows(_pad_to(np.ones(n, bool), npad, False))
-                return Column(
-                    "num",
-                    rt.shard_rows(_pad_to(vals.astype(np.float32), npad, np.float32(0))),
-                    mask,
-                    dtype_name="bigint",
-                    wide_hi=rt.shard_rows(_pad_to(whi, npad, np.int32(0))),
-                    wide_lo=rt.shard_rows(_pad_to(wlo, npad, np.int32(-(1 << 31)))),
-                )
+                return HostColumn("num", vals.astype(np.float32), np.ones(n, bool),
+                                  dtype_name="bigint", wide_hi=whi, wide_lo=wlo)
         else:
             host = vals.astype(np.int32) if vals.dtype.kind in "iu" else vals.astype(np.float32)
-        fill = host.dtype.type(0)
-    data = rt.shard_rows(_pad_to(host, npad, fill))
-    mask = rt.shard_rows(_pad_to(~isnull, npad, False))
-    return Column("num", data, mask, dtype_name=dtn)
+    return HostColumn("num", host, ~isnull, dtype_name=dtn)
+
+
+def _place_column(hc: HostColumn, npad: int, rt) -> Column:
+    """The device half: each array of ``hc`` padded to ``npad`` rows (a
+    padding row carries mask=False, code −1 in a cat column, the wide pair
+    (0, −2^31)) and put on ``rt``'s row sharding."""
+    def put(a, fill):
+        return rt.shard_rows(_pad_to(a, npad, fill))
+
+    wide = hc.wide_hi is not None
+    return Column(
+        hc.kind,
+        put(hc.data, -1 if hc.kind == "cat" else 0),
+        put(hc.mask, False),
+        vocab=hc.vocab,
+        dtype_name=hc.dtype_name,
+        wide_hi=put(hc.wide_hi, np.int32(0)) if wide else None,
+        wide_lo=put(hc.wide_lo, np.int32(-(1 << 31))) if wide else None,
+        wide_kind=hc.wide_kind,
+    )
+
+
+def _host_column_to_pandas(hc: HostColumn):
+    """The host half of :meth:`Table.to_pandas`: one column of the frame
+    from its host arrays."""
+    data, mask = hc.data, hc.mask
+    if hc.kind == "cat":
+        vals = np.empty(len(data), dtype=object)
+        valid = mask & (data >= 0)
+        vals[valid] = hc.vocab[data[valid]]
+        vals[~valid] = None
+        return vals
+    if hc.kind == "ts":
+        vals = data.astype("int64") * np.int64(1_000_000_000)
+        s = pd.Series(vals.view("datetime64[ns]").copy())
+        s[~mask] = pd.NaT
+        return s
+    if hc.wide_hi is not None:
+        vals = _wide_exact(hc.wide_hi, hc.wide_lo, hc.wide_kind)  # exact int64 / float64
+        if hc.wide_kind == "float":
+            vals = vals.copy()
+            vals[~mask] = np.nan
+            return vals
+        if mask.all():
+            return vals
+        # nullable after outer joins: pandas Int64 keeps exactness
+        return pd.arrays.IntegerArray(vals, ~mask)
+    if np.issubdtype(data.dtype, np.integer) and mask.all():
+        return data
+    vals = data.astype("float64")
+    vals[~mask] = np.nan
+    return vals
+
+
+def _frame_arrays(df, encode) -> Dict[str, Union[np.ndarray, NativeEncodedStrings]]:
+    """A pandas frame's columns as :meth:`Table.from_numpy` takes them: a
+    column of a string dtype or of dtype ``category`` through ``encode``, from
+    the Series; an ``object`` column as objects; every other dtype as its
+    numpy array."""
+    data = {}
+    for name in df.columns:
+        s = df[name]
+        if isinstance(s.dtype, (pd.StringDtype, pd.CategoricalDtype)):
+            data[name] = encode(s)
+        elif s.dtype == object:
+            data[name] = s.to_numpy(dtype=object)
+        else:
+            data[name] = s.to_numpy()
+    return data
+
+
+def host_table_frame(df):
+    """``Table.from_pandas(df).to_pandas()`` without the device: the two
+    host halves of that round trip (:func:`_plain_to_host`,
+    :func:`_host_column_to_pandas`) composed, so the frame comes back as a
+    ``Table`` would return it (``bool`` and an ``int64`` that fits as
+    ``int32``, wide ints and floats that ``float32`` cannot hold exact,
+    strings through :func:`encode_strings`' vocabulary with ``None`` for a
+    null, timestamps to the second) with no ``device_put``, no ``device_get``,
+    no padding to the row bucket and no ``ingest/encode`` / ``ingest/h2d``
+    span.  For a frame that is on the host and is only being written."""
+    def encode(values):
+        return _encode_with_counts(values)[0]
+
+    n = len(df)
+    out = {}
+    for name, arr in _frame_arrays(df, encode).items():
+        if not isinstance(arr, NativeEncodedStrings):
+            arr = np.asarray(arr)
+            if arr.dtype.kind in "OUS":
+                arr = encode(arr[:n])
+        out[name] = _host_column_to_pandas(_plain_to_host(arr, n))
+    return pd.DataFrame(out, columns=list(out))
 
 
 def make_column_from_device(

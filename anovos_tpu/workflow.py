@@ -202,6 +202,12 @@ def save(
     With ``writer`` (and no read-back requested) the disk write is queued on
     the async artifact writer under ``key`` and the in-memory data returns
     immediately; the queue is drained before ``main()`` returns.
+
+    ``data`` goes to ``write_dataset`` as it is, queued or not: a ``Table``
+    is on the device and is fetched there; a pandas frame (a stats table) is
+    on the host and is written from it, the same files with the same bytes
+    as when it went through ``Table.from_pandas``, with no ``device_put``,
+    no ``device_get`` and no ``ingest/*`` span from the writer thread.
     """
     if not write_configs:
         return data
@@ -212,31 +218,15 @@ def save(
     write.pop("log_mlflow", False)
     write["file_path"] = os.path.join(write["file_path"], folder_name)
     from_disk = reread and os.environ.get("ANOVOS_REREAD_FROM_DISK", "0") == "1"
-    if isinstance(data, pd.DataFrame):
-        from anovos_tpu.shared.table import Table as _T
-
-        if writer is not None and not from_disk:
-            writer.submit(
-                key or f"ckpt:{folder_name}",
-                lambda: data_ingest.write_dataset(_T.from_pandas(data), **write),
-            )
-            return data
-        data_t = _T.from_pandas(data)
-        data_ingest.write_dataset(data_t, **write)
-        if from_disk:
-            return data_ingest.read_dataset(
-                write["file_path"], write.get("file_type", "csv"),
-                _clean_read_cfg(write.get("file_configs")),
-            ).to_pandas()
-        return data
     if writer is not None and not from_disk:
         writer.submit(key or f"ckpt:{folder_name}", data_ingest.write_dataset, data, **write)
         return data
     data_ingest.write_dataset(data, **write)
     if from_disk:
-        return data_ingest.read_dataset(
+        back = data_ingest.read_dataset(
             write["file_path"], write.get("file_type", "csv"), _clean_read_cfg(write.get("file_configs"))
         )
+        return back.to_pandas() if isinstance(data, pd.DataFrame) else back
     return data
 
 
