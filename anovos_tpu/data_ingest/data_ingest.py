@@ -27,11 +27,13 @@ import numpy as np
 import pandas as pd
 import pyarrow as pa
 import pyarrow.csv as pacsv
+import pyarrow.parquet as pq
 
 from anovos_tpu.data_ingest import avro_io
 from anovos_tpu.data_ingest import guard
 from anovos_tpu.shared.runtime import get_runtime
-from anovos_tpu.shared.table import Column, Table, _host_to_column, _pad_to, host_table_frame
+from anovos_tpu.shared.table import (
+    Column, Table, _host_to_column, _pad_to, arrow_typed_kind, arrow_typed_to_numpy, host_table_frame)
 from anovos_tpu.shared.utils import ends_with, pairwise_reduce, parse_cols
 
 logger = logging.getLogger(__name__)
@@ -232,7 +234,9 @@ def _read_one_part(f: str, file_type: str, cfg: dict) -> pd.DataFrame:
             raise ValueError(f"CSV part {f}: columns {bad} are not valid UTF-8")
         return tbl.to_pandas()
     if file_type == "parquet":
-        return pd.read_parquet(f)
+        # pd.read_parquet but for the columns whose Arrow type pandas has no
+        # numpy dtype for: they stay Arrow until _assemble_frames converts them
+        return pq.read_table(f).to_pandas(types_mapper=_keep_arrow_typed)
     if file_type == "avro":
         from anovos_tpu.shared.native import NativeEncodedStrings
 
@@ -247,6 +251,40 @@ def _read_one_part(f: str, file_type: str, cfg: dict) -> pd.DataFrame:
         with opener(f, "rt") as fh:
             return pd.read_json(fh, lines=True)
     raise ValueError(f"unsupported file_type: {file_type}")
+
+
+def _keep_arrow_typed(t: pa.DataType) -> Optional[pd.ArrowDtype]:
+    """``types_mapper`` of a part's ``to_pandas``: a decimal or a date (the
+    types that pandas would make one Python object a value of: a
+    ``decimal.Decimal``, a ``datetime.date``) stays an Arrow array in the part
+    frame; every other type converts as ``pd.read_parquet`` converts it."""
+    dtype = pd.ArrowDtype(t)
+    return dtype if arrow_typed_kind(dtype) else None
+
+
+def _arrow_typed(df: pd.DataFrame) -> List[tuple]:
+    """``(column, kind)`` of the columns :func:`_keep_arrow_typed` left in
+    Arrow: ``decimal`` or ``date``."""
+    return [(c, kind) for c in df.columns if (kind := arrow_typed_kind(df[c].dtype))]
+
+
+def _convert_arrow_typed(df: pd.DataFrame) -> pd.DataFrame:
+    """The columns :func:`_keep_arrow_typed` left in Arrow, converted once
+    for the whole table, in Arrow and numpy (``table.arrow_typed_to_numpy``):
+    a decimal to float64 (``num``; ``_plain_to_host`` adds the exact wide
+    pair where f32 does not hold the value), a date to ``datetime64[s]`` at
+    midnight (``ts``, an ``other`` column as in the upstream; null: NaT).
+    One ``ingest/convert`` span a column (``kind``, ``rows``)."""
+    from anovos_tpu.obs import get_tracer
+
+    converted = {}
+    for c, kind in _arrow_typed(df):
+        with get_tracer().phase("ingest/convert", cat="io", kind=kind, rows=len(df)):
+            converted[c] = arrow_typed_to_numpy(df[c])
+    if not converted:
+        return df
+    # a new frame over the same arrays: assigning into the old one copies a column a time
+    return pd.DataFrame({c: converted.get(c, df[c]) for c in df.columns}, copy=False)
 
 
 def read_host_frame(files: List[str], file_type: str, cfg: dict) -> pd.DataFrame:
@@ -274,7 +312,10 @@ def read_host_frame(files: List[str], file_type: str, cfg: dict) -> pd.DataFrame
             f"every {file_type} part was quarantined ({len(files)} file(s), "
             f"first: {files[0] if files else '<none>'}) — no schema left to "
             "build a frame")
-    with tracer.phase("ingest/assemble", cat="io"):
+    with tracer.phase("ingest/assemble", cat="io") as sp:
+        # how many columns arrive in their Arrow type and are converted here:
+        # 0 says that this program converts and had nothing to convert
+        sp.add(arrow_typed=len(_arrow_typed(frames[0][1])))
         return _assemble_frames(frames, cfg, pol)
 
 
@@ -283,6 +324,7 @@ def _assemble_frames(frames: List, cfg: dict, pol) -> pd.DataFrame:
     concatenated, ``inferSchema`` re-coercion, hostile values sanitized."""
     aligned = guard.reconcile_frames(frames, pol)
     df = aligned[0] if len(aligned) == 1 else pd.concat(aligned, ignore_index=True)
+    df = _convert_arrow_typed(df)
     if str(cfg.get("inferSchema", True)).lower() in ("true", "1", "none"):
         # whole-dataset schema inference (Spark inferSchema parity): per-part
         # readers can disagree (an all-null part decodes as string/null), so

@@ -214,15 +214,19 @@ class Tracer:
                               th.name, th.ident or 0, attrs, self.run_id), tree)
 
     def phase(self, name: str, cat: str = "anovos", **attrs):
-        """A span of the pass's phase tree: ``cat="phase"`` where the span
-        that encloses it on this thread is itself of the tree (a phase, the
-        root being ``run_pass()``'s, or a scheduler node of the pass), so
-        every row's parent is a row.  Anywhere else (on a writer thread,
-        outside any pass) the same work is an ordinary span of category
-        ``cat``."""
+        """A span of the pass's phase tree: ``cat="phase"`` where a span open
+        on this thread is itself of the tree (a phase, the root being
+        ``run_pass()``'s, or a scheduler node of the pass), and its parent is
+        the innermost such span, so every row's parent is a row: an op span
+        in between (``obs.timed`` around a library call) is none and is
+        passed over.  Anywhere else (on a writer thread, outside any pass)
+        the same work is an ordinary span of category ``cat``."""
         stack = self._stack()
-        if stack and stack[-1].tree:
+        row = next((sp for sp in reversed(stack) if sp.tree), None)
+        if row is not None:
             cat = "phase"
+            if row is not stack[-1]:
+                attrs["parent"] = row.name
         return self.span(name, cat=cat, **attrs)
 
     def in_pass(self) -> bool:

@@ -273,6 +273,10 @@ def describe_cat(C: jax.Array, M: jax.Array, max_vocab: int) -> Dict[str, jax.Ar
 # high-cardinality columns (ids) go through the sort-based kernel on their
 # codes instead — same count/nunique/mode outputs
 _CAT_SWEEP_MAX_VOCAB = 1024
+# the sort path takes the codes through f32 (describe_numeric's one dtype),
+# which holds every integer only below 2^24: a vocabulary of that length or
+# more would merge neighbouring codes, silently
+_CAT_SORT_MAX_VOCAB = 1 << 24
 
 
 @timed("ops.table_describe")
@@ -309,50 +313,32 @@ def table_describe(idf: Table, num_cols: List[str], cat_cols: List[str]) -> Tupl
 
 def _table_describe(idf: Table, num_cols: List[str], cat_cols: List[str],
                     compensated: bool) -> Tuple[dict, dict]:
-    """The unmemoized body of :func:`table_describe`."""
+    """The unmemoized body of :func:`table_describe`, under the span
+    ``describe`` with one child a kernel family that ran: ``describe/numeric``,
+    ``describe/wide``, ``describe/cat_sweep``, ``describe/cat_sort``, each from
+    the stacking of its block to the fetch of its results, with ``rows`` and
+    ``cols`` of the stacked block as the program takes it (padding included)
+    and on the cat spans ``vocab_max``."""
+    too_long = [c for c in cat_cols if len(idf.columns[c].vocab) >= _CAT_SORT_MAX_VOCAB]
+    if too_long:  # before any dispatch
+        raise ValueError(
+            f"describe: the vocabulary of {too_long} has {_CAT_SORT_MAX_VOCAB} values or more; "
+            "the sort path holds codes in float32, which is exact only below 2^24")
+    with get_tracer().phase("describe", cat="op", num_cols=len(num_cols), cat_cols=len(cat_cols)):
+        return _describe_blocks(idf, num_cols, cat_cols, compensated)
+
+
+def _describe_blocks(idf: Table, num_cols: List[str], cat_cols: List[str],
+                     compensated: bool) -> Tuple[dict, dict]:
+    phase = get_tracer().phase
     num_out: dict = {}
     if num_cols:
-        X, M = idf.numeric_block(num_cols)
-        # numeric_block column-buckets to k_pad dead lanes (mask=False);
-        # slice every per-column output back to the live k before the host
-        # arrays escape to consumers that zip/stack them against num_cols
-        kk_live = len(num_cols)
-        num_out = {k: np.asarray(v)[..., :kk_live]
-                   for k, v in describe_numeric(X, M).items()}
-        if compensated:
-            comp = compensated_moments(X, M)
-            for kk in ("mean", "variance", "stddev", "skewness", "kurtosis"):
-                num_out[kk] = comp[kk][..., :kk_live]
+        with phase("describe/numeric", cat="op") as sp:
+            num_out = _numeric_stats(idf, num_cols, compensated, sp)
         wide = [c for c in num_cols if idf.columns[c].is_wide]
         if wide:
-            # overwrite the f32-approximate order stats with exact values
-            # from the (hi, lo) int32-pair kernel (moments stay f32-approx);
-            # the lexicographic sort is order-correct for BOTH wide kinds.
-            # Stacks are column-bucketed like numeric_block; the j-indexed
-            # reads below never touch the dead lanes.
-            from anovos_tpu.shared.table import stack_padded
-
-            Hi, Mw = stack_padded([idf.columns[c].wide_hi for c in wide],
-                                  [idf.columns[c].mask for c in wide], dtype=jnp.int32)
-            Lo, _ = stack_padded([idf.columns[c].wide_lo for c in wide],
-                                 [idf.columns[c].mask for c in wide], dtype=jnp.int32)
-            w = {kk: np.asarray(v) for kk, v in describe_wide_int(Hi, Lo, Mw).items()}
-            kinds = [idf.columns[c].wide_kind for c in wide]
-            pctl = _wide_pair_to_f64(w["pctl_hi"], w["pctl_lo"], kinds)  # (nq, kw)
-            mode = _wide_pair_to_f64(w["mode_hi"], w["mode_lo"], kinds)
-            num_out = {kk: v.copy() for kk, v in num_out.items()}
-            for kk in ("percentiles", "min", "max", "mode_value"):
-                num_out[kk] = num_out[kk].astype(np.float64)
-            for j, c in enumerate(wide):
-                if w["count"][j] == 0:
-                    continue  # all-null: keep describe_numeric's NaNs, not the sort sentinel
-                i = num_cols.index(c)
-                num_out["nunique"][i] = w["nunique"][j]
-                num_out["percentiles"][:, i] = pctl[:, j]
-                num_out["min"][i] = pctl[0, j]
-                num_out["max"][i] = pctl[-1, j]
-                num_out["mode_value"][i] = mode[j]
-                num_out["mode_count"][i] = w["mode_count"][j]
+            with phase("describe/wide", cat="op") as sp:
+                num_out = _wide_order_stats(idf, num_cols, wide, num_out, sp)
     cat_out: dict = {}
     if cat_cols:
         k = len(cat_cols)
@@ -364,58 +350,125 @@ def _table_describe(idf: Table, num_cols: List[str], cat_cols: List[str],
         }
         small = [c for c in cat_cols if len(idf.columns[c].vocab) <= _CAT_SWEEP_MAX_VOCAB]
         large = [c for c in cat_cols if c not in set(small)]
-        # bucket by vocab size (powers of 4): one 1000-category column must
-        # not multiply the lane count of thirty binary columns
-        buckets: Dict[int, List[str]] = {}
-        for c in small:
-            v = max(len(idf.columns[c].vocab), 1)
-            b = 4
-            while b < v:
-                b *= 4
-            buckets.setdefault(b, []).append(c)
-        # dispatch every bucket's program before fetching any result: the
-        # per-bucket kernels overlap on the device stream instead of each
-        # waiting for the previous bucket's download (graftcheck GC001)
-        from anovos_tpu.shared.table import stack_padded
-
-        bucket_res = []
-        for b, cols_b in sorted(buckets.items()):
-            # column-bucketed stack (dead lanes code 0 / mask False → zero
-            # counts); reads below are j-indexed over the live cols_b
-            C, Mc = stack_padded([idf.columns[c].data for c in cols_b],
-                                 [idf.columns[c].mask for c in cols_b], dtype=jnp.int32)
-            bucket_res.append((cols_b, describe_cat(C, Mc, b)))
-        for cols_b, res in bucket_res:
-            sw = {kk: np.asarray(v) for kk, v in res.items()}
-            for j, c in enumerate(cols_b):
-                i = cat_cols.index(c)
-                cat_out["count"][i] = sw["count"][j]
-                cat_out["nunique"][i] = sw["nunique"][j]
-                cat_out["mode_code"][i] = sw["mode_code"][j]
-                cat_out["mode_count"][i] = sw["mode_count"][j]
+        if small:
+            with phase("describe/cat_sweep", cat="op") as sp:
+                _cat_sweep_stats(idf, cat_cols, small, cat_out, sp)
         if large:
-            # codes are just ints: the sort-based numeric kernel yields
-            # count/nunique/mode directly, no per-vocab lanes
-            from anovos_tpu.ops.segment import cat_valid_mask
-
-            lg_masks = [cat_valid_mask(idf.columns[c].data, idf.columns[c].mask)
-                        for c in large]
-            C, Mc = stack_padded(
-                [idf.columns[c].data for c in large],
-                lg_masks,
-                dtype=jnp.int32,
-            )
-            lg_dev = describe_numeric(C, Mc)
-            # bulk-materialize the four stats once: per-element int()/float()
-            # in the loop was one blocking device round-trip per column per
-            # stat (graftcheck GC001)
-            lg = {kk: np.asarray(lg_dev[kk])
-                  for kk in ("count", "nunique", "mode_value", "mode_count")}
-            for j, c in enumerate(large):
-                i = cat_cols.index(c)
-                cat_out["count"][i] = int(lg["count"][j])
-                cat_out["nunique"][i] = int(lg["nunique"][j])
-                mv = float(lg["mode_value"][j])
-                cat_out["mode_code"][i] = int(mv) if mv == mv else -1
-                cat_out["mode_count"][i] = float(lg["mode_count"][j])
+            with phase("describe/cat_sort", cat="op") as sp:
+                _cat_sort_stats(idf, cat_cols, large, cat_out, sp)
     return num_out, cat_out
+
+
+def _numeric_stats(idf: Table, num_cols: List[str], compensated: bool, sp) -> dict:
+    X, M = idf.numeric_block(num_cols)
+    sp.add(rows=X.shape[0], cols=X.shape[1])
+    # numeric_block column-buckets to k_pad dead lanes (mask=False);
+    # slice every per-column output back to the live k before the host
+    # arrays escape to consumers that zip/stack them against num_cols
+    kk_live = len(num_cols)
+    num_out = {k: np.asarray(v)[..., :kk_live]
+               for k, v in describe_numeric(X, M).items()}
+    if compensated:
+        comp = compensated_moments(X, M)
+        for kk in ("mean", "variance", "stddev", "skewness", "kurtosis"):
+            num_out[kk] = comp[kk][..., :kk_live]
+    return num_out
+
+
+def _wide_order_stats(idf: Table, num_cols: List[str], wide: List[str], num_out: dict, sp) -> dict:
+    """``num_out`` with the f32-approximate order stats of the ``wide``
+    columns overwritten by exact values from the (hi, lo) int32-pair kernel
+    (moments stay f32-approx); the lexicographic sort is order-correct for
+    BOTH wide kinds.  Stacks are column-bucketed like numeric_block; the
+    j-indexed reads below never touch the dead lanes."""
+    from anovos_tpu.shared.table import stack_padded
+
+    Hi, Mw = stack_padded([idf.columns[c].wide_hi for c in wide],
+                          [idf.columns[c].mask for c in wide], dtype=jnp.int32)
+    Lo, _ = stack_padded([idf.columns[c].wide_lo for c in wide],
+                         [idf.columns[c].mask for c in wide], dtype=jnp.int32)
+    sp.add(rows=Hi.shape[0], cols=Hi.shape[1])
+    w = {kk: np.asarray(v) for kk, v in describe_wide_int(Hi, Lo, Mw).items()}
+    kinds = [idf.columns[c].wide_kind for c in wide]
+    pctl = _wide_pair_to_f64(w["pctl_hi"], w["pctl_lo"], kinds)  # (nq, kw)
+    mode = _wide_pair_to_f64(w["mode_hi"], w["mode_lo"], kinds)
+    num_out = {kk: v.copy() for kk, v in num_out.items()}
+    for kk in ("percentiles", "min", "max", "mode_value"):
+        num_out[kk] = num_out[kk].astype(np.float64)
+    for j, c in enumerate(wide):
+        if w["count"][j] == 0:
+            continue  # all-null: keep describe_numeric's NaNs, not the sort sentinel
+        i = num_cols.index(c)
+        num_out["nunique"][i] = w["nunique"][j]
+        num_out["percentiles"][:, i] = pctl[:, j]
+        num_out["min"][i] = pctl[0, j]
+        num_out["max"][i] = pctl[-1, j]
+        num_out["mode_value"][i] = mode[j]
+        num_out["mode_count"][i] = w["mode_count"][j]
+    return num_out
+
+
+def _cat_sweep_stats(idf: Table, cat_cols: List[str], small: List[str], cat_out: dict, sp) -> None:
+    """The lane sweep over the ``small`` vocabularies, into ``cat_out``."""
+    from anovos_tpu.shared.table import stack_padded
+
+    # bucket by vocab size (powers of 4): one 1000-category column must
+    # not multiply the lane count of thirty binary columns
+    buckets: Dict[int, List[str]] = {}
+    for c in small:
+        v = max(len(idf.columns[c].vocab), 1)
+        b = 4
+        while b < v:
+            b *= 4
+        buckets.setdefault(b, []).append(c)
+    # dispatch every bucket's program before fetching any result: the
+    # per-bucket kernels overlap on the device stream instead of each
+    # waiting for the previous bucket's download (graftcheck GC001)
+    bucket_res = []
+    for b, cols_b in sorted(buckets.items()):
+        # column-bucketed stack (dead lanes code 0 / mask False → zero
+        # counts); reads below are j-indexed over the live cols_b
+        C, Mc = stack_padded([idf.columns[c].data for c in cols_b],
+                             [idf.columns[c].mask for c in cols_b], dtype=jnp.int32)
+        sp.add(cols=C.shape[1])
+        bucket_res.append((cols_b, describe_cat(C, Mc, b)))
+    sp.add(rows=idf.padded_rows, vocab_max=max(buckets))
+    for cols_b, res in bucket_res:
+        sw = {kk: np.asarray(v) for kk, v in res.items()}
+        for j, c in enumerate(cols_b):
+            i = cat_cols.index(c)
+            cat_out["count"][i] = sw["count"][j]
+            cat_out["nunique"][i] = sw["nunique"][j]
+            cat_out["mode_code"][i] = sw["mode_code"][j]
+            cat_out["mode_count"][i] = sw["mode_count"][j]
+
+
+def _cat_sort_stats(idf: Table, cat_cols: List[str], large: List[str], cat_out: dict, sp) -> None:
+    """The ``large`` vocabularies, into ``cat_out``: codes are just ints, so
+    the sort-based numeric kernel yields count/nunique/mode directly, no
+    per-vocab lanes (in f32: ``_CAT_SORT_MAX_VOCAB``)."""
+    from anovos_tpu.ops.segment import cat_valid_mask
+    from anovos_tpu.shared.table import stack_padded
+
+    lg_masks = [cat_valid_mask(idf.columns[c].data, idf.columns[c].mask)
+                for c in large]
+    C, Mc = stack_padded(
+        [idf.columns[c].data for c in large],
+        lg_masks,
+        dtype=jnp.int32,
+    )
+    sp.add(rows=C.shape[0], cols=C.shape[1],
+           vocab_max=max(len(idf.columns[c].vocab) for c in large))
+    lg_dev = describe_numeric(C, Mc)
+    # bulk-materialize the four stats once: per-element int()/float()
+    # in the loop was one blocking device round-trip per column per
+    # stat (graftcheck GC001)
+    lg = {kk: np.asarray(lg_dev[kk])
+          for kk in ("count", "nunique", "mode_value", "mode_count")}
+    for j, c in enumerate(large):
+        i = cat_cols.index(c)
+        cat_out["count"][i] = int(lg["count"][j])
+        cat_out["nunique"][i] = int(lg["nunique"][j])
+        mv = float(lg["mode_value"][j])
+        cat_out["mode_code"][i] = int(mv) if mv == mv else -1
+        cat_out["mode_count"][i] = float(lg["mode_count"][j])

@@ -24,7 +24,10 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import os
+import sys
 import time
+from concurrent.futures import ThreadPoolExecutor
 from collections import OrderedDict
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple, Union
 
@@ -148,7 +151,12 @@ def _wide_exact(hi: np.ndarray, lo: np.ndarray, wide_kind: str) -> np.ndarray:
 
 def wide_int_parts(v64: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
     """Split int64 → (hi, lo) int32 pair in the sortable encoding."""
-    v64 = v64.astype(np.int64)
+    v64 = np.ascontiguousarray(v64, np.int64)
+    if sys.byteorder == "little":
+        # the two halves as they lie in memory: no shift, mask and cast over
+        # int64 temporaries; flipping the low half's top bit subtracts 2^31
+        halves = v64.view(np.int32).reshape(v64.shape + (2,))
+        return np.ascontiguousarray(halves[..., 1]), halves[..., 0] ^ np.int32(-(1 << 31))
     hi = (v64 >> 32).astype(np.int32)
     lo = ((v64 & 0xFFFFFFFF) - (1 << 31)).astype(np.int32)
     return hi, lo
@@ -157,14 +165,12 @@ def wide_int_parts(v64: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
 def float_order_key(v64: np.ndarray) -> np.ndarray:
     """float64 → int64 key whose numeric order equals the float order.
 
-    IEEE-754 trick: negative floats flip every bit, non-negatives flip only
-    the sign bit, giving a monotonic unsigned map; re-flipping the top bit
-    recenters it to signed int64.  (-0.0 and +0.0 map to distinct keys —
-    acceptable for distinct-count semantics.)"""
-    b = np.ascontiguousarray(v64, np.float64).view(np.uint64)
-    flip = np.where(b >> np.uint64(63), np.uint64(0xFFFFFFFFFFFFFFFF),
-                    np.uint64(0x8000000000000000))
-    return (b ^ flip ^ np.uint64(0x8000000000000000)).view(np.int64)
+    IEEE-754 trick on the bit pattern read as int64: a non-negative float is
+    already in order and stays; a negative one flips every bit but its sign,
+    which reverses the order of its magnitudes below zero.  (-0.0 and +0.0
+    map to distinct keys — acceptable for distinct-count semantics.)"""
+    i = np.ascontiguousarray(v64, np.float64).view(np.int64)
+    return i ^ ((i >> 63) & np.int64(0x7FFFFFFFFFFFFFFF))
 
 
 def float_from_order_key(key: np.ndarray) -> np.ndarray:
@@ -179,6 +185,15 @@ def float_order_parts(v64: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
     """float64 → (hi, lo) int32 pair whose signed lexicographic order equals
     the float numeric order (same pair encoding as wide_int_parts)."""
     return wide_int_parts(float_order_key(v64))
+
+
+def _f32_holds(host: np.ndarray, v64: np.ndarray) -> bool:
+    """Whether every float64 of ``v64`` survives the round trip through its
+    float32 in ``host``; a piece at a time, so that a column that does not
+    (the common case for a measure with decimals) says so on its first piece."""
+    step = 1 << 20
+    return all(np.array_equal(host[i:i + step].astype(np.float64), v64[i:i + step])
+               for i in range(0, len(v64), step))
 
 
 def _pad_to(arr: np.ndarray, n: int, fill) -> np.ndarray:
@@ -637,19 +652,105 @@ def _sorted_vocab_codes(first: np.ndarray, uniques) -> NativeEncodedStrings:
     return NativeEncodedStrings(remap[first], vocab)
 
 
-def _arrow_sorted_vocab_codes(first: np.ndarray, dictionary: pa.Array) -> NativeEncodedStrings:
-    """The same for distinct values that are an Arrow string array, the
-    ``dictionary`` of a ``dictionary_encode``: non-null, valid UTF-8 and no
-    two equal, so there is nothing to merge.  Arrow orders them by their
-    UTF-8 bytes, whose order is code-point order (``np.unique``'s over Python
-    ``str``), and Python neither compares them nor makes a ``str`` of one
-    more than once: the vocab is built from the dictionary already in order."""
+def _arrow_ordered(first: np.ndarray, dictionary: pa.Array) -> Tuple[np.ndarray, pa.Array]:
+    """Codes into ``dictionary`` (−1 null), the distinct values of a
+    ``dictionary_encode`` (an Arrow string array: non-null, valid UTF-8 and no
+    two equal, so there is nothing to merge) → int32 codes into the same
+    values in order, and those, still an Arrow array.  Arrow orders them by
+    their UTF-8 bytes, whose order is code-point order (``np.unique``'s over
+    Python ``str``)."""
     order = pc.array_sort_indices(dictionary)
     rank = np.empty(len(order) + 1, dtype=np.int32)
     rank[order.to_numpy()] = np.arange(len(order), dtype=np.int32)
     rank[-1] = -1  # first == −1 reads the −1
-    vocab = dictionary.take(order).to_numpy(zero_copy_only=False)
-    return NativeEncodedStrings(rank[first], vocab)
+    return rank[first], dictionary.take(order)
+
+
+def _arrow_sorted_vocab_codes(first: np.ndarray, dictionary: pa.Array) -> NativeEncodedStrings:
+    """:func:`_sorted_vocab_codes` for distinct values that are an Arrow
+    string array: Python neither compares them nor makes a ``str`` of one
+    more than once, the vocab is built from the dictionary already in order."""
+    codes, vocab = _arrow_ordered(first, dictionary)
+    return NativeEncodedStrings(codes, vocab.to_numpy(zero_copy_only=False))
+
+
+# A column of a million rows or more whose values are mostly distinct (free
+# text, ids) costs seconds in one hash table and one sort; its rows are
+# partitioned by their first bytes and the partitions encoded side by side.
+_BUCKETED_ENCODE_MIN_ROWS = 1 << 20
+_BUCKETED_ENCODE_SAMPLE = 1 << 16
+_BUCKETED_ENCODE_BUCKETS_A_WORKER = 4
+_PREFIX_BYTES = 8
+
+
+def _mostly_distinct(strings: pa.Array) -> bool:
+    """Whether a column looks like free text or ids: more than a quarter of
+    its first 65,536 rows are distinct.  A wrong guess costs time, not
+    correctness: both encodings give the same codes and the same vocab."""
+    head = strings.slice(0, _BUCKETED_ENCODE_SAMPLE)
+    return 4 * pc.count_distinct(head).as_py() > len(head)
+
+
+def _prefix_keys(strings: pa.Array) -> np.ndarray:
+    """Per row of a ``large_string`` array the first eight bytes of its UTF-8
+    as one big-endian uint64, a shorter (or null) value padded with zero
+    bytes: ``s <= t`` bytewise implies ``key(s) <= key(t)``."""
+    n = len(strings)
+    _, offsets, data = strings.buffers()
+    start = np.frombuffer(offsets, dtype=np.int64)[strings.offset:strings.offset + n + 1]
+    length = np.diff(start)
+    if strings.null_count:
+        length = np.where(strings.is_valid().to_numpy(zero_copy_only=False), length, 0)
+    data = np.frombuffer(data, dtype=np.uint8) if data is not None else np.zeros(0, np.uint8)
+    data = np.concatenate([data, np.zeros(_PREFIX_BYTES, np.uint8)])  # a window at the last byte stays inside
+    first = np.lib.stride_tricks.sliding_window_view(data, _PREFIX_BYTES)[start[:-1]]  # (n, 8), one gather
+    short = np.flatnonzero(length < _PREFIX_BYTES)  # their windows reach into the next value
+    first[short] = np.where(np.arange(_PREFIX_BYTES) < length[short, None], first[short], 0)
+    return first.view(">u8").ravel().astype(np.uint64)
+
+
+def _bucketed_encode(strings: pa.Array) -> Tuple[NativeEncodedStrings, Dict[str, float]]:
+    """:func:`_hash_encode`'s Arrow path for a long column of mostly distinct
+    values, in parallel and in pieces that fit a cache: the rows are cut into
+    buckets by :func:`_prefix_keys` at splitters taken from a sample of the
+    keys, so that no value lies in two buckets and every value of a bucket
+    sorts before every value of the next; each bucket is hashed and its
+    distinct values ordered by Arrow on a thread of its own (Arrow's kernels
+    release the GIL); the vocab is the buckets' vocabs one after the other, a
+    row's code its bucket's code plus the distinct values of the buckets
+    before.  The same codes and vocab as the one hash table and one sort
+    give.  ``hash_s``: the seconds to the end of the last bucket; ``sort_s``:
+    those joining them and building the vocab of Python ``str``; ``buckets``:
+    how many."""
+    t0 = time.perf_counter()
+    n = len(strings)
+    keys = _prefix_keys(strings)
+    workers = max(1, min(os.cpu_count() or 1, 16))
+    sample = np.sort(keys[:: max(1, n // _BUCKETED_ENCODE_SAMPLE)])
+    splitters = np.unique(sample[np.linspace(0, len(sample), workers * _BUCKETED_ENCODE_BUCKETS_A_WORKER + 1)
+                                 .astype(np.int64)[1:-1]])
+    bucket = np.searchsorted(splitters, keys, side="right").astype(np.uint16)  # equal keys, one bucket
+    order = np.argsort(bucket, kind="stable")  # rows by bucket, in their own order within one
+    edges = np.concatenate([[0], np.cumsum(np.bincount(bucket, minlength=len(splitters) + 1))])
+
+    def encode(lo: int, hi: int):
+        rows = order[lo:hi]
+        enc = strings.take(pa.array(rows)).dictionary_encode()
+        first = enc.indices.fill_null(-1).to_numpy(zero_copy_only=False)
+        return rows, _arrow_ordered(first, enc.dictionary)
+
+    with ThreadPoolExecutor(max_workers=workers) as pool:
+        parts = list(pool.map(encode, edges[:-1], edges[1:]))
+    t1 = time.perf_counter()
+    codes = np.empty(n, dtype=np.int32)
+    before = 0
+    for rows, (local, vocab) in parts:
+        codes[rows] = np.where(local >= 0, local + np.int32(before), np.int32(-1))
+        before += len(vocab)
+    vocab = pa.concat_arrays([v for _, (_, v) in parts]).to_numpy(zero_copy_only=False)
+    return NativeEncodedStrings(codes, vocab), {
+        "hashed": 1, "native_sort": 1, "hash_s": t1 - t0, "sort_s": time.perf_counter() - t1,
+        "buckets": len(parts)}
 
 
 def _hash_encode(values) -> Optional[Tuple[NativeEncodedStrings, Dict[str, float]]]:
@@ -678,6 +779,8 @@ def _hash_encode(values) -> Optional[Tuple[NativeEncodedStrings, Dict[str, float
         return None
     if isinstance(strings, pa.ChunkedArray):  # a pd.concat of part files
         strings = strings.combine_chunks()
+    if len(strings) >= _BUCKETED_ENCODE_MIN_ROWS and _mostly_distinct(strings):
+        return _bucketed_encode(strings)
     t0 = time.perf_counter()
     enc = strings.dictionary_encode()
     t1 = time.perf_counter()
@@ -770,11 +873,11 @@ def _plain_to_host(arr: np.ndarray, n: int) -> HostColumn:
                           dtype_name="string")
     if arr.dtype.kind == "M":
         # timestamps → epoch seconds int32
-        vals = arr[:n].astype("datetime64[s]")
-        isnull = np.isnat(vals)
-        secs = vals.astype("int64")
-        secs = np.where(isnull, 0, secs).astype(np.int32)
-        return HostColumn("ts", secs, ~isnull, dtype_name="timestamp")
+        secs = arr[:n].astype("datetime64[s]", copy=False).view(np.int64)
+        isnull = secs == np.iinfo(np.int64).min  # NaT
+        if isnull.any():
+            secs = np.where(isnull, 0, secs)
+        return HostColumn("ts", secs.astype(np.int32), ~isnull, dtype_name="timestamp")
     if arr.dtype.kind == "b":
         return HostColumn("num", arr[:n].astype(np.int32), np.ones(n, bool),
                           dtype_name="boolean")
@@ -783,10 +886,11 @@ def _plain_to_host(arr: np.ndarray, n: int) -> HostColumn:
     vals = arr[:n]
     if vals.dtype.kind == "f":
         isnull = np.isnan(vals)
-        host = np.where(isnull, 0.0, vals).astype(np.float32)
+        clean = np.where(isnull, 0.0, vals) if isnull.any() else vals
+        host = clean.astype(np.float32)
         if vals.dtype.itemsize > 4:
-            v64 = np.where(isnull, 0.0, vals).astype(np.float64)
-            if not np.array_equal(host.astype(np.float64), v64):
+            v64 = clean.astype(np.float64, copy=False)
+            if not _f32_holds(host, v64):
                 # values don't survive the f32 round-trip: keep the exact
                 # order-preserving (hi, lo) pair for distinct/mode/percentiles
                 whi, wlo = float_order_parts(v64)
@@ -861,16 +965,66 @@ def _host_column_to_pandas(hc: HostColumn):
     return vals
 
 
+def arrow_typed_kind(dtype) -> Optional[str]:
+    """``"decimal"`` or ``"date"`` for a pandas dtype that holds such an Arrow
+    type (``pd.ArrowDtype``): the types that pandas would otherwise make one
+    Python object a value of (a ``decimal.Decimal``, a ``datetime.date``).
+    None for every other dtype."""
+    if not isinstance(dtype, pd.ArrowDtype):
+        return None
+    t = dtype.pyarrow_dtype
+    return "decimal" if pa.types.is_decimal(t) else "date" if pa.types.is_date(t) else None
+
+
+def _decimal_to_float64(arr: pa.ChunkedArray) -> np.ndarray:
+    """A decimal column as float64 (null: NaN) without an object per value.
+    Up to 18 digits of ``decimal128`` the unscaled integer is the low word of
+    the 16 bytes a value has in the array's buffer; it is divided by 10^scale,
+    which for the 15 digits float64 holds exactly is ``float(Decimal)`` to
+    the bit (Arrow's own cast multiplies by 10^-scale and is an ulp off on
+    one value in eight).  Wider decimals take Arrow's cast."""
+    t = arr.type
+    if not pa.types.is_decimal128(t) or t.precision > 18:
+        return pc.cast(arr, pa.float64()).to_numpy()
+    out = np.empty(len(arr), dtype=np.float64)
+    at = 0
+    for chunk in arr.chunks:
+        if not len(chunk):  # an empty part file: a chunk with no buffer to read
+            continue
+        words = np.frombuffer(chunk.buffers()[1], dtype=np.int64)
+        vals = out[at:at + len(chunk)]
+        np.divide(words[2 * chunk.offset:2 * (chunk.offset + len(chunk)):2],
+                  float(10 ** t.scale), out=vals)
+        if chunk.null_count:
+            vals[~chunk.is_valid().to_numpy(zero_copy_only=False)] = np.nan
+        at += len(chunk)
+    return out
+
+
+def arrow_typed_to_numpy(s) -> np.ndarray:
+    """A Series of an :func:`arrow_typed_kind` dtype as the numpy array
+    :func:`_plain_to_host` takes, in Arrow and numpy with no object a value:
+    a decimal as float64 (null: NaN), a date as ``datetime64[s]`` at midnight
+    (null: NaT)."""
+    arr = s.array.__arrow_array__()
+    if arrow_typed_kind(s.dtype) == "decimal":
+        return _decimal_to_float64(arr)
+    return pc.cast(arr, pa.timestamp("s")).to_numpy()
+
+
 def _frame_arrays(df, encode) -> Dict[str, Union[np.ndarray, NativeEncodedStrings]]:
     """A pandas frame's columns as :meth:`Table.from_numpy` takes them: a
     column of a string dtype or of dtype ``category`` through ``encode``, from
     the Series; an ``object`` column as objects; every other dtype as its
-    numpy array."""
+    numpy array; an Arrow-typed decimal or date column
+    (:func:`arrow_typed_kind`) as float64 or ``datetime64[s]``."""
     data = {}
     for name in df.columns:
         s = df[name]
         if isinstance(s.dtype, (pd.StringDtype, pd.CategoricalDtype)):
             data[name] = encode(s)
+        elif arrow_typed_kind(s.dtype):
+            data[name] = arrow_typed_to_numpy(s)
         elif s.dtype == object:
             data[name] = s.to_numpy(dtype=object)
         else:

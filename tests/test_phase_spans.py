@@ -104,8 +104,10 @@ def test_manifest_holds_every_phase_of_the_pass(stats_pass):
     for r in man["phases"]:
         assert set(r) == {"name", "parent", "start_s", "end_s", "thread", "counts"}
         assert 0.0 <= r["start_s"] <= r["end_s"]
-        # the scheduler's nodes are rows too, each on its worker's thread under ``dag``
-        assert (r["thread"] == "MainThread") != (r["parent"] == "dag"), r
+        # the scheduler's nodes are rows too, each on its worker's thread under ``dag``,
+        # and so is what a node opens: the describe under the node that computes it
+        in_node = r["parent"] == "dag" or r["parent"] in man["scheduler"]["nodes"] or r["parent"] == "describe"
+        assert (r["thread"] == "MainThread") != in_node, r
     nodes = {r["name"]: r for r in man["phases"] if r["parent"] == "dag"}
     assert set(nodes) == set(man["scheduler"]["nodes"])
     # one describe a pass: of the two nodes that need it one computes it and one reads the memo
@@ -281,6 +283,10 @@ def test_a_phase_is_a_phase_only_under_a_phase():
         with tr.span("a_node", cat="node") as node:  # a scheduler node of the pass: a row
             with tr.phase("place/d2d", cat="place", bytes=8):  # and so is a phase inside it
                 assert tr.enclosing("node") is node and tr.enclosing("op") is None
+            with tr.span("ops.table_describe", cat="op"):  # obs.timed around a library call: no row,
+                with tr.phase("describe", cat="op"):  # and a phase inside it is one, under the node
+                    with tr.phase("describe/numeric", cat="op", rows=4):
+                        pass
         seen = []
         worker = threading.Thread(target=lambda: seen.append(tr.in_pass()))  # another thread's stack
         worker.start()
@@ -291,11 +297,12 @@ def test_a_phase_is_a_phase_only_under_a_phase():
             pass
     cats = {sp.name: sp.cat for sp in tr.snapshot()}
     assert cats == {"ingest/decode": "phase", "ingest": "phase", "ingest/encode": "io", "place/d2d": "phase",
-                    "a_node": "node", "run": "phase"}  # run_pass cleared what came before
+                    "a_node": "node", "run": "phase", "ops.table_describe": "op", "describe": "phase",
+                    "describe/numeric": "phase"}  # run_pass cleared what came before
     rows = tr.phases()
     assert [(r["name"], r["parent"]) for r in rows] == [
         ("run", None), ("ingest", "run"), ("ingest/decode", "ingest"), ("a_node", "run"),
-        ("place/d2d", "a_node")]
+        ("place/d2d", "a_node"), ("describe", "a_node"), ("describe/numeric", "describe")]
     assert rows[4]["counts"] == {"bytes": 8}
     assert rows[1]["counts"] == {"rows": 5}
     assert not tr.in_pass() and tr.current() is None

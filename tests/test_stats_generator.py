@@ -112,3 +112,34 @@ def test_subset_describe_cache_then_full_counts():
     table_describe(t, [f"n{i}" for i in range(8)], ["c1"])
     out = sg.missingCount_computation(t).set_index("attribute")
     assert len(out) == 10 and (out["missing_count"] == 0).all()
+
+
+def test_describe_sorts_a_large_vocabulary_and_refuses_one_float32_cannot_index(monkeypatch):
+    """Above ``_CAT_SWEEP_MAX_VOCAB`` a categorical is described by a sort of
+    its codes, which go through float32: exact below 2^24 codes.  A vocabulary
+    of 2^24 values or more is refused before anything is dispatched, not
+    rounded (the length is fabricated: a view of one string, not 16 M)."""
+    from anovos_tpu.ops import describe as dsc
+
+    n = 3000
+    ids = np.array([f"id{i:05d}" for i in range(n)], dtype=object)
+    ids[[5, 17]] = ids[3]  # one value three times, the rest once
+    df = pd.DataFrame({"x": np.arange(n, dtype=float), "id": ids})
+    t = Table.from_pandas(df)
+    assert len(t.columns["id"].vocab) == n - 2 > dsc._CAT_SWEEP_MAX_VOCAB
+    _, cat = dsc.table_describe(t, ["x"], ["id"])
+    assert (cat["count"][0], cat["nunique"][0], cat["mode_count"][0]) == (n, n - 2, 3)
+    assert t.columns["id"].vocab[int(cat["mode_code"][0])] == "id00003"
+
+    t2 = Table.from_pandas(df)
+    t2.columns["id"].vocab = np.broadcast_to(np.array("v", dtype=object), (dsc._CAT_SORT_MAX_VOCAB,))
+    dispatched = []
+    monkeypatch.setattr(dsc, "describe_numeric", lambda *a, **k: dispatched.append("numeric"))
+    monkeypatch.setattr(dsc, "describe_cat", lambda *a, **k: dispatched.append("cat"))
+    with pytest.raises(ValueError, match="2\\^24"):
+        dsc.table_describe(t2, ["x"], ["id"])
+    assert dispatched == []
+    t2.columns["id"].vocab = np.broadcast_to(np.array("v", dtype=object), (dsc._CAT_SORT_MAX_VOCAB - 1,))
+    monkeypatch.undo()
+    _, cat = dsc.table_describe(t2, ["x"], ["id"])  # one below the limit goes through
+    assert cat["nunique"][0] == n - 2

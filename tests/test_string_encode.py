@@ -270,3 +270,54 @@ def test_from_pandas_names_the_dtypes_it_means():
         assert list(np.asarray(col.data)[:4]) == [1, -1, 0, 1]
         assert list(np.asarray(col.mask)[:4]) == [True, False, True, True]
     assert tbl["number"].kind == "num"
+
+
+# ---------------------------------------- a long column of mostly distinct values ----
+def _free_text(n, seed):
+    """Mostly distinct values with what a partition by the first bytes has to
+    get right: empty strings, values shorter than the prefix, one a prefix
+    of another, NUL bytes, multi-byte characters, shared eight-byte
+    prefixes, nulls and repeats."""
+    g = np.random.default_rng(seed)
+    words = np.array(["", "a", "a\x00", "ab", "abcdefgh", "abcdefghi", "abcdefgh\x00", "é", "éa", "ée",
+                      "\U0001F600x", "zzzzzzzzzzzz", "id", "id0"], dtype=object)
+    ids = np.array([f"id{int(i):07d}" for i in g.integers(0, n // 3, n // 3)], dtype=object)
+    text = np.array(["".join(g.choice(list("ab é"), int(k))) for k in g.integers(0, 14, n // 3)], dtype=object)
+    vals = np.concatenate([words[g.integers(0, len(words), n - 2 * (n // 3))], ids, text])
+    vals[g.random(len(vals)) < 0.01] = None
+    g.shuffle(vals)
+    return vals
+
+
+@pytest.mark.parametrize("seed", [1, 2])
+def test_bucketed_encode_gives_the_one_hash_tables_codes_and_vocab(monkeypatch, seed):
+    import pyarrow as pa
+
+    from anovos_tpu.shared import table as table_mod
+
+    vals = _free_text(9000, seed)
+    arr = pa.array(vals, type=pa.large_string())
+    for strings in (arr, arr.slice(17, 8000)):  # a slice: an offset into the buffers
+        e = strings.dictionary_encode()
+        want = table_mod._arrow_sorted_vocab_codes(
+            e.indices.fill_null(-1).to_numpy(zero_copy_only=False), e.dictionary)
+        got, counts = table_mod._bucketed_encode(strings)
+        assert np.array_equal(got.codes, want.codes) and got.codes.dtype == np.int32
+        assert list(got.vocab) == list(want.vocab)
+        assert counts["hashed"] == counts["native_sort"] == 1 and counts["buckets"] > 1
+    keys = table_mod._prefix_keys(arr)
+    by_bytes = sorted(range(len(vals)), key=lambda i: (vals[i] or "").encode())
+    assert (np.diff(keys[by_bytes].astype(np.float64)) >= 0).all()  # s <= t bytewise: key(s) <= key(t)
+    # the path is taken by size and by the share of distinct values in the column's head, and only then
+    assert table_mod._mostly_distinct(arr) and not table_mod._mostly_distinct(pa.array(["x", "y"] * 50, pa.large_string()))
+    taken = []
+    real = table_mod._bucketed_encode
+    monkeypatch.setattr(table_mod, "_bucketed_encode", lambda s: taken.append(len(s)) or real(s))
+    plain = encode_strings(pd.Series(vals, dtype="str"))
+    assert taken == []  # 9,000 rows: one hash table
+    monkeypatch.setattr(table_mod, "_BUCKETED_ENCODE_MIN_ROWS", 1000)
+    bucketed = encode_strings(pd.Series(vals, dtype="str"))
+    encode_strings(pd.Series(["x", "y"] * 1000, dtype="str"))  # long enough, two values: one hash table
+    assert taken == [len(vals)]
+    assert np.array_equal(bucketed.codes, plain.codes) and list(bucketed.vocab) == list(plain.vocab)
+    assert (plain.codes < 0).sum() == sum(v is None for v in vals)
