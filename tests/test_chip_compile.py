@@ -89,6 +89,31 @@ def test_describe_numeric_compiles(shapes):
     _compile(_describe_numeric, shapes["X"], shapes["M"])
 
 
+def test_describe_wide_int_compiles_to_one_two_operand_sort(topo):
+    """The wide pair's order statistics as the chip's compiler leaves them: one
+    sort whose only operands are the two key halves (a stable sort would carry
+    an iota besides, an argsort its permutation) and no gather of row length."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from anovos_tpu.ops.describe import PCTL_QS, _describe_wide_int
+
+    rows, k = 1 << 16, 3
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    pair = jax.ShapeDtypeStruct((rows, k), jnp.int32, sharding=one_chip)
+    mask = jax.ShapeDtypeStruct((rows, k), jnp.bool_, sharding=one_chip)
+    text = _compile(_describe_wide_int, pair, pair, mask, cp=False).as_text()
+    sorts = re.findall(r" sort\(([^)]*)\)", text)
+    assert len(sorts) == 1, sorts
+    assert len(sorts[0].split(",")) == 2, sorts[0]
+    # the takes that stay: the percentile grid's (11, k) and the mode's (k,)
+    gathered = re.findall(r"= s32\[([0-9,]*)\]\S* gather\(", text)
+    assert gathered and all(shape.split(",")[0] in (str(len(PCTL_QS)), str(k)) for shape in gathered), gathered
+
+
 def test_dense_binned_histograms_compiles(shapes, monkeypatch):
     """_flat_counts with the TPU-only dense budget (1 << 30): at 4 M x 16 x
     10 the compare-and-reduce branch is taken, which no CPU test reaches."""
