@@ -11,6 +11,7 @@ host-side (SURVEY.md §2.10: "cross-shard joins via … host-side hash partition
 
 from __future__ import annotations
 
+import contextlib
 import glob
 import gzip
 import logging
@@ -235,8 +236,10 @@ def _read_one_part(f: str, file_type: str, cfg: dict) -> pd.DataFrame:
         return tbl.to_pandas()
     if file_type == "parquet":
         # pd.read_parquet but for the columns whose Arrow type pandas has no
-        # numpy dtype for: they stay Arrow until _assemble_frames converts them
-        return pq.read_table(f).to_pandas(types_mapper=_keep_arrow_typed)
+        # numpy dtype for: they stay Arrow until _assemble_frames converts them;
+        # and for integers with nulls, which stay integers
+        tbl = pq.read_table(f)
+        return tbl.to_pandas(types_mapper=_part_types_mapper(tbl))
     if file_type == "avro":
         from anovos_tpu.shared.native import NativeEncodedStrings
 
@@ -260,6 +263,26 @@ def _keep_arrow_typed(t: pa.DataType) -> Optional[pd.ArrowDtype]:
     frame; every other type converts as ``pd.read_parquet`` converts it."""
     dtype = pd.ArrowDtype(t)
     return dtype if arrow_typed_kind(dtype) else None
+
+
+_NULLABLE_INTEGERS = {
+    pa.int8(): pd.Int8Dtype(), pa.int16(): pd.Int16Dtype(), pa.int32(): pd.Int32Dtype(),
+    pa.int64(): pd.Int64Dtype(), pa.uint8(): pd.UInt8Dtype(), pa.uint16(): pd.UInt16Dtype(),
+    pa.uint32(): pd.UInt32Dtype(), pa.uint64(): pd.UInt64Dtype()}
+
+
+def _part_types_mapper(tbl: pa.Table):
+    """``types_mapper`` of one parquet part: :func:`_keep_arrow_typed`, and
+    an integer type of which a column of this part holds a null becomes
+    pandas' nullable integer of that width (values and validity taken from
+    Arrow's buffers), where ``to_pandas`` alone makes such a column float64
+    with NaN: exact only to 2^53, and a ``double`` to everything after it.
+    A part without a null in the type converts as it always did (a plain
+    numpy integer); ``pd.concat`` of the two is the nullable one."""
+    nullable = {col.type for col in tbl.columns if col.null_count and col.type in _NULLABLE_INTEGERS}
+    if not nullable:
+        return _keep_arrow_typed
+    return lambda t: _NULLABLE_INTEGERS[t] if t in nullable else _keep_arrow_typed(t)
 
 
 def _arrow_typed(df: pd.DataFrame) -> List[tuple]:
@@ -385,17 +408,56 @@ def write_dataset(
         elif mode == "error":
             raise FileExistsError(f"{file_path} exists (mode=error)")
     os.makedirs(file_path, exist_ok=True)
-    if on_host:
-        from anovos_tpu.obs import get_tracer
+    from anovos_tpu.obs import get_tracer
 
+    tracer = get_tracer()
+    if on_host:
         df = host_table_frame(idf)
-        write_span = get_tracer().enclosing("artifact")
+        write_span = tracer.enclosing("artifact")
         if write_span is not None:
             write_span.add(host_frame=1)
     else:
-        df = idf.to_pandas()
+        # the fetch of a device table: every array of every column, padding included
+        arrays = [a for c in idf.columns.values()
+                  for a in (c.data, c.mask, c.wide_hi, c.wide_lo) if a is not None]
+        with _write_phase(tracer, "write/d2h", arrays=len(arrays), bytes=sum(a.nbytes for a in arrays)):
+            df = idf.to_pandas()
+    with _write_phase(tracer, "write/" + file_type, rows=len(df)) as write_files:
+        written = _write_parts(df, file_path, file_type, cfg, repartition)
+        try:
+            n_bytes = sum(os.path.getsize(f) for f in written)
+        except OSError:
+            n_bytes = 0
+        if write_files is not None:
+            write_files.add(bytes=n_bytes)
+    # incremental-recompute capture: the pyarrow writers bypass the
+    # builtins.open hook, so this choke point books every part explicitly
+    # (a no-op unless a cache recorder is active on this thread)
+    from anovos_tpu.cache import capture as _capture
+
+    for f in written + [os.path.join(file_path, "_SUCCESS")]:
+        _capture.record_artifact(f)
+    from anovos_tpu.obs import get_metrics
+
+    reg = get_metrics()
+    reg.counter("bytes_written_total", "artifact bytes written to disk").inc(n_bytes)
+    reg.counter("rows_written_total", "rows persisted by write_dataset").inc(len(df))
+
+
+def _write_phase(tracer, name: str, **counts):
+    """A row of the pass's phases where the write runs on the pass's own
+    thread (the final dataset under ``write_main``); a queued write on a
+    writer thread is its ``write:<key>`` span already and opens nothing more."""
+    return tracer.phase(name, **counts) if tracer.in_pass() else contextlib.nullcontext()
+
+
+def _write_parts(df: pd.DataFrame, file_path: str, file_type: str, cfg: dict,
+                 repartition: int) -> List[str]:
+    """``df`` as ``repartition`` part files of ``file_type`` under
+    ``file_path`` and the ``_SUCCESS`` marker after them; the part files
+    THIS call wrote (append mode must not re-book pre-existing parts)."""
     parts = np.array_split(np.arange(len(df)), max(repartition, 1))
-    written: List[str] = []  # THIS call's files (append mode must not re-book pre-existing parts)
+    written: List[str] = []
     for i, part_idx in enumerate(parts):
         # single-part writes (the checkpoint default) skip the fancy-index
         # row copy — df.iloc[arange] materializes a full second frame
@@ -469,22 +531,7 @@ def write_dataset(
         else:
             raise ValueError(f"unsupported file_type: {file_type}")
     open(os.path.join(file_path, "_SUCCESS"), "w").close()
-    # incremental-recompute capture: the pyarrow writers bypass the
-    # builtins.open hook, so this choke point books every part explicitly
-    # (a no-op unless a cache recorder is active on this thread)
-    from anovos_tpu.cache import capture as _capture
-
-    for f in written + [os.path.join(file_path, "_SUCCESS")]:
-        _capture.record_artifact(f)
-    from anovos_tpu.obs import get_metrics
-
-    try:
-        n_bytes = sum(os.path.getsize(f) for f in written)
-    except OSError:
-        n_bytes = 0
-    reg = get_metrics()
-    reg.counter("bytes_written_total", "artifact bytes written to disk").inc(n_bytes)
-    reg.counter("rows_written_total", "rows persisted by write_dataset").inc(len(df))
+    return written
 
 
 # ----------------------------------------------------------------------

@@ -33,7 +33,7 @@ import pandas as pd
 
 from anovos_tpu.data_ingest.data_ingest import _resolve_files, read_host_frame
 from anovos_tpu.data_ingest.guard import IngestError, policy_from_env
-from anovos_tpu.shared.table import Column, Table, wide_int_parts
+from anovos_tpu.shared.table import Column, Table, _spark_dtype_name, wide_int_parts
 from anovos_tpu.shared.runtime import DATA_AXIS, get_runtime
 
 
@@ -125,7 +125,10 @@ def read_dataset_distributed(
         # distinguish int/float: hosts MUST agree on the device dtype branch
         # (a host whose shard has nulls reads float64 where another reads
         # int64 — divergent branches would run mismatched collective
-        # sequences and hang the cluster)
+        # sequences and hang the cluster).  A parquet integer column with
+        # nulls is pandas' nullable integer on the hosts whose slice holds a
+        # null and a plain one on the others: "num_i" on all of them, and the
+        # integer branch below carries a mask, as the one-host reader does
         return "num_f" if s.dtype.kind == "f" else "num_i"
 
     local_schema = {c: _col_kind(df[c]) for c in df.columns}
@@ -196,9 +199,8 @@ def read_dataset_distributed(
                 dtype_name="timestamp",
             )
         else:
-            vals = s.to_numpy()
             if kind == "num_f":  # globally-agreed branch, never local dtype
-                fvals = vals.astype(np.float64)
+                fvals = s.to_numpy(dtype=np.float64, na_value=np.nan)
                 isnull = np.isnan(fvals)
                 host = np.where(isnull, 0.0, fvals).astype(np.float32)
                 columns[c] = Column(
@@ -208,7 +210,8 @@ def read_dataset_distributed(
                     dtype_name="double",
                 )
             else:
-                v64 = vals.astype(np.int64)
+                isnull = pd.isna(s).to_numpy()
+                v64 = s.to_numpy(dtype=np.int64, na_value=0)  # zero under the mask, as a padding row holds
                 # wide detection must agree globally: allgather local ranges
                 ranges = _allgather_obj([int(v64.min(initial=0)), int(v64.max(initial=0))])
                 gmin = min(r[0] for r in ranges)
@@ -217,15 +220,15 @@ def read_dataset_distributed(
                     columns[c] = Column(
                         "num",
                         _global_sharded(_pad(v64.astype(np.int32), np.int32(0)), 0),
-                        _global_sharded(_pad(np.ones(n, bool), False), False),
-                        dtype_name="int",
+                        _global_sharded(_pad(~isnull, False), False),
+                        dtype_name=_spark_dtype_name(getattr(s.dtype, "numpy_dtype", s.dtype)),
                     )
                 else:
                     whi, wlo = wide_int_parts(v64)
                     columns[c] = Column(
                         "num",
                         _global_sharded(_pad(v64.astype(np.float32), np.float32(0)), 0.0),
-                        _global_sharded(_pad(np.ones(n, bool), False), False),
+                        _global_sharded(_pad(~isnull, False), False),
                         dtype_name="bigint",
                         wide_hi=_global_sharded(_pad(whi, np.int32(0)), 0),
                         wide_lo=_global_sharded(_pad(wlo, np.int32(-(1 << 31))), 0),

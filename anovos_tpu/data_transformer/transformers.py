@@ -28,7 +28,9 @@ from anovos_tpu.data_transformer.model_io import load_model_df, save_model_df
 from anovos_tpu.ops.histogram import digitize, masked_bincount
 from anovos_tpu.ops.quantiles import masked_quantiles
 from anovos_tpu.ops.reductions import masked_moments
-from anovos_tpu.ops.segment import code_counts, code_label_counts, masked_nunique
+from anovos_tpu.obs import get_tracer
+from anovos_tpu.ops.segment import (
+    _bucket_segments, code_counts, code_label_counts, masked_nunique, vocab_lookup)
 from anovos_tpu.shared.runtime import get_runtime
 from anovos_tpu.shared.table import Column, Table, pad_lane_params
 from anovos_tpu.shared.utils import parse_cols
@@ -73,8 +75,10 @@ def _impute_num_program(data, mask, fill):
 def _impute_num_int_program(data, mask, fill):
     """Integer-column MMM fill with an integral value: the int cast stays
     INSIDE the program (an eager astype after the fused fill re-added the
-    per-column convert dispatch this layer exists to remove)."""
-    return jnp.where(mask, data.astype(jnp.float32), fill).astype(jnp.int32)
+    per-column convert dispatch this layer exists to remove), and it is the
+    fill that is cast: a value that is there stays the int32 it is, also
+    beyond 2^24 where a pass through f32 would round it."""
+    return jnp.where(mask, data, fill.astype(jnp.int32))
 
 
 @jax.jit
@@ -444,8 +448,6 @@ def cat_to_num_unsupervised(
         for j, v in enumerate(col.vocab):
             if str(v) in mp:
                 code_map[j] = mp[str(v)]
-        from anovos_tpu.ops.segment import _bucket_segments, vocab_lookup
-
         if method_type == "label_encoding":
             # LUT gather + null fold + validity in one program; the LUT
             # is padded to its 2^k class so every vocab size shares one
@@ -495,38 +497,54 @@ def cat_to_num_supervised(
     # the event vector is FIT-time state only: the pre-existing-model path
     # applies the persisted rate maps and must not require the label column
     # (serving requests carry features, never labels)
-    y = ym = None
-    if not pre_existing_model:
-        y, ym = _event_vector(idf, label_col, event_label)
+    tracer = get_tracer()
+    k, rows = len(cols), idf.padded_rows
+    vocab_max = max(len(idf.columns[c].vocab) for c in cols)
+    lanes = sum(_bucket_segments(max(len(idf.columns[c].vocab), 1)) for c in cols)
+    shape = dict(cols=k, rows=rows, vocab_max=vocab_max, segments_max=_bucket_segments(vocab_max))
+    # what the calls below read and write, summed: padded rows into a group
+    # count (with and without the label), lanes of the padded count vectors,
+    # rows gathered, bytes of the padded LUTs (a bool and an f32 a column)
+    # and of the gathered columns
+    fitted = {} if pre_existing_model else dict(count_rows=k * rows, label_rows=k * rows, seg_lanes=2 * lanes)
+    rates_of: Dict[str, np.ndarray] = {}
+    with tracer.phase("transform/fit", **shape, **fitted):
+        if not pre_existing_model:
+            y, ym = _event_vector(idf, label_col, event_label)
+        for c in cols:
+            col = idf.columns[c]
+            vsize = max(len(col.vocab), 1)
+            if pre_existing_model:
+                dfm = load_model_df(model_path, f"cat_to_num_supervised/{c}", fmt="csv")
+                rate_map = dict(zip(dfm[c].astype(str), dfm[c + "_encoded"].astype(float)))
+                rates = np.array([rate_map.get(str(v), np.nan) for v in col.vocab], dtype=np.float32)
+            else:
+                # one group count in flight at a time: each is fetched before
+                # the next is dispatched (on a mesh each holds a collective)
+                m_eff = col.mask & ym
+                tot = np.asarray(code_counts(col.data, m_eff, vsize))[:vsize]
+                ev = np.asarray(code_label_counts(col.data, m_eff, y, vsize))[:vsize]
+                with np.errstate(divide="ignore", invalid="ignore"):
+                    rates = np.round(ev / np.maximum(tot, 1e-30), 4).astype(np.float32)
+                rates[tot == 0] = np.nan
+            rates_of[c] = rates
+        if not pre_existing_model and model_path != "NA":
+            # the model frame holds one Python str a distinct value: built
+            # only where it is written
+            for c, rates in rates_of.items():
+                dfm = pd.DataFrame({c: [str(v) for v in idf.columns[c].vocab],
+                                    c + "_encoded": rates.astype(np.float64)})
+                save_model_df(dfm, model_path, f"cat_to_num_supervised/{c}", fmt="csv")
     new_cols: "OrderedDict[str, Column]" = OrderedDict()
-    model_rows: Dict[str, pd.DataFrame] = {}
-    for c in cols:
-        col = idf.columns[c]
-        vsize = max(len(col.vocab), 1)
-        if pre_existing_model:
-            dfm = load_model_df(model_path, f"cat_to_num_supervised/{c}", fmt="csv")
-            rate_map = dict(zip(dfm[c].astype(str), dfm[c + "_encoded"].astype(float)))
-            rates = np.array([rate_map.get(str(v), np.nan) for v in col.vocab], dtype=np.float32)
-        else:
-            m_eff = col.mask & ym
-            tot = np.asarray(code_counts(col.data, m_eff, vsize))[:vsize]
-            ev = np.asarray(code_label_counts(col.data, m_eff, y, vsize))[:vsize]
-            with np.errstate(divide="ignore", invalid="ignore"):
-                rates = np.round(ev / np.maximum(tot, 1e-30), 4).astype(np.float32)
-            rates[tot == 0] = np.nan
-            model_rows[c] = pd.DataFrame(
-                {c: [str(v) for v in col.vocab], c + "_encoded": rates.astype(np.float64)}
-            )
-        from anovos_tpu.ops.segment import vocab_lookup
-
-        valid_code = col.data >= 0
-        nanmask_h = ~np.isnan(rates) if len(rates) else np.zeros(1, bool)
-        ok = col.mask & valid_code & vocab_lookup(nanmask_h, col.data)
-        enc = jnp.where(ok, vocab_lookup(np.nan_to_num(rates, nan=0.0), col.data), 0.0)
-        new_cols[c] = Column("num", enc.astype(jnp.float32), ok, dtype_name="double")
-    if not pre_existing_model and model_path != "NA":
-        for c, dfm in model_rows.items():
-            save_model_df(dfm, model_path, f"cat_to_num_supervised/{c}", fmt="csv")
+    with tracer.phase("transform/apply", **shape, gather_rows=2 * k * rows, lut_bytes=5 * lanes,
+                      gather_out_bytes=5 * k * rows):
+        for c, rates in rates_of.items():
+            col = idf.columns[c]
+            valid_code = col.data >= 0
+            nanmask_h = ~np.isnan(rates) if len(rates) else np.zeros(1, bool)
+            ok = col.mask & valid_code & vocab_lookup(nanmask_h, col.data)
+            enc = jnp.where(ok, vocab_lookup(np.nan_to_num(rates, nan=0.0), col.data), 0.0)
+            new_cols[c] = Column("num", enc.astype(jnp.float32), ok, dtype_name="double")
     odf = _emit(idf, new_cols, output_mode, "_encoded")
     if print_impact:
         logger.info(f"Target-encoded columns: {cols}")
@@ -551,22 +569,24 @@ def z_standardization(
     if not cols:
         warnings.warn("No Standardization Computation - No numerical column(s) to transform")
         return idf
-    if pre_existing_model:
-        dfm = load_model_df(model_path, "z_standardization").set_index("attribute")
-        cols = [c for c in cols if c in dfm.index]
-        mean = dfm.loc[cols, "mean"].to_numpy(np.float32)
-        std = dfm.loc[cols, "stddev"].to_numpy(np.float32)
-    else:
-        X, M = idf.numeric_block(cols)
-        mom = masked_moments(X, M)
-        mean = np.asarray(mom["mean"], np.float32)[: len(cols)]
-        std = np.asarray(mom["stddev"], np.float32)[: len(cols)]
-        if model_path != "NA":
-            save_model_df(
-                pd.DataFrame({"attribute": cols, "mean": mean.astype(float), "stddev": std.astype(float)}),
-                model_path,
-                "z_standardization",
-            )
+    tracer = get_tracer()
+    with tracer.phase("transform/fit", cols=len(cols), rows=idf.padded_rows):
+        if pre_existing_model:
+            dfm = load_model_df(model_path, "z_standardization").set_index("attribute")
+            cols = [c for c in cols if c in dfm.index]
+            mean = dfm.loc[cols, "mean"].to_numpy(np.float32)
+            std = dfm.loc[cols, "stddev"].to_numpy(np.float32)
+        else:
+            X, M = idf.numeric_block(cols)
+            mom = masked_moments(X, M)
+            mean = np.asarray(mom["mean"], np.float32)[: len(cols)]
+            std = np.asarray(mom["stddev"], np.float32)[: len(cols)]
+            if model_path != "NA":
+                save_model_df(
+                    pd.DataFrame({"attribute": cols, "mean": mean.astype(float), "stddev": std.astype(float)}),
+                    model_path,
+                    "z_standardization",
+                )
     keep = (std > 0) & ~np.isnan(std)
     skipped = [c for c, k in zip(cols, keep) if not k]
     if skipped:
@@ -575,15 +595,16 @@ def z_standardization(
     mean, std = mean[keep], std[keep]
     if not cols:
         return idf
-    X, M = idf.numeric_block(cols)
-    # params padded to the bucketed lane count (σ=1 keeps dead lanes finite)
-    mean_p = pad_lane_params(mean, X.shape[1])
-    std_p = pad_lane_params(std, X.shape[1], fill=1.0)
-    Z = (X - jnp.asarray(mean_p)[None, :]) / jnp.asarray(std_p)[None, :]
-    new_cols = OrderedDict(
-        (c, Column("num", Z[:, i].astype(jnp.float32), idf.columns[c].mask, dtype_name="double"))
-        for i, c in enumerate(cols)
-    )
+    with tracer.phase("transform/apply", cols=len(cols), rows=idf.padded_rows):
+        X, M = idf.numeric_block(cols)
+        # params padded to the bucketed lane count (σ=1 keeps dead lanes finite)
+        mean_p = pad_lane_params(mean, X.shape[1])
+        std_p = pad_lane_params(std, X.shape[1], fill=1.0)
+        Z = (X - jnp.asarray(mean_p)[None, :]) / jnp.asarray(std_p)[None, :]
+        new_cols = OrderedDict(
+            (c, Column("num", Z[:, i].astype(jnp.float32), idf.columns[c].mask, dtype_name="double"))
+            for i, c in enumerate(cols)
+        )
     odf = _emit(idf, new_cols, output_mode, "_scaled")
     if print_impact:
         logger.info(f"z-standardized: {cols}")
@@ -743,68 +764,88 @@ def imputation_MMM(
 
     num_cols = [c for c in cols if idf.columns[c].kind == "num"]
     cat_cols = [c for c in cols if idf.columns[c].kind == "cat"]
+    tracer = get_tracer()
+    vocab_max = max((len(idf.columns[c].vocab) for c in cat_cols), default=0)
+    shape = dict(cols=len(cols), rows=idf.padded_rows, vocab_max=vocab_max,
+                 segments_max=_bucket_segments(vocab_max) if cat_cols else 0)
+    # a group count a categorical: the padded rows it reads and the lanes of its count vector, summed
+    fitted = {} if pre_existing_model or not cat_cols else dict(
+        count_rows=len(cat_cols) * idf.padded_rows,
+        seg_lanes=sum(_bucket_segments(max(len(idf.columns[c].vocab), 1)) for c in cat_cols))
     fills: Dict[str, object] = {}
-    if pre_existing_model:
-        dfm = load_model_df(model_path, "imputation_MMM")
-        for _, r in dfm.iterrows():
-            fills[r["attribute"]] = (r["kind"], r["fill_value"])
-    else:
-        if num_cols:
-            X, M = idf.numeric_block(num_cols)
-            if method_type == "mean":
-                vals = np.asarray(masked_moments(X, M)["mean"])
-            else:
-                vals = np.asarray(
-                    masked_quantiles(X, M, jnp.array([0.5], jnp.float32), interpolation="lower")
-                )[0]
-            for c, v in zip(num_cols, vals):
-                fills[c] = ("num", float(v))
-        for c in cat_cols:
-            col = idf.columns[c]
-            cnts = np.asarray(code_counts(col.data, col.mask, max(len(col.vocab), 1)))[: max(len(col.vocab), 1)]
-            fills[c] = ("cat", str(col.vocab[int(np.argmax(cnts))]) if len(col.vocab) and cnts.max() > 0 else None)
-        if model_path != "NA":
-            save_model_df(
-                pd.DataFrame(
-                    [{"attribute": c, "kind": k, "fill_value": str(v)} for c, (k, v) in fills.items()]
-                ),
-                model_path,
-                "imputation_MMM",
-            )
+    mode_code: Dict[str, int] = {}  # a mode counted here: its code, so nobody looks its value up again
+    with tracer.phase("transform/fit", **shape, **fitted):
+        if pre_existing_model:
+            dfm = load_model_df(model_path, "imputation_MMM")
+            for _, r in dfm.iterrows():
+                fills[r["attribute"]] = (r["kind"], r["fill_value"])
+        else:
+            if num_cols:
+                X, M = idf.numeric_block(num_cols)
+                if method_type == "mean":
+                    vals = np.asarray(masked_moments(X, M)["mean"])
+                else:
+                    vals = np.asarray(
+                        masked_quantiles(X, M, jnp.array([0.5], jnp.float32), interpolation="lower")
+                    )[0]
+                for c, v in zip(num_cols, vals):
+                    fills[c] = ("num", float(v))
+            for c in cat_cols:
+                col = idf.columns[c]
+                vsize = max(len(col.vocab), 1)
+                cnts = np.asarray(code_counts(col.data, col.mask, vsize))[:vsize]
+                code = int(np.argmax(cnts))  # a tie: the lowest code, the first value in code-point order
+                if len(col.vocab) and cnts[code] > 0:
+                    fills[c] = ("cat", str(col.vocab[code]))
+                    mode_code[c] = code
+                else:
+                    fills[c] = ("cat", None)
+            if model_path != "NA":
+                save_model_df(
+                    pd.DataFrame(
+                        [{"attribute": c, "kind": k, "fill_value": str(v)} for c, (k, v) in fills.items()]
+                    ),
+                    model_path,
+                    "imputation_MMM",
+                )
 
     new_cols: "OrderedDict[str, Column]" = OrderedDict()
-    for c in cols:
-        if c not in fills:
-            continue
-        kind, v = fills[c]
-        col = idf.columns[c]
-        if col.kind == "num":
-            fv = float(v)
-            if np.isnan(fv):
+    with tracer.phase("transform/apply", **shape):
+        for c in cols:
+            if c not in fills:
                 continue
-            # fill + cast in one shared program per (shape, dtype)
-            if col.data.dtype == jnp.int32 and float(fv).is_integer():
-                data = _impute_num_int_program(col.data, col.mask,
+            kind, v = fills[c]
+            col = idf.columns[c]
+            if col.kind == "num":
+                fv = float(v)
+                if np.isnan(fv):
+                    continue
+                # fill + cast in one shared program per (shape, dtype)
+                if col.data.dtype == jnp.int32 and float(fv).is_integer():
+                    data = _impute_num_int_program(col.data, col.mask,
+                                                   np.float32(fv))
+                else:
+                    data = _impute_num_program(col.data, col.mask,
                                                np.float32(fv))
+                rv = _row_valid_program(col.mask, np.int32(idf.nrows))
+                new_cols[c] = Column("num", data, rv, dtype_name=col.dtype_name)
             else:
-                data = _impute_num_program(col.data, col.mask,
-                                           np.float32(fv))
-            rv = _row_valid_program(col.mask, np.int32(idf.nrows))
-            new_cols[c] = Column("num", data, rv, dtype_name=col.dtype_name)
-        else:
-            if v is None:
-                continue
-            hits = np.nonzero(col.vocab == v)[0]
-            if len(hits) == 0:
-                vocab = np.append(col.vocab, v).astype(object)
-                code = len(vocab) - 1
-            else:
-                vocab, code = col.vocab, int(hits[0])
-            data, rv = _impute_cat_program(col.data, col.mask,
-                                           np.int32(code),
-                                           np.int32(idf.nrows))
-            new_cols[c] = Column("cat", data, rv, vocab=vocab,
-                                 dtype_name="string")
+                if v is None:
+                    continue
+                if c in mode_code:
+                    vocab, code = col.vocab, mode_code[c]
+                else:  # a saved model's value: found in this table's vocab, or added to it
+                    hits = np.nonzero(col.vocab == v)[0]
+                    if len(hits) == 0:
+                        vocab = np.append(col.vocab, v).astype(object)
+                        code = len(vocab) - 1
+                    else:
+                        vocab, code = col.vocab, int(hits[0])
+                data, rv = _impute_cat_program(col.data, col.mask,
+                                               np.int32(code),
+                                               np.int32(idf.nrows))
+                new_cols[c] = Column("cat", data, rv, vocab=vocab,
+                                     dtype_name="string")
     odf = _emit(idf, new_cols, output_mode, "_imputed")
     if print_impact:
         logger.info(f"imputed ({method_type}): {list(new_cols)}")
@@ -1033,7 +1074,6 @@ def outlier_categories(
         # replays ONE compiled gather per row shape instead of one per
         # vocab size (the eager per-column indexing compiled a gather
         # program per column here — cold-compile census)
-        from anovos_tpu.ops.segment import vocab_lookup
 
         data = jnp.where(col.data >= 0, vocab_lookup(code_map, col.data), -1)
         new_cols[c] = Column("cat", data.astype(jnp.int32), col.mask, vocab=new_vocab, dtype_name="string")

@@ -47,16 +47,27 @@ def _masked_nunique(X: jax.Array, M: jax.Array, cp: bool = False) -> jax.Array:
 
 
 def _bucket_segments(n: int) -> int:
-    """Static segment counts round up to 4^k size classes (min 16): every
-    vocab size in a table then reuses ONE compiled program per row shape —
-    unbucketed, a 19-column describe compiled code_counts 16 times on
-    identical array shapes, a fresh XLA compile each.
+    """Static segment counts round up to 16^k size classes (min 16) up to
+    65,536, and to 2^k classes above: every vocab size in a table then
+    reuses ONE compiled program per row shape — unbucketed, a 19-column
+    describe compiled code_counts 16 times on identical array shapes, a
+    fresh XLA compile each.
     Power-of-SIXTEEN (coarser than describe_cat's dense-sweep pow-4
     buckets, which pay O(rows·k·vocab) per lane and must stay fine):
     segment_sum cost is rows-driven and the outputs are (vocab,)-scale
     vectors, so the coarse classes {16, 256, 4096, 65536} trade idle
     output lanes for a near-minimal distinct-program count across a run's
-    vocab-size spread (cold-compile census)."""
+    vocab-size spread (cold-compile census).
+    Above 65,536 the next 16^k classes are 1,048,576 and 16,777,216: a
+    vocabulary of 65,537 values would pad to sixteen times its size, and
+    every count vector fetched and every LUT uploaded is that padded vector
+    (4 bytes a lane, up to 64 MB an array, per column per call).  There the
+    padded dimension is memory-proportional, which is what
+    ``bucket_segments_pow2`` is for: at most twice the vocabulary, and at
+    most eight more classes up to 2^24 (hashed ids of 10^5–10^7 values: a
+    click log's categoricals)."""
+    if n > 65536:
+        return bucket_segments_pow2(n)
     b = 16
     while b < max(n, 1):
         b *= 16
@@ -133,7 +144,7 @@ def _lut_gather(lut: jax.Array, codes: jax.Array) -> jax.Array:
 def vocab_lookup(lut_host, codes: jax.Array) -> jax.Array:
     """Per-code lookup through a small host-built table.
 
-    The LUT is padded to a 2^k size class so every vocab size shares one
+    The LUT is padded to its ``_bucket_segments`` class so every vocab size shares one
     compiled gather per row shape (eagerly indexing ``jnp.asarray(lut)[codes]``
     per column compiled ~70 distinct gather programs across an e2e run).
     Codes are clipped; callers keep their own null/validity masking."""

@@ -240,7 +240,9 @@ class Table:
         nrows: Optional[int] = None,
     ) -> "Table":
         """Build from host column arrays (object arrays → cat; datetime64 →
-        ts; numeric → num).  NaN/None become nulls."""
+        ts; numeric → num).  NaN/None become nulls, and so do the masked
+        entries of a ``np.ma.MaskedArray`` (integers with nulls: the values
+        stay integers and exact, the mask is the column's)."""
         rt = get_runtime()
         cols: "OrderedDict[str, Column]" = OrderedDict()
         if not data:
@@ -249,7 +251,7 @@ class Table:
         npad = rt.pad_rows(max(n, 1))
         for name, arr in data.items():
             if not isinstance(arr, NativeEncodedStrings):
-                arr = np.asarray(arr)
+                arr = np.asanyarray(arr)  # a masked array keeps its mask
             cols[name] = _host_to_column(arr, n, npad, rt)
         return Table(cols, n)
 
@@ -883,9 +885,15 @@ def _plain_to_host(arr: np.ndarray, n: int) -> HostColumn:
                           dtype_name="boolean")
     # numeric
     dtn = _spark_dtype_name(arr.dtype)
-    vals = arr[:n]
+    masked = np.ma.getmaskarray(arr)[:n] if isinstance(arr, np.ma.MaskedArray) else None
+    vals = np.ma.getdata(arr)[:n]
+    if masked is not None and masked.any():
+        # what lies under the mask is anything: zero, as a padding row holds
+        vals = np.where(masked, vals.dtype.type(0), vals)
+    else:
+        masked = None
     if vals.dtype.kind == "f":
-        isnull = np.isnan(vals)
+        isnull = np.isnan(vals) if masked is None else np.isnan(vals) | masked
         clean = np.where(isnull, 0.0, vals) if isnull.any() else vals
         host = clean.astype(np.float32)
         if vals.dtype.itemsize > 4:
@@ -897,7 +905,7 @@ def _plain_to_host(arr: np.ndarray, n: int) -> HostColumn:
                 return HostColumn("num", host, ~isnull, dtype_name=dtn,
                                   wide_hi=whi, wide_lo=wlo, wide_kind="float")
     else:
-        isnull = np.zeros(n, dtype=bool)
+        isnull = np.zeros(n, dtype=bool) if masked is None else masked
         if vals.dtype.itemsize > 4:
             lo, hi = vals.min(initial=0), vals.max(initial=0)
             if lo >= np.iinfo(np.int32).min and hi <= np.iinfo(np.int32).max:
@@ -906,7 +914,7 @@ def _plain_to_host(arr: np.ndarray, n: int) -> HostColumn:
                 # wide int64: f32 approximation for moment kernels + exact
                 # (hi, lo) int32 pair for distinct/mode/percentiles/joins
                 whi, wlo = wide_int_parts(vals)
-                return HostColumn("num", vals.astype(np.float32), np.ones(n, bool),
+                return HostColumn("num", vals.astype(np.float32), ~isnull,
                                   dtype_name="bigint", wide_hi=whi, wide_lo=wlo)
         else:
             host = vals.astype(np.int32) if vals.dtype.kind in "iu" else vals.astype(np.float32)
@@ -1017,7 +1025,8 @@ def _frame_arrays(df, encode) -> Dict[str, Union[np.ndarray, NativeEncodedString
     column of a string dtype or of dtype ``category`` through ``encode``, from
     the Series; an ``object`` column as objects; every other dtype as its
     numpy array; an Arrow-typed decimal or date column
-    (:func:`arrow_typed_kind`) as float64 or ``datetime64[s]``."""
+    (:func:`arrow_typed_kind`) as float64 or ``datetime64[s]``; a column of
+    pandas' nullable integers as a masked array of its integers."""
     data = {}
     for name in df.columns:
         s = df[name]
@@ -1025,6 +1034,10 @@ def _frame_arrays(df, encode) -> Dict[str, Union[np.ndarray, NativeEncodedString
             data[name] = encode(s)
         elif arrow_typed_kind(s.dtype):
             data[name] = arrow_typed_to_numpy(s)
+        elif isinstance(s.array, pd.arrays.IntegerArray):
+            # pandas' nullable integers (a parquet integer column with nulls):
+            # values and mask as they are, no float and no object a value
+            data[name] = np.ma.MaskedArray(s.array._data, s.array._mask)
         elif s.dtype == object:
             data[name] = s.to_numpy(dtype=object)
         else:
@@ -1049,7 +1062,7 @@ def host_table_frame(df):
     out = {}
     for name, arr in _frame_arrays(df, encode).items():
         if not isinstance(arr, NativeEncodedStrings):
-            arr = np.asarray(arr)
+            arr = np.asanyarray(arr)
             if arr.dtype.kind in "OUS":
                 arr = encode(arr[:n])
         out[name] = _host_column_to_pandas(_plain_to_host(arr, n))

@@ -56,6 +56,7 @@ _WORKER = textwrap.dedent(
                 "nunique": stats["nunique"].tolist(),
                 "cat_counts": cat_counts,
                 "vocabs": vocabs,
+                "dtype_names": {c: t.columns[c].dtype_name for c in t.col_names},
             },
             open(out, "w"),
         )
@@ -76,6 +77,12 @@ def test_two_process_stats_parity(tmp_path):
         }
     )
     df.loc[rng.choice(n, 200, replace=False), "a"] = np.nan
+    # an integer with nulls in the FIRST part only: host 0 reads pandas' nullable
+    # integer, host 1 a plain int64, and both must take the masked integer branch
+    clicks = pd.array(rng.integers(0, 1000, n), "Int64")
+    clicks[rng.choice(n // 2, 300, replace=False)] = pd.NA
+    clicks[5], clicks[n - 5] = 2**24 + 1, 2**24 + 3
+    df["b"] = clicks
     data_dir = tmp_path / "data"
     data_dir.mkdir()
     # two part files with DIFFERENT category mixes so the vocab union matters
@@ -109,6 +116,7 @@ def test_two_process_stats_parity(tmp_path):
         assert abs(got["mean"][i] - float(exp[c].mean())) < 1e-2 * max(1, abs(exp[c].mean())), c
         if c == "wide_id":  # exactness through the distributed wide pair
             assert got["nunique"][i] == exp[c].nunique(), c
+    assert got["dtype_names"] == {"a": "double", "b": "bigint", "wide_id": "bigint", "cat": "string"}
     vocab = got["vocabs"]["cat"]
     assert "only_in_part2" in vocab  # union across hosts
     exp_counts = exp["cat"].value_counts()

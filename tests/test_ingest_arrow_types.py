@@ -188,3 +188,95 @@ def test_stats_measures_describe_no_date_and_count_its_rows(parts):
     assert ct.loc["price", "median"] == 1005.05  # the fifth of nine values, from the exact pair
     card = sg.measures_of_cardinality(t).set_index("attribute")
     assert card.loc["price", "unique_values"] == 9 and card.loc["flag", "unique_values"] == 2
+
+
+# ------------------------------------------------- integers with nulls ----
+def _nullable_parts(tmp_path):
+    """Two parts: ``n`` has nulls in the first only and a value beyond 2^24,
+    ``w`` nulls and a value beyond 2^31, ``k`` and ``small`` none."""
+    os.makedirs(tmp_path / "in")
+    first = pa.table({"n": pa.array([1, None, 30_000_001, -5], pa.int64()), "k": pa.array([1, 2, 3, 4], pa.int64()),
+                      "w": pa.array([None, 2**40 + 1, 5, None], pa.int64()),
+                      "small": pa.array([7, None, 9, 9], pa.int16()), "s": ["x", None, "y", "x"]})
+    second = pa.table({"n": pa.array([7, 8, 9, 2**24 + 1], pa.int64()), "k": pa.array([5, 6, 7, 8], pa.int64()),
+                       "w": pa.array([1, 2, 3, 4], pa.int64()),
+                       "small": pa.array([1, 2, 3, 4], pa.int16()), "s": ["x", None, "y", "x"]})
+    pq.write_table(first, str(tmp_path / "in" / "part-00000.parquet"))
+    pq.write_table(second, str(tmp_path / "in" / "part-00001.parquet"))
+    return str(tmp_path / "in")
+
+
+def test_a_parquet_integer_with_nulls_stays_an_integer_with_a_mask(tmp_path, counted):
+    t = read_dataset(_nullable_parts(tmp_path), "parquet")
+    assert t.attribute_type_segregation() == (["n", "k", "w", "small"], ["s"], [])
+    n, k, w, small = (t.columns[c] for c in ("n", "k", "w", "small"))
+    assert (n.dtype_name, k.dtype_name, w.dtype_name, small.dtype_name) == ("bigint", "bigint", "bigint", "int")
+    assert str(n.data.dtype) == str(k.data.dtype) == str(small.data.dtype) == "int32" and not n.is_wide
+    assert np.asarray(n.data)[:8].tolist() == [1, 0, 30_000_001, -5, 7, 8, 9, 2**24 + 1]  # to the unit, not f32's
+    assert np.asarray(n.mask)[:8].tolist() == [True, False, True, True, True, True, True, True]
+    assert np.asarray(k.mask)[:8].all() and np.asarray(small.mask)[:8].tolist() == [True, False] + [True] * 6
+    assert w.is_wide_int and np.asarray(w.mask)[:8].tolist() == [False, True, True, False, True, True, True, True]
+    assert w.exact_host(8)[[1, 2, 4]].tolist() == [2**40 + 1, 5, 1]
+    back = t.to_pandas()
+    assert back["w"].tolist()[1:3] == [2**40 + 1, 5] and back["w"].isna().tolist()[:4] == [True, False, False, True]
+    assert back["k"].dtype == np.int32 and back["n"].isna().sum() == 1 and back["n"][2] == 30_000_001
+    assert counted == {"loop": 0, "to_numeric": 0}  # no value became a Python object on the way
+
+
+def test_the_types_mapper_touches_only_an_integer_type_with_a_null_in_that_part(tmp_path):
+    path = _nullable_parts(tmp_path)
+    first, second = (pq.read_table(os.path.join(path, f)) for f in sorted(os.listdir(path)))
+    assert data_ingest._part_types_mapper(second) is data_ingest._keep_arrow_typed  # no null: as it always read
+    mapper = data_ingest._part_types_mapper(first)
+    assert mapper(pa.int64()) == pd.Int64Dtype() and mapper(pa.int16()) == pd.Int16Dtype()
+    assert mapper(pa.int32()) is None and mapper(pa.string()) is None and mapper(pa.float64()) is None
+    assert isinstance(mapper(pa.decimal128(15, 2)), pd.ArrowDtype)
+    df = first.to_pandas(types_mapper=mapper)
+    assert str(df["n"].dtype) == "Int64" and str(df["k"].dtype) == "Int64" and str(df["small"].dtype) == "Int16"
+    assert str(second.to_pandas(types_mapper=data_ingest._part_types_mapper(second))["n"].dtype) == "int64"
+
+
+def test_a_nullable_integer_beyond_2_to_the_24_survives_read_impute_and_write(tmp_path):
+    from anovos_tpu.data_transformer import transformers as T
+
+    t = read_dataset(_nullable_parts(tmp_path), "parquet")
+    filled = T.imputation_MMM(t, list_of_cols=["n", "small"], method_type="median")
+    write_dataset(filled, str(tmp_path / "out"), "parquet", {"mode": "overwrite"})
+    back = pd.read_parquet(str(tmp_path / "out" / "part-00000.parquet"))
+    # n: the lower median of -5 1 7 8 9 16777217 30000001 is 8; small: of 1 2 3 4 7 9 9 it is 4
+    assert back["n"].tolist() == [1, 8, 30_000_001, -5, 7, 8, 9, 2**24 + 1] and back["n"].dtype == np.int32
+    assert back["small"].tolist() == [7, 4, 9, 9, 1, 2, 3, 4]
+    assert back["k"].tolist() == list(range(1, 9)) and back["s"].isna().sum() == 2  # untouched, row order kept
+    assert back["w"].tolist()[1:3] == [2**40 + 1, 5] and back["w"].isna().sum() == 2
+
+
+def test_the_multi_host_reader_takes_the_same_masked_integers(tmp_path):
+    """One process of ``read_dataset_distributed`` against ``read_dataset`` on
+    the same parts: the same kind, dtype name, device dtype, mask and exact
+    values, column by column (a part without a null reads a plain integer
+    there too, and the schema agreement calls both ``num_i``)."""
+    from anovos_tpu.data_ingest.distributed_ingest import read_dataset_distributed
+
+    path = _nullable_parts(tmp_path)
+    one, many = read_dataset(path, "parquet"), read_dataset_distributed(path, "parquet")
+    assert many.nrows == one.nrows == 8 and many.col_names == one.col_names
+    for name in ("n", "k", "w", "small"):
+        a, b = one.columns[name], many.columns[name]
+        assert (b.kind, b.dtype_name, str(b.data.dtype), b.is_wide_int) == \
+            (a.kind, a.dtype_name, str(a.data.dtype), a.is_wide_int), name
+        mask = np.asarray(a.mask)[:8]
+        assert np.asarray(b.mask)[:8].tolist() == mask.tolist(), name
+        assert a.exact_host(8)[mask].tolist() == b.exact_host(8)[mask].tolist(), name
+    assert np.asarray(many.columns["n"].data)[:8].tolist() == [1, 0, 30_000_001, -5, 7, 8, 9, 2**24 + 1]
+
+
+def test_a_frame_of_pandas_nullable_integers_goes_the_same_way(counted):
+    df = pd.DataFrame({"n": pd.array([1, None, 2**24 + 1], "Int64"), "u": pd.array([3, 4, None], "UInt8"),
+                       "full": pd.array([1, 2, 3], "Int32")})
+    t = Table.from_pandas(df)
+    assert np.asarray(t.columns["n"].data)[:3].tolist() == [1, 0, 2**24 + 1]
+    assert np.asarray(t.columns["n"].mask)[:3].tolist() == [True, False, True]
+    assert np.asarray(t.columns["u"].mask)[:3].tolist() == [True, True, False]
+    assert np.asarray(t.columns["full"].mask)[:3].all() and t.columns["full"].dtype_name == "int"
+    pd.testing.assert_frame_equal(host_table_frame(df), t.to_pandas())
+    assert counted == {"loop": 0, "to_numeric": 0}
