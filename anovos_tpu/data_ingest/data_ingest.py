@@ -32,6 +32,7 @@ import pyarrow.parquet as pq
 
 from anovos_tpu.data_ingest import avro_io
 from anovos_tpu.data_ingest import guard
+from anovos_tpu.shared.host_pool import get_host_pool, record_units
 from anovos_tpu.shared.runtime import get_runtime
 from anovos_tpu.shared.table import (
     Column, Table, _host_to_column, _pad_to, arrow_typed_kind, arrow_typed_to_numpy, host_table_frame)
@@ -103,6 +104,15 @@ def shard_files_for_process(files: List[str]) -> List[str]:
     if _jax.process_count() <= 1:
         return files
     return files[_jax.process_index() :: _jax.process_count()]
+
+
+# The part files of a read of this many bytes or more are decoded side by side
+# on the host pool; a smaller read's in a loop.  What ``read_host_frame`` can
+# see of a read's size before it has decoded it is its files' bytes: 8 MiB of
+# parquet is 1.4-2.2 x 10^5 rows of the benchmark's tables, about where
+# ``shared/table.py`` starts to encode a frame's string columns side by side
+# (``_POOLED_ENCODE_MIN_ROWS``).
+_POOLED_DECODE_MIN_BYTES = 1 << 23
 
 
 def _file_bytes(f: str) -> int:
@@ -314,22 +324,38 @@ def read_host_frame(files: List[str], file_type: str, cfg: dict) -> pd.DataFrame
     """Host pandas frame from part files (shared by the single-process and
     multi-host loaders) — GUARDED: each part decodes under the quarantine/
     retry policy, schemas reconcile across parts, and hostile values are
-    sanitized at this boundary (anovos_tpu.data_ingest.guard)."""
+    sanitized at this boundary (anovos_tpu.data_ingest.guard).
+
+    The parts of a read of ``_POOLED_DECODE_MIN_BYTES`` or more are units of
+    the host pool (``shared.host_pool``), decoded side by side, each under
+    its own guard and its own ``ingest/decode`` span; the frame is assembled
+    in file order, a quarantined part left out, and under a ``raise`` policy
+    the error is the first bad part's in file order (parts after it may have
+    been read by then, which a loop would not have started).
+    ``decode_workers`` (threads that decoded a part; 0 for the loop) and
+    ``decode_wall_s`` (first start to last end) go on the row of the pass's
+    tree the call runs under."""
     if file_type not in ("csv", "parquet", "avro", "json"):
         raise ValueError(f"unsupported file_type: {file_type}")
     from anovos_tpu.obs import get_tracer
 
     tracer = get_tracer()
     pol = guard.policy_from_env()
-    frames: List = []
-    for f in files:
-        with tracer.phase("ingest/decode", cat="io", bytes=_file_bytes(f)) as sp:
+    sizes = {f: _file_bytes(f) for f in files}
+
+    def decode(f: str):
+        with tracer.phase("ingest/decode", cat="io", bytes=sizes[f]) as sp:
             df = guard.guarded_part_read(
-                f, lambda f=f: _read_one_part(f, file_type, cfg),
+                f, lambda: _read_one_part(f, file_type, cfg),
                 file_type=file_type, policy=pol)
             if df is not None:
                 sp.add(rows=len(df))
-                frames.append((f, df))
+            return df
+
+    ran = get_host_pool().run(
+        decode, files, side_by_side=sum(sizes.values()) >= _POOLED_DECODE_MIN_BYTES)
+    record_units("decode", ran)
+    frames = [(f, df) for f, df in zip(files, ran.results) if df is not None]
     if not frames:
         raise guard.IngestError(
             f"every {file_type} part was quarantined ({len(files)} file(s), "

@@ -221,13 +221,41 @@ class Tracer:
         in between (``obs.timed`` around a library call) is none and is
         passed over.  Anywhere else (on a writer thread, outside any pass)
         the same work is an ordinary span of category ``cat``."""
-        stack = self._stack()
-        row = next((sp for sp in reversed(stack) if sp.tree), None)
+        row = self.tree_row()
         if row is not None:
             cat = "phase"
-            if row is not stack[-1]:
+            if row is not self._stack()[-1]:
                 attrs["parent"] = row.name
         return self.span(name, cat=cat, **attrs)
+
+    def tree_row(self) -> Optional[OpenSpan]:
+        """The innermost span open on THIS thread that is a row of the pass's
+        tree (what a ``phase()`` opened here would name as its parent), or
+        None."""
+        return next((sp for sp in reversed(self._stack()) if sp.tree), None)
+
+    def open_spans(self) -> List[OpenSpan]:
+        """The spans open on THIS thread, outermost first: what a thread
+        that hands work to another passes to :meth:`under`."""
+        return list(self._stack())
+
+    @contextmanager
+    def under(self, spans: List[OpenSpan]):
+        """Run the body as work handed over by the thread that had ``spans``
+        open (its :meth:`open_spans`): on a thread with no span of its own
+        open (a pool thread) a span opened inside has the parent, and a
+        ``phase()`` the row of the tree, that it would have had on the
+        submitting thread; without this a pool thread's phases would be
+        filed outside the tree.  The hand-over itself records no span."""
+        stack = self._stack()
+        if stack or not spans:
+            yield
+            return
+        stack.extend(spans)
+        try:
+            yield
+        finally:
+            del stack[:]
 
     def in_pass(self) -> bool:
         """Whether THIS thread is inside a ``run_pass()``."""

@@ -24,10 +24,8 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import os
 import sys
 import time
-from concurrent.futures import ThreadPoolExecutor
 from collections import OrderedDict
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple, Union
 
@@ -39,6 +37,7 @@ import pyarrow as pa
 import pyarrow.compute as pc
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from anovos_tpu.shared.host_pool import get_host_pool, record_units
 from anovos_tpu.shared.native import NativeEncodedStrings
 from anovos_tpu.shared.runtime import get_runtime
 
@@ -680,6 +679,12 @@ def _arrow_sorted_vocab_codes(first: np.ndarray, dictionary: pa.Array) -> Native
 # text, ids) costs seconds in one hash table and one sort; its rows are
 # partitioned by their first bytes and the partitions encoded side by side.
 _BUCKETED_ENCODE_MIN_ROWS = 1 << 20
+# The string columns of a frame of this many rows or more are encoded side by
+# side on the host pool; a shorter frame's in a loop (the stats tables, a
+# node's small frames, a 32,561-row dataset): thirteen columns of 65,536 rows
+# are 3 ms each and threads that wake for them gave nothing back in the
+# median, at 131,072 rows they halved the wall (PERF.md section 3).
+_POOLED_ENCODE_MIN_ROWS = 1 << 17
 _BUCKETED_ENCODE_SAMPLE = 1 << 16
 _BUCKETED_ENCODE_BUCKETS_A_WORKER = 4
 _PREFIX_BYTES = 8
@@ -717,8 +722,10 @@ def _bucketed_encode(strings: pa.Array) -> Tuple[NativeEncodedStrings, Dict[str,
     buckets by :func:`_prefix_keys` at splitters taken from a sample of the
     keys, so that no value lies in two buckets and every value of a bucket
     sorts before every value of the next; each bucket is hashed and its
-    distinct values ordered by Arrow on a thread of its own (Arrow's kernels
-    release the GIL); the vocab is the buckets' vocabs one after the other, a
+    distinct values ordered by Arrow as a unit of the host pool (Arrow's
+    kernels release the GIL; the thread that partitioned the rows takes
+    buckets too, so a column that is itself a unit of the pool waits for no
+    queue); the vocab is the buckets' vocabs one after the other, a
     row's code its bucket's code plus the distinct values of the buckets
     before.  The same codes and vocab as the one hash table and one sort
     give.  ``hash_s``: the seconds to the end of the last bucket; ``sort_s``:
@@ -727,7 +734,8 @@ def _bucketed_encode(strings: pa.Array) -> Tuple[NativeEncodedStrings, Dict[str,
     t0 = time.perf_counter()
     n = len(strings)
     keys = _prefix_keys(strings)
-    workers = max(1, min(os.cpu_count() or 1, 16))
+    pool = get_host_pool()
+    workers = pool.threads
     sample = np.sort(keys[:: max(1, n // _BUCKETED_ENCODE_SAMPLE)])
     splitters = np.unique(sample[np.linspace(0, len(sample), workers * _BUCKETED_ENCODE_BUCKETS_A_WORKER + 1)
                                  .astype(np.int64)[1:-1]])
@@ -735,14 +743,13 @@ def _bucketed_encode(strings: pa.Array) -> Tuple[NativeEncodedStrings, Dict[str,
     order = np.argsort(bucket, kind="stable")  # rows by bucket, in their own order within one
     edges = np.concatenate([[0], np.cumsum(np.bincount(bucket, minlength=len(splitters) + 1))])
 
-    def encode(lo: int, hi: int):
-        rows = order[lo:hi]
+    def encode(edge):
+        rows = order[edge[0]:edge[1]]
         enc = strings.take(pa.array(rows)).dictionary_encode()
         first = enc.indices.fill_null(-1).to_numpy(zero_copy_only=False)
         return rows, _arrow_ordered(first, enc.dictionary)
 
-    with ThreadPoolExecutor(max_workers=workers) as pool:
-        parts = list(pool.map(encode, edges[:-1], edges[1:]))
+    parts = pool.run(encode, list(zip(edges[:-1], edges[1:]))).results
     t1 = time.perf_counter()
     codes = np.empty(n, dtype=np.int32)
     before = 0
@@ -1026,12 +1033,20 @@ def _frame_arrays(df, encode) -> Dict[str, Union[np.ndarray, NativeEncodedString
     the Series; an ``object`` column as objects; every other dtype as its
     numpy array; an Arrow-typed decimal or date column
     (:func:`arrow_typed_kind`) as float64 or ``datetime64[s]``; a column of
-    pandas' nullable integers as a masked array of its integers."""
+    pandas' nullable integers as a masked array of its integers.  The string
+    columns of a frame of ``_POOLED_ENCODE_MIN_ROWS`` rows or more are units
+    of the host pool (``shared.host_pool``), encoded side by side once this
+    thread has taken the other columns' arrays; the dict is in the frame's
+    column order either way.  ``encode_workers`` (threads that
+    encoded a column; 0 for the loop) and ``encode_wall_s`` (first start to
+    last end) go on the row of the pass's tree the call runs under."""
     data = {}
+    strings = []
     for name in df.columns:
         s = df[name]
         if isinstance(s.dtype, (pd.StringDtype, pd.CategoricalDtype)):
-            data[name] = encode(s)
+            data[name] = None  # its place in the frame's order
+            strings.append(name)
         elif arrow_typed_kind(s.dtype):
             data[name] = arrow_typed_to_numpy(s)
         elif isinstance(s.array, pd.arrays.IntegerArray):
@@ -1042,6 +1057,11 @@ def _frame_arrays(df, encode) -> Dict[str, Union[np.ndarray, NativeEncodedString
             data[name] = s.to_numpy(dtype=object)
         else:
             data[name] = s.to_numpy()
+    if strings:
+        ran = get_host_pool().run(lambda name: encode(df[name]), strings,
+                                  side_by_side=len(df) >= _POOLED_ENCODE_MIN_ROWS)
+        data.update(zip(strings, ran.results))
+        record_units("encode", ran)
     return data
 
 
