@@ -22,9 +22,11 @@ import pandas as pd
 
 from anovos_tpu.data_ingest.geo_auto_detection import ll_gh_cols
 from anovos_tpu.data_transformer.geo_utils import geohash_decode
+from anovos_tpu.obs import get_tracer
 from anovos_tpu.ops.cluster import dbscan_fit, kmeans_elbow, kmeans_fit
 from anovos_tpu.shared.table import Table
 from anovos_tpu.shared.utils import ends_with
+from anovos_tpu.shared.utils import write_csv_counted as _write_csv
 
 
 def _latlon_points(idf: Table, lat_col: str, lon_col: str, max_records: int) -> np.ndarray:
@@ -219,37 +221,46 @@ def descriptive_stats_gen(
     summary plus the top-geohash table.  Returns the flat stats row that
     ``geospatial_stats.csv`` aggregates."""
     Path(master_path).mkdir(parents=True, exist_ok=True)
+    phase, out = get_tracer().phase, ends_with(master_path)
     if lat_col is not None and long_col is not None:
-        pts = _pts if _pts is not None else _latlon_points(idf, lat_col, long_col, _max_records)
-        stats, pair_counts = _pair_profile(idf, lat_col, long_col, pts)
-        top = (
-            pair_counts.head(max_val).reset_index(name="count")
-            if pair_counts is not None
-            else pd.DataFrame(columns=["lat", "lon", "count"])
-        )
-        top.to_csv(ends_with(master_path) + f"geospatial_top_{lat_col}_{long_col}.csv", index=False)
-        _write_geo_charts(master_path, f"{lat_col}_{long_col}", top)
-        if stats.get("records"):
-            pd.DataFrame(
-                {
-                    "stats": [
-                        "Distinct {Lat, Long} Pair", "Distinct Latitude", "Distinct Longitude",
-                        "Most Common {Lat, Long} Pair", "Most Common Pair Occurrence",
-                    ],
-                    "count": [
-                        stats["distinct_pairs"], stats["distinct_lat"], stats["distinct_lon"],
-                        stats["most_common_pair"], stats["most_common_pair_count"],
-                    ],
-                }
-            ).to_csv(
-                ends_with(master_path) + f"geospatial_overall_{lat_col}_{long_col}.csv", index=False
+        with phase("geo/stats", cat="block") as sp:  # pandas over the pair's points on the host
+            pts = _pts if _pts is not None else _latlon_points(idf, lat_col, long_col, _max_records)
+            stats, pair_counts = _pair_profile(idf, lat_col, long_col, pts)
+            top = (
+                pair_counts.head(max_val).reset_index(name="count")
+                if pair_counts is not None
+                else pd.DataFrame(columns=["lat", "lon", "count"])
             )
+            sp.add(rows=len(pts), distinct=stats.get("distinct_pairs", 0))
+        with phase("geo/write", cat="block") as sp:
+            _write_csv(top, out + f"geospatial_top_{lat_col}_{long_col}.csv", sp)
+        with phase("geo/charts", cat="block", rows=len(top)):
+            _write_geo_charts(master_path, f"{lat_col}_{long_col}", top)
+        with phase("geo/write", cat="block") as sp:
+            if stats.get("records"):
+                _write_csv(pd.DataFrame(
+                    {
+                        "stats": [
+                            "Distinct {Lat, Long} Pair", "Distinct Latitude", "Distinct Longitude",
+                            "Most Common {Lat, Long} Pair", "Most Common Pair Occurrence",
+                        ],
+                        "count": [
+                            stats["distinct_pairs"], stats["distinct_lat"], stats["distinct_lon"],
+                            stats["most_common_pair"], stats["most_common_pair_count"],
+                        ],
+                    }
+                ), out + f"geospatial_overall_{lat_col}_{long_col}.csv", sp)
         return stats
     if geohash_col is not None:
-        top_gh, overall, row = _geohash_profile(idf, geohash_col, max_val)
-        top_gh.to_csv(ends_with(master_path) + f"geospatial_top_{geohash_col}.csv", index=False)
-        _write_geo_charts(master_path, geohash_col, top_gh)
-        overall.to_csv(ends_with(master_path) + f"geospatial_overall_{geohash_col}.csv", index=False)
+        with phase("geo/stats", cat="block", rows=idf.padded_rows, fetches=1) as sp:  # one group count, then the vocab
+            top_gh, overall, row = _geohash_profile(idf, geohash_col, max_val)
+            sp.add(distinct=row["distinct_pairs"])
+        with phase("geo/write", cat="block") as sp:
+            _write_csv(top_gh, out + f"geospatial_top_{geohash_col}.csv", sp)
+        with phase("geo/charts", cat="block", rows=len(top_gh)):
+            _write_geo_charts(master_path, geohash_col, top_gh)
+        with phase("geo/write", cat="block") as sp:
+            _write_csv(overall, out + f"geospatial_overall_{geohash_col}.csv", sp)
         return row
     return None
 
@@ -293,7 +304,8 @@ def stats_gen_lat_long_geo(
     rows = lat_long_col_stats_gen(idf, lat_col, long_col, id_col, master_path, max_val)
     rows += geohash_col_stats_gen(idf, geohash_col, id_col, master_path, max_val)
     if rows:
-        pd.DataFrame(rows).to_csv(ends_with(master_path) + "geospatial_stats.csv", index=False)
+        with get_tracer().phase("geo/write", cat="block") as sp:
+            _write_csv(pd.DataFrame(rows), ends_with(master_path) + "geospatial_stats.csv", sp)
     return rows
 
 
@@ -394,12 +406,15 @@ def cluster_analysis(
 ) -> Tuple[pd.DataFrame, pd.DataFrame]:
     """KMeans elbow + DBSCAN grid (reference :390-733).  Returns
     (kmeans_centers_frame, dbscan_grid_frame)."""
-    best_k, inertias = kmeans_elbow(pts, max_k=min(max_cluster, max(2, len(pts) // 10 or 2)))
-    # host f32 cast: jnp.asarray compiled a convert program per call; a np
-    # cast rounds identically and rides the jit boundary as a plain transfer
-    centers, labels, _ = kmeans_fit(np.asarray(pts, np.float32), best_k)
-    centers = np.asarray(centers)
-    counts = np.bincount(np.asarray(labels), minlength=best_k)
+    phase = get_tracer().phase
+    with phase("geo/cluster/kmeans", cat="block", rows=len(pts)) as sp:  # the elbow's fits, then the chosen k's
+        best_k, inertias = kmeans_elbow(pts, max_k=min(max_cluster, max(2, len(pts) // 10 or 2)))
+        # host f32 cast: jnp.asarray compiled a convert program per call; a np
+        # cast rounds identically and rides the jit boundary as a plain transfer
+        centers, labels, _ = kmeans_fit(np.asarray(pts, np.float32), best_k)
+        centers = np.asarray(centers)
+        counts = np.bincount(np.asarray(labels), minlength=best_k)
+        sp.add(k=best_k)
     km = pd.DataFrame(
         {
             "cluster": range(best_k),
@@ -434,30 +449,32 @@ def cluster_analysis(
     # 4096 default — use the tiled on-device propagation path instead.
     eps_values = [float(e) for e in np.arange(e0, e1 + 1e-9, estep)]
     D2 = None
-    if eps_values and len(sub) <= int(os.environ.get("ANOVOS_DBSCAN_HOST_CC_MAX", 6144)):
-        Xc = np.asarray(sub, np.float32)
-        Xc = Xc - Xc.mean(axis=0, keepdims=True)  # f32 bits follow the spread
-        D2 = np.asarray(jax.device_get(pairwise_d2(jnp.asarray(Xc))))
-        all_labels = dbscan_host_grid_multi(D2, eps_values, ms_eff)
-    combos = []  # (eps, min_samples, labels)
-    for a, e in enumerate(eps_values):
+    with phase("geo/cluster/dbscan", cat="block", rows=len(sub), combos=len(eps_values) * len(ms_values)):
+        if eps_values and len(sub) <= int(os.environ.get("ANOVOS_DBSCAN_HOST_CC_MAX", 6144)):
+            Xc = np.asarray(sub, np.float32)
+            Xc = Xc - Xc.mean(axis=0, keepdims=True)  # f32 bits follow the spread
+            D2 = np.asarray(jax.device_get(pairwise_d2(jnp.asarray(Xc))))
+            all_labels = dbscan_host_grid_multi(D2, eps_values, ms_eff)
+        combos = []  # (eps, min_samples, labels)
+        for a, e in enumerate(eps_values):
+            if D2 is not None:
+                labels_b = all_labels[a]
+            else:
+                # one neighbor-count pass per eps; all min_samples labeled in ONE
+                # batched device program (fixed shapes — one compile for the grid)
+                counts = neighbor_counts(sub, float(e))
+                labels_b = dbscan_grid(sub, float(e), ms_eff, counts=counts)
+            combos.extend((e, m, labels) for m, labels in zip(ms_values, labels_b))
+    with phase("geo/cluster/silhouette", cat="block", rows=len(sub), combos=len(combos)):
         if D2 is not None:
-            labels_b = all_labels[a]
+            # distances reused by every combo's silhouette sample; the
+            # silhouette path sqrt's AFTER sampling (bit-identical, ~1/64 the
+            # elementwise work), so it is handed the squared matrix
+            scores = _silhouettes_batched(D2, [lab for _, _, lab in combos],
+                                          squared=True)
         else:
-            # one neighbor-count pass per eps; all min_samples labeled in ONE
-            # batched device program (fixed shapes — one compile for the grid)
-            counts = neighbor_counts(sub, float(e))
-            labels_b = dbscan_grid(sub, float(e), ms_eff, counts=counts)
-        combos.extend((e, m, labels) for m, labels in zip(ms_values, labels_b))
-    if D2 is not None:
-        # distances reused by every combo's silhouette sample; the
-        # silhouette path sqrt's AFTER sampling (bit-identical, ~1/64 the
-        # elementwise work), so it is handed the squared matrix
-        scores = _silhouettes_batched(D2, [lab for _, _, lab in combos],
-                                      squared=True)
-    else:
-        # _silhouette itself returns -1.0 for <2 clusters / <10 valid points
-        scores = [_silhouette(sub, lab) for _, _, lab in combos]
+            # _silhouette itself returns -1.0 for <2 clusters / <10 valid points
+            scores = [_silhouette(sub, lab) for _, _, lab in combos]
     for (e, m, labels), score in zip(combos, scores):
         rows.append(
             {
@@ -492,10 +509,13 @@ def geo_cluster_analysis(
     pts = _pts if _pts is not None else _latlon_points(idf, lat_col, long_col, _max_records)
     if len(pts) < 50:
         return
-    km, db = cluster_analysis(pts, max_cluster or 20, eps, min_samples)
-    for name, frame in [("kmeans", km), ("dbscan", db)]:
-        frame.to_csv(ends_with(master_path) + f"geospatial_{name}_{col_name}.csv", index=False)
-        frame.to_csv(ends_with(master_path) + f"cluster_output_{name}_{col_name}.csv", index=False)
+    phase = get_tracer().phase
+    with phase("geo/cluster", cat="block", rows=len(pts)):
+        km, db = cluster_analysis(pts, max_cluster or 20, eps, min_samples)
+    with phase("geo/write", cat="block") as sp:
+        for name, frame in [("kmeans", km), ("dbscan", db)]:
+            _write_csv(frame, ends_with(master_path) + f"geospatial_{name}_{col_name}.csv", sp)
+            _write_csv(frame, ends_with(master_path) + f"cluster_output_{name}_{col_name}.csv", sp)
 
 
 def geo_cluster_generator(
@@ -549,17 +569,20 @@ def generate_loc_charts_processor(
 ) -> None:
     """Location-chart writer (reference :851-1027): scatter + density JSON
     per lat-long pair, and per geohash column after decode."""
+    phase = get_tracer().phase
     for lat_c, lon_c in zip(lat_col or [], long_col or []):
         # max_val caps the DISPLAYED top locations; the grid count itself
         # runs over the full analysis sample
-        pts = _latlon_points(idf, lat_c, lon_c, max(int(max_val), 100000))
-        _, pair_counts = _pair_profile(idf, lat_c, lon_c, pts)
-        if pair_counts is not None:
-            top = pair_counts.head(max_val).reset_index(name="count")
-            _write_geo_charts(master_path, f"{lat_c}_{lon_c}", top)
+        with phase("geo/charts", cat="block"):
+            pts = _latlon_points(idf, lat_c, lon_c, max(int(max_val), 100000))
+            _, pair_counts = _pair_profile(idf, lat_c, lon_c, pts)
+            if pair_counts is not None:
+                top = pair_counts.head(max_val).reset_index(name="count")
+                _write_geo_charts(master_path, f"{lat_c}_{lon_c}", top)
     for gh_c in geohash_col or []:
-        top_gh, _, _ = _geohash_profile(idf, gh_c, max_val)
-        _write_geo_charts(master_path, gh_c, top_gh)
+        with phase("geo/charts", cat="block"):
+            top_gh, _, _ = _geohash_profile(idf, gh_c, max_val)
+            _write_geo_charts(master_path, gh_c, top_gh)
 
 
 def generate_loc_charts_controller(
@@ -598,12 +621,17 @@ def geospatial_autodetection(
     ``geospatial_*`` stats/cluster CSVs + top-location dumps, return the
     detected (lat_cols, lon_cols, gh_cols)."""
     Path(master_path).mkdir(parents=True, exist_ok=True)
-    lat_cols, lon_cols, gh_cols = ll_gh_cols(idf, max_analysis_records)
+    phase = get_tracer().phase
+    with phase("geo/detect", cat="block", cols=len(idf.col_names)) as sp:
+        lat_cols, lon_cols, gh_cols = ll_gh_cols(idf, max_analysis_records)
+        sp.add(pairs=len(lat_cols), geohashes=len(gh_cols))
     stats_rows = []
     for lat_c, lon_c in zip(lat_cols, lon_cols):
         # points are extracted once per pair and shared by the stats writer
         # and the cluster scan (both accept them via _pts)
-        pts = _latlon_points(idf, lat_c, lon_c, max_analysis_records)
+        with phase("geo/points", cat="block", fetches=4) as sp:  # both columns, data and mask
+            pts = _latlon_points(idf, lat_c, lon_c, max_analysis_records)
+            sp.add(rows=len(pts))
         row = descriptive_stats_gen(
             idf, lat_c, lon_c, None, id_col, master_path, top_geo_records, _pts=pts
         )
@@ -615,7 +643,6 @@ def geospatial_autodetection(
         )
     stats_rows += geohash_col_stats_gen(idf, gh_cols, id_col, master_path, top_geo_records)
     if stats_rows:
-        pd.DataFrame(stats_rows).to_csv(
-            ends_with(master_path) + "geospatial_stats.csv", index=False
-        )
+        with phase("geo/write", cat="block") as sp:
+            _write_csv(pd.DataFrame(stats_rows), ends_with(master_path) + "geospatial_stats.csv", sp)
     return lat_cols, lon_cols, gh_cols

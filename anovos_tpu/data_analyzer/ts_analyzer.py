@@ -19,8 +19,10 @@ import jax.numpy as jnp
 import numpy as np
 import pandas as pd
 
+from anovos_tpu.obs import get_tracer
 from anovos_tpu.shared.table import Table
 from anovos_tpu.shared.utils import ends_with
+from anovos_tpu.shared.utils import write_csv_counted as _write_csv
 
 # the ts_stats.csv schema — shared by eligibility rows and the empty case
 TS_STATS_COLUMNS = [
@@ -316,73 +318,87 @@ def ts_viz_data(
     stationarity (report_generation.py:1942-3208 tab suite inputs)."""
     from anovos_tpu.data_transformer.datetime import aggregator
 
+    phase = get_tracer().phase
     out = ends_with(output_path)
     num_all, cat_all, _ = idf.attribute_type_segregation()
     num_cols = [c for c in num_all][:20]
     cat_cols = [c for c in cat_all][:10]
 
     feats = _feats if _feats is not None else ts_processed_feats(idf, col)
-    feats = feats.dropna(subset=[col])
-    daily = feats.groupby("yyyymmdd_col").size().reset_index(name="count")
-    daily.to_csv(out + f"ts_daily_{col}.csv", index=False)
+    with phase("ts/viz/counts", cat="block") as sp:  # the host's groupbys over the calendar features
+        feats = feats.dropna(subset=[col])
+        daily = feats.groupby("yyyymmdd_col").size().reset_index(name="count")
+        hourly = feats.groupby("hour").size().reset_index(name="count")
+        weekly = feats.groupby("dayofweek").size().reset_index(name="count")
+        dayparts = feats.groupby("daypart").size().reset_index(name="count")
+        sp.add(rows=len(feats))
+    with phase("ts/viz/write", cat="block") as sp:
+        _write_csv(daily, out + f"ts_daily_{col}.csv", sp)
 
     # numeric viz: all three grains in ONE fused dispatch
     # (_ts_num_viz_all); the per-grain path — daily via the device
     # groupby-aggregator, small grains via one segment program each — is
     # taken where that returns None (all-null or degenerate span)
     if num_cols:
-        viz = _ts_num_viz_all(idf, col, num_cols)
-        if viz is not None:
-            dv, hourly_df, weekly_df = viz
-        else:
-            dv = aggregator(idf, num_cols, _TS_NUM_AGGS, col, "%Y-%m-%d")
-            hourly_df = _num_viz_small_grain(idf, col, num_cols, "hourly")
-            weekly_df = _num_viz_small_grain(idf, col, num_cols, "weekly")
-        long_rows = []
-        for c in num_cols:
-            sub = pd.DataFrame(
-                {
-                    "date": dv[col],
-                    "attribute": c,
-                    "count": dv[f"{c}_count"],
-                    "min": dv[f"{c}_min"].round(4),
-                    "max": dv[f"{c}_max"].round(4),
-                    "mean": dv[f"{c}_mean"].round(4),
-                    "median": dv[f"{c}_median"].round(4),
-                }
-            )
-            long_rows.append(sub[sub["count"] > 0])
-        pd.concat(long_rows, ignore_index=True).to_csv(out + f"ts_num_daily_{col}.csv", index=False)
-        hourly_df.to_csv(out + f"ts_num_hourly_{col}.csv", index=False)
-        weekly_df.to_csv(out + f"ts_num_weekly_{col}.csv", index=False)
+        with phase("ts/viz/num", cat="block", cols=len(num_cols), rows=idf.padded_rows) as sp:
+            viz = _ts_num_viz_all(idf, col, num_cols)
+            if viz is not None:
+                dv, hourly_df, weekly_df = viz
+                sp.add(fetches=3)  # the span's two ends, then the three aggregates in one
+            else:
+                dv = aggregator(idf, num_cols, _TS_NUM_AGGS, col, "%Y-%m-%d")
+                hourly_df = _num_viz_small_grain(idf, col, num_cols, "hourly")
+                weekly_df = _num_viz_small_grain(idf, col, num_cols, "weekly")
+        with phase("ts/viz/frame", cat="block", cols=len(num_cols)) as sp:  # the daily aggregate in long form
+            long_rows = []
+            for c in num_cols:
+                sub = pd.DataFrame(
+                    {
+                        "date": dv[col],
+                        "attribute": c,
+                        "count": dv[f"{c}_count"],
+                        "min": dv[f"{c}_min"].round(4),
+                        "max": dv[f"{c}_max"].round(4),
+                        "mean": dv[f"{c}_mean"].round(4),
+                        "median": dv[f"{c}_median"].round(4),
+                    }
+                )
+                long_rows.append(sub[sub["count"] > 0])
+            num_daily = pd.concat(long_rows, ignore_index=True)
+            sp.add(rows=len(num_daily))
+        with phase("ts/viz/write", cat="block") as sp:
+            _write_csv(num_daily, out + f"ts_num_daily_{col}.csv", sp)
+            _write_csv(hourly_df, out + f"ts_num_hourly_{col}.csv", sp)
+            _write_csv(weekly_df, out + f"ts_num_weekly_{col}.csv", sp)
     if cat_cols:
-        _cat_viz(idf, col, cat_cols).to_csv(out + f"ts_cat_daily_{col}.csv", index=False)
+        with phase("ts/viz/cat", cat="block", cols=len(cat_cols), rows=idf.padded_rows):
+            cat_daily = _cat_viz(idf, col, cat_cols)
+        with phase("ts/viz/write", cat="block") as sp:
+            _write_csv(cat_daily, out + f"ts_cat_daily_{col}.csv", sp)
 
     # seasonal decomposition + stationarity of the daily count series
-    dec = seasonal_decompose_ma(daily["count"].to_numpy(), period=7)
-    if dec is not None:
-        trend, seas, resid = dec
-        pd.DataFrame(
-            {
-                "date": daily["yyyymmdd_col"],
-                "observed": daily["count"],
-                "trend": np.round(trend, 4),
-                "seasonal": np.round(seas, 4),
-                "residual": np.round(resid, 4),
-            }
-        ).to_csv(out + f"ts_decompose_{col}.csv", index=False)
-    adf = adf_test(daily["count"].to_numpy())
-    kpss = kpss_test(daily["count"].to_numpy())
-    if adf is not None or kpss is not None:
-        pd.DataFrame([{"attribute": col, **(adf or {}), **(kpss or {})}]).to_csv(
-            ends_with(output_path) + f"ts_stationarity_{col}.csv", index=False
-        )
-    hourly = feats.groupby("hour").size().reset_index(name="count")
-    hourly.to_csv(out + f"ts_hourly_{col}.csv", index=False)
-    weekly = feats.groupby("dayofweek").size().reset_index(name="count")
-    weekly.to_csv(out + f"ts_weekly_{col}.csv", index=False)
-    dayparts = feats.groupby("daypart").size().reset_index(name="count")
-    dayparts.to_csv(out + f"ts_daypart_{col}.csv", index=False)
+    with phase("ts/viz/decompose", cat="block", rows=len(daily)):
+        dec = seasonal_decompose_ma(daily["count"].to_numpy(), period=7)
+        adf = adf_test(daily["count"].to_numpy())
+        kpss = kpss_test(daily["count"].to_numpy())
+    with phase("ts/viz/write", cat="block") as sp:
+        if dec is not None:
+            trend, seas, resid = dec
+            _write_csv(pd.DataFrame(
+                {
+                    "date": daily["yyyymmdd_col"],
+                    "observed": daily["count"],
+                    "trend": np.round(trend, 4),
+                    "seasonal": np.round(seas, 4),
+                    "residual": np.round(resid, 4),
+                }
+            ), out + f"ts_decompose_{col}.csv", sp)
+        if adf is not None or kpss is not None:
+            _write_csv(pd.DataFrame([{"attribute": col, **(adf or {}), **(kpss or {})}]),
+                       out + f"ts_stationarity_{col}.csv", sp)
+        _write_csv(hourly, out + f"ts_hourly_{col}.csv", sp)
+        _write_csv(weekly, out + f"ts_weekly_{col}.csv", sp)
+        _write_csv(dayparts, out + f"ts_daypart_{col}.csv", sp)
 
 
 def seasonal_decompose_ma(series: np.ndarray, period: int = 7):
@@ -523,25 +539,31 @@ def ts_analyzer(
     """Entry (reference :408-550): run eligibility + viz dumps for every
     timestamp column; write ``ts_stats.csv`` summary."""
     Path(output_path).mkdir(parents=True, exist_ok=True)
+    phase = get_tracer().phase
     ts_cols = [c for c in idf.col_names if idf.columns[c].kind == "ts"]
     rows = []
     eligible = []
     feats_map: dict = {}
     for c in ts_cols:
-        stats = ts_eligiblity_check(idf, c, id_col, max_days)
+        # a stage a column: the column's fetch (data and mask) and pandas over its rows
+        with phase("ts/eligibility", cat="block", rows=idf.nrows, fetches=2):
+            stats = ts_eligiblity_check(idf, c, id_col, max_days)
         rows.append(stats)
         if stats.get("eligible"):
             eligible.append(c)
             # calendar feats computed ONCE per column, shared by the viz
             # dump and the landscape sweep
-            feats_map[c] = ts_processed_feats(idf, c)
-            ts_viz_data(idf, c, output_path, output_type,
-                        _feats=feats_map[c])
+            with phase("ts/feats", cat="block", rows=idf.nrows, fetches=2):
+                feats_map[c] = ts_processed_feats(idf, c)
+            with phase("ts/viz", cat="block"):
+                ts_viz_data(idf, c, output_path, output_type,
+                            _feats=feats_map[c])
     if eligible:
-        ts_landscape(idf, eligible, id_col, output_path,
-                     _feats_map=feats_map)
+        with phase("ts/landscape", cat="block", cols=len(eligible)):
+            ts_landscape(idf, eligible, id_col, output_path,
+                         _feats_map=feats_map)
     # always emit the same headered schema — a headerless empty CSV breaks
     # readers and per-run schema drift breaks downstream joins
-    pd.DataFrame(rows).reindex(columns=TS_STATS_COLUMNS).to_csv(
-        ends_with(output_path) + "ts_stats.csv", index=False
-    )
+    with phase("ts/write", cat="block") as sp:
+        _write_csv(pd.DataFrame(rows).reindex(columns=TS_STATS_COLUMNS),
+                   ends_with(output_path) + "ts_stats.csv", sp)

@@ -221,13 +221,19 @@ def ts_preprocess(
 ) -> Table:
     """Detect + convert timestamp columns; persist ``ts_cols_stats.csv``
     (reference :622-761)."""
+    from anovos_tpu.obs import get_tracer
+
+    phase = get_tracer().phase
     odf = idf
     rows = []
     for c in ts_loop_cols_pre(idf, id_col):
-        try:
-            new_col, frac, fam = regex_date_time_parser(idf, c)
-        except Exception:  # detection must never break the pipeline (ref :707)
-            new_col, frac, fam = None, 0.0, ""
+        # a stage a candidate column: its values parsed against the format families
+        with phase("ts/detect", cat="block", rows=idf.nrows) as sp:
+            try:
+                new_col, frac, fam = regex_date_time_parser(idf, c)
+            except Exception:  # detection must never break the pipeline (ref :707)
+                new_col, frac, fam = None, 0.0, ""
+            sp.add(converted=int(new_col is not None))
         rows.append(
             {
                 "attribute": c,
@@ -239,8 +245,11 @@ def ts_preprocess(
         if new_col is not None:
             odf = odf.with_column(c, new_col)
     if output_path and output_path != "NA":
-        Path(output_path).mkdir(parents=True, exist_ok=True)
-        pd.DataFrame(
-            rows, columns=["attribute", "parsed_fraction", "format_family", "status"]
-        ).to_csv(ends_with(output_path) + "ts_cols_stats.csv", index=False)
+        with phase("ts/write", cat="block", files=1, rows=len(rows)) as sp:
+            Path(output_path).mkdir(parents=True, exist_ok=True)
+            out = ends_with(output_path) + "ts_cols_stats.csv"
+            pd.DataFrame(
+                rows, columns=["attribute", "parsed_fraction", "format_family", "status"]
+            ).to_csv(out, index=False)
+            sp.add(bytes=os.path.getsize(out))
     return odf

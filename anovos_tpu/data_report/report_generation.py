@@ -1484,28 +1484,33 @@ def anovos_report(
     READ from the store's local staging of ``master_path`` (where
     save_stats/charts_to_objects staged them) and the finished HTML is
     pushed to the configured ``final_report_path``."""
+    from anovos_tpu.obs import get_tracer
     from anovos_tpu.shared.artifact_store import for_run_type
 
+    phase = get_tracer().phase
     store = for_run_type(run_type, auth_key)
     configured_master = master_path
     master_path = store.staging_dir(master_path)
-    # A standalone report run over stats produced by an EARLIER job finds an
-    # empty staging dir — pull the remote master_path contents down first
-    # (reference report_generation.py:4053-4080 'aws s3 cp --recursive').
-    if master_path != configured_master and not (
-        os.path.isdir(master_path) and os.listdir(master_path)
-    ):
-        try:
-            master_path = store.pull_dir(configured_master, master_path)
-        except Exception as e:  # nothing remote: the tabs degrade per-section
-            logger.warning("stats pull from %s failed (%s); using staging", configured_master, e)
-    report_dest, final_report_path = final_report_path, store.staging_dir(final_report_path)
-    Path(final_report_path).mkdir(parents=True, exist_ok=True)
-    # remote dictionary CSVs are fetched before the wiki tab reads them
-    if dataDict_path != "NA":
-        dataDict_path = store.pull(dataDict_path, os.path.join(final_report_path, "_data_dictionary.csv"))
-    if metricDict_path != "NA":
-        metricDict_path = store.pull(metricDict_path, os.path.join(final_report_path, "_metric_dictionary.csv"))
+    # what the tabs read is staged: the stats directory pulled where it is remote, the two
+    # dictionaries; each tab then reads its own CSVs and charts inside its report/tab span
+    with phase("report/read", cat="block"):
+        # A standalone report run over stats produced by an EARLIER job finds an
+        # empty staging dir — pull the remote master_path contents down first
+        # (reference report_generation.py:4053-4080 'aws s3 cp --recursive').
+        if master_path != configured_master and not (
+            os.path.isdir(master_path) and os.listdir(master_path)
+        ):
+            try:
+                master_path = store.pull_dir(configured_master, master_path)
+            except Exception as e:  # nothing remote: the tabs degrade per-section
+                logger.warning("stats pull from %s failed (%s); using staging", configured_master, e)
+        report_dest, final_report_path = final_report_path, store.staging_dir(final_report_path)
+        Path(final_report_path).mkdir(parents=True, exist_ok=True)
+        # remote dictionary CSVs are fetched before the wiki tab reads them
+        if dataDict_path != "NA":
+            dataDict_path = store.pull(dataDict_path, os.path.join(final_report_path, "_data_dictionary.csv"))
+        if metricDict_path != "NA":
+            metricDict_path = store.pull(metricDict_path, os.path.join(final_report_path, "_metric_dictionary.csv"))
     with _table_seq_lock:
         _table_seq[0] = 0
     tabs: List[tuple] = []
@@ -1569,65 +1574,53 @@ def anovos_report(
             f"<ul>{items}</ul>{qrows}</div>",
         ))
 
-    tabs.append(
-        (
-            "Executive Summary",
-            executive_summary_gen(master_path, label_col, None, id_col, iv_threshold, corr_threshold)
-            or "<p>no global summary found</p>",
-        )
-    )
-    tabs.append(
-        ("Wiki", wiki_generator(master_path, dataDict_path, metricDict_path) or "<p>no dictionaries configured</p>")
-    )
-    tabs.append(
-        (
-            "Descriptive Statistics",
-            descriptive_statistics(master_path, label_col=label_col) or "<p>no stats found</p>",
-        )
-    )
-    tabs.append(("Quality Check", quality_check(master_path) or "<p>no quality stats found</p>"))
-    tabs.append(
-        ("Attribute Associations", attribute_associations(master_path, label_col=label_col) or "<p>no association stats found</p>")
-    )
-    tabs.append(
-        (
-            "Drift & Stability",
-            data_drift_stability(master_path, None, id_col, drift_threshold_model) or "<p>no drift stats found</p>",
-        )
-    )
+    def _tab(title: str, build, fallback=None) -> None:
+        """One tab: its artifacts read and its HTML built, kept where it has
+        a body (``fallback`` stands in for a tab that is always shown)."""
+        with phase("report/tab", cat="block", tab=len(tabs), title=title) as sp:
+            body = build() or fallback
+            if body:
+                tabs.append((title, body))
+                sp.add(bytes=len(body))
 
-    ts_html = ts_viz_generate(master_path, id_col)
-    if ts_html:
-        tabs.append(("Time Series", ts_html))
-    geo_html = loc_report_gen(master_path=master_path)
-    if geo_html:
-        tabs.append(("Geospatial", geo_html))
-    timings_html = run_timings_gen(master_path)
-    if timings_html:
-        tabs.append(("Run Timings", timings_html))
-    ledger_html = perf_ledger_gen()
-    if ledger_html:
-        tabs.append(("Perf Ledger", ledger_html))
-    run_diff_html = run_diff_gen(master_path)
-    if run_diff_html:
-        tabs.append(("Run Diff", run_diff_html))
+    _tab("Executive Summary",
+         lambda: executive_summary_gen(master_path, label_col, None, id_col, iv_threshold, corr_threshold),
+         "<p>no global summary found</p>")
+    _tab("Wiki", lambda: wiki_generator(master_path, dataDict_path, metricDict_path),
+         "<p>no dictionaries configured</p>")
+    _tab("Descriptive Statistics", lambda: descriptive_statistics(master_path, label_col=label_col),
+         "<p>no stats found</p>")
+    _tab("Quality Check", lambda: quality_check(master_path), "<p>no quality stats found</p>")
+    _tab("Attribute Associations", lambda: attribute_associations(master_path, label_col=label_col),
+         "<p>no association stats found</p>")
+    _tab("Drift & Stability", lambda: data_drift_stability(master_path, None, id_col, drift_threshold_model),
+         "<p>no drift stats found</p>")
+    _tab("Time Series", lambda: ts_viz_generate(master_path, id_col))
+    _tab("Geospatial", lambda: loc_report_gen(master_path=master_path))
+    _tab("Run Timings", lambda: run_timings_gen(master_path))
+    _tab("Perf Ledger", perf_ledger_gen)
+    _tab("Run Diff", lambda: run_diff_gen(master_path))
 
-    nav = "".join(
-        f"<button class=\"{'active' if i == 0 else ''}\" onclick='showTab({i})'>{escape(t)}</button>"
-        for i, (t, _) in enumerate(tabs)
-    )
-    sections = "".join(
-        f"<section class=\"{'active' if i == 0 else ''}\">{body}</section>"
-        for i, (_, body) in enumerate(tabs)
-    )
-    html = (
-        "<!DOCTYPE html><html><head><meta charset='utf-8'><title>Anovos-TPU Report</title>"
-        f"{_plotly_script_tag()}<style>{_CSS}</style><script>{_JS}</script></head>"
-        "<body><header><h2>Anovos-TPU — Data Report</h2></header>"
-        f"<nav>{nav}</nav><main>{sections}</main></body></html>"
-    )
+    with phase("report/render", cat="block", tabs=len(tabs)) as sp:  # nav, sections, the plotly script
+        nav = "".join(
+            f"<button class=\"{'active' if i == 0 else ''}\" onclick='showTab({i})'>{escape(t)}</button>"
+            for i, (t, _) in enumerate(tabs)
+        )
+        sections = "".join(
+            f"<section class=\"{'active' if i == 0 else ''}\">{body}</section>"
+            for i, (_, body) in enumerate(tabs)
+        )
+        html = (
+            "<!DOCTYPE html><html><head><meta charset='utf-8'><title>Anovos-TPU Report</title>"
+            f"{_plotly_script_tag()}<style>{_CSS}</style><script>{_JS}</script></head>"
+            "<body><header><h2>Anovos-TPU — Data Report</h2></header>"
+            f"<nav>{nav}</nav><main>{sections}</main></body></html>"
+        )
+        sp.add(bytes=len(html))
     out = ends_with(final_report_path) + "ml_anovos_report.html"
-    with open(out, "w") as f:
-        f.write(html)
-    store.push(out, report_dest)
+    with phase("report/write", cat="block", files=1) as sp:
+        with open(out, "w") as f:
+            f.write(html)
+        store.push(out, report_dest)
+        sp.add(bytes=os.path.getsize(out))
     return out

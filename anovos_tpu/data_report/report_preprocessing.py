@@ -22,6 +22,7 @@ import jax.numpy as jnp
 import numpy as np
 import pandas as pd
 
+from anovos_tpu.obs import get_tracer
 from anovos_tpu.ops.drift_kernels import binned_histograms, fit_cutoffs
 from anovos_tpu.ops.quantiles import masked_quantiles
 from anovos_tpu.ops.segment import code_counts
@@ -345,115 +346,122 @@ def charts_to_objects(
     num_cols = [c for c in cols if idf.columns[c].kind == "num"]
     cat_cols = [c for c in cols if idf.columns[c].kind == "cat"]
 
-    # label event vector (for eventDist charts)
-    y = ym = None
-    if label_col and label_col in idf.columns:
-        from anovos_tpu.data_transformer.transformers import _event_vector
+    phase = get_tracer().phase
+    with phase("charts/read", cat="block", cols=len(cols)):
+        # label event vector (for eventDist charts)
+        y = ym = None
+        if label_col and label_col in idf.columns:
+            from anovos_tpu.data_transformer.transformers import _event_vector
 
-        y, ym = _event_vector(idf, label_col, event_label)
+            y, ym = _event_vector(idf, label_col, event_label)
 
-    # drift source frequencies (reuse the persisted drift model when present;
-    # "NA" falls back to the drift detector's default dir, reference :573-574)
-    drift_freqs = {}
-    drift_model_dir = os.path.join(
-        source_path if source_path != "NA" else "intermediate_data", model_directory
-    )
-    if drift_detector and drift_model_dir and os.path.isdir(os.path.join(drift_model_dir, "frequency_counts")):
-        for c in cols:
-            fpath = os.path.join(drift_model_dir, "frequency_counts", c, "part-00000.csv")
-            if os.path.exists(fpath):
-                fdf = pd.read_csv(fpath, dtype=str)
-                drift_freqs[c] = (fdf.iloc[:, 0].astype(str).tolist(), fdf["p"].astype(float).to_numpy())
+        # drift source frequencies (reuse the persisted drift model when present;
+        # "NA" falls back to the drift detector's default dir, reference :573-574)
+        drift_freqs = {}
+        drift_model_dir = os.path.join(
+            source_path if source_path != "NA" else "intermediate_data", model_directory
+        )
+        if drift_detector and drift_model_dir and os.path.isdir(os.path.join(drift_model_dir, "frequency_counts")):
+            for c in cols:
+                fpath = os.path.join(drift_model_dir, "frequency_counts", c, "part-00000.csv")
+                if os.path.exists(fpath):
+                    fdf = pd.read_csv(fpath, dtype=str)
+                    drift_freqs[c] = (fdf.iloc[:, 0].astype(str).tolist(), fdf["p"].astype(float).to_numpy())
 
     # ---- numeric columns: bin once (reuse drift cutoffs when available) ----
     if num_cols:
-        cut_map = _load_cut_map(drift_model_dir)
-        fit_cols = [c for c in num_cols if c not in cut_map]
-        if fit_cols:
-            # column-bucketed fit (dead lanes all-NaN); zip() truncates the
-            # readback to the live fit_cols
-            from anovos_tpu.drift_stability.drift_detector import _padded_col_tuples
+        # one stage for the block: cutoffs fitted where no model has them, the
+        # histograms (and the label's) fetched, then a figure or three a column
+        with phase("charts/num", cat="block", rows=idf.padded_rows, cols=len(num_cols)):
+            cut_map = _load_cut_map(drift_model_dir)
+            fit_cols = [c for c in num_cols if c not in cut_map]
+            if fit_cols:
+                # column-bucketed fit (dead lanes all-NaN); zip() truncates the
+                # readback to the live fit_cols
+                from anovos_tpu.drift_stability.drift_detector import _padded_col_tuples
 
-            cuts = np.asarray(
-                fit_cutoffs(*_padded_col_tuples(idf, fit_cols), bin_size, bin_method)
-            )
-            for c, row in zip(fit_cols, cuts):
-                cut_map[c] = row
-        X, M = idf.numeric_block(num_cols)
-        # cutoff rows padded to the block's bucketed lane count (dead-lane
-        # histogram rows are all-masked zeros, never indexed below); cast
-        # f32 on HOST — the eager jnp.asarray cast compiled one convert
-        # program per width, and a host np cast rounds identically
-        cutoffs = pad_lane_params(
-            np.stack([cut_map[c] for c in num_cols]), X.shape[1]
-        ).astype(np.float32)
-        counts = np.asarray(binned_histograms(X, M, cutoffs, bin_size))
-        ev_counts = None
-        if y is not None:
-            # one fused program: the eager digitize → mask-combine →
-            # two-bincount chain compiled ~5 programs per width here
-            tot_d, evs_d = _binned_label_counts(X, M, cutoffs, ym, y, bin_size)
-            ev_counts = (np.asarray(tot_d), np.asarray(evs_d))
-        for i, c in enumerate(num_cols):
-            labels = [f"{j + 1}" for j in range(bin_size)]
-            _emit(_bar_fig(labels, counts[i].tolist(), c), ends_with(master_path) + "freqDist_" + c)
-            if ev_counts is not None:
-                tot, evs = ev_counts
-                with np.errstate(invalid="ignore", divide="ignore"):
-                    rate = np.where(tot[i] > 0, evs[i] / np.maximum(tot[i], 1), 0.0)
-                _emit(
-                    _bar_fig(labels, rate.tolist(), f"event rate: {c}", global_theme_r),
-                    ends_with(master_path) + "eventDist_" + c,
+                cuts = np.asarray(
+                    fit_cutoffs(*_padded_col_tuples(idf, fit_cols), bin_size, bin_method)
                 )
-            if c in drift_freqs:
-                skeys, sfreq = drift_freqs[c]
-                tfreq = counts[i] / max(counts[i].sum(), 1)
-                _emit(
-                    _grouped_fig(skeys, {"source": sfreq, "target": tfreq[: len(skeys)]}, f"drift: {c}"),
-                    ends_with(master_path) + "drift_" + c,
-                )
-            if outlier_charts:
-                vals = np.asarray(idf.columns[c].data)[: idf.nrows].astype(float)
-                mask = np.asarray(idf.columns[c].mask)[: idf.nrows]
-                sample = vals[mask]
-                if len(sample) > chart_sample:
-                    sample = np.random.default_rng(0).choice(sample, chart_sample, replace=False)
-                _emit(_violin_fig(sample, c), ends_with(master_path) + "outlier_" + c)
+                for c, row in zip(fit_cols, cuts):
+                    cut_map[c] = row
+            X, M = idf.numeric_block(num_cols)
+            # cutoff rows padded to the block's bucketed lane count (dead-lane
+            # histogram rows are all-masked zeros, never indexed below); cast
+            # f32 on HOST — the eager jnp.asarray cast compiled one convert
+            # program per width, and a host np cast rounds identically
+            cutoffs = pad_lane_params(
+                np.stack([cut_map[c] for c in num_cols]), X.shape[1]
+            ).astype(np.float32)
+            counts = np.asarray(binned_histograms(X, M, cutoffs, bin_size))
+            ev_counts = None
+            if y is not None:
+                # one fused program: the eager digitize → mask-combine →
+                # two-bincount chain compiled ~5 programs per width here
+                tot_d, evs_d = _binned_label_counts(X, M, cutoffs, ym, y, bin_size)
+                ev_counts = (np.asarray(tot_d), np.asarray(evs_d))
+            for i, c in enumerate(num_cols):
+                labels = [f"{j + 1}" for j in range(bin_size)]
+                _emit(_bar_fig(labels, counts[i].tolist(), c), ends_with(master_path) + "freqDist_" + c)
+                if ev_counts is not None:
+                    tot, evs = ev_counts
+                    with np.errstate(invalid="ignore", divide="ignore"):
+                        rate = np.where(tot[i] > 0, evs[i] / np.maximum(tot[i], 1), 0.0)
+                    _emit(
+                        _bar_fig(labels, rate.tolist(), f"event rate: {c}", global_theme_r),
+                        ends_with(master_path) + "eventDist_" + c,
+                    )
+                if c in drift_freqs:
+                    skeys, sfreq = drift_freqs[c]
+                    tfreq = counts[i] / max(counts[i].sum(), 1)
+                    _emit(
+                        _grouped_fig(skeys, {"source": sfreq, "target": tfreq[: len(skeys)]}, f"drift: {c}"),
+                        ends_with(master_path) + "drift_" + c,
+                    )
+                if outlier_charts:
+                    vals = np.asarray(idf.columns[c].data)[: idf.nrows].astype(float)
+                    mask = np.asarray(idf.columns[c].mask)[: idf.nrows]
+                    sample = vals[mask]
+                    if len(sample) > chart_sample:
+                        sample = np.random.default_rng(0).choice(sample, chart_sample, replace=False)
+                    _emit(_violin_fig(sample, c), ends_with(master_path) + "outlier_" + c)
 
     # ---- categorical columns ------------------------------------------------
     for c in cat_cols:
         col = idf.columns[c]
         vsize = max(len(col.vocab), 1)
-        cnts = np.asarray(code_counts(col.data, col.mask, vsize))[:vsize]
-        order = np.argsort(-cnts)
-        cats = [str(col.vocab[j]) for j in order if cnts[j] > 0]
-        vals = [float(cnts[j]) for j in order if cnts[j] > 0]
-        _emit(_bar_fig(cats, vals, c), ends_with(master_path) + "freqDist_" + c)
-        if y is not None:
-            # one fused program per column (shared with the IV/IG group
-            # sweep): mask combine + both label segment-sums
-            from anovos_tpu.data_analyzer.association_evaluator import (
-                _label_group_counts_fused,
-            )
+        # a stage a column: its group count fetched (and the label's, where there is one), its figures
+        with phase("charts/cat", cat="block", rows=idf.padded_rows, distinct=vsize):
+            cnts = np.asarray(code_counts(col.data, col.mask, vsize))[:vsize]
+            order = np.argsort(-cnts)
+            cats = [str(col.vocab[j]) for j in order if cnts[j] > 0]
+            vals = [float(cnts[j]) for j in order if cnts[j] > 0]
+            _emit(_bar_fig(cats, vals, c), ends_with(master_path) + "freqDist_" + c)
+            if y is not None:
+                # one fused program per column (shared with the IV/IG group
+                # sweep): mask combine + both label segment-sums
+                from anovos_tpu.data_analyzer.association_evaluator import (
+                    _label_group_counts_fused,
+                )
 
-            tot, evs, _, _ = _label_group_counts_fused(
-                col.data, col.mask, y, ym, idf.nrows, vsize)
-            tot, evs = tot[:vsize], evs[:vsize]
-            with np.errstate(invalid="ignore", divide="ignore"):
-                rate = np.where(tot > 0, evs / np.maximum(tot, 1), 0.0)
-            _emit(
-                _bar_fig([str(col.vocab[j]) for j in order if cnts[j] > 0],
-                         [float(rate[j]) for j in order if cnts[j] > 0],
-                         f"event rate: {c}", global_theme_r),
-                ends_with(master_path) + "eventDist_" + c,
-            )
-        if c in drift_freqs:
-            skeys, sfreq = drift_freqs[c]
-            tmap = {str(col.vocab[j]): cnts[j] / max(cnts.sum(), 1) for j in range(vsize)}
-            _emit(
-                _grouped_fig(skeys, {"source": sfreq, "target": [tmap.get(k, 0.0) for k in skeys]}, f"drift: {c}"),
-                ends_with(master_path) + "drift_" + c,
-            )
+                tot, evs, _, _ = _label_group_counts_fused(
+                    col.data, col.mask, y, ym, idf.nrows, vsize)
+                tot, evs = tot[:vsize], evs[:vsize]
+                with np.errstate(invalid="ignore", divide="ignore"):
+                    rate = np.where(tot > 0, evs / np.maximum(tot, 1), 0.0)
+                _emit(
+                    _bar_fig([str(col.vocab[j]) for j in order if cnts[j] > 0],
+                             [float(rate[j]) for j in order if cnts[j] > 0],
+                             f"event rate: {c}", global_theme_r),
+                    ends_with(master_path) + "eventDist_" + c,
+                )
+            if c in drift_freqs:
+                skeys, sfreq = drift_freqs[c]
+                tmap = {str(col.vocab[j]): cnts[j] / max(cnts.sum(), 1) for j in range(vsize)}
+                _emit(
+                    _grouped_fig(skeys, {"source": sfreq, "target": [tmap.get(k, 0.0) for k in skeys]}, f"drift: {c}"),
+                    ends_with(master_path) + "drift_" + c,
+                )
 
     # ---- label distribution chart (exec-summary pie source, reference :560) --
     # the label is excluded from the per-attribute loops above, but its own
@@ -461,17 +469,18 @@ def charts_to_objects(
     if label_col and label_col in idf.columns:
         _emit(plot_frequency(idf, label_col), ends_with(master_path) + "freqDist_" + label_col)
 
-    # ---- dtype manifest (reference :712) -----------------------------------
-    pd.DataFrame(idf.dtypes(), columns=["attribute", "data_type"]).to_csv(
-        ends_with(master_path) + "data_type.csv", index=False
-    )
+    with phase("charts/write", cat="block"):  # the dtype manifest, the queued chart files landed, the publish
+        # ---- dtype manifest (reference :712) -----------------------------------
+        pd.DataFrame(idf.dtypes(), columns=["attribute", "data_type"]).to_csv(
+            ends_with(master_path) + "data_type.csv", index=False
+        )
 
-    # publish the staged chart/manifest files to the configured destination
-    # (no-op for local; aws/azcopy per file for emr/ak8s — ref :634-710 cp's);
-    # queued chart writes must land before the dir listing sees them
-    if async_writer is not None:
-        async_writer.wait([async_key])
-    for fname in sorted(os.listdir(master_path)):
-        fpath = os.path.join(master_path, fname)
-        if os.path.isfile(fpath):
-            store.push(fpath, dest_path)
+        # publish the staged chart/manifest files to the configured destination
+        # (no-op for local; aws/azcopy per file for emr/ak8s — ref :634-710 cp's);
+        # queued chart writes must land before the dir listing sees them
+        if async_writer is not None:
+            async_writer.wait([async_key])
+        for fname in sorted(os.listdir(master_path)):
+            fpath = os.path.join(master_path, fname)
+            if os.path.isfile(fpath):
+                store.push(fpath, dest_path)

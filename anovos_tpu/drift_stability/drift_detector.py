@@ -27,6 +27,7 @@ import numpy as np
 import pandas as pd
 
 from anovos_tpu.drift_stability.validations import check_distance_method
+from anovos_tpu.obs import get_tracer
 from anovos_tpu.shared.table import Table
 from anovos_tpu.shared.utils import parse_cols
 
@@ -157,47 +158,51 @@ def statistics(
     # flight can interleave their rendezvous — see Table.gather_rows)
     pipeline_ok = bool(get_runtime().n_devices == 1 and not pre_existing_source and num_cols)
 
-    # ---- numeric cutoffs: fit on source (1 kernel) or load the model ------
-    num_cols_eff = list(num_cols)
-    cutoffs = None
-    cuts_d = None
-    if num_cols:
-        if pre_existing_source:
-            dfm = load_model_df(model_dir, "attribute_binning")
-            cut_map = {r["attribute"]: list(r["parameters"]) for _, r in dfm.iterrows()}
-            num_cols_eff = [c for c in num_cols if c in cut_map]
-            cutoffs = np.array([cut_map[c] for c in num_cols_eff], dtype=np.float64)
-        else:
-            cuts_d = _fit_cutoffs_dev(idf_source, num_cols, bin_size, bin_method)
-            if not pipeline_ok:
-                # slice the column-bucketed fit back to the live columns
-                # BEFORE the all-NaN drop — the dead lanes are all-NaN by
-                # construction and must not masquerade as dropped columns
-                cutoffs, num_cols_eff, _ = _drop_allnan_cutoffs(
-                    np.asarray(cuts_d)[: len(num_cols)], num_cols
-                )
+    # the cutoffs fitted on the source (dispatched; fetched with the sides where one chip
+    # pipelines them) or the saved model read back, and the union vocabularies on the host
+    phase = get_tracer().phase
+    with phase("drift/fit", cat="block", cols=len(cols)):
+        # ---- numeric cutoffs: fit on source (1 kernel) or load the model ------
+        num_cols_eff = list(num_cols)
+        cutoffs = None
+        cuts_d = None
+        if num_cols:
+            if pre_existing_source:
+                dfm = load_model_df(model_dir, "attribute_binning")
+                cut_map = {r["attribute"]: list(r["parameters"]) for _, r in dfm.iterrows()}
+                num_cols_eff = [c for c in num_cols if c in cut_map]
+                cutoffs = np.array([cut_map[c] for c in num_cols_eff], dtype=np.float64)
+            else:
+                cuts_d = _fit_cutoffs_dev(idf_source, num_cols, bin_size, bin_method)
+                if not pipeline_ok:
+                    # slice the column-bucketed fit back to the live columns
+                    # BEFORE the all-NaN drop — the dead lanes are all-NaN by
+                    # construction and must not masquerade as dropped columns
+                    cutoffs, num_cols_eff, _ = _drop_allnan_cutoffs(
+                        np.asarray(cuts_d)[: len(num_cols)], num_cols
+                    )
 
-    # ---- union vocabularies for categorical columns -----------------------
-    union_vocabs: Dict[str, np.ndarray] = {}
-    freq_p: Dict[str, np.ndarray] = {}
-    if pre_existing_source:
-        for c in cols:
-            smap = load_frequency_map(model_dir, c)
-            if smap is None:
-                # e.g. a column the fit run dropped (all-null in source)
-                warnings.warn(f"drift statistics: no persisted source frequencies for {c}; skipping")
-                continue
-            if c in num_cols_eff:
-                freq_p[c] = np.array([smap.get(str(k), 0.0) for k in range(1, bin_size + 1)])
-            elif c in cat_cols:
-                tgt_vocab = {str(v) for v in idf_target.columns[c].vocab}
-                uni = np.array(sorted(set(smap) | tgt_vocab), dtype=object)
-                union_vocabs[c] = uni
-                freq_p[c] = np.array([smap.get(str(v), 0.0) for v in uni])
-            # numeric columns absent from the binning model are skipped
-        cat_cols = [c for c in cat_cols if c in union_vocabs]
-    else:
-        union_vocabs = _union_vocabs_for(idf_source, idf_target, cat_cols)
+        # ---- union vocabularies for categorical columns -----------------------
+        union_vocabs: Dict[str, np.ndarray] = {}
+        freq_p: Dict[str, np.ndarray] = {}
+        if pre_existing_source:
+            for c in cols:
+                smap = load_frequency_map(model_dir, c)
+                if smap is None:
+                    # e.g. a column the fit run dropped (all-null in source)
+                    warnings.warn(f"drift statistics: no persisted source frequencies for {c}; skipping")
+                    continue
+                if c in num_cols_eff:
+                    freq_p[c] = np.array([smap.get(str(k), 0.0) for k in range(1, bin_size + 1)])
+                elif c in cat_cols:
+                    tgt_vocab = {str(v) for v in idf_target.columns[c].vocab}
+                    uni = np.array(sorted(set(smap) | tgt_vocab), dtype=object)
+                    union_vocabs[c] = uni
+                    freq_p[c] = np.array([smap.get(str(v), 0.0) for v in uni])
+                # numeric columns absent from the binning model are skipped
+            cat_cols = [c for c in cat_cols if c in union_vocabs]
+        else:
+            union_vocabs = _union_vocabs_for(idf_source, idf_target, cat_cols)
 
     # ---- ONE fused program per dataset side --------------------------------
     n_union = max((len(union_vocabs[c]) for c in cat_cols), default=1)
@@ -207,61 +212,66 @@ def statistics(
     else:
         cuts_dev = jnp.asarray(cutoffs, jnp.float32) if num_cols_eff else jnp.zeros((0, bin_size - 1))
 
-    def side(idf: Table, sync: bool = True):
-        out = drift_side_full(
-            *_side_args(
-                idf, num_cols_eff, cat_cols, cuts_dev,
-                _lut_for(idf, cat_cols, union_vocabs), bin_size, n_union,
-            )
-        )
-        return jax.device_get(out) if sync else out
-
-    if pipeline_ok:
-        # async dispatch of all three programs, one host sync
-        tgt_pair = side(idf_target, sync=False)
-        src_pair = side(idf_source, sync=False)
-        cutoffs, (tgt_num, tgt_cat), (src_num, src_cat) = jax.device_get(
-            (cuts_dev, tgt_pair, src_pair)
-        )
-        # live-column slice first (column-bucketed dead lanes are all-NaN
-        # cutoffs + all-zero histogram rows), then the real all-null drop
-        k_live = len(num_cols_eff)
-        cutoffs, num_cols_eff, keep = _drop_allnan_cutoffs(cutoffs[:k_live], num_cols_eff)
-        tgt_num = tgt_num[:k_live][keep]
-        src_num = src_num[:k_live][keep]
-    else:
-        tgt_num, tgt_cat = side(idf_target)
-        if not pre_existing_source:
-            src_num, src_cat = side(idf_source)
-
-    if not pre_existing_source and cutoffs is not None:
-        save_model_df(
-            pd.DataFrame(
-                {"attribute": num_cols_eff, "parameters": [list(map(float, c)) for c in cutoffs]}
-            ),
-            model_dir,
-            "attribute_binning",
-        )
-
-    freq_q: Dict[str, np.ndarray] = {}
-    for i, c in enumerate(num_cols_eff):
-        freq_q[c] = tgt_num[i] / max(count_target, 1)
-    for j, c in enumerate(cat_cols):
-        freq_q[c] = tgt_cat[j][: len(union_vocabs[c])] / max(count_target, 1)
-
-    if not pre_existing_source:
-        for i, c in enumerate(num_cols_eff):
-            freq_p[c] = src_num[i] / max(idf_source.nrows, 1)
-        for j, c in enumerate(cat_cols):
-            freq_p[c] = src_cat[j][: len(union_vocabs[c])] / max(idf_source.nrows, 1)
-        if source_save:
-            for c in num_cols_eff + cat_cols:
-                keys = (
-                    list(range(1, bin_size + 1)) if c in num_cols_eff else list(union_vocabs[c])
+    # both sides' histograms: two programs, one fetch where the fit stayed on the device
+    with phase("drift/sides", cat="block", rows=idf_target.padded_rows, cols=len(num_cols_eff) + len(cat_cols)):
+        def side(idf: Table, sync: bool = True):
+            out = drift_side_full(
+                *_side_args(
+                    idf, num_cols_eff, cat_cols, cuts_dev,
+                    _lut_for(idf, cat_cols, union_vocabs), bin_size, n_union,
                 )
-                save_frequency_map(model_dir, c, keys, freq_p[c])
+            )
+            return jax.device_get(out) if sync else out
 
-    odf = _metrics_frame(freq_p, freq_q, cols, methods, threshold)
+        if pipeline_ok:
+            # async dispatch of all three programs, one host sync
+            tgt_pair = side(idf_target, sync=False)
+            src_pair = side(idf_source, sync=False)
+            cutoffs, (tgt_num, tgt_cat), (src_num, src_cat) = jax.device_get(
+                (cuts_dev, tgt_pair, src_pair)
+            )
+            # live-column slice first (column-bucketed dead lanes are all-NaN
+            # cutoffs + all-zero histogram rows), then the real all-null drop
+            k_live = len(num_cols_eff)
+            cutoffs, num_cols_eff, keep = _drop_allnan_cutoffs(cutoffs[:k_live], num_cols_eff)
+            tgt_num = tgt_num[:k_live][keep]
+            src_num = src_num[:k_live][keep]
+        else:
+            tgt_num, tgt_cat = side(idf_target)
+            if not pre_existing_source:
+                src_num, src_cat = side(idf_source)
+
+    # the binning model and the source frequencies, a file a column
+    with phase("drift/model", cat="block", cols=len(num_cols_eff) + len(cat_cols)):
+        if not pre_existing_source and cutoffs is not None:
+            save_model_df(
+                pd.DataFrame(
+                    {"attribute": num_cols_eff, "parameters": [list(map(float, c)) for c in cutoffs]}
+                ),
+                model_dir,
+                "attribute_binning",
+            )
+
+        freq_q: Dict[str, np.ndarray] = {}
+        for i, c in enumerate(num_cols_eff):
+            freq_q[c] = tgt_num[i] / max(count_target, 1)
+        for j, c in enumerate(cat_cols):
+            freq_q[c] = tgt_cat[j][: len(union_vocabs[c])] / max(count_target, 1)
+
+        if not pre_existing_source:
+            for i, c in enumerate(num_cols_eff):
+                freq_p[c] = src_num[i] / max(idf_source.nrows, 1)
+            for j, c in enumerate(cat_cols):
+                freq_p[c] = src_cat[j][: len(union_vocabs[c])] / max(idf_source.nrows, 1)
+            if source_save:
+                for c in num_cols_eff + cat_cols:
+                    keys = (
+                        list(range(1, bin_size + 1)) if c in num_cols_eff else list(union_vocabs[c])
+                    )
+                    save_frequency_map(model_dir, c, keys, freq_p[c])
+
+    with phase("drift/frame", cat="block", cols=len(cols)):
+        odf = _metrics_frame(freq_p, freq_q, cols, methods, threshold)
     if print_impact:
         logger.info(odf.to_string(index=False))
     return odf

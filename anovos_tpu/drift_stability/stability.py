@@ -30,6 +30,7 @@ from anovos_tpu.drift_stability.validations import (
     compute_score,
     compute_si,
 )
+from anovos_tpu.obs import get_tracer
 from anovos_tpu.ops.reductions import masked_moments
 from anovos_tpu.shared.table import Table
 from anovos_tpu.shared.utils import parse_cols
@@ -66,20 +67,25 @@ def stability_index_computation(
         raise TypeError("Invalid input for Column(s)")
 
     # one batched moments kernel per dataset → (n_idfs, k) metric arrays
+    phase = get_tracer().phase
     hist_rows = []
     existing = None
     start_idx = 1
     if existing_metric_path:
-        files = sorted(glob.glob(os.path.join(existing_metric_path, "*.csv"))) or [existing_metric_path]
-        existing = pd.concat([pd.read_csv(f) for f in files], ignore_index=True)
-        if len(existing):
-            start_idx = int(existing["idx"].astype(int).max()) + 1
+        with phase("stability/history", cat="block") as sp:  # the metric history of earlier runs
+            files = sorted(glob.glob(os.path.join(existing_metric_path, "*.csv"))) or [existing_metric_path]
+            existing = pd.concat([pd.read_csv(f) for f in files], ignore_index=True)
+            if len(existing):
+                start_idx = int(existing["idx"].astype(int).max()) + 1
+            sp.add(files=len(files), rows=len(existing))
     for di, idf in enumerate(idfs):
-        X, M = idf.numeric_block(cols)
-        mom = masked_moments(X, M)
-        mean = np.asarray(mom["mean"], np.float64)
-        std = np.asarray(mom["stddev"], np.float64)
-        kurt = np.asarray(mom["kurtosis"], np.float64) + 3.0  # reference adds 3 (:243)
+        # a stage a dataset: one program, its three moments fetched
+        with phase("stability/moments", cat="block", rows=idf.padded_rows, cols=len(cols), fetches=3):
+            X, M = idf.numeric_block(cols)
+            mom = masked_moments(X, M)
+            mean = np.asarray(mom["mean"], np.float64)
+            std = np.asarray(mom["stddev"], np.float64)
+            kurt = np.asarray(mom["kurtosis"], np.float64) + 3.0  # reference adds 3 (:243)
         for i, c in enumerate(cols):
             hist_rows.append(
                 {
@@ -91,18 +97,19 @@ def stability_index_computation(
                     "kurtosis": kurt[i],
                 }
             )
-    hist = pd.DataFrame(hist_rows)
-    if existing is not None and len(existing):
-        hist = pd.concat([existing, hist], ignore_index=True)
-    if appended_metric_path:
-        os.makedirs(appended_metric_path, exist_ok=True)
-        hist.sort_values("idx").to_csv(
-            os.path.join(appended_metric_path, "part-00000.csv"), index=False
-        )
-
-    odf = stability_frame_from_history(
-        hist, cols=cols, metric_weightages=metric_weightages,
-        threshold=threshold, binary_cols=binary_cols)
+    with phase("stability/frame", cat="block", cols=len(cols)) as sp:  # the history, its file, CV to SI a column
+        hist = pd.DataFrame(hist_rows)
+        if existing is not None and len(existing):
+            hist = pd.concat([existing, hist], ignore_index=True)
+        if appended_metric_path:
+            os.makedirs(appended_metric_path, exist_ok=True)
+            out = os.path.join(appended_metric_path, "part-00000.csv")
+            hist.sort_values("idx").to_csv(out, index=False)
+            sp.add(files=1, bytes=os.path.getsize(out))
+        odf = stability_frame_from_history(
+            hist, cols=cols, metric_weightages=metric_weightages,
+            threshold=threshold, binary_cols=binary_cols)
+        sp.add(rows=len(hist))
     if print_impact:
         logger.info(odf.to_string(index=False))
     return odf

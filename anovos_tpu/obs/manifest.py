@@ -138,7 +138,7 @@ def build_manifest(
         # dataset fingerprints — see anovos_tpu.obs.diffing
         "env": _env_section(all_configs),
         # the pass's phase tree (obs.tracing): ``[{name, parent, start_s,
-        # end_s, thread, counts}]``, seconds from the start of the root span
+        # end_s, thread, counts, usage}]``, seconds from the start of the root span
         # ``run``, and ``clock``: the pass's ``run_id`` and
         # ``scheduler_origin_s``, what ``scheduler.nodes[*].start_s/end_s``
         # count from, on the same origin (one addition places a node among

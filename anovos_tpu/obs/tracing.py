@@ -36,6 +36,7 @@ from __future__ import annotations
 import json
 import logging
 import os
+import resource
 import threading
 import time
 import uuid
@@ -66,6 +67,10 @@ _DEFAULT_BUFFER = 200_000
 # profiler session as TraceAnnotations
 ROOT_PHASE = "run"
 _ANNOTATED_CATS = ("phase", "node")
+# what a row of the tree consumed between its two ends (``phases()`` keeps
+# them apart from the counts of the work, under ``usage``): ``cpu_s`` on
+# every row, the process's four on the root and its direct children
+_USAGE = ("cpu_s", "proc_cpu_s", "minflt", "majflt", "nivcsw")
 
 # ``jax.profiler.TraceAnnotation`` while a profiler session is on, else None:
 # the one check ``span()`` makes.  ``workflow.run`` sets it around the
@@ -198,6 +203,13 @@ class Tracer:
         if _ANNOTATION is not None and cat in _ANNOTATED_CATS:
             note = _ANNOTATION(name)
             note.__enter__()
+        # what a row of the tree consumed besides wall: this thread's seconds
+        # on a CPU, and on the root and its direct children (the main
+        # thread's ten to twelve phases) the whole process's (native threads
+        # too), its page faults and involuntary context switches
+        usage0 = (resource.getrusage(resource.RUSAGE_SELF)
+                  if cat == "phase" and attrs.get("parent") in (None, ROOT_PHASE) else None)
+        cpu0 = time.thread_time_ns() if tree else 0
         t0 = time.perf_counter_ns()
         try:
             yield stack[-1]
@@ -206,6 +218,14 @@ class Tracer:
             raise
         finally:
             dur = time.perf_counter_ns() - t0
+            if tree:
+                attrs["cpu_s"] = (time.thread_time_ns() - cpu0) / 1e9
+            if usage0 is not None:
+                u = resource.getrusage(resource.RUSAGE_SELF)
+                attrs.update(
+                    proc_cpu_s=u.ru_utime + u.ru_stime - usage0.ru_utime - usage0.ru_stime,
+                    minflt=u.ru_minflt - usage0.ru_minflt, majflt=u.ru_majflt - usage0.ru_majflt,
+                    nivcsw=u.ru_nivcsw - usage0.ru_nivcsw)
             if note is not None:
                 note.__exit__(None, None, None)
             stack.pop()
@@ -256,6 +276,19 @@ class Tracer:
             yield
         finally:
             del stack[:]
+
+    @contextmanager
+    def holding(self, lock, wait_name: str, cat: str = "anovos"):
+        """Hold ``lock`` for the body.  Where another thread has it, the wait
+        is a ``phase(wait_name)``: a row under the waiting node and not its
+        self time; uncontended, no span."""
+        if not lock.acquire(blocking=False):
+            with self.phase(wait_name, cat=cat):
+                lock.acquire()
+        try:
+            yield
+        finally:
+            lock.release()
 
     def in_pass(self) -> bool:
         """Whether THIS thread is inside a ``run_pass()``."""
@@ -343,9 +376,9 @@ class Tracer:
     def phases(self) -> List[dict]:
         """The finished spans of the pass's tree (phases and scheduler nodes)
         as the manifest holds them: ``{name, parent, start_s, end_s, thread,
-        counts}``, seconds from the root span's start, in order of start.
-        ``counts`` are the span's numeric attributes.  Empty until the root
-        has ended."""
+        counts, usage}``, seconds from the root span's start, in order of
+        start.  ``counts`` are the span's numeric attributes, ``usage`` what
+        it consumed (``_USAGE``).  Empty until the root has ended."""
         with self._lock:
             spans, root = list(self._phases), self._root
         if root is None:
@@ -361,7 +394,9 @@ class Tracer:
                 "thread": sp.thread,
                 "counts": {k: round(v, 6) if isinstance(v, float) else v
                            for k, v in sp.args.items()
-                           if isinstance(v, (int, float)) and not isinstance(v, bool)},
+                           if isinstance(v, (int, float)) and not isinstance(v, bool)
+                           and k not in _USAGE},
+                "usage": {k: round(sp.args[k], 6) for k in _USAGE if k in sp.args},
             })
         rows.sort(key=lambda r: (r["start_s"], -r["end_s"]))
         return rows

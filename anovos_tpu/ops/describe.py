@@ -290,12 +290,12 @@ def table_describe(idf: Table, num_cols: List[str], cat_cols: List[str]) -> Tupl
     lock makes the memo single-flight: of the scheduler nodes that ask for
     the same table at once, one computes and the others wait for its
     result (without it six of a stats pass's seven nodes each dispatched
-    the whole describe).  The scheduler node a call runs under counts the
-    outcome on its span: ``describe_computed`` 1 for the compute, 0 for a
-    memo hit.
+    the whole describe), under the span ``describe/wait``.  The scheduler
+    node a call runs under counts the outcome on its span:
+    ``describe_computed`` 1 for the compute, 0 for a memo hit.
     """
     lock = idf.__dict__.setdefault("_describe_lock", threading.Lock())
-    with lock:
+    with get_tracer().holding(lock, "describe/wait", cat="op"):
         cache = idf.__dict__.setdefault("_describe_cache", {})
         # the compensated mode is a cache INPUT: toggling the env var mid-process
         # must not serve the other mode's moments.  The threshold compares the

@@ -282,8 +282,9 @@ class AsyncArtifactWriter:
             return
         from anovos_tpu.obs import get_tracer
 
-        with get_tracer().span("artifact:wait", cat="artifact",
-                               keys=list(keys), pending=len(futs)):
+        # inside a pass a row of its tree, under the node that waits for another's writes
+        with get_tracer().phase("artifact:wait", cat="artifact",
+                                keys=list(keys), pending=len(futs)):
             for f in futs:
                 f.result()  # re-raises the write's exception with its traceback
 

@@ -10,6 +10,7 @@ helpers and ``pairwise_reduce`` (utils.py:113-132).
 from __future__ import annotations
 
 import functools
+import os
 from typing import Callable, Iterable, List, Sequence, Union
 
 
@@ -62,6 +63,14 @@ def pairwise_reduce(op: Callable, items: Iterable):
 def ends_with(string: str, end_str: str = "/") -> str:
     """Ensure trailing separator (reference utils.py:93)."""
     return string if string.endswith(end_str) else string + end_str
+
+
+def write_csv_counted(frame, path: str, span) -> None:
+    """``frame.to_csv(path, index=False)``, counted on the open stage span
+    ``span`` (``files``, ``rows``, ``bytes`` on disk): how the analyzers
+    write the CSVs the report reads."""
+    frame.to_csv(path, index=False)
+    span.add(files=1, rows=len(frame), bytes=os.path.getsize(path))
 
 
 def output_to_local(path: str) -> str:

@@ -152,16 +152,6 @@ def block_times() -> dict:
     }
 
 
-def __getattr__(name: str):
-    # compatibility shim for the retired module-level dict: BLOCK_TIMES now
-    # reads as a point-in-time snapshot derived from the MetricsRegistry.
-    # Mutating the returned dict no longer feeds the table — use
-    # ``block_times()`` (readers) / ``_log_block_time`` (writers).
-    if name == "BLOCK_TIMES":
-        return block_times()
-    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
-
-
 def ETL(args: dict) -> Table:
     """read_dataset + chained column ops by reflection (reference :45-61)."""
     read_args = args.get("read_dataset", None)
@@ -594,14 +584,16 @@ class _PipelineRun:
         self._claim(v)
         reads = tuple(reads)
         placement = self._effective_placement(placement)
-        turn = (self._version_locks.setdefault(v, threading.Lock())
-                if share_lane else contextlib.nullcontext())
+        turn = self._version_locks.setdefault(v, threading.Lock()) if share_lane else None
 
         def body():
             self.writer.wait(reads)
             df_in = self._resolve(v).to_active_placement()
             t0 = time.monotonic()
-            with turn:
+            # where another reader of this version has the turn, the wait is a
+            # row of the pass's tree under this node (``lane/wait``)
+            with (get_tracer().holding(turn, "lane/wait", cat="node") if turn is not None
+                  else contextlib.nullcontext()):
                 fn(df_in)
             if timed:
                 _log_block_time(timed, t0)

@@ -5,7 +5,9 @@ spans, read from a real profiler session's ``.xplane.pb`` on the CPU."""
 
 import gc
 import glob
+import json
 import os
+import sys
 import threading
 import time
 
@@ -17,6 +19,10 @@ from anovos_tpu import obs, workflow
 from anovos_tpu.data_ingest import synthetic
 from anovos_tpu.obs import devprof, tracing
 from anovos_tpu.parallel.scheduler import DagScheduler
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:  # the benchmark's generator, driver and readers, for the ``full`` rehearsal
+    sys.path.insert(0, ROOT)
 
 ROWS = 2000
 TOP = ["config", "reset", "ingest", "register", "dag", "artifact:drain", "manifest", "close",
@@ -102,7 +108,7 @@ def test_manifest_holds_every_phase_of_the_pass(stats_pass):
     assert names >= {"run", *TOP, *UNDER_INGEST}
     assert "input_dataset/ETL" not in {sp.name for sp in stats_pass["spans"]}
     for r in man["phases"]:
-        assert set(r) == {"name", "parent", "start_s", "end_s", "thread", "counts"}
+        assert set(r) == {"name", "parent", "start_s", "end_s", "thread", "counts", "usage"}
         assert 0.0 <= r["start_s"] <= r["end_s"]
         # the scheduler's nodes are rows too, each on its worker's thread under ``dag``,
         # and so is what a node opens: the describe under the node that computes it
@@ -434,3 +440,327 @@ def test_anovos_profile_runs_without_the_python_tracer_and_names_the_pass(
         (start, dur), = events[name]
         assert start - shift == pytest.approx(row["start_s"], abs=2e-3)
         assert dur == pytest.approx(row["end_s"] - row["start_s"], abs=2e-3)
+
+
+# ------------------------------------------------- what a row consumed ----
+USAGE = {"cpu_s", "proc_cpu_s", "minflt", "majflt", "nivcsw"}
+
+
+def _spin(seconds):
+    end = time.perf_counter() + seconds
+    while time.perf_counter() < end:
+        pass
+
+
+def test_every_row_carries_cpu_s_and_only_the_top_rows_the_process_counts(stats_pass):
+    rows = stats_pass["manifest"]["phases"]
+    for r in rows:
+        top = r["parent"] in (None, "run")
+        assert set(r["usage"]) == (USAGE if top else {"cpu_s"}), r
+        assert not USAGE & set(r["counts"]), r  # kept apart from the counts of the work
+        # a thread's own clock: never more than the row's wall (and a tick of either clock)
+        assert 0.0 <= r["usage"]["cpu_s"] <= r["end_s"] - r["start_s"] + 0.005, r
+        if top:
+            u = r["usage"]
+            assert u["proc_cpu_s"] >= 0.0 and min(u["minflt"], u["majflt"], u["nivcsw"]) >= 0
+            assert all(isinstance(u[k], int) for k in ("minflt", "majflt", "nivcsw"))
+    tops = {r["name"] for r in rows if r["parent"] == "run"}
+    assert tops == set(TOP)
+    # the process's seconds hold the main thread's: ingest decodes and encodes on it
+    ingest = _one(stats_pass, "ingest")["usage"]
+    assert ingest["proc_cpu_s"] >= ingest["cpu_s"] - 0.005 and ingest["cpu_s"] > 0
+    # the dag phase's thread only waits for its workers; the process works meanwhile
+    dag = _one(stats_pass, "dag")
+    assert dag["usage"]["cpu_s"] < 0.5 * (dag["end_s"] - dag["start_s"])
+    assert dag["usage"]["proc_cpu_s"] > dag["usage"]["cpu_s"]
+    run = next(r for r in rows if r["parent"] is None)
+    assert run["usage"]["proc_cpu_s"] >= ingest["proc_cpu_s"] + dag["usage"]["proc_cpu_s"] - 0.005
+
+
+def test_a_row_that_spins_reads_its_wall_and_one_that_sleeps_reads_nothing():
+    tr = obs.Tracer(buffer=100)
+    with tr.run_pass():
+        with tr.phase("spin"):
+            _spin(0.2)
+        with tr.phase("sleep"):
+            time.sleep(0.2)
+        with tr.span("a_node", cat="node"):
+            with tr.phase("stage/spin"):
+                _spin(0.1)
+    rows = {r["name"]: r for r in tr.phases()}
+    wall = {n: r["end_s"] - r["start_s"] for n, r in rows.items()}
+    assert rows["spin"]["usage"]["cpu_s"] >= 0.3 * wall["spin"] >= 0.06  # loose: the sandbox's cores are shared
+    assert rows["sleep"]["usage"]["cpu_s"] <= 0.05 and wall["sleep"] >= 0.2
+    assert rows["stage/spin"]["usage"]["cpu_s"] >= 0.3 * wall["stage/spin"]
+    assert rows["a_node"]["usage"]["cpu_s"] >= rows["stage/spin"]["usage"]["cpu_s"]
+    # the process's clock on the direct children of the root, and on no row below them
+    assert rows["spin"]["usage"]["proc_cpu_s"] >= 0.3 * wall["spin"]
+    assert rows["sleep"]["usage"]["proc_cpu_s"] <= 0.1
+    assert set(rows["run"]["usage"]) == set(rows["spin"]["usage"]) == USAGE
+    assert set(rows["a_node"]["usage"]) == set(rows["stage/spin"]["usage"]) == {"cpu_s"}
+    assert rows["run"]["usage"]["cpu_s"] >= rows["spin"]["usage"]["cpu_s"] + rows["stage/spin"]["usage"]["cpu_s"]
+
+
+def test_a_unit_handed_to_a_pool_thread_records_that_threads_cpu():
+    """``Tracer.under``: the row lies under the submitting thread's row and
+    its ``cpu_s`` is the pool thread's, while the submitter only waits."""
+    tr = obs.Tracer(buffer=100)
+    with tr.run_pass():
+        with tr.phase("ingest"):
+            spans = tr.open_spans()
+
+            def unit():
+                with tr.under(spans), tr.phase("ingest/decode", cat="io"):
+                    _spin(0.15)
+
+            worker = threading.Thread(target=unit, name="anovos-host_9")
+            worker.start()
+            worker.join()
+    rows = {r["name"]: r for r in tr.phases()}
+    unit_row, ingest = rows["ingest/decode"], rows["ingest"]
+    assert unit_row["parent"] == "ingest" and unit_row["thread"] == "anovos-host_9"
+    assert unit_row["usage"]["cpu_s"] >= 0.3 * (unit_row["end_s"] - unit_row["start_s"]) >= 0.045
+    assert ingest["usage"]["cpu_s"] <= 0.05  # the caller's thread slept in join()
+    assert ingest["usage"]["proc_cpu_s"] >= unit_row["usage"]["cpu_s"] - 0.02  # the process did the work
+    assert set(unit_row["usage"]) == {"cpu_s"}
+
+
+def test_spans_outside_the_tree_carry_none_of_it():
+    """An op span, a writer thread's span, a span outside any pass and a
+    phase outside any pass: as they were."""
+    tr = obs.Tracer(buffer=100)
+    with tr.span("before", cat="node"), tr.phase("ingest/encode", cat="io"):
+        pass
+    kept = list(tr.snapshot())
+    with tr.run_pass():
+        with tr.span("ops.something", cat="op"):
+            pass
+        def write():
+            with tr.span("write:stats", cat="artifact"), tr.phase("write/parquet", cat="io"):
+                pass
+
+        writer = threading.Thread(target=write)
+        writer.start()
+        writer.join()
+    spans = {sp.name: sp for sp in kept + tr.snapshot()}
+    for name in ("before", "ingest/encode", "ops.something", "write:stats", "write/parquet"):
+        assert not USAGE & set(spans[name].args), name
+    assert USAGE <= set(spans["run"].args)
+    assert [r["name"] for r in tr.phases()] == ["run"]
+
+
+def test_a_node_that_finds_the_describe_in_flight_waits_under_describe_wait(monkeypatch):
+    """Three scheduler nodes ask for the same table's describe at once: one
+    computes it, and each of the others shows its wait for the table's lock
+    as a row ``describe/wait`` under itself."""
+    import numpy as np
+
+    from anovos_tpu.ops import describe
+    from anovos_tpu.shared.table import Table
+
+    tbl = Table.from_numpy({"x": np.arange(64, dtype=np.float32), "y": np.ones(64, np.float32)})
+    real = describe._table_describe
+    started = threading.Event()
+
+    def slow(*a, **k):
+        started.set()
+        time.sleep(0.3)  # long enough for the others to reach the lock
+        return real(*a, **k)
+
+    monkeypatch.setattr(describe, "_table_describe", slow)
+    tr = obs.get_tracer()
+
+    def first():
+        describe.table_describe(tbl, ["x", "y"], [])
+
+    def later():
+        started.wait(10)
+        describe.table_describe(tbl, ["x", "y"], [])
+
+    s = DagScheduler(name="describe_wait")
+    s.add("computes", first)
+    s.add("waits_a", later)
+    s.add("waits_b", later)
+    with tr.run_pass(), tr.phase("dag"):
+        s.run(mode="concurrent", max_workers=3, node_timeout=60)
+    rows = tr.phases()
+    nodes = {r["name"]: r for r in rows if r["parent"] == "dag"}
+    assert {n: r["counts"]["describe_computed"] for n, r in nodes.items()} == {
+        "computes": 1, "waits_a": 0, "waits_b": 0}
+    waits = [r for r in rows if r["name"] == "describe/wait"]
+    assert sorted(r["parent"] for r in waits) == ["waits_a", "waits_b"]
+    for r in waits:
+        node = nodes[r["parent"]]
+        assert node["start_s"] <= r["start_s"] <= r["end_s"] <= node["end_s"] and r["thread"] == node["thread"]
+        assert r["end_s"] - r["start_s"] >= 0.1 and r["usage"]["cpu_s"] <= 0.05  # a wait: wall and no work
+    (computed,) = [r for r in rows if r["name"] == "describe"]
+    assert computed["parent"] == "computes"
+    # uncontended, no row: a fourth call reads the memo without a wait
+    with tr.run_pass(), tr.span("alone", cat="node"):
+        describe.table_describe(tbl, ["x", "y"], [])
+    assert [r["name"] for r in tr.phases()] == ["run", "alone"]
+
+
+def test_the_readers_of_one_table_version_wait_for_their_turn_under_lane_wait(stats_pass):
+    """``stats_generator``'s nodes share the rendezvous lane and run one at
+    a time under the version's lock (``_PipelineRun.fanout(share_lane=True)``):
+    a node that found the turn taken shows the wait as a ``lane/wait`` row
+    (it may still be the one that computes the describe: ``global_summary``
+    takes a turn and needs none)."""
+    rows = stats_pass["manifest"]["phases"]
+    nodes = {r["name"]: r for r in rows if r["parent"] == "dag"}
+    waits = [r for r in rows if r["name"] == "lane/wait"]
+    assert {r["parent"] for r in waits} <= set(nodes)
+    assert len({r["parent"] for r in waits}) == len(waits)  # at most one a node
+    for r in waits:
+        node = nodes[r["parent"]]
+        assert node["start_s"] <= r["start_s"] <= r["end_s"] <= node["end_s"] and r["thread"] == node["thread"]
+        assert r["usage"]["cpu_s"] <= 0.5 * (r["end_s"] - r["start_s"]) + 0.005  # a wait, not work
+    assert not [r for r in rows if r["name"] == "describe/wait"]  # the turn is taken first
+
+
+def test_ten_thousand_empty_rows_cost_microseconds_each():
+    tr = obs.Tracer(buffer=100)
+    n = 10_000
+    with tr.run_pass():
+        with tr.span("a_node", cat="node"):
+            t0 = time.perf_counter()
+            for _ in range(n):
+                with tr.phase("stage"):
+                    pass
+            each = (time.perf_counter() - t0) / n
+    assert each < 50e-6, f"{each * 1e6:.1f} us a row"
+    assert len(tr.phases()) == n + 2
+
+
+# ------------------------------ stage rows inside the long host blocks ----
+# the names PERF.md section 3 gives, by the node they lie under (children first,
+# then what lies under ``ts/viz`` and ``geo/cluster``)
+STAGES = {
+    "timeseries_analyzer/inspection": (
+        {"ts/eligibility", "ts/feats", "ts/viz", "ts/landscape", "ts/write"},
+        {"ts/viz": {"ts/viz/counts", "ts/viz/num", "ts/viz/frame", "ts/viz/cat", "ts/viz/decompose",
+                    "ts/viz/write"}}),
+    "geospatial_controller": (
+        {"geo/detect", "geo/points", "geo/stats", "geo/charts", "geo/write", "geo/cluster"},
+        {"geo/cluster": {"geo/cluster/kmeans", "geo/cluster/dbscan", "geo/cluster/silhouette"}}),
+    "quality_checker/invalidEntries_detection": (
+        {"invalid/unique", "invalid/scan", "invalid/mask", "invalid/frame", "invalid/treat"}, {}),
+    "report_generation": ({"report/read", "report/tab", "report/render", "report/write"}, {}),
+}
+# the other nodes of the pass's critical path, by the same rule
+MORE_STAGES = {
+    "timeseries_analyzer/auto_detection": {"ts/detect", "ts/write"},
+    "quality_checker/duplicate_detection": {"duplicate/signature", "duplicate/verify"},
+    "quality_checker/nullRows_detection": {"nullrows/count", "nullrows/frame"},
+    "quality_checker/IDness_detection": {"idness/stats"},
+    "quality_checker/biasedness_detection": {"biasedness/stats"},
+    "quality_checker/outlier_detection": {"outlier/bounds", "outlier/flags", "outlier/treat"},
+    "quality_checker/nullColumns_detection": {"nullcols/stats", "nullcols/treat"},
+    "drift_detector/drift_statistics": {"drift/fit", "drift/sides", "drift/model", "drift/frame"},
+    "drift_detector/stability_index": {"stability/moments", "stability/frame"},
+    "report_preprocessing/charts_to_objects": {"charts/read", "charts/num", "charts/cat", "charts/write"},
+}
+COLUMNS, TABS = 24, 11  # of the income table; of the report
+
+
+@pytest.fixture(scope="module")
+def full_pass(tmp_path_factory):
+    """One pass of the benchmark's ``full`` mix at 2,000 rows: its manifest."""
+    from benchmark.datasets import income
+    from benchmark.drivers import pipeline
+
+    work = tmp_path_factory.mktemp("full_stages")
+    with open(os.path.join(ROOT, "benchmark", "traffic", "full.json")) as f:
+        traffic = json.load(f)
+    income.generate(str(work / "dataset"), 36, traffic["dataset_parts"], rows=ROWS, source_rows=ROWS // 4)
+    config_path = str(work / "pipeline.yaml")
+    pipeline.write_pipeline_config(os.path.join(ROOT, "benchmark", "traffic", "full.yaml"),
+                                   str(work / "dataset"), config_path)
+    p = pipeline.inspect(pipeline.one_pass(config_path, str(work / "pass")), traffic, "cpu")
+    assert not p["bad"], p["bad"]
+    return p["manifest"]
+
+
+def _kids(rows, parent, node):
+    """The rows named ``parent`` as their parent that lie inside ``node``."""
+    return [r for r in rows if r["parent"] == parent
+            and node["start_s"] - 1e-6 <= r["start_s"] and r["end_s"] <= node["end_s"] + 1e-6]
+
+
+def _covered(node, kids):
+    """The share of ``node`` under the union of ``kids``, as ``critical_unnamed_s`` counts it."""
+    from benchmark.harness.names import load_module
+
+    left = load_module("layer_metrics", "critical_unnamed_s").uncovered(node, kids)
+    return 1.0 - left / max(node["end_s"] - node["start_s"], 1e-9)
+
+
+@pytest.mark.parametrize("node_name", sorted(STAGES))
+def test_the_four_long_host_blocks_have_their_stage_rows(full_pass, node_name):
+    rows = full_pass["phases"]
+    (node,) = [r for r in rows if r["name"] == node_name and r["parent"] == "dag"]
+    children, below = STAGES[node_name]
+    mine = [r for r in rows if r["parent"] == node_name]
+    # a node that found another's writes still queued waits for them first, under artifact:wait
+    assert {r["name"] for r in mine} - {"artifact:wait"} == children
+    for r in mine:  # inside the node's interval, on the node's thread, with what a row carries
+        assert node["start_s"] <= r["start_s"] <= r["end_s"] <= node["end_s"], r
+        assert r["thread"] == node["thread"] and set(r["usage"]) == {"cpu_s"}, r
+    assert _covered(node, mine) >= 0.8  # what the node did lies under a named stage
+    for parent, names in below.items():
+        for holder in (r for r in mine if r["name"] == parent):
+            inner = _kids(rows, parent, holder)
+            assert {r["name"] for r in inner} <= names and inner, parent
+            assert _covered(holder, inner) >= 0.8
+    # a span a column or a tab at the most: never one a row or a distinct value
+    family = next(iter(children)).split("/")[0]
+    names = [r["name"] for r in rows if r["name"].split("/")[0] == family]
+    assert max(names.count(n) for n in set(names)) <= COLUMNS + TABS
+
+
+def test_stage_rows_carry_their_counts(full_pass):
+    rows = full_pass["phases"]
+    by = {}
+    for r in rows:
+        by.setdefault(r["name"], []).append(r)
+    uniq = by["invalid/unique"]
+    assert all(r["counts"]["rows"] == 2048 and r["counts"]["fetches"] == 2 and 0 < r["counts"]["distinct"] <= ROWS
+               for r in uniq)
+    assert len(by["invalid/scan"]) == len(by["invalid/mask"]) >= len(uniq) > 0
+    tabs = by["report/tab"]
+    assert [r["counts"]["tab"] for r in tabs if "bytes" in r["counts"]] == list(
+        range(sum("bytes" in r["counts"] for r in tabs)))
+    assert by["report/render"][0]["counts"]["tabs"] == sum("bytes" in r["counts"] for r in tabs) >= 8
+    assert by["report/write"][0]["counts"]["bytes"] >= by["report/render"][0]["counts"]["bytes"] > 0
+    writes = by["ts/viz/write"] + by["ts/write"] + by["geo/write"]
+    assert all(r["counts"]["files"] >= 1 and r["counts"]["bytes"] > 0 for r in writes)
+    assert all(r["counts"]["fetches"] == 2 for r in by["ts/eligibility"] + by["ts/feats"])
+    assert all(r["counts"]["combos"] > 0 for r in by["geo/cluster/silhouette"])
+
+
+def test_the_critical_path_is_named_by_stage(full_pass):
+    """Every node of the pass's critical path lies under stage rows for the
+    most part, and the reader of what is left agrees with the tree."""
+    from benchmark.harness.names import load_module
+
+    rows, sched = full_pass["phases"], full_pass["scheduler"]
+    nodes = {r["name"]: r for r in rows if r["parent"] == "dag"}
+    assert sched["critical_path"] and set(sched["critical_path"]) <= set(nodes)
+    known = {**{n: c for n, (c, _) in STAGES.items()}, **MORE_STAGES}
+    for name in sched["critical_path"]:
+        mine = {r["name"] for r in rows if r["parent"] == name}
+        if name in known:
+            assert mine - {"io:read_dataset", "artifact:wait", "lane/wait"} - {
+                n for n in mine if n.startswith(("ingest/", "transform/", "describe", "place/"))} <= known[name]
+            assert mine, name
+            # loose (the smallest nodes are milliseconds long): a node whose stages went missing reads 0
+            assert _covered(nodes[name], [r for r in rows if r["parent"] == name]) >= 0.3, name
+    run = {"passes": [{"wall_s": 1.0, "manifest": full_pass}]}
+    unnamed = load_module("layer_metrics", "critical_unnamed_s").read(run)
+    by_hand = sum((1.0 - _covered(nodes[n], [r for r in rows if r["parent"] == n]))
+                  * (nodes[n]["end_s"] - nodes[n]["start_s"]) for n in sched["critical_path"])
+    assert unnamed == pytest.approx(by_hand, abs=1e-6) and 0.0 <= unnamed <= sched["critical_path_s"] + 0.01
+    assert load_module("layer_metrics", "dag_cpu_s").read(run) > 0
+    assert load_module("layer_metrics", "ingest_cpu_s").read(run) > 0
+    assert len(rows) <= 600  # a few hundred rows a pass, not thousands

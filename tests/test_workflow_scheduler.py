@@ -264,8 +264,8 @@ def test_main_and_run_have_no_mutable_default_auth():
 
 
 def test_block_times_thread_safe_accumulation():
-    """Block walls now accumulate in the obs MetricsRegistry; the
-    BLOCK_TIMES module attribute survives as a read-only snapshot shim."""
+    """Block walls accumulate in the obs MetricsRegistry; ``block_times()``
+    reads them."""
     from anovos_tpu import workflow
     from anovos_tpu.obs import get_metrics
 
@@ -282,8 +282,6 @@ def test_block_times_thread_safe_accumulation():
     bt = workflow.block_times()
     assert len(bt) == 1  # all 8 accumulated onto one label
     assert bt["label"] >= 0.0
-    # compatibility shim: the module attribute reads as the same snapshot
-    assert workflow.BLOCK_TIMES == bt
 
 
 # ---------------------------------------------------------------------------
