@@ -35,7 +35,8 @@ from anovos_tpu.data_ingest import guard
 from anovos_tpu.shared.host_pool import get_host_pool, record_units
 from anovos_tpu.shared.runtime import get_runtime
 from anovos_tpu.shared.table import (
-    Column, Table, _host_to_column, _pad_to, arrow_typed_kind, arrow_typed_to_numpy, host_table_frame)
+    FETCH_PHASE, Column, Table, _host_to_column, _pad_to, arrow_typed_kind, arrow_typed_to_numpy,
+    host_table_frame)
 from anovos_tpu.shared.utils import ends_with, pairwise_reduce, parse_cols
 
 logger = logging.getLogger(__name__)
@@ -110,8 +111,8 @@ def shard_files_for_process(files: List[str]) -> List[str]:
 # on the host pool; a smaller read's in a loop.  What ``read_host_frame`` can
 # see of a read's size before it has decoded it is its files' bytes: 8 MiB of
 # parquet is 1.4-2.2 x 10^5 rows of the benchmark's tables, about where
-# ``shared/table.py`` starts to encode a frame's string columns side by side
-# (``_POOLED_ENCODE_MIN_ROWS``).
+# ``shared/table.py`` starts to run a frame's column units side by side
+# (``_POOLED_COLUMNS_MIN_ROWS``).
 _POOLED_DECODE_MIN_BYTES = 1 << 23
 
 
@@ -413,8 +414,10 @@ def write_dataset(
     writers.
 
     ``idf`` is on the device or on the host, and its type says which.  A
-    ``Table`` is fetched (``Table.to_pandas``: one ``device_get`` an array,
-    under a ``d2h`` transfer bracket).  A pandas frame (a stats table a node
+    ``Table`` is fetched (``Table.to_pandas``: the arrays' copies in flight
+    ahead of the column being converted, one ``d2h`` transfer record a
+    column; on the pass's thread a row ``write/column`` a column under
+    ``write/d2h``).  A pandas frame (a stats table a node
     computed on the host) is written from where it is:
     ``host_table_frame`` gives the frame that ``Table.from_pandas`` +
     ``to_pandas`` would, so the part files have the bytes they had when the
@@ -444,9 +447,8 @@ def write_dataset(
             write_span.add(host_frame=1)
     else:
         # the fetch of a device table: every array of every column, padding included
-        arrays = [a for c in idf.columns.values()
-                  for a in (c.data, c.mask, c.wide_hi, c.wide_lo) if a is not None]
-        with _write_phase(tracer, "write/d2h", arrays=len(arrays), bytes=sum(a.nbytes for a in arrays)):
+        arrays = [a for c in idf.columns.values() for a in c.device_arrays()]
+        with _write_phase(tracer, FETCH_PHASE, arrays=len(arrays), bytes=sum(a.nbytes for a in arrays)):
             df = idf.to_pandas()
     with _write_phase(tracer, "write/" + file_type, rows=len(df)) as write_files:
         written = _write_parts(df, file_path, file_type, cfg, repartition)

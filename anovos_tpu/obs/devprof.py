@@ -75,6 +75,7 @@ __all__ = [
     "reset",
     "current_node",
     "current_frame",
+    "under",
     "node_bracket",
     "dispatch_bracket",
     "transfer_bracket",
@@ -478,6 +479,23 @@ def current_frame():
     bracket or with devprof disabled).  Prefetch pools capture it at
     construction so worker-thread decode books to the consuming node."""
     return getattr(_TL, "frame", None)
+
+
+@contextmanager
+def under(frame):
+    """Run the body as work handed over by the thread whose
+    :func:`current_frame` was ``frame``: on a thread with no frame of its own
+    (a host-pool thread) what the body books (a fetch's ``d2h`` record) lands
+    on the node that handed the work over, as it would have on that node's
+    thread.  ``Tracer.under`` does the same for spans."""
+    if frame is None or getattr(_TL, "frame", None) is not None:
+        yield
+        return
+    _TL.frame = frame
+    try:
+        yield
+    finally:
+        _TL.frame = None
 
 
 def current_node() -> "Optional[str]":

@@ -17,6 +17,7 @@ threads and the callers themselves run ingest at a time.
 
 from __future__ import annotations
 
+import functools
 import threading
 import time
 from concurrent.futures import ThreadPoolExecutor
@@ -50,7 +51,8 @@ class HostPool:
         first such unit in the items' order is raised once the units
         already running have ended.  A span that a unit opens on a pool
         thread has the parent it would have had on this thread
-        (``Tracer.under``)."""
+        (``Tracer.under``), and a transfer it books lands on the scheduler
+        node this thread runs (``devprof.under``)."""
         call = _Call(fn, items)
         helpers = min(len(items), self.threads) - 1 if side_by_side else 0
         for _ in range(helpers):
@@ -67,12 +69,14 @@ class _Call:
     """One :meth:`HostPool.run`: the units and who has claimed which."""
 
     def __init__(self, fn: Callable, items: Sequence):
+        from anovos_tpu.obs import devprof
         from anovos_tpu.obs.tracing import get_tracer
 
         self.fn, self.items = fn, items
         self.results: list = [None] * len(items)
         self._tracer = get_tracer()
         self._spans = self._tracer.open_spans()  # the calling thread's
+        self._under_frame = functools.partial(devprof.under, devprof.current_frame())  # and its node's
         self.errors: Dict[int, BaseException] = {}
         self.ran_on: set = set()
         self.first_start = float("inf")
@@ -83,7 +87,7 @@ class _Call:
 
     def drain(self) -> None:
         """Claim and run units until none is left or one has failed."""
-        with self._tracer.under(self._spans):
+        with self._tracer.under(self._spans), self._under_frame():
             while True:
                 with self._cv:
                     if self.errors or self._next == len(self.items):

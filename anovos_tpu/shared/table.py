@@ -22,9 +22,11 @@ double/int/bigint/float/long/decimal→num) maps onto ``Column.kind``.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import sys
+import threading
 import time
 from collections import OrderedDict
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple, Union
@@ -91,6 +93,10 @@ class Column:
     def astype_float(self, dtype=jnp.float32) -> jax.Array:
         return self.data.astype(dtype)
 
+    def device_arrays(self) -> List[jax.Array]:
+        """Every array the column holds on the device: what a fetch copies."""
+        return [a for a in (self.data, self.mask, self.wide_hi, self.wide_lo) if a is not None]
+
     def exact_host(self, nrows: Optional[int] = None) -> np.ndarray:
         """Host values with exactness preserved (wide pair → int64/float64)."""
         n = self.data.shape[0] if nrows is None else nrows
@@ -133,7 +139,9 @@ def _fetch(arrays, n: int, label: str) -> List[np.ndarray]:
     """d2h materialization boundary: the first ``n`` entries of each device
     array.  ``device_get`` blocks until the producing programs retire, so
     the wall includes the device tail a fetch waits on (devprof books it as
-    transfer — "what the host was waiting ON", see obs.devprof)."""
+    transfer — "what the host was waiting ON", see obs.devprof).  Where the
+    array's copy is already in flight (``Table.to_pandas`` starts them ahead)
+    the call waits for that copy and the seconds booked are that wait."""
     from anovos_tpu.obs import devprof
 
     with devprof.transfer_bracket("d2h", sum(a.nbytes for a in arrays), label=label):
@@ -539,10 +547,71 @@ class Table:
     # host materialization
     # ------------------------------------------------------------------
     def to_pandas(self):
+        """The table's first ``nrows`` rows as a pandas frame, every column
+        fetched whole (padding included) and converted on the host.
+
+        The device→host copies are started ahead of the column being
+        converted (``jax.Array.copy_to_host_async``): those of the first
+        ``get_host_pool().threads`` columns before any is waited for, then,
+        as a column's unit is taken up, those of the columns up to that many
+        past it.  So the link has copies queued while the host converts, and
+        a table of any length has no more raw buffers in flight than the
+        columns being converted and that window.  A column's unit waits for
+        its arrays (:meth:`Column.to_host`: the same ``d2h`` records, whose
+        seconds are now the wait for a copy in flight) and converts them
+        (:func:`_host_column_to_pandas`); the units of a table of
+        ``_POOLED_COLUMNS_MIN_ROWS`` rows or more run side by side on the
+        host pool, a shorter table's in a loop on this thread.  The frame is
+        the same either way.
+
+        Inside ``write_dataset``'s ``write/d2h`` row of a pass's tree each
+        unit is a row ``write/column`` under it: ``arrays``, ``bytes``, and
+        ``wait_s``, the seconds the unit was blocked on its copies (the rest
+        of its wall is conversion).  Anywhere else a unit opens nothing.
+
+        A unit that raises stops the units not yet started; the copies
+        already in flight are waited for before the error leaves."""
+        from anovos_tpu.obs.tracing import get_tracer
+
         n = self.nrows
-        out = {name: _host_column_to_pandas(c.to_host(n))
-               for name, c in self.columns.items()}
-        return pd.DataFrame(out, columns=list(self.columns.keys()))
+        cols = list(self.columns.values())
+        pool = get_host_pool()
+        ahead = pool.threads
+        tracer = get_tracer()
+        row = tracer.tree_row()
+        in_write = row is not None and row.name == FETCH_PHASE
+        lock = threading.Lock()
+        started = 0
+
+        def start_copies(upto: int) -> None:
+            nonlocal started
+            with lock:
+                while started < min(upto, len(cols)):
+                    for a in cols[started].device_arrays():
+                        a.copy_to_host_async()
+                    started += 1
+
+        def unit(i: int):
+            start_copies(i + 1 + ahead)
+            arrays = cols[i].device_arrays()
+            column_row = (tracer.phase("write/column", arrays=len(arrays), bytes=sum(a.nbytes for a in arrays))
+                          if in_write else contextlib.nullcontext())
+            with column_row as span:
+                t0 = time.perf_counter()
+                hc = cols[i].to_host(n)
+                if span is not None:
+                    span.add(wait_s=time.perf_counter() - t0)
+                return _host_column_to_pandas(hc)
+
+        start_copies(ahead)
+        try:
+            out = pool.run(unit, range(len(cols)), side_by_side=n >= _POOLED_COLUMNS_MIN_ROWS).results
+        except BaseException:
+            for c in cols[:started]:  # a copy never outlives the call that started it
+                for a in c.device_arrays():
+                    np.asarray(a)
+            raise
+        return pd.DataFrame(dict(zip(self.columns, out)), columns=list(self.columns))
 
     def head(self, k: int = 5):
         return self.to_pandas().head(k)
@@ -679,12 +748,17 @@ def _arrow_sorted_vocab_codes(first: np.ndarray, dictionary: pa.Array) -> Native
 # text, ids) costs seconds in one hash table and one sort; its rows are
 # partitioned by their first bytes and the partitions encoded side by side.
 _BUCKETED_ENCODE_MIN_ROWS = 1 << 20
-# The string columns of a frame of this many rows or more are encoded side by
-# side on the host pool; a shorter frame's in a loop (the stats tables, a
+# The column-sized units of a frame or table of this many rows or more run
+# side by side on the host pool: the string columns of a frame being encoded
+# (``_frame_arrays``), the columns of a table being fetched and converted
+# (``Table.to_pandas``); a shorter one's in a loop (the stats tables, a
 # node's small frames, a 32,561-row dataset): thirteen columns of 65,536 rows
 # are 3 ms each and threads that wake for them gave nothing back in the
 # median, at 131,072 rows they halved the wall (PERF.md section 3).
-_POOLED_ENCODE_MIN_ROWS = 1 << 17
+_POOLED_COLUMNS_MIN_ROWS = 1 << 17
+# The row of a pass's tree under which ``Table.to_pandas`` files a row a
+# column: ``write_dataset`` opens it around the fetch of the table it writes.
+FETCH_PHASE = "write/d2h"
 _BUCKETED_ENCODE_SAMPLE = 1 << 16
 _BUCKETED_ENCODE_BUCKETS_A_WORKER = 4
 _PREFIX_BYTES = 8
@@ -1034,7 +1108,7 @@ def _frame_arrays(df, encode) -> Dict[str, Union[np.ndarray, NativeEncodedString
     numpy array; an Arrow-typed decimal or date column
     (:func:`arrow_typed_kind`) as float64 or ``datetime64[s]``; a column of
     pandas' nullable integers as a masked array of its integers.  The string
-    columns of a frame of ``_POOLED_ENCODE_MIN_ROWS`` rows or more are units
+    columns of a frame of ``_POOLED_COLUMNS_MIN_ROWS`` rows or more are units
     of the host pool (``shared.host_pool``), encoded side by side once this
     thread has taken the other columns' arrays; the dict is in the frame's
     column order either way.  ``encode_workers`` (threads that
@@ -1059,7 +1133,7 @@ def _frame_arrays(df, encode) -> Dict[str, Union[np.ndarray, NativeEncodedString
             data[name] = s.to_numpy()
     if strings:
         ran = get_host_pool().run(lambda name: encode(df[name]), strings,
-                                  side_by_side=len(df) >= _POOLED_ENCODE_MIN_ROWS)
+                                  side_by_side=len(df) >= _POOLED_COLUMNS_MIN_ROWS)
         data.update(zip(strings, ran.results))
         record_units("encode", ran)
     return data

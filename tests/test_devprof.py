@@ -78,6 +78,50 @@ def test_transfer_bracket_books_bytes_and_direction():
         "transfer_h2d_bytes_total").value() == reg_before_h2d + 100
 
 
+@pytest.mark.parametrize("rows,threads", [(1_000, 4), (200_000, 4), (200_000, 1)])
+def test_to_pandas_books_one_d2h_record_a_column_and_one_a_wide_pair_on_the_callers_node(
+        monkeypatch, rows, threads):
+    """The fetch of a table inside a node: a ``table.to_pandas`` record of
+    ``data`` + ``mask`` bytes a column and a ``column.exact_host`` record a
+    wide pair, on the node's frame whichever thread waited for the copy
+    (the units of a long table run on the host pool's threads)."""
+    import numpy as np
+
+    from anovos_tpu.shared import host_pool
+    from anovos_tpu.shared.table import Table
+
+    made = host_pool.HostPool(threads)
+    monkeypatch.setattr(host_pool, "_POOL", made)
+    tbl = Table.from_numpy({**{f"c{i}": np.arange(rows, dtype="float32") for i in range(8)},
+                            "wide": np.arange(rows, dtype="int64") + (1 << 40)})
+    padded = tbl.padded_rows
+    booked, lock = [], threading.Lock()
+    real = devprof.record_transfer
+
+    def noting(direction, nbytes, seconds, label="", shards=0):
+        with lock:
+            booked.append((direction, nbytes, label, threading.current_thread().name))
+        return real(direction, nbytes, seconds, label, shards)
+
+    monkeypatch.setattr(devprof, "record_transfer", noting)
+    devprof.reset()
+    before = sum(v for _, v in obs.get_metrics().counter("transfer_d2h_bytes_total").items())
+    with devprof.node_bracket("fetching"):
+        df = tbl.to_pandas()
+    if made._executor is not None:
+        made._executor.shutdown(wait=True)
+    assert df.shape == (rows, 9)
+    assert sorted(b[:3] for b in booked) == sorted(
+        [("d2h", 5 * padded, "table.to_pandas")] * 9 + [("d2h", 8 * padded, "column.exact_host")])
+    on_pool = {b[3] for b in booked} - {threading.current_thread().name}
+    assert bool(on_pool) == (rows >= 131_072 and threads > 1)
+    out = devprof.results()["fetching"]
+    assert out["transfers"] == 10 and out["d2h_bytes"] == (9 * 5 + 8) * padded and out["h2d_bytes"] == 0
+    assert out["device_time_s"] + out["dispatch_s"] + out["transfer_s"] + out["host_s"] <= out["wall_s"] + 1e-6
+    after = sum(v for _, v in obs.get_metrics().counter("transfer_d2h_bytes_total").items())
+    assert after - before == (9 * 5 + 8) * padded
+
+
 def test_record_transfer_rejects_bad_direction():
     with pytest.raises(ValueError):
         devprof.record_transfer("sideways", 1, 0.0)

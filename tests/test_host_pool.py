@@ -28,7 +28,7 @@ def pool(request, monkeypatch):
     calling thread counted), and every frame and read long enough for it."""
     made = host_pool.HostPool(request.param)
     monkeypatch.setattr(host_pool, "_POOL", made)
-    monkeypatch.setattr(table_mod, "_POOLED_ENCODE_MIN_ROWS", 1)
+    monkeypatch.setattr(table_mod, "_POOLED_COLUMNS_MIN_ROWS", 1)
     monkeypatch.setattr(data_ingest, "_POOLED_DECODE_MIN_BYTES", 1)
     yield made
     if made._executor is not None:
@@ -36,7 +36,7 @@ def pool(request, monkeypatch):
 
 
 def _inline(monkeypatch):
-    monkeypatch.setattr(table_mod, "_POOLED_ENCODE_MIN_ROWS", 1 << 40)
+    monkeypatch.setattr(table_mod, "_POOLED_COLUMNS_MIN_ROWS", 1 << 40)
     monkeypatch.setattr(data_ingest, "_POOLED_DECODE_MIN_BYTES", 1 << 60)
 
 
@@ -256,7 +256,7 @@ def test_a_frame_under_the_threshold_opens_no_pool_task(tmp_path, monkeypatch):
     submitted = []
     monkeypatch.setattr(made._executor, "submit", lambda fn, *a: submitted.append(fn))
     assert sum(os.path.getsize(p) for p in paths) < data_ingest._POOLED_DECODE_MIN_BYTES
-    assert 1200 < table_mod._POOLED_ENCODE_MIN_ROWS <= 400_000 and 32_561 < table_mod._POOLED_ENCODE_MIN_ROWS
+    assert 1200 < table_mod._POOLED_COLUMNS_MIN_ROWS <= 400_000 and 32_561 < table_mod._POOLED_COLUMNS_MIN_ROWS
     tbl, rows = _read_in_a_pass(str(tmp_path / "d"))
     (read,) = [r for r in rows if r["name"] == "io:read_dataset"]
     assert tbl.nrows == 1200 and submitted == []

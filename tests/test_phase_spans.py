@@ -764,3 +764,65 @@ def test_the_critical_path_is_named_by_stage(full_pass):
     assert load_module("layer_metrics", "dag_cpu_s").read(run) > 0
     assert load_module("layer_metrics", "ingest_cpu_s").read(run) > 0
     assert len(rows) <= 600  # a few hundred rows a pass, not thousands
+
+
+# ---------------------------------------------- the write of a device table ----
+def _write_in_a_pass(tbl, path):
+    from anovos_tpu.data_ingest.data_ingest import write_dataset
+
+    tr = obs.get_tracer()
+    with tr.run_pass():
+        with tr.phase("dag"):
+            with tr.span("a_node", cat="node"):
+                tbl.to_pandas()  # a node's fetch: no row of its own
+        with tr.phase("write_main"):
+            write_dataset(tbl, path, "parquet", {"mode": "overwrite"})
+    return tr.phases()
+
+
+@pytest.mark.parametrize("rows,side_by_side", [(131_072, True), (32_561, False)])
+def test_a_written_tables_columns_are_rows_under_write_d2h_and_a_nodes_fetch_opens_none(
+        tmp_path, monkeypatch, rows, side_by_side):
+    import numpy as np
+
+    from anovos_tpu.shared import host_pool
+    from anovos_tpu.shared.table import Table
+
+    made = host_pool.HostPool(4)
+    monkeypatch.setattr(host_pool, "_POOL", made)
+    big = np.arange(rows, dtype="int64") + (1 << 40)
+    tbl = Table.from_numpy({"a": np.arange(rows, dtype="float32"), "b": np.arange(rows, dtype="int32"),
+                            "wide": big, "c": np.linspace(0.0, 1.0, rows).astype("float32"),
+                            "d": np.ones(rows, dtype="float32"), "e": np.zeros(rows, dtype="int32")})
+    padded = tbl.padded_rows
+
+    def written():
+        return [sum(v for _, v in obs.get_metrics().counter(c).items())
+                for c in ("rows_written_total", "bytes_written_total")]
+
+    before = written()
+    phases = _write_in_a_pass(tbl, str(tmp_path / "out"))
+    made._executor.shutdown(wait=True)
+    (part,) = glob.glob(str(tmp_path / "out" / "part-*.parquet"))
+    assert [a - b for a, b in zip(written(), before)] == [rows, os.path.getsize(part)]
+    write = [r for r in phases if r["parent"] == "write_main"]
+    assert [r["name"] for r in write] == ["write/d2h", "write/parquet"]
+    d2h = write[0]
+    assert d2h["counts"] == {"arrays": 14, "bytes": padded * (5 * 5 + 13)}
+    columns = [r for r in phases if r["name"] == "write/column"]
+    assert len(columns) == 6 and all(r["parent"] == "write/d2h" for r in columns)
+    assert all(set(r["counts"]) == {"arrays", "bytes", "wait_s"} and "cpu_s" in r["usage"] for r in columns)
+    assert sorted(r["counts"]["arrays"] for r in columns) == [2, 2, 2, 2, 2, 4]
+    assert sum(r["counts"]["bytes"] for r in columns) == d2h["counts"]["bytes"]
+    assert sum(r["counts"]["arrays"] for r in columns) == d2h["counts"]["arrays"]
+    for r in columns:
+        assert d2h["start_s"] <= r["start_s"] <= r["end_s"] <= d2h["end_s"]
+        assert 0.0 <= r["counts"]["wait_s"] <= r["end_s"] - r["start_s"] + 1e-6
+    threads = {r["thread"] for r in columns}
+    if side_by_side:
+        assert len(threads) <= 4 and threads != {d2h["thread"]}  # the pool's threads took units
+    else:
+        assert threads == {d2h["thread"]}
+    # the node fetched the same table and its row has no child
+    assert [r["name"] for r in phases if r["parent"] == "a_node"] == []
+    assert len([r for r in phases if r["name"].startswith("write/")]) == 2 + 6
