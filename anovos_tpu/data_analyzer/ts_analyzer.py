@@ -10,7 +10,6 @@ tabs.  Calendar decomposition is int32 epoch math in one vectorized pass.
 from __future__ import annotations
 
 import functools
-import os
 from pathlib import Path
 from typing import List, Optional
 
@@ -35,7 +34,7 @@ def _ts_frame(idf: Table, col: str) -> pd.Series:
     c = idf.columns[col]
     secs = np.asarray(c.data)[: idf.nrows].astype("int64")
     mask = np.asarray(c.mask)[: idf.nrows]
-    ts = pd.Series(secs.view("datetime64[s]") if False else secs.astype("datetime64[s]"))
+    ts = pd.Series(secs.astype("datetime64[s]"))
     ts[~mask] = pd.NaT
     return ts
 
@@ -51,7 +50,10 @@ def daypart_cat(hour: pd.Series) -> pd.Series:
 
 
 def ts_processed_feats(idf: Table, col: str) -> pd.DataFrame:
-    """Per-row calendar features for one ts column (reference :87-158)."""
+    """Per-row calendar features for one ts column (reference :87-158): the
+    public per-row API, a host frame of the table's length.  ``ts_analyzer``
+    does not call it: everything the inspection writes is a count over a
+    calendar bucket and comes from :func:`ts_calendar`."""
     ts = _ts_frame(idf, col)
     out = pd.DataFrame({col: ts})
     out["date"] = ts.dt.date
@@ -69,22 +71,87 @@ def ts_processed_feats(idf: Table, col: str) -> pd.DataFrame:
     return out
 
 
-def ts_eligiblity_check(idf: Table, col: str, id_col: Optional[str] = None, max_days: int = 3600) -> dict:
-    """Eligibility stats (reference :160-257): span, distinct days, null pct."""
-    ts = _ts_frame(idf, col)
-    valid = ts.dropna()
-    if len(valid) == 0:
+def _fetch(tree, idf: Table, span=None):
+    """``jax.device_get``, counted on the open stage row ``span``: one
+    ``fetches`` a call and, as ``host_rows``, the rows of every fetched array
+    that is as long as the table.  The inspection brings aggregates to the
+    host and never a column, so its rows read 0 (a table padded to exactly
+    ``CALENDAR_DAY_LANES`` rows reads its day lanes too: high, never low)."""
+    out = jax.device_get(tree)
+    if span is not None:
+        span.add(fetches=1, host_rows=sum(
+            a.shape[0] for a in jax.tree_util.tree_leaves(out)
+            if np.ndim(a) and a.shape[0] == idf.padded_rows))
+    return out
+
+
+def ts_calendar(idf: Table, col: str, span=None) -> dict:
+    """One timestamp column's calendar counts, from ONE device program and
+    one fetch (``ops/datetime_kernels.calendar_counts``): ``n`` valid rows of
+    ``rows``, ``min`` / ``max`` second, ``day_lo`` (epoch day of the first
+    valid row), ``daily`` (records per day from ``day_lo`` to the last day,
+    zeros included), ``hour`` (24) and ``dow`` (7, Mon=0) as int64.  What
+    eligibility, the count tables, the landscape and the daily series of the
+    decomposition are built from; an all-null column has ``n`` 0 and no days."""
+    from anovos_tpu.ops.datetime_kernels import SECS_PER_DAY, calendar_counts
+
+    c = idf.columns[col]
+    got = {k: np.asarray(v).astype("int64")
+           for k, v in _fetch(calendar_counts(c.data, c.mask), idf, span).items()}
+    n = int(got["n"])
+    lo, hi = (int(got["min"]), int(got["max"])) if n else (0, -1)
+    day_lo = lo // SECS_PER_DAY
+    return {"n": n, "rows": idf.nrows, "min": lo, "max": hi, "day_lo": day_lo,
+            "daily": got["daily"][: hi // SECS_PER_DAY - day_lo + 1] if n else got["daily"][:0],
+            "hour": got["hour"], "dow": got["dow"]}
+
+
+def _daypart_counts(cal: dict) -> list:
+    """[(daypart label, records), ...] of the dayparts that have rows, in label order."""
+    part = np.bincount(_DAYPART_LUT, weights=cal["hour"], minlength=len(_DAYPART_NAMES)).astype("int64")
+    return sorted((lbl, n) for lbl, n in zip(_DAYPART_NAMES, part) if n)
+
+
+def _count_frames(cal: dict):
+    """(daily, hourly, weekly, dayparts) as the host ``groupby(...).size()``
+    over the per-row frame gave them: only the buckets that have rows, in
+    key order (days as ``%Y-%m-%d`` strings, daypart labels as strings), the
+    hour and the weekday as floats where the column has a null (pandas'
+    ``.dt.hour`` of a column with ``NaT``) and as int32 where it has none."""
+    key = np.float64 if cal["n"] < cal["rows"] else np.int32
+    days = np.nonzero(cal["daily"])[0]
+    daily = pd.DataFrame({
+        "yyyymmdd_col": (days + cal["day_lo"]).astype("datetime64[D]").astype(str).astype(object),
+        "count": cal["daily"][days]})
+    hours = np.nonzero(cal["hour"])[0]
+    hourly = pd.DataFrame({"hour": hours.astype(key), "count": cal["hour"][hours]})
+    dows = np.nonzero(cal["dow"])[0]
+    weekly = pd.DataFrame({"dayofweek": dows.astype(key), "count": cal["dow"][dows]})
+    return daily, hourly, weekly, pd.DataFrame(_daypart_counts(cal), columns=["daypart", "count"])
+
+
+def _ts_str(sec: int) -> str:
+    return str(pd.Timestamp(sec, unit="s"))
+
+
+def ts_eligiblity_check(idf: Table, col: str, id_col: Optional[str] = None, max_days: int = 3600,
+                        _calendar: Optional[dict] = None) -> dict:
+    """Eligibility stats (reference :160-257): span, distinct days, null pct.
+    ``id_col`` is unused: it stays in the signature for parity with the
+    reference's API."""
+    cal = _calendar if _calendar is not None else ts_calendar(idf, col)
+    if cal["n"] == 0:
         return {"attribute": col, "eligible": 0, "reason": "all null"}
-    span_days = (valid.max() - valid.min()).days
-    distinct_days = valid.dt.date.nunique()
+    span_days = (cal["max"] - cal["min"]) // 86400
+    distinct_days = int(np.count_nonzero(cal["daily"]))
     return {
         "attribute": col,
         "eligible": int(0 < span_days <= max_days and distinct_days > 1),
         "span_days": span_days,
         "distinct_days": distinct_days,
-        "null_pct": round(1 - len(valid) / max(idf.nrows, 1), 4),
-        "min_ts": str(valid.min()),
-        "max_ts": str(valid.max()),
+        "null_pct": round(1 - cal["n"] / max(idf.nrows, 1), 4),
+        "min_ts": _ts_str(cal["min"]),
+        "max_ts": _ts_str(cal["max"]),
     }
 
 
@@ -135,7 +202,8 @@ def _small_grain_frame(agg, num_cols: List[str], labels: List[str]) -> pd.DataFr
     return pd.DataFrame(rows, columns=["bucket", "attribute", "count", "min", "max", "mean", "median"])
 
 
-def _num_viz_small_grain(idf: Table, ts_col: str, num_cols: List[str], grain: str) -> pd.DataFrame:
+def _num_viz_small_grain(idf: Table, ts_col: str, num_cols: List[str], grain: str,
+                         span=None) -> pd.DataFrame:
     """min/max/mean/median of every numeric column per daypart / weekday —
     one device segment program (reference ts_viz_data :259-406 hourly/weekly)."""
     from anovos_tpu.data_transformer.datetime import _segment_aggregate
@@ -143,64 +211,52 @@ def _num_viz_small_grain(idf: Table, ts_col: str, num_cols: List[str], grain: st
     tcol = idf.columns[ts_col]
     ids, labels = _grain_buckets(tcol, grain)
     V, Mv = idf.numeric_block(num_cols)
-    agg = jax.device_get(_segment_aggregate(ids, tcol.mask, V, Mv, len(labels)))
+    agg = _fetch(_segment_aggregate(ids, tcol.mask, V, Mv, len(labels)), idf, span)
     return _small_grain_frame(agg, num_cols, labels)
 
 
 @functools.partial(jax.jit, static_argnames=("nseg_d", "nseg_h", "nseg_w", "cp"))
-def _ts_num_viz_program(day_ids, day_lo, tdata, valid, V, Mv,
+def _ts_num_viz_program(day_lo, tdata, valid, V, Mv,
                         nseg_d: int, nseg_h: int, nseg_w: int, cp: bool):
-    """ALL THREE numeric viz grains — daily (offset day buckets), daypart
+    """ALL THREE numeric viz grains — day (offset from ``day_lo``), daypart
     and weekday ids, and the three segment aggregates — in ONE compiled
     program: the per-grain path dispatched three id programs and three
     aggregate programs with blocking fetches between them."""
-    from anovos_tpu.data_transformer.datetime import (
-        _segment_aggregate_jit, _segment_aggregate_jit_off,
-    )
+    from anovos_tpu.data_transformer.datetime import _bucket_ids, _segment_aggregate_jit
 
-    ids_h = _grain_ids(tdata, "hourly")
-    ids_w = _grain_ids(tdata, "weekly")
     return (
-        _segment_aggregate_jit_off(day_ids, day_lo, valid, V, Mv, nseg_d, cp=cp),
-        _segment_aggregate_jit(ids_h, valid, V, Mv, nseg_h, cp=cp),
-        _segment_aggregate_jit(ids_w, valid, V, Mv, nseg_w, cp=cp),
+        _segment_aggregate_jit(_bucket_ids(tdata, "day") - day_lo, valid, V, Mv, nseg_d, cp=cp),
+        _segment_aggregate_jit(_grain_ids(tdata, "hourly"), valid, V, Mv, nseg_h, cp=cp),
+        _segment_aggregate_jit(_grain_ids(tdata, "weekly"), valid, V, Mv, nseg_w, cp=cp),
     )
 
 
 _TS_NUM_AGGS = ["count", "min", "max", "mean", "median"]
 
 
-def _ts_num_viz_all(idf: Table, ts_col: str, num_cols: List[str]):
+def _ts_num_viz_all(idf: Table, ts_col: str, num_cols: List[str], cal: dict, span=None):
     """(daily frame, hourly frame, weekly frame) from ONE device dispatch
-    + ONE fetch.  Daily formatting goes through the aggregator's shared
+    + ONE fetch; the day span is the calendar's (``cal``: a column with valid
+    rows).  Daily formatting goes through the aggregator's shared
     ``format_segment_aggregate`` so the frames match the per-grain path
-    byte-for-byte.  Returns None on the aggregator's fallback conditions
-    (all-null span, degenerate span) — the caller then takes the
-    per-grain path."""
-    from anovos_tpu.data_transformer.datetime import (
-        _bucket_ids_minmax, format_segment_aggregate,
-    )
+    byte-for-byte."""
+    from anovos_tpu.data_transformer.datetime import format_segment_aggregate
+    from anovos_tpu.ops.segment import segment_class
     from anovos_tpu.shared.runtime import wants_column_parallel
 
     tcol = idf.columns[ts_col]
-    day_ids, lo_d, hi_d = _bucket_ids_minmax(tcol.data, tcol.mask, "day")
-    lo, hi = int(lo_d), int(hi_d)
-    if lo > hi or (hi - lo + 1) > 4_000_000:
-        return None
-    nseg_d, nseg_h, nseg_w = hi - lo + 1, len(_DAYPART_NAMES), len(_DOW_NAMES)
-    if os.environ.get("ANOVOS_SHAPE_BUCKETS", "1") != "0":
-        # same segment-class bucketing as _segment_aggregate's wrapper, so
-        # the fused and per-grain programs reduce over identical widths
-        from anovos_tpu.ops.segment import bucket_segments_pow2
-
-        nseg_d = bucket_segments_pow2(nseg_d)
-        nseg_h, nseg_w = bucket_segments_pow2(nseg_h), bucket_segments_pow2(nseg_w)
+    lo = cal["day_lo"]
+    # same segment classes as _segment_aggregate's wrapper, so the fused and
+    # per-grain programs reduce over identical widths
+    nseg_d, nseg_h, nseg_w = (segment_class(n) for n in
+                              (len(cal["daily"]), len(_DAYPART_NAMES), len(_DOW_NAMES)))
     V, Mv = idf.numeric_block(num_cols)
-    cp = wants_column_parallel(day_ids, tcol.mask, V, Mv,
-                               replicate=(day_ids, tcol.mask))
-    agg_d, agg_h, agg_w = jax.device_get(_ts_num_viz_program(
-        day_ids, np.int32(lo), tcol.data, tcol.mask, V, Mv,
-        nseg_d, nseg_h, nseg_w, cp))
+    cp = wants_column_parallel(tcol.data, tcol.mask, V, Mv,
+                               replicate=(tcol.data, tcol.mask))
+    if span is not None:
+        span.add(segments=nseg_d + nseg_h + nseg_w)  # the bucket lanes of the call, for its roofline
+    agg_d, agg_h, agg_w = _fetch(_ts_num_viz_program(
+        np.int32(lo), tcol.data, tcol.mask, V, Mv, nseg_d, nseg_h, nseg_w, cp), idf, span)
     dv = format_segment_aggregate(agg_d, num_cols, _TS_NUM_AGGS, ts_col,
                                   "%Y-%m-%d", lo, "day")
     return (dv,
@@ -208,20 +264,19 @@ def _ts_num_viz_all(idf: Table, ts_col: str, num_cols: List[str]):
             _small_grain_frame(agg_w, num_cols, _DOW_NAMES))
 
 
-def _cat_viz(idf: Table, ts_col: str, cat_cols: List[str], n_cat: int = 10) -> pd.DataFrame:
+def _cat_viz(idf: Table, ts_col: str, cat_cols: List[str], cal: dict, n_cat: int = 10,
+             span=None) -> pd.DataFrame:
     """Top-N + Others category counts per day per categorical column
-    (reference's string branch of ts_viz_data).
+    (reference's string branch of ts_viz_data); the day span is the
+    calendar's (``cal``).
 
     Batched (round 5): ONE vocab-padded histogram program for every column
     and ONE stacked day×category combo program — two device dispatches
     total instead of two per column."""
-    from anovos_tpu.data_transformer.datetime import (
-        _bucket_ids_minmax, _bucket_start_secs,
-    )
+    from anovos_tpu.data_transformer.datetime import _bucket_start_secs
 
     tcol = idf.columns[ts_col]
-    day_ids, lo_d, hi_d = _bucket_ids_minmax(tcol.data, tcol.mask, "day")
-    lo, hi = int(lo_d), int(hi_d)
+    lo, hi = cal["day_lo"], cal["day_lo"] + len(cal["daily"]) - 1
     if lo > hi or not cat_cols:
         return pd.DataFrame(columns=["date", "attribute", "category", "count"])
     ndays = hi - lo + 1
@@ -237,8 +292,7 @@ def _cat_viz(idf: Table, ts_col: str, cat_cols: List[str], n_cat: int = 10) -> p
     # out here would compile broadcast+concat programs per arity
     datas = tuple(idf.columns[c].data for c in cat_cols)
     masks = tuple(idf.columns[c].mask for c in cat_cols)
-    cnts = np.asarray(jax.device_get(
-        _all_code_counts_cols(datas, masks, nv_b)))  # (k, nv_b)
+    cnts = np.asarray(_fetch(_all_code_counts_cols(datas, masks, nv_b), idf, span))  # (k, nv_b)
     # top-N per column (codes beyond a column's own vocab count zero)
     lut = np.full((k, nv_b), n_cat, np.int32)  # → Others
     tops = []
@@ -247,9 +301,9 @@ def _cat_viz(idf: Table, ts_col: str, cat_cols: List[str], n_cat: int = 10) -> p
         top = np.argsort(-cnts[j, :v])[:n_cat]
         lut[j, top] = np.arange(len(top), dtype=np.int32)
         tops.append(top)
-    combo = np.asarray(jax.device_get(_combo_counts_all_cols(
-        datas, masks, tcol.mask, lut, day_ids, np.int32(lo), ndays_b, n_cat + 1
-    ))).reshape(k, ndays_b, n_cat + 1)[:, :ndays, :]
+    combo = np.asarray(_fetch(_combo_counts_all_cols(
+        datas, masks, tcol.data, tcol.mask, lut, np.int32(lo), ndays_b, n_cat + 1
+    ), idf, span)).reshape(k, ndays_b, n_cat + 1)[:, :ndays, :]
     rows = []
     for j, c in enumerate(cat_cols):
         labels = [str(idf.columns[c].vocab[t]) for t in tops[j]] + ["Others"]
@@ -280,13 +334,15 @@ def _all_code_counts_cols(datas, masks, nv: int):
 
 
 @functools.partial(jax.jit, static_argnames=("ndays", "ncat"))
-def _combo_counts_all_cols(datas, masks, tmask, lut, day_ids, day_lo,
+def _combo_counts_all_cols(datas, masks, tdata, tmask, lut, day_lo,
                            ndays: int, ncat: int):
     """Column-tuple variant of _combo_counts_all: stack + ts-mask combine
-    + day-offset subtraction + LUT upload fold into the one program."""
+    + day ids and their offset + LUT upload fold into the one program."""
+    from anovos_tpu.data_transformer.datetime import _bucket_ids
+
     C = jnp.stack(datas, axis=1)
     Mc = jnp.stack(masks, axis=1) & tmask[:, None]
-    return _combo_counts_all(C, Mc, lut, day_ids - day_lo, ndays, ncat)
+    return _combo_counts_all(C, Mc, lut, _bucket_ids(tdata, "day") - day_lo, ndays, ncat)
 
 
 @functools.partial(jax.jit, static_argnames=("ndays", "ncat"))
@@ -308,7 +364,7 @@ def _combo_counts_all(C, M, lut, day0, ndays: int, ncat: int):
 
 def ts_viz_data(
     idf: Table, col: str, output_path: str, output_type: str = "daily",
-    _feats: Optional[pd.DataFrame] = None,
+    _calendar: Optional[dict] = None,
 ) -> None:
     """Per-column visualization data at THREE grains (reference :259-406):
     daily (date buckets), hourly (dayparts), weekly (weekdays) — numeric
@@ -324,31 +380,25 @@ def ts_viz_data(
     num_cols = [c for c in num_all][:20]
     cat_cols = [c for c in cat_all][:10]
 
-    feats = _feats if _feats is not None else ts_processed_feats(idf, col)
-    with phase("ts/viz/counts", cat="block") as sp:  # the host's groupbys over the calendar features
-        feats = feats.dropna(subset=[col])
-        daily = feats.groupby("yyyymmdd_col").size().reset_index(name="count")
-        hourly = feats.groupby("hour").size().reset_index(name="count")
-        weekly = feats.groupby("dayofweek").size().reset_index(name="count")
-        dayparts = feats.groupby("daypart").size().reset_index(name="count")
-        sp.add(rows=len(feats))
+    cal = _calendar if _calendar is not None else ts_calendar(idf, col)
+    with phase("ts/viz/counts", cat="block") as sp:  # the count tables, from the calendar's few hundred integers
+        daily, hourly, weekly, dayparts = _count_frames(cal)
+        sp.add(rows=cal["n"])
     with phase("ts/viz/write", cat="block") as sp:
         _write_csv(daily, out + f"ts_daily_{col}.csv", sp)
 
     # numeric viz: all three grains in ONE fused dispatch
     # (_ts_num_viz_all); the per-grain path — daily via the device
     # groupby-aggregator, small grains via one segment program each — is
-    # taken where that returns None (all-null or degenerate span)
+    # taken for a column without a valid row (no span to fuse over)
     if num_cols:
         with phase("ts/viz/num", cat="block", cols=len(num_cols), rows=idf.padded_rows) as sp:
-            viz = _ts_num_viz_all(idf, col, num_cols)
-            if viz is not None:
-                dv, hourly_df, weekly_df = viz
-                sp.add(fetches=3)  # the span's two ends, then the three aggregates in one
+            if cal["n"]:
+                dv, hourly_df, weekly_df = _ts_num_viz_all(idf, col, num_cols, cal, sp)
             else:
                 dv = aggregator(idf, num_cols, _TS_NUM_AGGS, col, "%Y-%m-%d")
-                hourly_df = _num_viz_small_grain(idf, col, num_cols, "hourly")
-                weekly_df = _num_viz_small_grain(idf, col, num_cols, "weekly")
+                hourly_df = _num_viz_small_grain(idf, col, num_cols, "hourly", sp)
+                weekly_df = _num_viz_small_grain(idf, col, num_cols, "weekly", sp)
         with phase("ts/viz/frame", cat="block", cols=len(num_cols)) as sp:  # the daily aggregate in long form
             long_rows = []
             for c in num_cols:
@@ -371,8 +421,8 @@ def ts_viz_data(
             _write_csv(hourly_df, out + f"ts_num_hourly_{col}.csv", sp)
             _write_csv(weekly_df, out + f"ts_num_weekly_{col}.csv", sp)
     if cat_cols:
-        with phase("ts/viz/cat", cat="block", cols=len(cat_cols), rows=idf.padded_rows):
-            cat_daily = _cat_viz(idf, col, cat_cols)
+        with phase("ts/viz/cat", cat="block", cols=len(cat_cols), rows=idf.padded_rows) as sp:
+            cat_daily = _cat_viz(idf, col, cat_cols, cal, span=sp)
         with phase("ts/viz/write", cat="block") as sp:
             _write_csv(cat_daily, out + f"ts_cat_daily_{col}.csv", sp)
 
@@ -498,27 +548,27 @@ def kpss_test(series: np.ndarray, regression: str = "c"):
 
 
 def ts_landscape(idf: Table, ts_cols: List[str], id_col: Optional[str], output_path: str,
-                 _feats_map: Optional[dict] = None) -> None:
+                 _calendars: Optional[dict] = None) -> None:
     """Per-ts-column landscape summary (reference ts_landscape :2636-2733):
     span, distinct days, records/day, weekend share, top daypart."""
     rows = []
     for c in ts_cols:
-        feats = (_feats_map[c] if _feats_map and c in _feats_map
-                 else ts_processed_feats(idf, c)).dropna(subset=[c])
-        if not len(feats):
+        cal = _calendars[c] if _calendars and c in _calendars else ts_calendar(idf, c)
+        if not cal["n"]:
             continue
-        daily = feats.groupby("yyyymmdd_col").size()
+        per_day = cal["daily"][cal["daily"] > 0]
         rows.append(
             {
                 "attribute": c,
-                "records": len(feats),
-                "distinct_days": int(daily.shape[0]),
-                "avg_records_per_day": round(float(daily.mean()), 2),
-                "max_records_per_day": int(daily.max()),
-                "weekend_pct": round(float(feats["is_weekend"].mean()), 4),
-                "top_daypart": feats["daypart"].mode().iloc[0] if len(feats) else "",
-                "start": str(feats[c].min()),
-                "end": str(feats[c].max()),
+                "records": cal["n"],
+                "distinct_days": len(per_day),
+                "avg_records_per_day": round(float(per_day.mean()), 2),
+                "max_records_per_day": int(per_day.max()),
+                "weekend_pct": round(float(cal["dow"][5:].sum() / cal["n"]), 4),
+                # in label order, so a tie goes to the first label, as mode()'s
+                "top_daypart": max(_daypart_counts(cal), key=lambda part: part[1])[0],
+                "start": _ts_str(cal["min"]),
+                "end": _ts_str(cal["max"]),
             }
         )
     if rows:
@@ -542,26 +592,20 @@ def ts_analyzer(
     phase = get_tracer().phase
     ts_cols = [c for c in idf.col_names if idf.columns[c].kind == "ts"]
     rows = []
-    eligible = []
-    feats_map: dict = {}
+    calendars: dict = {}  # of the eligible columns: computed ONCE, shared by the viz dump and the landscape
     for c in ts_cols:
-        # a stage a column: the column's fetch (data and mask) and pandas over its rows
-        with phase("ts/eligibility", cat="block", rows=idf.nrows, fetches=2):
-            stats = ts_eligiblity_check(idf, c, id_col, max_days)
+        # a stage a column: the calendar program and its one fetch
+        with phase("ts/eligibility", cat="block", rows=idf.nrows) as sp:
+            cal = ts_calendar(idf, c, sp)
+            stats = ts_eligiblity_check(idf, c, id_col, max_days, _calendar=cal)
         rows.append(stats)
         if stats.get("eligible"):
-            eligible.append(c)
-            # calendar feats computed ONCE per column, shared by the viz
-            # dump and the landscape sweep
-            with phase("ts/feats", cat="block", rows=idf.nrows, fetches=2):
-                feats_map[c] = ts_processed_feats(idf, c)
+            calendars[c] = cal
             with phase("ts/viz", cat="block"):
-                ts_viz_data(idf, c, output_path, output_type,
-                            _feats=feats_map[c])
-    if eligible:
-        with phase("ts/landscape", cat="block", cols=len(eligible)):
-            ts_landscape(idf, eligible, id_col, output_path,
-                         _feats_map=feats_map)
+                ts_viz_data(idf, c, output_path, output_type, _calendar=cal)
+    if calendars:
+        with phase("ts/landscape", cat="block", cols=len(calendars)):
+            ts_landscape(idf, list(calendars), id_col, output_path, _calendars=calendars)
     # always emit the same headered schema — a headerless empty CSV breaks
     # readers and per-run schema drift breaks downstream joins
     with phase("ts/write", cat="block") as sp:

@@ -167,8 +167,10 @@ def ts_loop_cols_pre(idf: Table, id_col: Optional[str] = None) -> List[str]:
                 except (ValueError, TypeError):
                     pass
         elif col.kind == "num" and col.dtype_name in ("int", "bigint", "long"):
-            host = np.asarray(col.data)[: min(idf.nrows, 1000)]
-            hmask = np.asarray(col.mask)[: min(idf.nrows, 1000)]
+            # a thousand values decide: sliced on the device, so that the
+            # fetch does not grow with the table
+            head = min(idf.nrows, 1000)
+            host, hmask = np.asarray(col.data[:head]), np.asarray(col.mask[:head])
             vals = host[hmask]  # null cells store 0 — judge valid entries only
             if len(vals) and np.all((vals >= 1e9) & (vals < 2e9)):
                 candidates.append(c)

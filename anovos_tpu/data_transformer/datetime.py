@@ -15,6 +15,7 @@ itself must live host-side.
 
 from __future__ import annotations
 
+import math
 import warnings
 from typing import List, Optional, Union
 
@@ -630,30 +631,23 @@ def _segment_aggregate(ids0: jax.Array, valid: jax.Array, V: jax.Array, Mv: jax.
 
     ids0: (rows,) int32 bucket ids already offset to [0, nseg); valid:
     (rows,) row validity; V: (rows, k) f32 values; Mv: (rows, k) value
-    validity.  Median comes from a per-column sort by (bucket, value) +
-    cumulative-count indexed gathers — one program, no host loop.  On a
+    validity.  One program, no host loop (``_segment_aggregate_jit``).  On a
     multi-device mesh the block is re-laid column-parallel (each device
-    lexsorts whole columns locally; ids/validity replicate) — see
+    sorts whole columns locally; ids/validity replicate) — see
     runtime.column_parallel.
 
     The static segment count is bucketed into 2^k classes (min 8 —
-    ops/segment.py ``bucket_segments_pow2``): a daypart sweep
-    (nseg 5), a weekday sweep (7) and a small date span then share one
-    compiled program per (rows, k) shape.  The returned arrays keep the
-    padded ``(k, nseg_pad)`` width — dead buckets count zero rows, and
-    every consumer either loops over its own label list or filters
-    ``cnt > 0``, so the extra buckets are never read."""
-    import os as _os
-
+    ops/segment.py ``segment_class``: NOT the coarse vocab classes, the
+    output is six (k, nseg) arrays): a daypart sweep (nseg 5), a weekday
+    sweep (7) and a small date span then share one compiled program per
+    (rows, k) shape.  The returned arrays keep the padded ``(k, nseg_pad)``
+    width — dead buckets count zero rows, and every consumer either loops
+    over its own label list or filters ``cnt > 0``, so the extra buckets are
+    never read."""
+    from anovos_tpu.ops.segment import segment_class
     from anovos_tpu.shared.runtime import wants_column_parallel
 
-    if _os.environ.get("ANOVOS_SHAPE_BUCKETS", "1") != "0":
-        # 2^k classes (shared bucket_segments_pow2 — NOT the coarse vocab
-        # classes): the output is six (k, nseg) arrays, so over-padding a
-        # wide date span costs real memory, while 2× stays trivial
-        from anovos_tpu.ops.segment import bucket_segments_pow2
-
-        nseg = bucket_segments_pow2(nseg)
+    nseg = segment_class(nseg)
     cp = wants_column_parallel(ids0, valid, V, Mv, replicate=(ids0, valid))
     if off is not None:
         # lo-offset subtraction fused into the aggregate program
@@ -669,38 +663,126 @@ def _segment_aggregate_jit_off(ids: jax.Array, off: jax.Array, valid: jax.Array,
     return _segment_aggregate_jit(ids - off, valid, V, Mv, nseg, cp=cp)
 
 
+# A segment class of at most this many buckets (dayparts, weekdays, the days
+# of a month or two) takes its count, sum and sum of squares from a one-hot
+# contraction on the MXU and its min and max from a masked reduce: no
+# scatter.  Wider classes (a daily grain over years) keep the scatters,
+# whose cost does not grow with the class.
+_DENSE_SEGMENTS_MAX = 64
+# rows a step of the dense path's scan: bounds the one-hot and the stacked
+# operand of the contraction whatever the table's length
+_DENSE_CHUNK_ROWS = 1 << 15
+# cells (rows x columns) the median's sort takes at once: the sort holds its
+# keys twice, so a block of 2^25 cells is about 0.5 GB of the chip's memory
+_SORT_BLOCK_CELLS = 1 << 25
+
+
+def _dense_moments(ids0, ok, V, nseg: int):
+    """(cnt, sm, sq, mn, mx), each (k, nseg), for a small segment class:
+    per chunk of rows the bucket one-hot (chunk, nseg) is contracted with
+    [ok, v, v*v] (chunk, 3k) at precision ``highest`` (f32 accumulation), and
+    min / max are reduces of the values masked by bucket; a ``lax.scan``
+    over the chunks carries the five results."""
+    rows, k = V.shape
+    chunk = math.gcd(rows, _DENSE_CHUNK_ROWS)
+    if chunk < min(rows, 4096):  # an unbucketed odd length: one chunk
+        chunk = rows
+    lanes = jnp.arange(nseg, dtype=ids0.dtype)
+
+    def one(ids_c, ok_c, v_c):
+        hot = ids_c[:, None] == lanes  # (chunk, nseg)
+        x = jnp.where(ok_c, v_c, 0.0)
+        moments = jnp.einsum(
+            "rs,rk->ks", hot.astype(jnp.float32),
+            jnp.concatenate([ok_c.astype(jnp.float32), x, x * x], axis=1),
+            precision=jax.lax.Precision.HIGHEST, preferred_element_type=jnp.float32)
+        sel = hot[:, None, :] & ok_c[:, :, None]  # (chunk, k, nseg)
+        return (moments,
+                jnp.where(sel, v_c[:, :, None], jnp.inf).min(axis=0),
+                jnp.where(sel, v_c[:, :, None], -jnp.inf).max(axis=0))
+
+    n = rows // chunk
+    if n == 1:
+        moments, mn, mx = one(ids0, ok, V)
+    else:
+        def step(carry, xs):
+            m, lo, hi = one(*xs)
+            return (carry[0] + m, jnp.minimum(carry[1], lo), jnp.maximum(carry[2], hi)), None
+
+        init = (jnp.zeros((3 * k, nseg), jnp.float32),
+                jnp.full((k, nseg), jnp.inf, jnp.float32), jnp.full((k, nseg), -jnp.inf, jnp.float32))
+        (moments, mn, mx), _ = jax.lax.scan(
+            step, init, (ids0.reshape(n, chunk), ok.reshape(n, chunk, k), V.reshape(n, chunk, k)))
+    return moments[:k], moments[k:2 * k], moments[2 * k:], mn, mx
+
+
+def _scatter_moments(ids0, valid, ok, V, nseg: int):
+    """(cnt, sm, sq, mn, mx), each (k, nseg), by segment scatters: three
+    adds, a min and a max per column, under ``vmap`` over the columns."""
+    seg = jnp.where(valid, ids0, nseg)
+
+    def per_col(v, o):
+        s = jnp.where(o, ids0, nseg)
+        cnt = jax.ops.segment_sum(jnp.where(o, 1.0, 0.0), seg, num_segments=nseg + 1)[:nseg]
+        sm = jax.ops.segment_sum(jnp.where(o, v, 0.0), seg, num_segments=nseg + 1)[:nseg]
+        sq = jax.ops.segment_sum(jnp.where(o, v * v, 0.0), seg, num_segments=nseg + 1)[:nseg]
+        mn = jax.ops.segment_min(jnp.where(o, v, jnp.inf), s, num_segments=nseg + 1)[:nseg]
+        mx = jax.ops.segment_max(jnp.where(o, v, -jnp.inf), s, num_segments=nseg + 1)[:nseg]
+        return cnt, sm, sq, mn, mx
+
+    return jax.vmap(per_col, in_axes=(1, 1), out_axes=0)(V, ok)
+
+
+def _segment_medians(ids0, ok, V, cnt, nseg: int):
+    """(k, nseg) medians: each column sorted by (bucket, value), the middle
+    one or two of every bucket picked through the cumulative counts.  The
+    columns sort ``_SORT_BLOCK_CELLS // rows`` at a time (a ``lax.map`` over
+    column blocks inside the one program), so the sort's memory does not
+    grow with the number of columns."""
+    rows, k = V.shape
+
+    def per_col(v, o, c):
+        s = jnp.where(o, ids0, nseg)
+        # both operands are keys: the unstable sort gives the same values
+        _, v_sorted = jax.lax.sort((s, v), num_keys=2, is_stable=False)
+        c = c.astype(jnp.int32)
+        starts = jnp.cumsum(c) - c  # (nseg,)
+        c_i = jnp.maximum(c - 1, 0)
+        lo_i = jnp.clip(starts + c_i // 2, 0, rows - 1)
+        hi_i = jnp.clip(starts + (c_i + 1) // 2, 0, rows - 1)
+        return (v_sorted[lo_i] + v_sorted[hi_i]) / 2
+
+    block = jax.vmap(per_col, in_axes=(1, 1, 0), out_axes=0)
+    b = max(1, min(k, _SORT_BLOCK_CELLS // rows))
+    while k % b:
+        b -= 1
+    if b == k:
+        return block(V, ok, cnt)
+    cut = jax.lax.dynamic_slice_in_dim
+    return jax.lax.map(
+        lambda i: block(cut(V, i * b, b, 1), cut(ok, i * b, b, 1), cut(cnt, i * b, b, 0)),
+        jnp.arange(k // b)).reshape(k, nseg)
+
+
 @_functools.partial(jax.jit, static_argnames=("nseg", "cp"))
 def _segment_aggregate_jit(ids0: jax.Array, valid: jax.Array, V: jax.Array,
                            Mv: jax.Array, nseg: int, cp: bool = False):
+    """(cnt, sm, sq, mn, mx, med), each (k, nseg): the ONE per-bucket
+    aggregate of ``aggregator``, the time-series inspection's fused
+    three-grain program and its per-grain path.  The static ``nseg`` picks
+    how the moments are taken (``_DENSE_SEGMENTS_MAX``); the median is a
+    sort either way."""
     from anovos_tpu.shared.runtime import column_parallel, replicated
 
-    V, Mv = column_parallel(V, cp), column_parallel(Mv, cp)
-    ids0, valid = replicated(ids0, cp), replicated(valid, cp)
-    seg = jnp.where(valid, ids0, nseg)
-    k = V.shape[1]
-    ones = jnp.ones_like(seg, jnp.float32)
-
-    def per_col(v, mv):
-        s = jnp.where(mv & valid, ids0, nseg)
-        cnt = jax.ops.segment_sum(jnp.where(mv & valid, 1.0, 0.0), seg, num_segments=nseg + 1)[:nseg]
-        sm = jax.ops.segment_sum(jnp.where(mv & valid, v, 0.0), seg, num_segments=nseg + 1)[:nseg]
-        sq = jax.ops.segment_sum(jnp.where(mv & valid, v * v, 0.0), seg, num_segments=nseg + 1)[:nseg]
-        mn = jax.ops.segment_min(jnp.where(mv & valid, v, jnp.inf), s, num_segments=nseg + 1)[:nseg]
-        mx = jax.ops.segment_max(jnp.where(mv & valid, v, -jnp.inf), s, num_segments=nseg + 1)[:nseg]
-        # median: sort values within buckets via composite sort key
-        order = jnp.lexsort((v, s))
-        v_sorted = v[order]
-        s_sorted = s[order]
-        starts = jnp.cumsum(cnt) - cnt  # (nseg,)
-        c_i = jnp.maximum(cnt - 1, 0)
-        lo_i = (starts + c_i // 2).astype(jnp.int32)
-        hi_i = (starts + (c_i + 1) // 2).astype(jnp.int32)
-        lo_i = jnp.clip(lo_i, 0, v.shape[0] - 1)
-        hi_i = jnp.clip(hi_i, 0, v.shape[0] - 1)
-        med = (v_sorted[lo_i] + v_sorted[hi_i]) / 2
-        return cnt, sm, sq, mn, mx, med
-
-    return jax.vmap(per_col, in_axes=(1, 1), out_axes=0)(V, Mv)
+    with jax.named_scope("ts/segment_aggregate"):
+        V, Mv = column_parallel(V, cp), column_parallel(Mv, cp)
+        ids0, valid = replicated(ids0, cp), replicated(valid, cp)
+        ok = Mv & valid[:, None]
+        if nseg <= _DENSE_SEGMENTS_MAX:
+            moments = _dense_moments(ids0, ok, V, nseg)
+        else:
+            moments = _scatter_moments(ids0, valid, ok, V, nseg)
+        return (*moments, _segment_medians(ids0, ok, V, moments[0], nseg))
 
 
 def aggregator(

@@ -165,6 +165,44 @@ def add_months(secs: jax.Array, months: int) -> jax.Array:
     return _days_from_civil(y2, m2, d2) * SECS_PER_DAY + c["sod"]
 
 
+# a ts column is int32 epoch-seconds, so no span exceeds 2^32 / 86400 = 49,711
+# days: one static class holds the per-day counts of ANY column, and the
+# calendar program compiles once per row shape
+CALENDAR_DAY_LANES = 65536
+
+
+@timed("ops.calendar_counts")
+@jax.jit
+def calendar_counts(secs: jax.Array, mask: jax.Array) -> Dict[str, jax.Array]:
+    """Every count the time-series inspection takes from one timestamp
+    column, in ONE program: valid rows, first and last second, records per
+    day of the span (lane 0 = the first valid day), per hour 0..23 and per
+    weekday (Mon=0) — a few hundred integers in place of a per-row host frame.
+
+    Per-day counts come from a sort of the day offsets and a binary search
+    of the lane edges (exact in int32, whatever the span and however the
+    rows collide on a day); the hour and weekday counts are compare-and-sum
+    reductions.  All-null column: ``n`` 0, every count 0."""
+    with jax.named_scope("ts/calendar_counts"):
+        c = civil_from_epoch(secs)
+        big = jnp.iinfo(jnp.int32).max
+        n = mask.sum(dtype=jnp.int32)
+        lo = jnp.where(mask, secs, big).min()
+        hi = jnp.where(mask, secs, -big).max()
+        rel = jnp.where(mask, c["days"] - _fdiv(lo, SECS_PER_DAY), CALENDAR_DAY_LANES)
+        srt, lane = jnp.sort(rel), jnp.arange(CALENDAR_DAY_LANES, dtype=jnp.int32)
+        # a lane ends where the next begins, the last one at the valid rows' end: one search
+        first = jnp.searchsorted(srt, lane, side="left").astype(jnp.int32)
+        daily = jnp.concatenate([first[1:], n[None]]) - first
+
+        def lanes(x, k):  # valid rows per value 0..k-1
+            return ((x[:, None] == jnp.arange(k, dtype=jnp.int32)) & mask[:, None]).sum(axis=0, dtype=jnp.int32)
+
+        return {"n": n, "min": lo, "max": hi,
+                "daily": daily,
+                "hour": lanes(c["hour"], 24), "dow": lanes(c["dayofweek"], 7)}
+
+
 @jax.jit
 def apply_offset_table(secs: jax.Array, transitions: jax.Array, offsets: jax.Array) -> jax.Array:
     """Timezone conversion on device: ``transitions`` (T,) sorted epoch-secs

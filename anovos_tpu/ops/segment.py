@@ -10,6 +10,7 @@ transition-counting / bincount does the rest.  Static shapes throughout —
 from __future__ import annotations
 
 import functools
+import os
 from typing import Tuple
 
 import jax
@@ -80,6 +81,13 @@ def bucket_segments_pow2(n: int) -> int:
     table): waste stays ≤2× where the coarse 4^k/16^k classes could cost
     16× real bytes."""
     return max(8, 1 << (max(n, 1) - 1).bit_length())
+
+
+def segment_class(n: int) -> int:
+    """The static segment count a (k, nseg) aggregate program is compiled
+    for: ``bucket_segments_pow2`` unless ``ANOVOS_SHAPE_BUCKETS=0`` asks for
+    exact shapes."""
+    return bucket_segments_pow2(n) if os.environ.get("ANOVOS_SHAPE_BUCKETS", "1") != "0" else n
 
 
 @jax.jit

@@ -114,6 +114,32 @@ def test_describe_wide_int_compiles_to_one_two_operand_sort(topo):
     assert gathered and all(shape.split(",")[0] in (str(len(PCTL_QS)), str(k)) for shape in gathered), gathered
 
 
+def test_ts_num_viz_program_compiles_without_a_scatter(topo, shapes):
+    """``nyc_taxi.ts_inspect``'s fused aggregate at a quarter of the month
+    (4,194,304 x 16; the cell runs 8,388,608, in blocks of 4 columns), classes
+    32 / 8 / 8: the moments by contraction, so no scatter; each grain's
+    medians from one sort whose two operands are both keys (no iota, no gather
+    of the order), 8 columns at a time."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from anovos_tpu.data_analyzer.ts_analyzer import _ts_num_viz_program
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    secs = jax.ShapeDtypeStruct((ROWS,), jnp.int32, sharding=one_chip)
+    valid = jax.ShapeDtypeStruct((ROWS,), jnp.bool_, sharding=one_chip)
+    lo = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    text = _compile(_ts_num_viz_program, lo, secs, valid, shapes["X"], shapes["M"],
+                    nseg_d=32, nseg_h=8, nseg_w=8, cp=False).as_text()
+    assert " scatter(" not in text and "ts/segment_aggregate" in text
+    sorts = re.findall(r" sort\(([^)]*)\)", text)
+    assert len(sorts) == 3 and all(len(s.split(",")) == 2 for s in sorts), sorts
+    assert len(re.findall(r"= \(s32\[8,%d\]" % ROWS, text)) >= 3  # (bucket, value) of 8 columns a block
+
+
 def test_dense_binned_histograms_compiles(shapes, monkeypatch):
     """_flat_counts with the TPU-only dense budget (1 << 30): at 4 M x 16 x
     10 the compare-and-reduce branch is taken, which no CPU test reaches."""

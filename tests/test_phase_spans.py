@@ -638,7 +638,7 @@ def test_ten_thousand_empty_rows_cost_microseconds_each():
 # then what lies under ``ts/viz`` and ``geo/cluster``)
 STAGES = {
     "timeseries_analyzer/inspection": (
-        {"ts/eligibility", "ts/feats", "ts/viz", "ts/landscape", "ts/write"},
+        {"ts/eligibility", "ts/viz", "ts/landscape", "ts/write"},
         {"ts/viz": {"ts/viz/counts", "ts/viz/num", "ts/viz/frame", "ts/viz/cat", "ts/viz/decompose",
                     "ts/viz/write"}}),
     "geospatial_controller": (
@@ -735,7 +735,9 @@ def test_stage_rows_carry_their_counts(full_pass):
     assert by["report/write"][0]["counts"]["bytes"] >= by["report/render"][0]["counts"]["bytes"] > 0
     writes = by["ts/viz/write"] + by["ts/write"] + by["geo/write"]
     assert all(r["counts"]["files"] >= 1 and r["counts"]["bytes"] > 0 for r in writes)
-    assert all(r["counts"]["fetches"] == 2 for r in by["ts/eligibility"] + by["ts/feats"])
+    # the inspection fetches aggregates only: one calendar a column, one fused aggregate, two category tables
+    assert "ts/feats" not in by and all(r["counts"]["fetches"] == 1 for r in by["ts/eligibility"] + by["ts/viz/num"])
+    assert all(r["counts"]["host_rows"] == 0 for r in by["ts/eligibility"] + by["ts/viz/num"] + by["ts/viz/cat"])
     assert all(r["counts"]["combos"] > 0 for r in by["geo/cluster/silhouette"])
 
 
