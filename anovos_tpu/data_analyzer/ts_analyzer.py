@@ -240,7 +240,7 @@ def _ts_num_viz_all(idf: Table, ts_col: str, num_cols: List[str], cal: dict, spa
     rows).  Daily formatting goes through the aggregator's shared
     ``format_segment_aggregate`` so the frames match the per-grain path
     byte-for-byte."""
-    from anovos_tpu.data_transformer.datetime import format_segment_aggregate
+    from anovos_tpu.data_transformer.datetime import format_segment_aggregate, median_routes
     from anovos_tpu.ops.segment import segment_class
     from anovos_tpu.shared.runtime import wants_column_parallel
 
@@ -254,7 +254,8 @@ def _ts_num_viz_all(idf: Table, ts_col: str, num_cols: List[str], cal: dict, spa
     cp = wants_column_parallel(tcol.data, tcol.mask, V, Mv,
                                replicate=(tcol.data, tcol.mask))
     if span is not None:
-        span.add(segments=nseg_d + nseg_h + nseg_w)  # the bucket lanes of the call, for its roofline
+        # the bucket lanes of the call, for its roofline, and how its medians are taken
+        span.add(segments=nseg_d + nseg_h + nseg_w, **median_routes(len(num_cols), nseg_d, nseg_h, nseg_w))
     agg_d, agg_h, agg_w = _fetch(_ts_num_viz_program(
         np.int32(lo), tcol.data, tcol.mask, V, Mv, nseg_d, nseg_h, nseg_w, cp), idf, span)
     dv = format_segment_aggregate(agg_d, num_cols, _TS_NUM_AGGS, ts_col,

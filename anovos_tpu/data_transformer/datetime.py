@@ -675,6 +675,18 @@ _DENSE_CHUNK_ROWS = 1 << 15
 # cells (rows x columns) the median's sort takes at once: the sort holds its
 # keys twice, so a block of 2^25 cells is about 0.5 GB of the chip's memory
 _SORT_BLOCK_CELLS = 1 << 25
+# bits of the 32-bit key that one counting pass of a small class's selection
+# settles (a digit: its 15 candidates a bucket are counted together), and the
+# passes over the rows a grain makes: one a digit, and one for the upper middle
+_SELECT_BITS = 4
+_SELECT_PASSES = 32 // _SELECT_BITS + 1
+
+
+def _dense_chunks(rows: int) -> int:
+    """Rows a step of a small class's scans (``_DENSE_CHUNK_ROWS`` where the
+    padded length allows it)."""
+    chunk = math.gcd(rows, _DENSE_CHUNK_ROWS)
+    return rows if chunk < min(rows, 4096) else chunk  # an unbucketed odd length: one chunk
 
 
 def _dense_moments(ids0, ok, V, nseg: int):
@@ -684,9 +696,7 @@ def _dense_moments(ids0, ok, V, nseg: int):
     min / max are reduces of the values masked by bucket; a ``lax.scan``
     over the chunks carries the five results."""
     rows, k = V.shape
-    chunk = math.gcd(rows, _DENSE_CHUNK_ROWS)
-    if chunk < min(rows, 4096):  # an unbucketed odd length: one chunk
-        chunk = rows
+    chunk = _dense_chunks(rows)
     lanes = jnp.arange(nseg, dtype=ids0.dtype)
 
     def one(ids_c, ok_c, v_c):
@@ -733,7 +743,90 @@ def _scatter_moments(ids0, valid, ok, V, nseg: int):
     return jax.vmap(per_col, in_axes=(1, 1), out_axes=0)(V, ok)
 
 
-def _segment_medians(ids0, ok, V, cnt, nseg: int):
+_NAN_KEY = int(np.float32(np.nan).view(np.int32))
+
+
+def _flip_negative(bits):
+    """An f32's int32 bits to a key whose signed order is the float's, and
+    back: all bits but the sign flipped where the sign is set."""
+    return jnp.where(bits < 0, bits ^ _I32_BIG, bits)
+
+
+def _sort_keys(v):
+    """int32 keys whose signed order is the order ``lax.sort`` gives the f32
+    ``v``: -0.0 as +0.0, every NaN one NaN above +inf."""
+    bits = jax.lax.bitcast_convert_type(v, jnp.int32)
+    return _flip_negative(jnp.where(jnp.isnan(v), _NAN_KEY, jnp.where(v == 0, 0, bits)))
+
+
+def _select_medians(ids0, ok, V, cnt, nseg: int):
+    """(k, nseg) medians of a small segment class by counting, the same
+    stored values the sort picks.  The key of a bucket's lower middle is
+    built from the top, ``_SELECT_BITS`` bits a pass over the rows (in the
+    chunks ``_dense_moments`` takes): a row looks up the prefix its own
+    bucket has so far (the bucket one-hot contracted with the prefix's four
+    bytes), its keys are compared with the prefix extended by each value of
+    the next digit, and the one-hot contracted with the 0/1 results counts a
+    bucket's valid keys below each candidate (0/1 and a byte are exact in
+    bf16, the sums in f32); the digit is the number of candidates whose count
+    does not pass the rank.  One more pass counts the keys up to the lower
+    middle and takes the least key above it, which is the upper middle where
+    the count stops at the rank.  No sort, no scatter, and nothing as long as
+    the rows but the inputs."""
+    rows, k = V.shape
+    chunk = _dense_chunks(rows)
+    lanes = jnp.arange(nseg, dtype=ids0.dtype)
+    xs = (ids0.reshape(-1, chunk), ok.reshape(-1, chunk, k), V.reshape(-1, chunk, k))
+    sign = jnp.uint32(1 << 31)  # the prefix is built as an unsigned number: its top bit is the key's sign, flipped
+    digits = jnp.arange(1, 1 << _SELECT_BITS, dtype=jnp.uint32)
+
+    def over_rows(one, init, merge):
+        def step(carry, x):
+            ids_c, ok_c, v_c = x
+            # an invalid value's key is above every candidate, so it is never counted
+            keys = jnp.where(ok_c, _sort_keys(v_c), _I32_BIG)  # (chunk, k)
+            return merge(carry, one(ids_c[:, None] == lanes, keys)), None
+
+        return jax.lax.scan(step, init, xs)[0]
+
+    c = cnt.astype(jnp.int32)
+    live = c > 0
+    lo_rank, hi_rank = jnp.maximum(c - 1, 0) // 2, c // 2  # 0-based, as the sort path picks them
+
+    def digit(i, prefix):
+        shift = (32 - _SELECT_BITS * (i + 1)).astype(jnp.uint32)
+        planes = jnp.stack([((prefix ^ sign) >> s) & 255 for s in (0, 8, 16, 24)]).astype(jnp.bfloat16)  # (4, k, nseg)
+
+        def below(hot, keys):
+            hot = hot.astype(jnp.bfloat16)
+            own = jnp.einsum("rs,bks->rbk", hot, planes, preferred_element_type=jnp.float32).astype(jnp.uint32)
+            own = own[:, 0] | (own[:, 1] << 8) | (own[:, 2] << 16) | (own[:, 3] << 24)  # (chunk, k)
+            # xor: below the prefix's bits it sets the digit, at the top it clears the flipped sign
+            cands = jax.lax.bitcast_convert_type(own[:, None, :] ^ (digits[None, :, None] << shift), jnp.int32)
+            under = (keys[:, None, :] < cands).astype(jnp.bfloat16)  # (chunk, 15, k)
+            return jnp.einsum("rs,rjk->jks", hot, under, preferred_element_type=jnp.float32).astype(jnp.int32)
+
+        counts = over_rows(below, jnp.zeros((digits.size, k, nseg), jnp.int32), jnp.add)
+        return prefix | ((counts <= lo_rank).sum(axis=0).astype(jnp.uint32) << shift)
+
+    prefix = jax.lax.fori_loop(0, 32 // _SELECT_BITS, digit, jnp.zeros((k, nseg), jnp.uint32))
+    lo = jnp.where(live, jax.lax.bitcast_convert_type(prefix ^ sign, jnp.int32), 0)
+
+    def around(hot, keys):
+        hot, keys = hot[:, None, :], keys[:, :, None]  # (chunk, k, nseg) by broadcast
+        return ((hot & (keys <= lo)).sum(axis=0, dtype=jnp.int32),
+                jnp.where(hot & (keys > lo), keys, _I32_BIG).min(axis=0))
+
+    upto, above = over_rows(
+        around, (jnp.zeros((k, nseg), jnp.int32), jnp.full((k, nseg), _I32_BIG, jnp.int32)),
+        lambda a, b: (a[0] + b[0], jnp.minimum(a[1], b[1])))
+    hi = jnp.where(live & (upto <= hi_rank), above, lo)
+
+    lo, hi = (jax.lax.bitcast_convert_type(_flip_negative(key), jnp.float32) for key in (lo, hi))
+    return (lo + hi) / 2  # an empty bucket reads 0.0; no consumer reads it
+
+
+def _sort_medians(ids0, ok, V, cnt, nseg: int):
     """(k, nseg) medians: each column sorted by (bucket, value), the middle
     one or two of every bucket picked through the cumulative counts.  The
     columns sort ``_SORT_BLOCK_CELLS // rows`` at a time (a ``lax.map`` over
@@ -764,14 +857,30 @@ def _segment_medians(ids0, ok, V, cnt, nseg: int):
         jnp.arange(k // b)).reshape(k, nseg)
 
 
+def _segment_medians(ids0, ok, V, cnt, nseg: int):
+    """(k, nseg) medians, by the static class like the moments: a selection
+    by counting up to ``_DENSE_SEGMENTS_MAX`` buckets, a sort above."""
+    if nseg <= _DENSE_SEGMENTS_MAX:
+        return _select_medians(ids0, ok, V, cnt, nseg)
+    return _sort_medians(ids0, ok, V, cnt, nseg)
+
+
+def median_routes(k: int, *nsegs: int) -> dict:
+    """What a call of ``k`` columns over grains of these classes counts on
+    its stage row: the (column, grain) medians by selection and by sort, and
+    the counting passes a selecting grain makes."""
+    selects = k * sum(n <= _DENSE_SEGMENTS_MAX for n in nsegs)
+    return {"median_selects": selects, "median_sorts": k * len(nsegs) - selects,
+            "select_passes": _SELECT_PASSES if selects else 0}
+
+
 @_functools.partial(jax.jit, static_argnames=("nseg", "cp"))
 def _segment_aggregate_jit(ids0: jax.Array, valid: jax.Array, V: jax.Array,
                            Mv: jax.Array, nseg: int, cp: bool = False):
     """(cnt, sm, sq, mn, mx, med), each (k, nseg): the ONE per-bucket
     aggregate of ``aggregator``, the time-series inspection's fused
     three-grain program and its per-grain path.  The static ``nseg`` picks
-    how the moments are taken (``_DENSE_SEGMENTS_MAX``); the median is a
-    sort either way."""
+    how the moments and the median are taken (``_DENSE_SEGMENTS_MAX``)."""
     from anovos_tpu.shared.runtime import column_parallel, replicated
 
     with jax.named_scope("ts/segment_aggregate"):

@@ -260,14 +260,17 @@ def test_wide_class_keeps_its_scatters_and_its_results():
 
 
 def test_the_median_sorts_in_column_blocks_with_the_same_result(monkeypatch):
-    ids, valid, V, Mv = _block(4096, 6, 8, seed=3)
+    """At a class that still sorts (128: above ``_DENSE_SEGMENTS_MAX``)."""
+    ids, valid, V, Mv = _block(4096, 6, 128, seed=3)
     args = (jnp.asarray(ids), jnp.asarray(valid), jnp.asarray(V), jnp.asarray(Mv))
-    whole = [np.asarray(a) for a in dtt._segment_aggregate_jit(*args, nseg=8)]
+    assert "while" not in dtt._segment_aggregate_jit.lower(*args, nseg=128).as_text()
+    whole = [np.asarray(a) for a in dtt._segment_aggregate_jit(*args, nseg=128)]
     monkeypatch.setattr(dtt, "_SORT_BLOCK_CELLS", 2 * 4096)  # two columns at a time: a lax.map of three steps
     dtt._segment_aggregate_jit.clear_cache()
     try:
-        assert "while" in dtt._segment_aggregate_jit.lower(*args, nseg=8).as_text()
-        blocked = [np.asarray(a) for a in dtt._segment_aggregate_jit(*args, nseg=8)]
+        text = dtt._segment_aggregate_jit.lower(*args, nseg=128).as_text()
+        assert "while" in text and "stablehlo.sort" in text
+        blocked = [np.asarray(a) for a in dtt._segment_aggregate_jit(*args, nseg=128)]
     finally:
         dtt._segment_aggregate_jit.clear_cache()
     for a, b in zip(whole, blocked):
