@@ -25,6 +25,7 @@ import pandas as pd
 
 from anovos_tpu.data_transformer.model_io import load_model_df, save_model_df
 from anovos_tpu.models.autoencoder import AutoEncoder
+from anovos_tpu.obs import get_tracer
 from anovos_tpu.ops.mxu import bf16_sweep, mm
 from anovos_tpu.ops.reductions import masked_moments
 from anovos_tpu.shared.runtime import get_runtime
@@ -97,34 +98,43 @@ def autoencoder_latentFeatures(
     n = len(cols)
     k = int(round(reduction_params * n)) if reduction_params < 1 else int(reduction_params)
     k = max(1, min(k, n))
-    X, mean, std = _prep_block(idf, cols, standardization, imputation)
+    # stage rows of the node; each ends on the completion of what it
+    # dispatched, so its seconds are the device's work and not the enqueueing
+    tracer = get_tracer()
+    with tracer.phase("ae/prep", rows=idf.padded_rows, cols=n):
+        X, _, _ = jax.block_until_ready(_prep_block(idf, cols, standardization, imputation))
 
     if pre_existing_model:
         ae, params = AutoEncoder.load(model_path)
     else:
         n_fit = min(idf.nrows, sample_size)
-        Xfit = X[: idf.nrows][:n_fit]
         split = int(n_fit * 0.8)
+        batch = int(min(batch_size, max(split, 1)))
         ae = AutoEncoder(n, k)
-        params = ae.fit(
-            Xfit[:split],
-            epochs=int(epochs),
-            batch_size=int(min(batch_size, max(split, 1))),
-            validation_X=Xfit[split:] if split < n_fit else None,
-            verbose=print_impact,
-        )
-        if model_path != "NA":
-            ae.save(params, model_path)
+        # a multiply-add is two operations, forward and backward are three products a matrix
+        with tracer.phase("ae/fit", steps=int(epochs) * max(split // batch, 1), epochs=int(epochs), batch=batch,
+                          fit_rows=split, val_rows=n_fit - split, params=ae.n_trainable,
+                          flops_per_step=6 * batch * ae.n_weights, bf16=int(ae.compute_dtype is not None)):
+            Xfit = X[: idf.nrows][:n_fit]
+            # fit() fetches the history when the last step is done: the barrier
+            params = ae.fit(
+                Xfit[:split],
+                epochs=int(epochs),
+                batch_size=batch,
+                validation_X=Xfit[split:] if split < n_fit else None,
+                verbose=print_impact,
+            )
 
-    Z = ae.latent(params, X)  # (padded_rows, k)
-    odf = idf
-    in_range = jnp.arange(idf.padded_rows) < idf.nrows
-    for i in range(ae.n_bottleneck):
-        odf = odf.with_column(
-            f"latent_{i}", Column("num", Z[:, i].astype(jnp.float32), in_range, dtype_name="float")
-        )
-    if output_mode == "replace":
-        odf = odf.drop(cols)
+    with tracer.phase("ae/apply", rows=idf.padded_rows, cols=n, latent=ae.n_bottleneck):
+        Z = jax.block_until_ready(ae.latent_columns(params, X))  # k arrays of padded_rows
+        in_range = jnp.arange(idf.padded_rows) < idf.nrows
+        odf = idf.with_columns(
+            (f"latent_{i}", Column("num", z, in_range, dtype_name="float")) for i, z in enumerate(Z))
+        if output_mode == "replace":
+            odf = odf.drop(cols)
+    if not pre_existing_model and model_path != "NA":
+        with tracer.phase("ae/save"):
+            ae.save(params, model_path)
     if print_impact:
         logger.info(f"autoencoder latent features: {ae.n_bottleneck} from {n} columns")
     return odf

@@ -350,6 +350,13 @@ class Table:
         cols[name] = col
         return Table(cols, self.nrows, self.valid_rows)
 
+    def with_columns(self, named) -> "Table":
+        """:meth:`with_column` for every ``(name, column)`` of ``named`` in
+        order, with one copy of the column dict in place of one a column."""
+        cols = OrderedDict(self.columns)
+        cols.update(named)
+        return Table(cols, self.nrows, self.valid_rows)
+
     def __getitem__(self, name: str) -> Column:
         return self.columns[name]
 
