@@ -1,7 +1,9 @@
 """The one bounded pool of host threads that ingest's independent units run
-on: the part files of a read, the string columns of a frame, the buckets of
-one long column.  Almost all of a unit's seconds are in Arrow's C kernels,
-which release the GIL, so units on threads overlap.
+on: the part files of a read, the columns of a table being built (a string
+column's encode, every column's conversion to the device dtypes, its padding
+and its ``device_put``), the buckets of one long column, the columns of a
+table being fetched.  Almost all of a unit's seconds are in Arrow's C kernels
+and numpy's loops, which release the GIL, so units on threads overlap.
 
 One pool a process, sized once from the CPUs the process may run on
 (``parallel.scheduler.available_cpus``, at most 16), the calling thread

@@ -29,7 +29,7 @@ import sys
 import threading
 import time
 from collections import OrderedDict
-from typing import Dict, Iterable, List, Optional, Sequence, Tuple, Union
+from typing import Dict, Iterable, List, NamedTuple, Optional, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -39,7 +39,7 @@ import pyarrow as pa
 import pyarrow.compute as pc
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from anovos_tpu.shared.host_pool import get_host_pool, record_units
+from anovos_tpu.shared.host_pool import UnitsRun, get_host_pool, record_units
 from anovos_tpu.shared.native import NativeEncodedStrings
 from anovos_tpu.shared.runtime import get_runtime
 
@@ -249,31 +249,35 @@ class Table:
         """Build from host column arrays (object arrays → cat; datetime64 →
         ts; numeric → num).  NaN/None become nulls, and so do the masked
         entries of a ``np.ma.MaskedArray`` (integers with nulls: the values
-        stay integers and exact, the mask is the column's)."""
-        rt = get_runtime()
-        cols: "OrderedDict[str, Column]" = OrderedDict()
+        stay integers and exact, the mask is the column's).
+
+        A column is one unit of the host pool (:func:`_upload_columns`: the
+        encode where the array still needs one, the conversion to the device
+        dtypes, the padding, the ``device_put`` calls): side by side for
+        ``_POOLED_COLUMNS_MIN_ROWS`` rows or more, a loop on this thread
+        under it; the table is the same either way."""
         if not data:
-            return Table(cols, 0)
+            return Table(OrderedDict(), 0)
         n = nrows if nrows is not None else len(next(iter(data.values())))
-        npad = rt.pad_rows(max(n, 1))
-        for name, arr in data.items():
-            if not isinstance(arr, NativeEncodedStrings):
-                arr = np.asanyarray(arr)  # a masked array keeps its mask
-            cols[name] = _host_to_column(arr, n, npad, rt)
-        return Table(cols, n)
+        return Table(_upload_columns(data, n), n)
 
     @staticmethod
     def from_pandas(df) -> "Table":
         """Build from a pandas frame.  A column of a string dtype (pandas 3's
         ``str``, ``string``; Arrow- or python-backed) or of dtype ``category``
         is dictionary-encoded from the Series by :func:`encode_strings` and
-        never becomes an object array; an ``object`` column goes to
-        :meth:`from_numpy` as objects and is encoded there, by the same
-        function; every other dtype goes as its numpy array.  The vocab of a
-        cat column is in code-point order, which is ``np.unique``'s over
-        Python ``str``; Arrow computes it over the UTF-8 bytes where the
-        distinct values are an Arrow string array."""
-        return Table.from_numpy(_frame_arrays(df, encode_strings), nrows=len(df))
+        never becomes an object array; an ``object`` column is encoded from
+        its objects, by the same function; every other dtype goes as its
+        numpy array (:func:`_frame_sources`).  The vocab of a cat column is
+        in code-point order, which is ``np.unique``'s over Python ``str``;
+        Arrow computes it over the UTF-8 bytes where the distinct values are
+        an Arrow string array.  A string column's encode and its upload are
+        one unit of :func:`_upload_columns`, so the other columns are on the
+        device while the longest encode still runs."""
+        sources = _frame_sources(df)
+        if not sources:
+            return Table(OrderedDict(), 0)
+        return Table(_upload_columns(sources, len(df)), len(df))
 
     # ------------------------------------------------------------------
     # basic introspection (the reference's utils.attributeType_segregation)
@@ -756,12 +760,15 @@ def _arrow_sorted_vocab_codes(first: np.ndarray, dictionary: pa.Array) -> Native
 # partitioned by their first bytes and the partitions encoded side by side.
 _BUCKETED_ENCODE_MIN_ROWS = 1 << 20
 # The column-sized units of a frame or table of this many rows or more run
-# side by side on the host pool: the string columns of a frame being encoded
-# (``_frame_arrays``), the columns of a table being fetched and converted
-# (``Table.to_pandas``); a shorter one's in a loop (the stats tables, a
-# node's small frames, a 32,561-row dataset): thirteen columns of 65,536 rows
-# are 3 ms each and threads that wake for them gave nothing back in the
-# median, at 131,072 rows they halved the wall (PERF.md section 3).
+# side by side on the host pool: the columns of a table being built, each
+# encoded where it is a string column, converted, padded and put on the
+# device (``_upload_columns``: ``Table.from_numpy`` / ``from_pandas``), the
+# string columns of a frame that stays on the host (``_frame_arrays``), the
+# columns of a table being fetched and converted (``Table.to_pandas``); a
+# shorter one's in a loop (the stats tables, a node's small frames, a
+# 32,561-row dataset): thirteen columns of 65,536 rows are 3 ms each and
+# threads that wake for them gave nothing back in the median, at 131,072
+# rows they halved the wall (PERF.md section 3).
 _POOLED_COLUMNS_MIN_ROWS = 1 << 17
 # The row of a pass's tree under which ``Table.to_pandas`` files a row a
 # column: ``write_dataset`` opens it around the fetch of the table it writes.
@@ -930,6 +937,74 @@ def _encode_with_counts(values) -> Tuple[NativeEncodedStrings, Dict[str, float]]
         _loop_encode(np.asarray(values, dtype=object)), {"hashed": 0, "native_sort": 0})
 
 
+class _UnencodedStrings(NamedTuple):
+    """A frame's string or category column on its way into a table: the
+    Series, which :func:`encode_strings` takes as it is."""
+    series: "pd.Series"
+
+
+def _record_unit_times(what: str, stamps: List[Tuple[int, float, float]], side_by_side: bool) -> None:
+    """What :func:`record_units` says of one kind of work inside a call's
+    units, where a unit is two kinds (a string column's encode, then its
+    upload), from the ``(thread, start, end)`` of each piece of it:
+    ``<what>_workers`` (threads that ran the work; 0 for the loop) and
+    ``<what>_wall_s`` (first start to last end), if any ran."""
+    if stamps:
+        threads, starts, ends = zip(*stamps)
+        record_units(what, UnitsRun([], len(set(threads)) if side_by_side else 0, max(ends) - min(starts)))
+
+
+def _upload_columns(sources: Dict[str, object], n: int) -> "OrderedDict[str, Column]":
+    """The device columns of a table of ``n`` rows from what
+    :meth:`Table.from_numpy` takes or :func:`_frame_sources` gives, one unit
+    of the host pool a column: a string column's encode
+    (:func:`encode_strings`, its ``ingest/encode`` span), then
+    :func:`_upload_column` (its ``ingest/h2d`` span).  Side by side for
+    ``_POOLED_COLUMNS_MIN_ROWS`` rows or more, a loop on this thread under
+    it.  The columns that need an encode are claimed first: they are the long
+    units (one of mostly distinct values hands its own buckets to the same
+    pool), and every other column is on the device before the longest encode
+    has ended.  The columns come back in ``sources``' order, each what the
+    loop makes of it.  A unit that raises stops the units not yet started;
+    the error of the first of them in the units' order is raised and no table
+    is made.  On the row of the pass's tree the call runs under
+    (``io:read_dataset`` inside a read): ``h2d_workers`` / ``h2d_wall_s`` of
+    the uploads and, where a frame's string columns were encoded here,
+    ``encode_workers`` / ``encode_wall_s``, as :func:`record_units` files
+    them."""
+    rt = get_runtime()
+    npad = rt.pad_rows(max(n, 1))
+    sources = {name: src if isinstance(src, (NativeEncodedStrings, _UnencodedStrings))
+               else np.asanyarray(src)  # a masked array keeps its mask
+               for name, src in sources.items()}
+    encodes, uploads = [], []  # (thread, start, end) of every encode and upload; an append is atomic
+
+    def needs_encode(name) -> bool:
+        src = sources[name]
+        return isinstance(src, _UnencodedStrings) or (
+            not isinstance(src, NativeEncodedStrings) and src.dtype.kind in "OUS")
+
+    def unit(name) -> Column:
+        src = sources[name]
+        if isinstance(src, _UnencodedStrings):
+            t0 = time.perf_counter()
+            src = encode_strings(src.series)
+            encodes.append((threading.get_ident(), t0, time.perf_counter()))
+        elif needs_encode(name):
+            src = encode_strings(src[:n])
+        t0 = time.perf_counter()
+        col = _upload_column(src, n, npad, rt)
+        uploads.append((threading.get_ident(), t0, time.perf_counter()))
+        return col
+
+    order = sorted(sources, key=lambda name: not needs_encode(name))  # stable: a kind keeps the table's order
+    ran = get_host_pool().run(unit, order, side_by_side=n >= _POOLED_COLUMNS_MIN_ROWS)
+    _record_unit_times("encode", encodes, ran.workers > 0)
+    _record_unit_times("h2d", uploads, ran.workers > 0)
+    made = dict(zip(order, ran.results))
+    return OrderedDict((name, made[name]) for name in sources)
+
+
 def _host_to_column(arr: np.ndarray, n: int, npad: int, rt) -> Column:
     """Convert one host array to a device Column (pad + shard).
 
@@ -938,18 +1013,33 @@ def _host_to_column(arr: np.ndarray, n: int, npad: int, rt) -> Column:
     non-null values are all ``str``, the per-value loop otherwise; the vocab
     in code-point order either way, which is ``np.unique``'s: computed by
     Arrow over UTF-8 bytes in the first case, by ``np.unique`` in the other).
-    Codes that arrive encoded (avro's decoder, ``Table.from_pandas``) skip that.  Then ``ingest/h2d`` around
-    the conversion to the device dtype (:func:`_plain_to_host`), the padding
-    and the ``device_put`` calls (:func:`_place_column`).
-    ``Runtime.shard_rows``'s transfer bracket puts ``bytes`` and
-    ``enqueue_s`` on the latter: ``device_put`` is async, so those seconds
-    are the time to enqueue, not to move."""
-    from anovos_tpu.obs.tracing import get_tracer
-
+    Codes that arrive encoded (avro's decoder) skip that.  Then
+    :func:`_upload_column`.  One column on the calling thread: a table's
+    columns go through :func:`_upload_columns`, whose units are these two
+    steps."""
     if not isinstance(arr, NativeEncodedStrings) and arr.dtype.kind in "OUS":
         arr = encode_strings(arr[:n])
-    with get_tracer().phase("ingest/h2d", cat="io"):
-        return _place_column(_plain_to_host(arr, n), npad, rt)
+    return _upload_column(arr, n, npad, rt)
+
+
+def _upload_column(arr: Union[np.ndarray, NativeEncodedStrings], n: int, npad: int, rt) -> Column:
+    """One column that needs no dictionary-encoding, from the host to the
+    device, under one ``ingest/h2d`` span (a row of the pass's tree where
+    ingest calls this; on a pool thread it has the parent it would have had
+    on the calling one): the conversion to the device dtypes
+    (:func:`_plain_to_host`; ``convert_s``), then array by array the padding
+    to ``npad`` rows (``pad_s``) and the ``device_put`` (:func:`_place_column`).
+    ``Runtime.shard_rows``'s transfer bracket puts ``bytes``, ``shards`` and
+    ``enqueue_s`` on the span for the latter: ``device_put`` is async, so
+    those seconds are the time to enqueue, not to move.  The three counts
+    are host seconds of this thread and sum to the span's wall."""
+    from anovos_tpu.obs.tracing import get_tracer
+
+    with get_tracer().phase("ingest/h2d", cat="io") as sp:
+        t0 = time.perf_counter()
+        hc = _plain_to_host(arr, n)
+        sp.add(convert_s=time.perf_counter() - t0)
+        return _place_column(hc, npad, rt, sp)
 
 
 def _plain_to_host(arr: np.ndarray, n: int) -> HostColumn:
@@ -1009,12 +1099,17 @@ def _plain_to_host(arr: np.ndarray, n: int) -> HostColumn:
     return HostColumn("num", host, ~isnull, dtype_name=dtn)
 
 
-def _place_column(hc: HostColumn, npad: int, rt) -> Column:
+def _place_column(hc: HostColumn, npad: int, rt, span) -> Column:
     """The device half: each array of ``hc`` padded to ``npad`` rows (a
     padding row carries mask=False, code −1 in a cat column, the wide pair
-    (0, −2^31)) and put on ``rt``'s row sharding."""
+    (0, −2^31)) and put on ``rt``'s row sharding, one array after the other,
+    so that an array is on its way while the next is padded.  The padding's
+    seconds go on ``span`` as ``pad_s``."""
     def put(a, fill):
-        return rt.shard_rows(_pad_to(a, npad, fill))
+        t0 = time.perf_counter()
+        padded = _pad_to(a, npad, fill)
+        span.add(pad_s=time.perf_counter() - t0)
+        return rt.shard_rows(padded)
 
     wide = hc.wide_hi is not None
     return Column(
@@ -1108,26 +1203,19 @@ def arrow_typed_to_numpy(s) -> np.ndarray:
     return pc.cast(arr, pa.timestamp("s")).to_numpy()
 
 
-def _frame_arrays(df, encode) -> Dict[str, Union[np.ndarray, NativeEncodedStrings]]:
-    """A pandas frame's columns as :meth:`Table.from_numpy` takes them: a
-    column of a string dtype or of dtype ``category`` through ``encode``, from
-    the Series; an ``object`` column as objects; every other dtype as its
-    numpy array; an Arrow-typed decimal or date column
-    (:func:`arrow_typed_kind`) as float64 or ``datetime64[s]``; a column of
-    pandas' nullable integers as a masked array of its integers.  The string
-    columns of a frame of ``_POOLED_COLUMNS_MIN_ROWS`` rows or more are units
-    of the host pool (``shared.host_pool``), encoded side by side once this
-    thread has taken the other columns' arrays; the dict is in the frame's
-    column order either way.  ``encode_workers`` (threads that
-    encoded a column; 0 for the loop) and ``encode_wall_s`` (first start to
-    last end) go on the row of the pass's tree the call runs under."""
+def _frame_sources(df) -> Dict[str, object]:
+    """A pandas frame's columns as :func:`_upload_columns` takes them, in the
+    frame's order: a column of a string dtype or of dtype ``category`` as its
+    Series, not yet encoded (:class:`_UnencodedStrings`); an ``object`` column
+    as objects; every other dtype as its numpy array; an Arrow-typed decimal
+    or date column (:func:`arrow_typed_kind`) as float64 or
+    ``datetime64[s]``; a column of pandas' nullable integers as a masked
+    array of its integers."""
     data = {}
-    strings = []
     for name in df.columns:
         s = df[name]
         if isinstance(s.dtype, (pd.StringDtype, pd.CategoricalDtype)):
-            data[name] = None  # its place in the frame's order
-            strings.append(name)
+            data[name] = _UnencodedStrings(s)
         elif arrow_typed_kind(s.dtype):
             data[name] = arrow_typed_to_numpy(s)
         elif isinstance(s.array, pd.arrays.IntegerArray):
@@ -1138,8 +1226,23 @@ def _frame_arrays(df, encode) -> Dict[str, Union[np.ndarray, NativeEncodedString
             data[name] = s.to_numpy(dtype=object)
         else:
             data[name] = s.to_numpy()
+    return data
+
+
+def _frame_arrays(df, encode) -> Dict[str, Union[np.ndarray, NativeEncodedStrings]]:
+    """:func:`_frame_sources` with the string and category columns through
+    ``encode``: a frame's columns as :meth:`Table.from_numpy` takes them, for
+    a frame that stays on the host (:func:`host_table_frame`).  The string
+    columns of a frame of ``_POOLED_COLUMNS_MIN_ROWS`` rows or more are units
+    of the host pool (``shared.host_pool``), encoded side by side once this
+    thread has taken the other columns' arrays; the dict is in the frame's
+    column order either way.  ``encode_workers`` (threads that
+    encoded a column; 0 for the loop) and ``encode_wall_s`` (first start to
+    last end) go on the row of the pass's tree the call runs under."""
+    data = _frame_sources(df)
+    strings = [name for name, src in data.items() if isinstance(src, _UnencodedStrings)]
     if strings:
-        ran = get_host_pool().run(lambda name: encode(df[name]), strings,
+        ran = get_host_pool().run(lambda name: encode(data[name].series), strings,
                                   side_by_side=len(df) >= _POOLED_COLUMNS_MIN_ROWS)
         data.update(zip(strings, ran.results))
         record_units("encode", ran)

@@ -28,6 +28,11 @@ def data_dir(work):
 
 @pytest.fixture(scope="module")
 def run(work, data_dir):
+    import jax
+
+    # a cold run whatever this worker ran before: xdist hands files to workers in an order that
+    # shifts with every new test file, and programs another file compiled at 2,000 rows leave none to count
+    jax.clear_caches()
     cfg = chip_smoke.write_config(data_dir, os.path.join(work, "configs_full.yaml"))
     return chip_smoke.run_pipeline(cfg, os.path.join(work, "run_cold"))
 
