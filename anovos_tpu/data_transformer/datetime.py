@@ -15,7 +15,6 @@ itself must live host-side.
 
 from __future__ import annotations
 
-import math
 import warnings
 from typing import List, Optional, Union
 
@@ -25,6 +24,7 @@ import numpy as np
 import pandas as pd
 
 from anovos_tpu.ops import datetime_kernels as dk
+from anovos_tpu.ops.segment import dense_chunks
 from anovos_tpu.shared.runtime import get_runtime
 from anovos_tpu.shared.table import Column, Table, _host_to_column
 
@@ -682,13 +682,6 @@ _SELECT_BITS = 4
 _SELECT_PASSES = 32 // _SELECT_BITS + 1
 
 
-def _dense_chunks(rows: int) -> int:
-    """Rows a step of a small class's scans (``_DENSE_CHUNK_ROWS`` where the
-    padded length allows it)."""
-    chunk = math.gcd(rows, _DENSE_CHUNK_ROWS)
-    return rows if chunk < min(rows, 4096) else chunk  # an unbucketed odd length: one chunk
-
-
 def _dense_moments(ids0, ok, V, nseg: int):
     """(cnt, sm, sq, mn, mx), each (k, nseg), for a small segment class:
     per chunk of rows the bucket one-hot (chunk, nseg) is contracted with
@@ -696,7 +689,7 @@ def _dense_moments(ids0, ok, V, nseg: int):
     min / max are reduces of the values masked by bucket; a ``lax.scan``
     over the chunks carries the five results."""
     rows, k = V.shape
-    chunk = _dense_chunks(rows)
+    chunk = dense_chunks(rows, _DENSE_CHUNK_ROWS)
     lanes = jnp.arange(nseg, dtype=ids0.dtype)
 
     def one(ids_c, ok_c, v_c):
@@ -774,7 +767,7 @@ def _select_medians(ids0, ok, V, cnt, nseg: int):
     the count stops at the rank.  No sort, no scatter, and nothing as long as
     the rows but the inputs."""
     rows, k = V.shape
-    chunk = _dense_chunks(rows)
+    chunk = dense_chunks(rows, _DENSE_CHUNK_ROWS)
     lanes = jnp.arange(nseg, dtype=ids0.dtype)
     xs = (ids0.reshape(-1, chunk), ok.reshape(-1, chunk, k), V.reshape(-1, chunk, k))
     sign = jnp.uint32(1 << 31)  # the prefix is built as an unsigned number: its top bit is the key's sign, flipped

@@ -30,7 +30,7 @@ from anovos_tpu.ops.quantiles import masked_quantiles
 from anovos_tpu.ops.reductions import masked_moments
 from anovos_tpu.obs import get_tracer
 from anovos_tpu.ops.segment import (
-    _bucket_segments, code_counts, code_label_counts, masked_nunique, vocab_lookup)
+    _bucket_segments, code_counts, code_label_counts, masked_nunique, on_one_device, segment_routes, vocab_lookup)
 from anovos_tpu.shared.runtime import get_runtime
 from anovos_tpu.shared.table import Column, Table, pad_lane_params
 from anovos_tpu.shared.utils import parse_cols
@@ -500,13 +500,17 @@ def cat_to_num_supervised(
     tracer = get_tracer()
     k, rows = len(cols), idf.padded_rows
     vocab_max = max(len(idf.columns[c].vocab) for c in cols)
-    lanes = sum(_bucket_segments(max(len(idf.columns[c].vocab), 1)) for c in cols)
+    classes = [_bucket_segments(max(len(idf.columns[c].vocab), 1)) for c in cols]
+    lanes = sum(classes)
+    sharded = not on_one_device(idf.columns[cols[0]].data)
     shape = dict(cols=k, rows=rows, vocab_max=vocab_max, segments_max=_bucket_segments(vocab_max))
     # what the calls below read and write, summed: padded rows into a group
     # count (with and without the label), lanes of the padded count vectors,
     # rows gathered, bytes of the padded LUTs (a bool and an f32 a column)
-    # and of the gathered columns
-    fitted = {} if pre_existing_model else dict(count_rows=k * rows, label_rows=k * rows, seg_lanes=2 * lanes)
+    # and of the gathered columns; and how many of the calls (two a column)
+    # take which route of ops/segment.py
+    fitted = {} if pre_existing_model else dict(count_rows=k * rows, label_rows=k * rows, seg_lanes=2 * lanes,
+                                                **segment_routes(2 * classes, "counts", sharded))
     rates_of: Dict[str, np.ndarray] = {}
     with tracer.phase("transform/fit", **shape, **fitted):
         if not pre_existing_model:
@@ -537,7 +541,7 @@ def cat_to_num_supervised(
                 save_model_df(dfm, model_path, f"cat_to_num_supervised/{c}", fmt="csv")
     new_cols: "OrderedDict[str, Column]" = OrderedDict()
     with tracer.phase("transform/apply", **shape, gather_rows=2 * k * rows, lut_bytes=5 * lanes,
-                      gather_out_bytes=5 * k * rows):
+                      gather_out_bytes=5 * k * rows, **segment_routes(2 * classes, "gathers", sharded)):
         for c, rates in rates_of.items():
             col = idf.columns[c]
             valid_code = col.data >= 0
@@ -768,10 +772,11 @@ def imputation_MMM(
     vocab_max = max((len(idf.columns[c].vocab) for c in cat_cols), default=0)
     shape = dict(cols=len(cols), rows=idf.padded_rows, vocab_max=vocab_max,
                  segments_max=_bucket_segments(vocab_max) if cat_cols else 0)
-    # a group count a categorical: the padded rows it reads and the lanes of its count vector, summed
+    # a group count a categorical: the padded rows it reads, the lanes of its count vector, summed, and its route
+    classes = [_bucket_segments(max(len(idf.columns[c].vocab), 1)) for c in cat_cols]
     fitted = {} if pre_existing_model or not cat_cols else dict(
-        count_rows=len(cat_cols) * idf.padded_rows,
-        seg_lanes=sum(_bucket_segments(max(len(idf.columns[c].vocab), 1)) for c in cat_cols))
+        count_rows=len(cat_cols) * idf.padded_rows, seg_lanes=sum(classes),
+        **segment_routes(classes, "counts", not on_one_device(idf.columns[cat_cols[0]].data)))
     fills: Dict[str, object] = {}
     mode_code: Dict[str, int] = {}  # a mode counted here: its code, so nobody looks its value up again
     with tracer.phase("transform/fit", **shape, **fitted):

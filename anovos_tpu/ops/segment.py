@@ -5,11 +5,39 @@ Replaces Spark's shuffle-based groupBy (stats_generator.py:386-401 mode loop;
 or raw numerics; a device sort turns equal keys into contiguous segments and
 transition-counting / bincount does the rest.  Static shapes throughout —
 "mask-don't-shrink" (SURVEY.md §7 hard part 2).
+
+The group counts (``code_counts``, ``code_label_counts``) and the per-code
+lookup (``vocab_lookup``) of ONE column each have two routes inside their one
+jitted program, picked by the static padded class (PR 44):
+
+* a class of at most ``_DENSE_COUNT_LANES_MAX`` / ``_DENSE_GATHER_LANES_MAX``
+  lanes is counted / looked up by contracting one-hots of the codes on the
+  MXU, the rows in chunks under a ``lax.scan`` so that no one-hot exists
+  whole.  The one-hots (and a count's 0/1 validity, and a LUT's bytes) are
+  exact in bfloat16 and the products accumulate in f32: a count is the exact
+  integer up to 2^24, where the f32 scatter-add of 1.0 stops counting too, and
+  a looked-up value is the LUT's bits.  No value with a mantissa passes
+  through bf16 (a label count's weights stay f32 at ``Precision.HIGHEST``).
+* a wider class keeps the scatter-add / the index gather, whose time on the
+  chip does not grow with the class (10-15 ms at 1,572,864 rows) while the
+  contraction's multiply-adds do.
+
+The two limits were measured on a TPU v5e (CHANGES.md, PR 44, has the
+timings by class).  At 1,572,864 rows and 65,536 lanes a count takes 2.8 ms,
+a label count 8.8, the gather of an f32 LUT 6.9 and of a bool LUT 1.3,
+against 10.5-15.2 ms for the scatter-add and the index gather; at 4,096
+lanes and below every contraction is 0.2-1.1 ms; at 32,768 rows every one is
+at the dispatch floor, 0.2 ms against 0.25-0.35.  At 131,072 lanes and above
+the contraction is still ahead by a tenth to a third (a bool LUT by more):
+those classes are ROADMAP A8's, where a sort of the codes may beat both.
+A column laid over a mesh keeps the scatter-add and the gather
+(``on_one_device``).
 """
 
 from __future__ import annotations
 
 import functools
+import math
 import os
 from typing import Tuple
 
@@ -98,9 +126,108 @@ def cat_valid_mask(codes: jax.Array, M: jax.Array) -> jax.Array:
     return M & (codes >= 0)
 
 
-@functools.partial(jax.jit, static_argnames=("vocab_size",))
-def _code_counts_p(codes: jax.Array, M: jax.Array, vocab_size: int) -> jax.Array:
+# A class of at most this many lanes takes its group counts (with and without
+# a row weight) from a contraction with the codes' one-hot on the MXU; a wider
+# one keeps the scatter-add, whose time does not grow with the class (10-14 ms
+# at 1,572,864 rows) while the contraction's multiply-adds do.
+_DENSE_COUNT_LANES_MAX = 65536
+# ... and this many for a LUT's gather, which contracts a plane a byte of the
+# LUT's dtype where a count contracts once.
+_DENSE_GATHER_LANES_MAX = 65536
+# rows a step of a dense program's scan: bounds the one-hots whatever the
+# table's length (two of 8,192 x 256 in bf16 are 8 MB; a count at 65,536 lanes
+# takes 2.8 ms in steps of 4,096 or 8,192 rows and 6.4 in steps of 16,384 or
+# more, the other programs the same either way)
+_DENSE_CHUNK_ROWS = 1 << 13
+
+
+def dense_chunks(rows: int, most: int) -> int:
+    """Rows a step of a small class's scans: ``most`` where the padded
+    length allows it."""
+    chunk = math.gcd(rows, most)
+    return rows if chunk < min(rows, 4096) else chunk  # an unbucketed odd length: one chunk
+
+
+def _dense_class(p: int, lanes_max: int) -> bool:
+    """Whether a padded class ``p`` is under the limit and splits into two
+    levels (every class of ``_bucket_segments`` is a power of two)."""
+    return p <= lanes_max and p & (p - 1) == 0
+
+
+def _levels(p: int) -> Tuple[int, int]:
+    """(hi, lo) lanes of a class's two one-hots, ``hi * lo == p``: a code is
+    ``hi_code * lo + lo_code``.  As square as a power of two splits (64 x 64
+    for 4,096 lanes, 256 x 256 for 65,536): the contraction's two sides then
+    fill the MXU's 128 x 128 alike."""
+    lo = 1 << (p.bit_length() // 2)
+    return p // lo, lo
+
+
+def on_one_device(a) -> bool:
+    """False for a concrete array laid over several devices (a ``Table``'s
+    column on a mesh).  The dense programs scan the rows in chunks, and a
+    ``reshape(n, chunk)`` of a row-sharded array would gather the rows where
+    the scatter-add and the index gather are partitioned over them (and end
+    in one all-reduce / in none): such a column keeps those."""
+    if isinstance(a, jax.core.Tracer) or not isinstance(a, jax.Array):
+        return True
+    return len(a.sharding.device_set) == 1
+
+
+def _contracts(p: int, lanes_max: int, codes) -> bool:
+    """The static ``dense`` of the three programs, from what a call can see:
+    the padded class and the layout of its concrete codes."""
+    return _dense_class(p, lanes_max) and on_one_device(codes)
+
+
+def segment_routes(classes, kind: str, sharded: bool = False) -> dict:
+    """What calls over these padded classes count on their stage row: for
+    ``kind`` "counts" the group counts by contraction and by scatter-add, for
+    "gathers" the gathers of a LUT of at most 32 bits an entry by contraction
+    and by index.  ``sharded``: the columns are laid over a mesh, where every
+    call keeps the second route (``on_one_device``)."""
+    names, lanes_max = {"counts": (("dense_counts", "scatter_counts"), _DENSE_COUNT_LANES_MAX),
+                        "gathers": (("dense_gathers", "index_gathers"), _DENSE_GATHER_LANES_MAX)}[kind]
+    dense = 0 if sharded else sum(_dense_class(p, lanes_max) for p in classes)
+    return {names[0]: dense, names[1]: len(classes) - dense}
+
+
+def _dense_group_sum(codes: jax.Array, w: jax.Array, p: int) -> jax.Array:
+    """(p,) f32 sums of ``w`` by code, ``w`` already zero on every row that
+    does not count.  Per chunk of rows the one-hot of the code's hi part
+    (``_levels``), carrying ``w``, is contracted over the rows with the lo
+    part's one-hot, and a ``lax.scan`` over the chunks carries the (hi, lo) sums.
+    A bf16 ``w`` (0 / 1) makes both operands exact in bf16 and the product one
+    MXU pass with f32 accumulation; an f32 ``w`` goes in at ``HIGHEST``."""
+    rows = codes.shape[0]
+    chunk = dense_chunks(rows, _DENSE_CHUNK_ROWS)
+    hi_n, lo_n = _levels(p)
+    hi_lanes = jnp.arange(hi_n, dtype=codes.dtype)
+    lo_lanes = jnp.arange(lo_n, dtype=codes.dtype)
+    precision = None if w.dtype == jnp.bfloat16 else jax.lax.Precision.HIGHEST
+
+    def one(c, w_c):
+        # a code outside [0, p) has no hi lane, as the scatter-add drops it
+        lhs = jnp.where((c // lo_n)[:, None] == hi_lanes, w_c[:, None], 0).astype(w.dtype)
+        hot_lo = ((c % lo_n)[:, None] == lo_lanes).astype(w.dtype)
+        return jnp.einsum("rh,rl->hl", lhs, hot_lo, precision=precision,
+                          preferred_element_type=jnp.float32)
+
+    n = rows // chunk
+    if n == 1:
+        sums = one(codes, w)
+    else:
+        sums, _ = jax.lax.scan(lambda acc, xs: (acc + one(*xs), None),
+                               jnp.zeros((hi_n, lo_n), jnp.float32),
+                               (codes.reshape(n, chunk), w.reshape(n, chunk)))
+    return sums.reshape(p)
+
+
+@functools.partial(jax.jit, static_argnames=("vocab_size", "dense"))
+def _code_counts_p(codes: jax.Array, M: jax.Array, vocab_size: int, dense: bool = False) -> jax.Array:
     valid = M & (codes >= 0)
+    if dense:
+        return _dense_group_sum(codes, valid.astype(jnp.bfloat16), vocab_size)
     safe = jnp.where(valid, codes, 0)
     return jax.ops.segment_sum(
         valid.astype(jnp.float32), safe, num_segments=vocab_size
@@ -117,19 +244,26 @@ def code_counts(codes: jax.Array, M: jax.Array, vocab_size: int) -> jax.Array:
     slice ``[:vocab_size]`` after host materialization: an on-device slice
     here compiled one dynamic_slice program per vocab size, re-creating
     exactly the per-shape compile tail the segment-class bucketing removes
-    (PERF.md cold-compile census)."""
-    return _code_counts_p(codes, M, _bucket_segments(vocab_size))
+    (PERF.md cold-compile census).
+
+    A class of at most ``_DENSE_COUNT_LANES_MAX`` lanes is counted by
+    one-hot contraction (``_dense_group_sum``), a wider one, or a column laid
+    over a mesh, by scatter-add.  Both give the exact integer count up to
+    2^24 rows a code, where f32 stops counting by ones on either route."""
+    p = _bucket_segments(vocab_size)
+    return _code_counts_p(codes, M, p, dense=_contracts(p, _DENSE_COUNT_LANES_MAX, codes))
 
 
-@functools.partial(jax.jit, static_argnames=("vocab_size",))
+@functools.partial(jax.jit, static_argnames=("vocab_size", "dense"))
 def _code_label_counts_p(
-    codes: jax.Array, M: jax.Array, y: jax.Array, vocab_size: int
+    codes: jax.Array, M: jax.Array, y: jax.Array, vocab_size: int, dense: bool = False
 ) -> jax.Array:
     valid = M & (codes >= 0)
+    w = jnp.where(valid, y, 0.0).astype(jnp.float32)
+    if dense:
+        return _dense_group_sum(codes, w, vocab_size)
     safe = jnp.where(valid, codes, 0)
-    return jax.ops.segment_sum(
-        jnp.where(valid, y, 0.0).astype(jnp.float32), safe, num_segments=vocab_size
-    )
+    return jax.ops.segment_sum(w, safe, num_segments=vocab_size)
 
 
 @timed("ops.code_label_counts")
@@ -138,13 +272,58 @@ def code_label_counts(
 ) -> jax.Array:
     """Per-code sum of a row weight/label (event counts for IV, target
     encoding).  Returns counts PADDED to the ``_bucket_segments`` class
-    (trailing lanes zero) — same host-slice contract as
-    :func:`code_counts`."""
-    return _code_label_counts_p(codes, M, y, _bucket_segments(vocab_size))
+    (trailing lanes zero) — same host-slice contract and the same two routes
+    as :func:`code_counts`.
+
+    ``y`` carries values, so on the dense route it stays f32 at
+    ``Precision.HIGHEST``.  For weights of 0 / 1 (every caller in the tree:
+    an event vector, or ones) the sum is exact in any order up to 2^24; for
+    other weights the dense route's is an f32 sum in another order than the
+    scatter-add's, and one non-finite weight on a counted row reaches every
+    lane of its chunk (0 x inf) where the scatter-add keeps it to its own."""
+    p = _bucket_segments(vocab_size)
+    return _code_label_counts_p(codes, M, y, p, dense=_contracts(p, _DENSE_COUNT_LANES_MAX, codes))
 
 
-@jax.jit
-def _lut_gather(lut: jax.Array, codes: jax.Array) -> jax.Array:
+def _dense_gather(lut: jax.Array, codes: jax.Array) -> jax.Array:
+    """``lut[clip(codes)]`` without an index: the LUT's BYTES are looked up.
+    Each byte of the LUT's dtype is a plane of 0..255, exact in bf16; per
+    chunk of rows the one-hot of the code's hi part is contracted with the
+    planes, laid (hi, byte, lo), the lo one-hot picks its lane, and the bytes
+    go back together by shifts.  One term of every sum is not an exact zero,
+    so any 8-, 16- or 32-bit LUT comes back bit for bit: -0.0, denormals, inf
+    and NaN, every int32."""
+    rows, p = codes.shape[0], lut.shape[0]
+    chunk = dense_chunks(rows, _DENSE_CHUNK_ROWS)
+    hi_n, lo_n = _levels(p)
+    word = {1: jnp.uint8, 2: jnp.uint16, 4: jnp.uint32}[lut.dtype.itemsize]
+    bits = lut.astype(word) if lut.dtype == jnp.bool_ else jax.lax.bitcast_convert_type(lut, word)
+    nbytes = lut.dtype.itemsize
+    planes = jnp.stack([(bits >> (8 * b)) & 255 for b in range(nbytes)], axis=0)  # (byte, p)
+    planes = planes.reshape(nbytes, hi_n, lo_n).transpose(1, 0, 2).astype(jnp.bfloat16)
+    hi_lanes = jnp.arange(hi_n, dtype=codes.dtype)
+    lo_lanes = jnp.arange(lo_n, dtype=codes.dtype)
+
+    def one(c):
+        c = jnp.clip(c, 0, p - 1)
+        hot_hi = ((c // lo_n)[:, None] == hi_lanes).astype(jnp.bfloat16)
+        got = jnp.einsum("rh,hbl->rbl", hot_hi, planes, preferred_element_type=jnp.float32)
+        hot_lo = (c % lo_n)[:, None] == lo_lanes
+        got = jnp.where(hot_lo[:, None, :], got, 0.0).sum(axis=2).astype(jnp.uint32)  # (chunk, byte)
+        out = got[:, 0]
+        for b in range(1, nbytes):
+            out = out | (got[:, b] << (8 * b))
+        return out.astype(word)
+
+    n = rows // chunk
+    out = one(codes) if n == 1 else jax.lax.map(one, codes.reshape(n, chunk)).reshape(rows)
+    return out != 0 if lut.dtype == jnp.bool_ else jax.lax.bitcast_convert_type(out, lut.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("dense",))
+def _lut_gather(lut: jax.Array, codes: jax.Array, dense: bool = False) -> jax.Array:
+    if dense:
+        return _dense_gather(lut, codes)
     return lut[jnp.clip(codes, 0, lut.shape[0] - 1)]
 
 
@@ -155,14 +334,20 @@ def vocab_lookup(lut_host, codes: jax.Array) -> jax.Array:
     The LUT is padded to its ``_bucket_segments`` class so every vocab size shares one
     compiled gather per row shape (eagerly indexing ``jnp.asarray(lut)[codes]``
     per column compiled ~70 distinct gather programs across an e2e run).
-    Codes are clipped; callers keep their own null/validity masking."""
+    Codes are clipped; callers keep their own null/validity masking.
+
+    A class of at most ``_DENSE_GATHER_LANES_MAX`` lanes is looked up by
+    one-hot contraction with the LUT's bytes (``_dense_gather``: the same
+    bits as the index gather for every dtype of at most 32 bits), a wider
+    one, a wider dtype, or codes laid over a mesh, by index."""
     import numpy as np
 
     lut_host = np.asarray(lut_host)
     p = _bucket_segments(len(lut_host))
     if p > len(lut_host):
         lut_host = np.concatenate([lut_host, np.zeros(p - len(lut_host), lut_host.dtype)])
-    return _lut_gather(jnp.asarray(lut_host), codes)
+    lut = jnp.asarray(lut_host)
+    return _lut_gather(lut, codes, dense=lut.dtype.itemsize <= 4 and _contracts(p, _DENSE_GATHER_LANES_MAX, codes))
 
 
 def mode_from_counts(counts: jax.Array) -> Tuple[jax.Array, jax.Array]:

@@ -72,9 +72,11 @@ def test_cat_to_num_supervised_on_200000_categories_against_float64_pandas(click
     padded = click_table.padded_rows
     assert spans["transform/fit"] == {
         "cols": 2, "rows": padded, "vocab_max": DISTINCT, "segments_max": 262_144,  # a power of two above 65,536
-        "count_rows": 2 * padded, "label_rows": 2 * padded, "seg_lanes": 2 * (262_144 + 16)}
+        "count_rows": 2 * padded, "label_rows": 2 * padded, "seg_lanes": 2 * (262_144 + 16),
+        "dense_counts": 0, "scatter_counts": 4}  # a count and a label count a column; on the suite's mesh none by contraction
     assert spans["transform/apply"]["lut_bytes"] == 5 * (262_144 + 16)  # not 5 x 1,048,576: the class is 2^18
     assert spans["transform/apply"]["gather_rows"] == 4 * padded
+    assert (spans["transform/apply"]["dense_gathers"], spans["transform/apply"]["index_gathers"]) == (0, 4)
 
 
 def test_group_counts_are_exact_at_the_padded_class(click_frame, click_table):
@@ -102,6 +104,8 @@ def test_imputation_mode_on_200000_categories_and_a_tie(click_frame, click_table
     assert spans["transform/fit"]["cols"] == 2 and spans["transform/apply"]["cols"] == 2
     assert spans["transform/fit"]["vocab_max"] == DISTINCT and spans["transform/fit"]["segments_max"] == 262_144
     assert spans["transform/fit"]["seg_lanes"] == 262_144 + 16 and "label_rows" not in spans["transform/fit"]
+    assert (spans["transform/fit"]["dense_counts"], spans["transform/fit"]["scatter_counts"]) == (0, 2)
+    assert "dense_counts" not in spans["transform/apply"]  # the fills are no call of ops/segment.py
 
 
 def test_the_model_frame_is_built_only_where_it_is_written_and_has_the_bytes_it_had(tmp_path, monkeypatch):

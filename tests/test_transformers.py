@@ -75,6 +75,28 @@ def test_cat_to_num_supervised(num_t):
     np.testing.assert_allclose(enc[0], round(1 / 3, 4), atol=1e-4)
 
 
+@pytest.mark.parametrize("fn,kwargs,fit,apply", [
+    ("cat_to_num_supervised", dict(list_of_cols=["g"], label_col="label", event_label=1),
+     dict(count_rows=16, label_rows=16, seg_lanes=32, dense_counts=0, scatter_counts=2),
+     dict(gather_rows=32, lut_bytes=80, gather_out_bytes=80, dense_gathers=0, index_gathers=2)),
+    ("imputation_MMM", dict(method_type="median"),
+     dict(count_rows=16, seg_lanes=16, dense_counts=0, scatter_counts=1), {}),
+])
+def test_the_stage_rows_count_what_their_group_counts_move_and_the_route_they_take(num_t, fn, kwargs, fit, apply):
+    """On the suite's mesh every call keeps the scatter-add and the index gather
+    (``tests/test_segment_dense_layout.py`` has the same rows of a table on one device)."""
+    from anovos_tpu.obs import get_tracer
+
+    assert num_t.padded_rows == 16
+    tracer = get_tracer()
+    with tracer.run_pass():
+        getattr(T, fn)(num_t, **kwargs)
+    rows = {r["name"]: r["counts"] for r in tracer.phases() if r["name"].startswith("transform/")}
+    shape = {"cols", "rows", "vocab_max", "segments_max"}
+    assert {k: v for k, v in rows["transform/fit"].items() if k not in shape} == fit
+    assert {k: v for k, v in rows["transform/apply"].items() if k not in shape} == apply
+
+
 def test_z_standardization(num_t):
     out = T.z_standardization(num_t, ["x"])
     z = out.to_pandas()["x"]
