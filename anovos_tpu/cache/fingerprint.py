@@ -43,7 +43,7 @@ __all__ = [
 # Environment variables whose value changes node ARTIFACTS.  Pure
 # performance/telemetry knobs (worker counts, timeouts, trace paths, probe
 # budgets, and the obs knobs ANOVOS_TPU_DEVPROF / ANOVOS_TPU_FLIGHTREC /
-# ANOVOS_PERF_LEDGER / ANOVOS_TPU_TELEMETRY / ANOVOS_TPU_TRACE_ROTATE /
+# ANOVOS_TPU_TELEMETRY / ANOVOS_TPU_TRACE_ROTATE /
 # ANOVOS_TPU_SLO_ERROR_BUDGET — the live telemetry plane and trace
 # rotation only READ run state, and their outputs live under the
 # parity-excluded obs/ subtree) deliberately stay off the list — they
@@ -172,10 +172,6 @@ EXEMPT_ENV_KNOBS = {
     "ANOVOS_INGEST_RETRIES":
         "retry budget — a successful re-read is byte-identical (same "
         "policy as ANOVOS_TPU_RETRIES)",
-    "ANOVOS_PERF_LEDGER":
-        "gates the report's Perf Ledger obs tab; obs-tab bytes are "
-        "parity-excluded by policy (ledger lives in the repo, not under "
-        "master_path)",
     "ANOVOS_PLOTLY_JS":
         "chart-runtime embedding choice (inline plotly.min.js vs CDN "
         "tag) — a rendering asset, not a computed statistic; the inline "

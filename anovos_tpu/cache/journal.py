@@ -59,16 +59,12 @@ and ``alert_emitted`` (a threshold-crossing drift/quality/quarantine
 alert appended to ``obs/continuum_alerts.jsonl`` with flight-recorder
 context).
 Sibling machine-readable contract (round 15): the perf-doctor
-**diagnosis** document — the ranked run-diff a gate failure attaches to
-its ``BENCH_LEDGER.jsonl`` entry under ``diagnosis``, the same schema
-``tools/perf_doctor`` prints and the HTML "Run Diff" tab renders.  Its
-full JSON schema (``diagnosis_version`` / ``kind`` / ``baseline`` /
-``candidate`` / ``nodes`` / ``programs`` / ``cache`` / ``env`` /
-``fields`` / ranked ``attributions``) lives with its validator in
-``anovos_tpu/obs/diffing.py`` and is pinned by
-``python -m tools.perf_doctor --self-check`` in tier-1 — like the event
-lines above, it is append-safe telemetry: attaching one never moves an
-entry's content id.
+**diagnosis** document — the ranked run-diff ``tools/perf_doctor``
+prints and the HTML "Run Diff" tab renders.  Its full JSON schema
+(``diagnosis_version`` / ``kind`` / ``baseline`` / ``candidate`` /
+``nodes`` / ``programs`` / ``cache`` / ``env`` / ranked
+``attributions``) lives with its validator in
+``anovos_tpu/obs/diffing.py``.
 The journal is append-only ACROSS runs in the same output directory, so
 a killed run's committed frontier is still on disk when ``--resume``
 re-runs the config: resumed nodes hit the cache store (the store commit,

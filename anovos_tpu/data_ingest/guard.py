@@ -213,7 +213,7 @@ def records() -> List[QuarantineRecord]:
 
 def summary() -> dict:
     """The manifest ``resilience.quarantine`` section: exact part names
-    and row counts, plus the totals bench exposes."""
+    and row counts, plus their totals."""
     with _LOCK:
         recs = list(_RECORDS)
     rows = [r.rows_lost for r in recs if r.rows_lost is not None]

@@ -36,8 +36,7 @@ Three pieces:
   identical at any setting (FIFO drain, ordered assembly).
 
 * :class:`StreamStats` — per-pass decode/fetch-wait/drain-wait tallies,
-  the numbers behind ``e2e_stream_overlap_pct`` and the devprof
-  ``decode_s`` split.
+  the numbers behind the devprof ``decode_s`` split.
 
 Device-residency contract: the window bounds dispatched-but-undrained
 device chunks exactly as before (O(window·chunk_rows·k)); the pool
@@ -200,7 +199,7 @@ class StreamController:
 
 @dataclasses.dataclass
 class StreamStats:
-    """Per-pass instrumentation the controller and bench read."""
+    """Per-pass instrumentation the controller and ``last_stream_summary`` read."""
 
     decode_s: float = 0.0
     decode_bytes: int = 0

@@ -1339,43 +1339,10 @@ def _devprof_split_html(dev: dict) -> str:
     return "".join(html)
 
 
-def perf_ledger_gen() -> str:
-    """"Perf Ledger" tab: the bench trajectory + gate verdicts from the
-    append-only ledger (tools/perf_ledger).  Env-gated: rendered only when
-    ``ANOVOS_PERF_LEDGER`` names a ledger file — the ledger lives in the
-    repo, not under a run's master_path, so an un-gated lookup would make
-    report bytes depend on checkout state (golden parity)."""
-    path = os.environ.get("ANOVOS_PERF_LEDGER", "")
-    if not path or not os.path.exists(path):
-        return ""
-    try:
-        from tools.perf_ledger import field_trends, load
-
-        entries = load(path)
-        rows = field_trends(entries)
-    except Exception as e:
-        logger.warning("perf ledger at %s unreadable (%s); omitting tab", path, e)
-        return ""
-    if not rows:
-        return ""
-    html = ["<h3>Perf Ledger</h3>",
-            f"<p>Bench trajectory from <code>{escape(path)}</code> "
-            f"({len(entries)} entries; see <code>tools/perf_ledger.py "
-            "--check</code> for the regression gate).</p>"]
-    html.append(_table_html(pd.DataFrame(rows), "tracked fields"))
-    regress = [e for e in entries if e.get("regressions")]
-    if regress:
-        items = "".join(
-            f"<li><code>{escape(str(e.get('source')))}</code>: "
-            f"{escape(', '.join(e['regressions']))}</li>" for e in regress)
-        html.append(f"<p><b>Entries flagged by the gate:</b></p><ul>{items}</ul>")
-    return "".join(html)
-
-
 def run_diff_gen(master_path: str = ".") -> str:
     """"Run Diff" tab: the perf doctor's ranked attribution table.
 
-    Env-gated like the Perf Ledger tab: rendered only when
+    Env-gated: rendered only when
     ``ANOVOS_RUN_DIFF_BASELINE`` names a baseline run (a manifest file, a
     run dir, or its obs dir) — an un-gated lookup would make report bytes
     depend on external state and break golden parity.  The candidate is
@@ -1598,7 +1565,6 @@ def anovos_report(
     _tab("Time Series", lambda: ts_viz_generate(master_path, id_col))
     _tab("Geospatial", lambda: loc_report_gen(master_path=master_path))
     _tab("Run Timings", lambda: run_timings_gen(master_path))
-    _tab("Perf Ledger", perf_ledger_gen)
     _tab("Run Diff", lambda: run_diff_gen(master_path))
 
     with phase("report/render", cat="block", tabs=len(tabs)) as sp:  # nav, sections, the plotly script

@@ -382,9 +382,8 @@ def _side_args(
     bin_size: int,
     n_union: int,
 ):
-    """The exact ``drift_side_full`` argument tuple ``statistics`` dispatches
-    for one dataset side — shared with ``drift_device_args`` so the
-    steady-state benchmark times the production program, not a copy.
+    """The ``drift_side_full`` argument tuple ``statistics`` dispatches for
+    one dataset side.
 
     Column-bucketed (``_padded_col_tuples``): both tuple families are
     extended to their lane classes, the cutoff matrix rows pad with NaN and
@@ -411,34 +410,6 @@ def _side_args(
         lut,
         bin_size,
         max(n_union, 1),
-    )
-
-
-def drift_device_args(
-    idf_target: Table, idf_source: Table, bin_size: int = 10, bin_method: str = "equal_range"
-):
-    """Argument tuples for ``drift_side_full`` over both sides, prepared with
-    the SAME helpers ``statistics`` uses (``_fit_cutoffs_dev`` /
-    ``_union_vocabs_for`` / ``_lut_for`` / ``_side_args``) — the pure
-    device-resident work of the drift pipeline with host orchestration,
-    model I/O and metric assembly stripped.  Used by the steady-state
-    benchmark (bench.py): the inclusive wall hides ~100× of device headroom
-    under host upload and dispatch, so the kernel claim needs
-    data-already-on-device timing."""
-    num_all, cat_all, _ = idf_target.attribute_type_segregation()
-    num_cols = [c for c in num_all if idf_target.columns[c].kind == "num"]
-    cat_cols = [c for c in cat_all if idf_target.columns[c].kind == "cat"]
-    if num_cols:
-        cuts = _fit_cutoffs_dev(idf_source, num_cols, bin_size, bin_method)
-    else:
-        cuts = jnp.zeros((0, bin_size - 1), jnp.float32)
-    union_vocabs = _union_vocabs_for(idf_source, idf_target, cat_cols)
-    n_union = max((len(union_vocabs[c]) for c in cat_cols), default=1)
-    return (
-        _side_args(idf_target, num_cols, cat_cols, cuts,
-                   _lut_for(idf_target, cat_cols, union_vocabs), bin_size, n_union),
-        _side_args(idf_source, num_cols, cat_cols, cuts,
-                   _lut_for(idf_source, cat_cols, union_vocabs), bin_size, n_union),
     )
 
 

@@ -20,7 +20,7 @@ Three cooperating, stdlib-only pieces:
 * **Run manifest** (``obs.manifest``): ``workflow.main`` writes
   ``obs/run_manifest.json`` next to the run's artifacts (config hash,
   executor mode, critical path, per-node spans, metrics snapshot);
-  ``bench.py`` / ``perf_report.py`` and the HTML report read it instead of
+  the benchmark's harness and the HTML report read it instead of
   re-deriving timings.
 * **Compile census** (``obs.compile_census``): a ``jax.monitoring``
   listener counting every real XLA backend compile with per-program
@@ -30,17 +30,17 @@ Three cooperating, stdlib-only pieces:
   split of wall into device / dispatch / transfer / host via boundary
   drain probes, ``timed()`` dispatch brackets, and transfer brackets at
   the Table materialization choke points, plus per-device HBM deltas —
-  the manifest ``devprof`` section and bench's ``e2e_device_time_s``.
+  the manifest ``devprof`` section.
 * **Flight recorder** (``obs.flight``): a bounded ring of lifecycle
   events dumped synchronously to ``obs/flightrec_<node>.json`` on
   timeout escalation, abandonment, backend failover, or fatal error —
   the postmortem a merely-survived wedge used to throw away.
 * **Perf doctor** (``obs.diffing``): the structural run-diff engine —
-  two manifests (or two perf-ledger entries) in, one ranked diagnosis
+  two manifests in, one ranked diagnosis
   out: per-node phase movement, compile-census program-set diff, cache
   hit-set diff with the moved fingerprint input named, env-knob diff,
   queue-wait separated from body movement.  ``tools/perf_doctor.py`` is
-  the CLI; ledger gate failures attach a ``diagnosis`` automatically.
+  the CLI.
 
 Recording is always on at negligible cost; trace-file export is gated by
 ``ANOVOS_TPU_TRACE=<path|1>``, attribution by ``ANOVOS_TPU_DEVPROF``,

@@ -47,8 +47,7 @@ the frame is marked ``clamped``.
 
 Everything lands in (a) the run manifest's ``devprof`` section (stripped
 by ``stable_view`` — pure telemetry), (b) ``devprof_*`` metric families,
-(c) a ``devprof:<node>`` tracer instant next to the node span, and (d)
-``bench.py``'s ``e2e_device_time_s`` / ``e2e_transfer_bytes`` fields.
+and (c) a ``devprof:<node>`` tracer instant next to the node span.
 ``ANOVOS_TPU_DEVPROF=0`` disables the brackets (one dict lookup per
 site remains).  The node names in a profiler trace are not this module's:
 the tracer annotates node and phase spans (``obs.tracing.annotate_with``),

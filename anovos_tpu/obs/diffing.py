@@ -1,21 +1,16 @@
 """Perf doctor: structural run-diffing with automated regression attribution.
 
 Every observability plane records WHAT happened — per-node devprof splits,
-the compile census, cache hit sets, the env fingerprint, trace spans, the
-perf-ledger trajectory — but until now nothing explained a DELTA: when
-``perf_ledger --check`` flagged a regression, a human diffed two
-``run_manifest.json`` files by hand.  This module is the diff engine: it
-takes two runs (full manifests, or two perf-ledger entries) and emits one
-machine-readable **diagnosis** — a ranked attribution list naming which
-knob / program set / cache input / node phase actually moved.
+the compile census, cache hit sets, the env fingerprint, trace spans — and
+nothing of it explains a DELTA between two runs.  This module is the diff
+engine: it takes the manifests of two runs and emits one machine-readable
+**diagnosis** — a ranked attribution list naming which knob / program set /
+cache input / node phase actually moved.
 
 Consumers:
 
 * ``tools/perf_doctor.py`` — the CLI (``--baseline``/``--candidate`` run
-  dirs or manifest files, ledger-entry mode, ``--self-check``);
-* ``tools/perf_ledger.record_and_check`` — a gate failure attaches a
-  ``diagnosis`` object to the flagged ledger entry and ``bench.py``
-  prints the top attribution lines instead of a bare field name;
+  dirs or manifest files);
 * ``obs.flight.build_snapshot`` — the live ``/statusz`` document carries
   :func:`live_node_summary` (this run's nodes vs the last completed run
   at the same output path: "what is slow *right now* vs last clean run");
@@ -24,20 +19,19 @@ Consumers:
 Diagnosis JSON schema (version 1)
 ---------------------------------
 
-The schema below is the contract ``validate_diagnosis`` enforces and the
-``--self-check`` CI gate pins (see also the event-catalogue cross-
-reference in ``anovos_tpu/cache/journal.py``)::
+The schema below is the contract ``validate_diagnosis`` enforces (see
+also the event-catalogue cross-reference in
+``anovos_tpu/cache/journal.py``)::
 
     {
       "diagnosis_version": 1,
-      "kind": "manifest" | "ledger",
+      "kind": "manifest",
       "backend_class": "cpu" | "accel" | "unknown",
       "baseline":  {"label", "config_hash"?, "backend"?, "wall_s"?,
                     "generated_unix"?},
       "candidate": {same shape},
       "wall_delta_s": float | null,          # scheduler wall movement
       "executor_change": [base, cand] | null,
-      # manifest kind -------------------------------------------------
       "nodes": {name: {                      # union of both node sets
           "status": "common" | "added" | "removed",
           "wall_s": [base|null, cand|null], "wall_delta_s": float|null,
@@ -63,11 +57,6 @@ reference in ``anovos_tpu/cache/journal.py``)::
       "env": {"changed_knobs": {knob: [base|null, cand|null]},
               "code_version": [base, cand] | null,
               "dataset_changed": bool | null} | null,
-      # ledger kind ---------------------------------------------------
-      "fields": {name: {"baseline": num|null, "candidate": num|null,
-                        "delta": num|null, "pct": float|null,
-                        "flagged": bool}} | null,
-      # both kinds ----------------------------------------------------
       "attributions": [{                     # ranked, rank 1..N
           "rank": int, "kind": str, "subject": str,
           "severity": "structural" | "timing" | "info",
@@ -78,7 +67,7 @@ reference in ``anovos_tpu/cache/journal.py``)::
 
 Attribution ``kind`` values: ``degraded`` / ``node_added`` /
 ``node_removed`` (structural), ``programs`` / ``phase`` / ``cache`` /
-``node`` / ``field`` (timing), ``env`` / ``executor`` (info).  Ranking is
+``node`` (timing), ``env`` / ``executor`` (info).  Ranking is
 ``(severity, -score, kind, subject)`` with structural first — a newly
 degraded section outranks any timing movement, and env-knob changes are
 listed but never outrank measured seconds.
@@ -86,11 +75,11 @@ listed but never outrank measured seconds.
 Determinism contract: the diagnosis is a pure function of its two inputs
 — no timestamps, no environment reads — and :func:`canonical` dumps it
 with sorted keys and fixed separators, so diffing the same pair twice is
-byte-identical (the ``--self-check`` gate).
+byte-identical.
 
 Cross-backend-class pairs are REFUSED loudly (:class:`DiffRefused`): a
-cpu-fallback run diffed against an accelerator run is a different
-machine, not a regression — same policy as the perf-ledger gate.
+CPU run diffed against an accelerator run is a different machine, not a
+regression.
 """
 
 from __future__ import annotations
@@ -104,7 +93,6 @@ __all__ = [
     "backend_class",
     "canonical",
     "diff_manifests",
-    "diff_ledger_entries",
     "find_manifest",
     "live_node_summary",
     "render_text",
@@ -128,8 +116,7 @@ class DiffRefused(ValueError):
 
 
 def backend_class(backend) -> str:
-    """'cpu' | 'accel' | 'unknown' — same partition as the perf-ledger
-    gate (tools/perf_ledger keeps its own copy; tests pin agreement)."""
+    """'cpu' | 'accel' | 'unknown'."""
     b = str(backend or "").lower()
     if not b or b == "none":
         return "unknown"
@@ -139,8 +126,7 @@ def backend_class(backend) -> str:
 
 
 def canonical(diagnosis: dict) -> str:
-    """Deterministic serialization (sorted keys, fixed separators) — the
-    byte-identity the self-check gate compares."""
+    """Deterministic serialization (sorted keys, fixed separators)."""
     return json.dumps(diagnosis, sort_keys=True, separators=(",", ":"))
 
 
@@ -450,113 +436,6 @@ def diff_manifests(baseline: dict, candidate: dict,
         "programs": programs,
         "cache": cache,
         "env": env,
-        "fields": None,
-        "attributions": _rank(attributions),
-    }
-
-
-# -- perf-ledger entry diff ----------------------------------------------
-
-def diff_ledger_entries(baseline: dict, candidate: dict,
-                        flagged: Iterable[str] = ()) -> dict:
-    """Diff two perf-ledger entries (``tools/perf_ledger`` schema).
-
-    ``flagged`` names the fields the gate judged regressions — they rank
-    structurally first so the diagnosis leads with the complaint.  When
-    both entries carry a ``nodes`` summary (bench's ``e2e_node_summary``),
-    per-node wall movement is attributed with its dominant phase."""
-    b_cls = baseline.get("backend_class") or backend_class(baseline.get("backend"))
-    c_cls = candidate.get("backend_class") or backend_class(candidate.get("backend"))
-    cls = _refuse_cross_class(b_cls, c_cls)
-    flagged = set(flagged)
-    b_fields = baseline.get("fields") or {}
-    c_fields = candidate.get("fields") or {}
-    fields_out: Dict[str, dict] = {}
-    attributions: List[dict] = []
-    for name in sorted(set(b_fields) | set(c_fields)):
-        bv, cv = b_fields.get(name), c_fields.get(name)
-        ok = all(isinstance(v, (int, float)) and not isinstance(v, bool)
-                 for v in (bv, cv))
-        delta = _r(cv - bv) if ok else None
-        pct = (_r((cv - bv) / bv * 100.0, 2)
-               if ok and bv not in (0, 0.0) else None)
-        fields_out[name] = {
-            "baseline": _r(bv) if isinstance(bv, (int, float)) else None,
-            "candidate": _r(cv) if isinstance(cv, (int, float)) else None,
-            "delta": delta, "pct": pct, "flagged": name in flagged,
-        }
-        if pct is not None and (delta or 0.0) != 0.0:
-            attributions.append({
-                "kind": "field", "subject": name,
-                "severity": "structural" if name in flagged else "timing",
-                "score": _r(abs(pct) / 100.0) or 0.0, "delta_s": None,
-                "detail": (f"field {name} moved {bv:g} -> {cv:g} "
-                           f"({pct:+.1f}%)"
-                           + (" — FLAGGED by the ledger gate"
-                              if name in flagged else "")),
-            })
-
-    b_nodes = baseline.get("nodes") or {}
-    c_nodes = candidate.get("nodes") or {}
-    nodes_out = None
-    if b_nodes and c_nodes:
-        nodes_out = {}
-        for name in sorted(set(b_nodes) | set(c_nodes)):
-            bn, cn = b_nodes.get(name) or {}, c_nodes.get(name) or {}
-            bw, cw = bn.get("wall_s"), cn.get("wall_s")
-            ok = all(isinstance(v, (int, float)) for v in (bw, cw))
-            phases = {k: _r(float(cn.get(k) or 0.0) - float(bn.get(k) or 0.0))
-                      for k in PHASE_KEYS if k in bn or k in cn}
-            dominant = (max(phases, key=lambda k: (abs(phases[k]), k))
-                        if phases and any(abs(v or 0) > 0 for v in phases.values())
-                        else None)
-            nodes_out[name] = {
-                "status": "common" if (bn and cn) else ("added" if cn else "removed"),
-                "wall_s": [_r(bw), _r(cw)],
-                "wall_delta_s": _r(cw - bw) if ok else None,
-                "phases": phases or None,
-                "dominant_phase": dominant,
-                "queue_wait_delta_s": None,
-                "cached": [None, None],
-                "degraded": [False, False],
-            }
-            if ok and bw > 0:
-                rel = (cw - bw) / bw
-                if abs(rel) >= 0.05 and abs(cw - bw) >= _MIN_S:
-                    dom_txt = ""
-                    if dominant:
-                        dom_txt = (f"; dominant phase: {dominant} "
-                                   f"({phases[dominant]:+.3f}s)")
-                    attributions.append({
-                        "kind": "node", "subject": name, "severity": "timing",
-                        "score": _r(abs(rel)) or 0.0, "delta_s": _r(cw - bw),
-                        "detail": (f"node {name!r} wall {bw:.3f}s -> {cw:.3f}s "
-                                   f"({rel * 100:+.1f}%){dom_txt}"),
-                    })
-
-    def _label(e: dict) -> dict:
-        return {
-            "label": str(e.get("source") or "entry")
-                     + (f" (round {e.get('round')})" if e.get("round") else ""),
-            "config_hash": None,
-            "backend": e.get("backend"),
-            "wall_s": None,
-            "generated_unix": e.get("t_unix"),
-        }
-
-    return {
-        "diagnosis_version": DIAGNOSIS_VERSION,
-        "kind": "ledger",
-        "backend_class": cls,
-        "baseline": _label(baseline),
-        "candidate": _label(candidate),
-        "wall_delta_s": None,
-        "executor_change": None,
-        "nodes": nodes_out,
-        "programs": None,
-        "cache": None,
-        "env": None,
-        "fields": fields_out or None,
         "attributions": _rank(attributions),
     }
 
@@ -624,8 +503,7 @@ def live_node_summary(baseline_manifest: Optional[dict],
 # -- rendering / validation ----------------------------------------------
 
 def render_text(diagnosis: dict, top: int = 3) -> List[str]:
-    """Human-facing attribution lines, most severe first (what bench
-    prints on a gate failure instead of a bare field name)."""
+    """Human-facing attribution lines, most severe first."""
     out = []
     for a in (diagnosis.get("attributions") or [])[: top or None]:
         out.append(f"#{a['rank']} [{a['kind']}:{a['subject']}] {a['detail']}")
@@ -651,13 +529,13 @@ def find_manifest(path: str) -> str:
 
 _TOP_KEYS = ("diagnosis_version", "kind", "backend_class", "baseline",
              "candidate", "wall_delta_s", "executor_change", "nodes",
-             "programs", "cache", "env", "fields", "attributions")
+             "programs", "cache", "env", "attributions")
 _ATTR_KEYS = ("rank", "kind", "subject", "severity", "score", "delta_s", "detail")
 
 
 def validate_diagnosis(diagnosis: dict) -> List[str]:
     """Schema check (module-docstring contract); returns error strings,
-    empty when valid — the ``--self-check`` gate and tests assert []."""
+    empty when valid."""
     errs: List[str] = []
     if not isinstance(diagnosis, dict):
         return ["diagnosis is not a dict"]
@@ -666,8 +544,8 @@ def validate_diagnosis(diagnosis: dict) -> List[str]:
             errs.append(f"missing top-level key {k!r}")
     if diagnosis.get("diagnosis_version") != DIAGNOSIS_VERSION:
         errs.append(f"diagnosis_version != {DIAGNOSIS_VERSION}")
-    if diagnosis.get("kind") not in ("manifest", "ledger"):
-        errs.append(f"kind must be manifest|ledger, got {diagnosis.get('kind')!r}")
+    if diagnosis.get("kind") != "manifest":
+        errs.append(f"kind must be manifest, got {diagnosis.get('kind')!r}")
     if diagnosis.get("backend_class") not in ("cpu", "accel", "unknown"):
         errs.append(f"bad backend_class {diagnosis.get('backend_class')!r}")
     for side in ("baseline", "candidate"):

@@ -1,11 +1,12 @@
 """Run manifest: the machine-readable record of one ``workflow.main`` run.
 
 ``obs/run_manifest.json`` lands next to the run's other artifacts and is
-the single source every timing consumer reads — ``bench.py`` and
-``perf_report.py`` take their e2e block/critical-path fields from it
-instead of re-deriving them from module globals, the HTML report renders
-its node-timing table from it, and a CI gate can diff two manifests
-(``stable_view`` strips the timestamp-valued fields first).
+the single source every timing consumer reads — the benchmark's harness
+(``benchmark/harness/manifest.py``) and the tests take their block, node
+and phase fields from it instead of re-deriving them from module globals,
+the HTML report renders its node-timing table from it, and
+``tools/perf_doctor.py`` diffs two of them (``stable_view`` strips the
+timestamp-valued fields first).
 
 Determinism contract: ``write_manifest`` serializes with sorted keys and
 fixed separators, and every non-timing field (config hash, node names,
@@ -113,9 +114,9 @@ def build_manifest(
         "metrics": metrics_snapshot,
         # per-run XLA compile census (obs.compile_census delta): compile
         # count, distinct program signatures, distinct kernels, and the
-        # top programs by compile wall — the record bench.py's
-        # e2e_cold_compiles / e2e_distinct_programs fields and the
-        # tools/compile_census.py gate read
+        # top programs by compile wall — the record the benchmark's
+        # fresh_programs / window_compiles and the tools/compile_census.py
+        # gate read
         "compile_census": compile_census,
         # incremental-recompute record (anovos_tpu.cache): store root,
         # per-run hits/misses/restore wall, resumed frontier — present only
@@ -130,8 +131,7 @@ def build_manifest(
         # per-node device-time attribution (obs.devprof): node wall split
         # into device_time_s / dispatch_s / transfer_s / host_s plus
         # h2d/d2h byte counts and per-device HBM deltas — the section
-        # bench.py's e2e_device_time_s / e2e_transfer_bytes fields and
-        # the HTML report's devprof split read
+        # the HTML report's devprof split and the perf doctor read
         "devprof": devprof,
         # fingerprint-input record (the perf doctor's knob/code/dataset
         # diff material): audited env-knob VALUES, code version, env and

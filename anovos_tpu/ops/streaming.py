@@ -54,7 +54,7 @@ from anovos_tpu.data_ingest.guard import IngestError, policy_from_env, raw_reade
 from anovos_tpu.data_ingest.prefetch import DecodePool, StreamController, StreamStats
 from anovos_tpu.obs import timed
 
-# the most recent streaming pass' instrumentation (bench + tooling read
+# the most recent streaming pass' instrumentation (tests + tooling read
 # it after a call; pure telemetry, never an input).  Lock-guarded:
 # concurrently scheduled streaming nodes (the aside fan-out) race the
 # rebind otherwise.
@@ -66,7 +66,7 @@ _LAST_STREAM_LOCK = _threading.Lock()
 
 def last_stream_summary() -> dict:
     """Decode/overlap instrumentation of the most recent streaming call
-    in this process (``e2e_stream_overlap_pct``'s source)."""
+    in this process."""
     with _LAST_STREAM_LOCK:
         return dict(_LAST_STREAM)
 

@@ -60,7 +60,7 @@ Design properties:
 * **Observability.**  Per-node start/end/thread spans are recorded and
   ``run()`` returns a summary with the measured critical path (longest
   dependency chain by wall time) and the parallel speedup — surfaced in the
-  run log and in ``bench.py``'s e2e section.  Every node additionally emits
+  run log and in the run manifest's ``scheduler`` section.  Every node additionally emits
   a tracer span (``anovos_tpu.obs``: worker lane, queue wait, deps waited
   on) for the Chrome-trace export, and books wall/queue-wait time into the
   process metrics registry (``node_wall_seconds``,
@@ -1185,7 +1185,7 @@ class DagScheduler:
 
         # max concurrently in-flight nodes, from the measured spans: the
         # multi-device acceptance metric (>1 proves the executor really
-        # overlapped nodes; bench surfaces it as e2e_multidev_overlap)
+        # overlapped nodes; ``__graft_entry__.executor_pass`` gates on it)
         events = sorted(
             ev for n in executed for ev in ((n.start, 1), (n.end, -1)))
         in_flight = overlap = 0

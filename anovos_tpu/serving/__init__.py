@@ -31,9 +31,7 @@ with three layers, each riding machinery earlier PRs built:
   path, and dumps a flight-recorder postmortem on fatal apply errors.
 
 ``python -m anovos_tpu.serving export|smoke`` is the CLI;
-``tools/chaos_run.py --scenario serve-fault`` is the fault gate; bench's
-``e2e_serve_*`` fields track sustained QPS and p50/p99 latency in the
-perf ledger.
+``tools/chaos_run.py --scenario serve-fault`` is the fault gate.
 """
 
 from anovos_tpu.serving.bundle import (  # noqa: F401

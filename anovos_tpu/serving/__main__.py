@@ -13,8 +13,7 @@
     client mixed-width load, verify a parity sample byte-identically
     against the batch apply, and print one JSON line with
     ``serve_qps`` / ``serve_p50_ms`` / ``serve_p99_ms`` /
-    ``serve_cold_start_s`` — the fields ``bench.py`` lifts into the
-    perf ledger.
+    ``serve_cold_start_s``.
 """
 
 from __future__ import annotations
@@ -131,7 +130,7 @@ def _cmd_smoke(ns) -> int:
     # exact same load with the embedded HTTP server up and two scraper
     # threads hammering /metrics + /healthz throughout — the A/B delta in
     # one process is the telemetry overhead (no process-boot or compile
-    # variance), and the scrape latencies give e2e_scrape_p99_ms under
+    # variance), and the scrape latencies give scrape_p99_ms under
     # genuine concurrent-client load.
     telemetry_fields: dict = {}
     if getattr(ns, "telemetry", False):

@@ -1,10 +1,9 @@
 """Shared demo/benchmark material for the serving subsystem.
 
 One synthetic income-shaped dataset and one FULL-COVERAGE transformer
-chain (every servable family fires at least once), used by three
+chain (every servable family fires at least once), used by two
 consumers that must agree on shape: the ``python -m anovos_tpu.serving
-smoke`` CLI, ``bench.py``'s ``e2e_serve_*`` smoke load, and
-``tools/chaos_run.py --scenario serve-fault``.
+smoke`` CLI and ``tools/chaos_run.py --scenario serve-fault``.
 """
 
 from __future__ import annotations

@@ -189,8 +189,8 @@ class FeatureServer:
     """Threaded micro-batching server over one :class:`ApplyProgram`.
 
     In-process transport: clients call :meth:`serve` from their own
-    threads (the CLI, bench's concurrent-client smoke load, and the
-    chaos gate all drive it this way); the batching/apply loop runs on
+    threads (the CLI's concurrent-client smoke load and the chaos gate
+    both drive it this way); the batching/apply loop runs on
     one background thread so device dispatch stays single-lane and
     devprof's drain attribution is meaningful."""
 

@@ -96,13 +96,12 @@ from anovos_tpu.shared.table import Table
 logger = logging.getLogger("anovos_tpu.workflow")
 
 # scheduler summary (mode, wall/serial/critical-path seconds, speedup,
-# per-node spans) of the most recent main() run — bench.py's e2e section
-# surfaces these fields so the trajectory JSONs capture the win
+# per-node spans) of the most recent main() run
 LAST_RUN_SUMMARY: dict = {}
 
 # absolute path of the most recent run's obs/run_manifest.json — the
-# machine-readable record bench.py / perf_report.py / tooling read instead
-# of re-deriving timings from module globals
+# machine-readable record tests and tooling read instead of re-deriving
+# timings from module globals
 LAST_MANIFEST_PATH: str = ""
 
 # what _main leaves for _pass to write once the root span has ended:
@@ -123,10 +122,9 @@ MAINFUNC_TO_ARGS = {
 
 
 def _log_block_time(label: str, start: float) -> None:
-    """Book one block's wall time into the metrics registry (successor of
-    the module-level BLOCK_TIMES dict — the reference logs these per block,
-    workflow.py:227-244; recording them machine-readably lets the e2e suite
-    assert the committed per-block budget, tests/golden/e2e_block_budget.csv).
+    """Book one block's wall time into the metrics registry (the reference
+    logs these per block, workflow.py:227-244; the run manifest carries them
+    as ``block_seconds``).
     The registry is lock-protected, so concurrent-executor worker threads
     accumulate safely; timings are monotonic-clock based."""
     secs = round(time.monotonic() - start, 4)
@@ -142,8 +140,7 @@ def _log_block_time(label: str, start: float) -> None:
 
 def block_times() -> dict:
     """Per-block wall seconds of the most recent ``main()`` run, read from
-    the metrics registry.  The canonical reader for
-    ``tools/record_block_budget.py`` and the bench harness."""
+    the metrics registry."""
     counter = get_metrics().counter("anovos_block_seconds")
     return {
         labels["block"]: round(v, 4)

@@ -16,6 +16,7 @@ import pandas as pd
 import pytest
 
 from anovos_tpu.shared import Table
+from tests.oracles import pandas_reference_psi
 
 
 def _random_frame(rng: np.random.Generator) -> pd.DataFrame:
@@ -79,19 +80,13 @@ def test_describe_matches_pandas_on_random_frames(seed):
         assert out["mode_count"][i] == vc.iloc[0], c
 
 
-@pytest.mark.parametrize("seed", range(4))
+@pytest.mark.parametrize("seed", range(8))
 def test_drift_matches_pandas_loop_on_random_frames(seed):
     """The full drift pipeline (binning with source cutoffs, union-vocab
-    cat counts, PSI) vs bench.py's pandas per-column oracle on random
-    mixed frames with disjoint vocab tails and nulls."""
-    import importlib.util
+    cat counts, PSI) vs the pandas per-column oracle on random mixed
+    frames with disjoint vocab tails and nulls."""
     import os
     import tempfile
-
-    spec = importlib.util.spec_from_file_location(
-        "bench", os.path.join(os.path.dirname(__file__), "..", "bench.py"))
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
 
     from anovos_tpu.drift_stability import statistics
 
@@ -108,7 +103,7 @@ def test_drift_matches_pandas_loop_on_random_frames(seed):
         "c": rng.choice(["a", "b", "d", "tgt_only"], n),
     })
     src.loc[rng.random(n) < 0.05, "x"] = np.nan
-    ref = bench.pandas_reference_psi(src, tgt, bin_size=10)
+    ref = pandas_reference_psi(src, tgt, bin_size=10)
     with tempfile.TemporaryDirectory() as d:
         odf = statistics(
             Table.from_pandas(tgt), Table.from_pandas(src),

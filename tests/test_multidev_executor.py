@@ -293,19 +293,16 @@ def _fresh_env():
     return env
 
 
-def test_workflow_concurrent_on_8dev_mesh_parity_and_overlap(tmp_path):
+def test_workflow_concurrent_on_8dev_mesh_parity_and_overlap():
     """THE acceptance gate: on the 8-virtual-device mesh, workflow.main
     no longer degrades to sequential — the concurrent executor completes
     the pipeline with artifacts byte-identical to sequential, >= 2 nodes
     concurrently in flight, and a warm wall that holds the sequential
-    wall (tools/dryrun_multichip runs the same pass as the MULTICHIP
-    bench leg)."""
-    env = _fresh_env()
-    env["ANOVOS_PERF_LEDGER"] = str(tmp_path / "ledger.jsonl")  # not the repo's
+    wall."""
     p = subprocess.run(
         [sys.executable, "-m", "tools.dryrun_multichip", "--executor-only",
          "--devices", "8"],
-        capture_output=True, text=True, timeout=560, env=env, cwd=REPO,
+        capture_output=True, text=True, timeout=560, env=_fresh_env(), cwd=REPO,
     )
     assert p.returncode == 0, p.stdout[-2000:] + p.stderr[-3000:]
     line = [ln for ln in p.stdout.splitlines()
@@ -313,8 +310,6 @@ def test_workflow_concurrent_on_8dev_mesh_parity_and_overlap(tmp_path):
     rec = json.loads(line.split(":", 1)[1])
     assert rec["e2e_multidev_overlap"] > 1
     assert rec["e2e_multidev_devices"] == 8
-    # the pass appended its record to the (redirected) perf ledger
-    assert (tmp_path / "ledger.jsonl").exists()
 
 
 def test_chaos_hang_collective_fresh_process(tmp_path):

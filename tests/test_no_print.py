@@ -1,39 +1,35 @@
-"""Tier-1 wiring for tools/check_no_print.py: library modules must not call
+"""Tier-1 wiring for graftcheck's GC007: library modules must not call
 ``print()`` (module loggers own diagnostics) or ``logging.basicConfig()``
 (the importing application owns the root logger).  ``__main__``-guarded
 blocks are entrypoints and exempt (e.g. the backend probe's stdout
 handshake protocol)."""
 
-import importlib.util
+import ast
 import os
 import textwrap
 
+from tools.graftcheck.engine import iter_py_files
+from tools.graftcheck.rules.gc007_no_print import check_tree
 
-def _load_checker():
-    spec = importlib.util.spec_from_file_location(
-        "check_no_print",
-        os.path.join(os.path.dirname(__file__), "..", "tools", "check_no_print.py"),
-    )
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def test_package_is_print_free():
-    checker = _load_checker()
-    violations = checker.check_package()
+    violations = []
+    for path in iter_py_files([os.path.join(REPO, "anovos_tpu")]):
+        with open(path) as f:
+            tree = ast.parse(f.read(), filename=path)
+        violations += [f"{os.path.relpath(path, REPO)}:{lineno}: {msg}" for lineno, msg in check_tree(tree)]
     assert not violations, "\n".join(
         ["library print()/basicConfig() found — route through module loggers:"]
         + violations
     )
 
 
-def test_checker_flags_and_allowlists(tmp_path):
+def test_checker_flags_and_allowlists():
     """The checker itself: flags library print/basicConfig, allowlists the
     __main__ guard, and ignores prints inside string literals."""
-    checker = _load_checker()
-    bad = tmp_path / "bad.py"
-    bad.write_text(textwrap.dedent("""\
+    found = check_tree(ast.parse(textwrap.dedent("""\
         import logging
         logging.basicConfig(level=logging.INFO)
         def f():
@@ -41,8 +37,7 @@ def test_checker_flags_and_allowlists(tmp_path):
         CODE = "print('inside a string: not a call')"
         if __name__ == "__main__":
             print("cli output: allowed")
-    """))
-    found = checker.check_file(str(bad))
+    """)))
     assert len(found) == 2, found
     lines = sorted(l for l, _ in found)
     assert lines == [2, 4]

@@ -10,8 +10,6 @@ Covers the ISSUE-15 acceptance surface:
 * the compile-census program-set diff with node attribution and the
   cache hit-set diff naming the moved fingerprint input;
 * determinism (byte-identical double diff) + schema validity;
-* ``python -m tools.perf_doctor --self-check`` wired tier-1 (diffs the
-  committed BENCH_r04 -> r05 ledger entries);
 * the flight recorder's live doctor summary ("slow vs the last clean
   run" on /statusz);
 * a program-set change that comes with a dispatch_s drop and a flipped
@@ -158,9 +156,6 @@ def test_cross_backend_class_pair_refused_loudly():
     cand = _man({"a": _node(1.0)}, backend="tpu")
     with pytest.raises(diffing.DiffRefused, match="backend classes"):
         diffing.diff_manifests(base, cand)
-    with pytest.raises(diffing.DiffRefused):
-        diffing.diff_ledger_entries({"backend_class": "cpu", "fields": {}},
-                                    {"backend_class": "accel", "fields": {}})
 
 
 def test_program_set_diff_names_nodes_and_wall():
@@ -272,44 +267,6 @@ def test_diff_is_deterministic_and_schema_valid():
     assert diffing.validate_diagnosis(broken)
 
 
-def test_backend_class_agrees_with_perf_ledger():
-    from tools.perf_ledger import _backend_class
-
-    for b in ("cpu", "cpu-fallback (x)", "tpu", "TPU v5e", "", None, "none"):
-        assert diffing.backend_class(b) == _backend_class(b)
-
-
-# -- ledger-entry diff ----------------------------------------------------
-
-def test_ledger_diff_flagged_fields_lead_and_gaps_tolerated():
-    base = {"backend_class": "cpu", "source": "r1",
-            "fields": {"e2e_warm_s": 6.0, "value": 100.0, "old_only": 1.0}}
-    cand = {"backend_class": "cpu", "source": "r2",
-            "fields": {"e2e_warm_s": 9.0, "value": 101.0, "new_only": 2.0}}
-    d = diffing.diff_ledger_entries(base, cand, flagged=["e2e_warm_s"])
-    assert diffing.validate_diagnosis(d) == []
-    assert d["attributions"][0]["subject"] == "e2e_warm_s"
-    assert d["attributions"][0]["severity"] == "structural"
-    assert "FLAGGED" in d["attributions"][0]["detail"]
-    assert d["fields"]["old_only"]["candidate"] is None
-    assert d["fields"]["new_only"]["baseline"] is None
-
-
-def test_ledger_diff_node_summaries_name_dominant_phase():
-    base = {"backend_class": "cpu", "source": "r1", "fields": {"value": 1.0},
-            "nodes": {"assoc/IV": {"wall_s": 0.4, "dispatch_s": 0.3,
-                                   "host_s": 0.1}}}
-    cand = {"backend_class": "cpu", "source": "r2", "fields": {"value": 1.0},
-            "nodes": {"assoc/IV": {"wall_s": 1.2, "dispatch_s": 1.0,
-                                   "host_s": 0.2}}}
-    d = diffing.diff_ledger_entries(base, cand)
-    node = d["nodes"]["assoc/IV"]
-    assert node["dominant_phase"] == "dispatch_s"
-    attr = next(a for a in d["attributions"] if a["kind"] == "node")
-    assert "assoc/IV" in attr["detail"] and "dispatch_s" in attr["detail"]
-    assert attr["delta_s"] == pytest.approx(0.8)
-
-
 # -- flight recorder / live doctor summary --------------------------------
 
 def test_live_node_summary_flags_slow_and_inflight_nodes():
@@ -357,20 +314,6 @@ def test_flight_snapshot_carries_doctor_summary(tmp_path, monkeypatch):
 
 # -- CLI ------------------------------------------------------------------
 
-def test_cli_self_check_deterministic_schema_valid():
-    """Satellite: tier-1 self-check — diffs the committed BENCH_r04->r05
-    ledger entries and asserts a deterministic, schema-valid diagnosis."""
-    outs = []
-    for _ in range(2):
-        p = subprocess.run(
-            [sys.executable, "-m", "tools.perf_doctor", "--self-check"],
-            cwd=REPO, capture_output=True, text=True, timeout=120)
-        assert p.returncode == 0, p.stdout + p.stderr
-        assert "self-check ok" in p.stdout
-        outs.append(p.stdout)
-    assert outs[0] == outs[1]  # byte-identical double run
-
-
 def test_cli_manifest_mode_and_run_dir_resolution(tmp_path):
     from anovos_tpu.obs import write_manifest
 
@@ -405,19 +348,6 @@ def test_cli_refuses_cross_backend_pair(tmp_path):
         cwd=REPO, capture_output=True, text=True, timeout=120)
     assert p.returncode == 1
     assert "REFUSED" in p.stderr
-
-
-def test_cli_ledger_entry_mode():
-    p = subprocess.run(
-        [sys.executable, "-m", "tools.perf_doctor", "--json",
-         "--entry-baseline", "BENCH_r04.json",
-         "--entry-candidate", "BENCH_r05.json"],
-        cwd=REPO, capture_output=True, text=True, timeout=120)
-    assert p.returncode == 0, p.stdout + p.stderr
-    diag = json.loads(p.stdout.strip().splitlines()[-1])
-    assert diag["kind"] == "ledger"
-    assert diag["attributions"]
-    assert diffing.validate_diagnosis(diag) == []
 
 
 # -- HTML report "Run Diff" tab -------------------------------------------

@@ -47,10 +47,7 @@ Usage::
     python -m tools.chaos_run --scenario full [--workdir DIR] [--json]
     python -m tools.chaos_run --config cfg.yaml --spec 'exc@node:my_node'
 
-``bench.py`` runs the ``full`` scenario in a subprocess and records the
-recovery overhead (``e2e_chaos_recovery_wall_s``) next to the cache and
-compile trajectories; tier-1 wires the fast ``exc`` scenario
-(``tests/test_resilience.py``).
+Tier-1 wires the fast ``exc`` scenario (``tests/test_resilience.py``).
 """
 
 from __future__ import annotations

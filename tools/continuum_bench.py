@@ -13,8 +13,7 @@ service against a from-scratch batch run over the union:
   order-insensitivity of the contract, not a lucky duplicate
   implementation).
 
-Emitted fields (``--json``; ``bench.py`` lifts them when
-``BENCH_CONTINUUM`` ≠ 0):
+Emitted fields (``--json``):
 
 * ``e2e_continuum_fold_s`` — median per-day incremental fold wall;
 * ``e2e_continuum_vs_batch_ratio`` — that median over the batch-leg
@@ -172,11 +171,8 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(
         description="30-day continuum feed bench: incremental fold vs "
                     "from-scratch batch")
-    ap.add_argument("--days", type=int,
-                    default=int(os.environ.get("BENCH_CONTINUUM_DAYS", 30)))
-    ap.add_argument("--rows", type=int,
-                    default=int(os.environ.get("BENCH_CONTINUUM_ROWS", 2000)),
-                    help="rows per day")
+    ap.add_argument("--days", type=int, default=30)
+    ap.add_argument("--rows", type=int, default=2000, help="rows per day")
     ap.add_argument("--workdir")
     ap.add_argument("--json", action="store_true")
     ns = ap.parse_args(argv)

@@ -1,4 +1,4 @@
-"""CLI wrapper for the multi-chip dry run (the MULTICHIP bench leg).
+"""CLI wrapper for the multi-chip dry run.
 
 ``__graft_entry__.dryrun_multichip(n)`` is the driver's entry point; this
 wrapper makes the same gate runnable by hand::
@@ -11,9 +11,7 @@ It builds an (data x model) mesh over N virtual CPU devices, compiles +
 executes the flagship kernels sharded, and — since round 8 — runs the
 collective-aware concurrent-executor pass: the synthetic pipeline once per
 executor mode, asserting byte-identical artifacts, >= 2 nodes concurrently
-in flight, and concurrent wall <= sequential wall on the same box.  The
-executor record is appended to BENCH_LEDGER.jsonl (``e2e_multidev_overlap``
-/ ``e2e_multidev_wall_s`` join the regression trajectory).
+in flight, and concurrent wall <= sequential wall on the same box.
 
 Must run in a FRESH process (the virtual-device count is latched at
 backend init).

@@ -1,16 +1,12 @@
 """GC007 — print()/logging.basicConfig() in library code.
 
-The former ``tools/check_no_print.py`` gate as a graftcheck rule: library
-output goes through module loggers (the importing application owns stdout
+Library output goes through module loggers (the importing application owns stdout
 and the root logger); ``logging.basicConfig`` belongs in the entrypoints
 (``main.py`` / ``anovos_tpu/__main__.py``) only.  Calls inside a module's
 top-level ``if __name__ == "__main__":`` block are allowlisted — that
 block IS an entrypoint (CLI protocols like the backend probe's stdout
 handshake live there), and prints inside string literals never
 false-positive because the check is AST-based.
-
-``tools/check_no_print.py`` is now a thin deprecated shim over this rule
-so its historical API (``check_file`` / ``check_package``) keeps working.
 """
 
 from __future__ import annotations
@@ -43,8 +39,8 @@ def main_guard_ranges(tree: ast.Module) -> List[Tuple[int, int]]:
 
 
 def check_nodes(tree: ast.Module) -> List[Tuple[ast.Call, str]]:
-    """[(offending call node, message), …] — THE implementation; both the
-    rule and the legacy shim are thin views over it."""
+    """[(offending call node, message), …] — THE implementation; the rule
+    and ``check_tree`` are thin views over it."""
     guards = main_guard_ranges(tree)
 
     def allowlisted(lineno: int) -> bool:
@@ -67,7 +63,7 @@ def check_nodes(tree: ast.Module) -> List[Tuple[ast.Call, str]]:
 
 
 def check_tree(tree: ast.Module) -> List[Tuple[int, str]]:
-    """[(lineno, message), …] — the legacy shim's view."""
+    """[(lineno, message), …] — the view ``tests/test_no_print.py`` reads."""
     return [(node.lineno, msg) for node, msg in check_nodes(tree)]
 
 
