@@ -93,14 +93,23 @@ class VarClusJax:
         varprops = vals[:n_pcs] / max(raw_vals.sum(), 1e-30)
         return vals[:n_pcs], vecs[:, :n_pcs], varprops
 
+    def _first_eig(self, feats: List[str]) -> Tuple[float, float]:
+        """(largest eigenvalue, its share of the trace) of a cluster's
+        correlation submatrix, without the eigenvectors: what the search phase
+        asks two thousand times a fit, at a third of ``_correig``'s time."""
+        if len(feats) <= 1:
+            return float(len(feats)), 1.0
+        vals = np.linalg.eigvalsh(self._sub(feats))
+        return float(vals[-1]), float(vals[-1] / max(vals.sum(), 1e-30))
+
     def _tot_var(self, *cluster_lists: List[str]) -> Tuple[float, float]:
         tot_len, tot_var, tot_prop = 0, 0.0, 0.0
         for clus in cluster_lists:
             if not clus:
                 continue
-            vals, _, props = self._correig(clus)
-            tot_var += float(vals[0])
-            tot_prop = (tot_prop * tot_len + float(props[0]) * len(clus)) / (tot_len + len(clus))
+            first, prop = self._first_eig(clus)
+            tot_var += first
+            tot_prop = (tot_prop * tot_len + prop * len(clus)) / (tot_len + len(clus))
             tot_len += len(clus)
         return tot_var, tot_prop
 

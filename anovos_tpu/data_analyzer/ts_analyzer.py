@@ -19,7 +19,7 @@ import numpy as np
 import pandas as pd
 
 from anovos_tpu.obs import get_tracer
-from anovos_tpu.shared.table import Table
+from anovos_tpu.shared.table import Table, counted_fetch
 from anovos_tpu.shared.utils import ends_with
 from anovos_tpu.shared.utils import write_csv_counted as _write_csv
 
@@ -71,20 +71,6 @@ def ts_processed_feats(idf: Table, col: str) -> pd.DataFrame:
     return out
 
 
-def _fetch(tree, idf: Table, span=None):
-    """``jax.device_get``, counted on the open stage row ``span``: one
-    ``fetches`` a call and, as ``host_rows``, the rows of every fetched array
-    that is as long as the table.  The inspection brings aggregates to the
-    host and never a column, so its rows read 0 (a table padded to exactly
-    ``CALENDAR_DAY_LANES`` rows reads its day lanes too: high, never low)."""
-    out = jax.device_get(tree)
-    if span is not None:
-        span.add(fetches=1, host_rows=sum(
-            a.shape[0] for a in jax.tree_util.tree_leaves(out)
-            if np.ndim(a) and a.shape[0] == idf.padded_rows))
-    return out
-
-
 def ts_calendar(idf: Table, col: str, span=None) -> dict:
     """One timestamp column's calendar counts, from ONE device program and
     one fetch (``ops/datetime_kernels.calendar_counts``): ``n`` valid rows of
@@ -97,7 +83,7 @@ def ts_calendar(idf: Table, col: str, span=None) -> dict:
 
     c = idf.columns[col]
     got = {k: np.asarray(v).astype("int64")
-           for k, v in _fetch(calendar_counts(c.data, c.mask), idf, span).items()}
+           for k, v in counted_fetch(calendar_counts(c.data, c.mask), idf, span).items()}
     n = int(got["n"])
     lo, hi = (int(got["min"]), int(got["max"])) if n else (0, -1)
     day_lo = lo // SECS_PER_DAY
@@ -211,7 +197,7 @@ def _num_viz_small_grain(idf: Table, ts_col: str, num_cols: List[str], grain: st
     tcol = idf.columns[ts_col]
     ids, labels = _grain_buckets(tcol, grain)
     V, Mv = idf.numeric_block(num_cols)
-    agg = _fetch(_segment_aggregate(ids, tcol.mask, V, Mv, len(labels)), idf, span)
+    agg = counted_fetch(_segment_aggregate(ids, tcol.mask, V, Mv, len(labels)), idf, span)
     return _small_grain_frame(agg, num_cols, labels)
 
 
@@ -256,7 +242,7 @@ def _ts_num_viz_all(idf: Table, ts_col: str, num_cols: List[str], cal: dict, spa
     if span is not None:
         # the bucket lanes of the call, for its roofline, and how its medians are taken
         span.add(segments=nseg_d + nseg_h + nseg_w, **median_routes(len(num_cols), nseg_d, nseg_h, nseg_w))
-    agg_d, agg_h, agg_w = _fetch(_ts_num_viz_program(
+    agg_d, agg_h, agg_w = counted_fetch(_ts_num_viz_program(
         np.int32(lo), tcol.data, tcol.mask, V, Mv, nseg_d, nseg_h, nseg_w, cp), idf, span)
     dv = format_segment_aggregate(agg_d, num_cols, _TS_NUM_AGGS, ts_col,
                                   "%Y-%m-%d", lo, "day")
@@ -293,7 +279,7 @@ def _cat_viz(idf: Table, ts_col: str, cat_cols: List[str], cal: dict, n_cat: int
     # out here would compile broadcast+concat programs per arity
     datas = tuple(idf.columns[c].data for c in cat_cols)
     masks = tuple(idf.columns[c].mask for c in cat_cols)
-    cnts = np.asarray(_fetch(_all_code_counts_cols(datas, masks, nv_b), idf, span))  # (k, nv_b)
+    cnts = np.asarray(counted_fetch(_all_code_counts_cols(datas, masks, nv_b), idf, span))  # (k, nv_b)
     # top-N per column (codes beyond a column's own vocab count zero)
     lut = np.full((k, nv_b), n_cat, np.int32)  # → Others
     tops = []
@@ -302,7 +288,7 @@ def _cat_viz(idf: Table, ts_col: str, cat_cols: List[str], cal: dict, n_cat: int
         top = np.argsort(-cnts[j, :v])[:n_cat]
         lut[j, top] = np.arange(len(top), dtype=np.int32)
         tops.append(top)
-    combo = np.asarray(_fetch(_combo_counts_all_cols(
+    combo = np.asarray(counted_fetch(_combo_counts_all_cols(
         datas, masks, tcol.data, tcol.mask, lut, np.int32(lo), ndays_b, n_cat + 1
     ), idf, span)).reshape(k, ndays_b, n_cat + 1)[:, :ndays, :]
     rows = []

@@ -25,7 +25,7 @@ import pandas as pd
 from anovos_tpu.obs import get_tracer
 from anovos_tpu.ops.drift_kernels import binned_histograms, fit_cutoffs
 from anovos_tpu.ops.quantiles import masked_quantiles
-from anovos_tpu.ops.segment import code_counts
+from anovos_tpu.ops.segment import code_counts, code_label_counts
 from anovos_tpu.shared.table import Table, pad_lane_params
 from anovos_tpu.shared.utils import ends_with, parse_cols
 
@@ -438,15 +438,11 @@ def charts_to_objects(
             vals = [float(cnts[j]) for j in order if cnts[j] > 0]
             _emit(_bar_fig(cats, vals, c), ends_with(master_path) + "freqDist_" + c)
             if y is not None:
-                # one fused program per column (shared with the IV/IG group
-                # sweep): mask combine + both label segment-sums
-                from anovos_tpu.data_analyzer.association_evaluator import (
-                    _label_group_counts_fused,
-                )
-
-                tot, evs, _, _ = _label_group_counts_fused(
-                    col.data, col.mask, y, ym, idf.nrows, vsize)
-                tot, evs = tot[:vsize], evs[:vsize]
+                # the labelled rows and the events per category, one group
+                # count in flight at a time as cat_to_num_supervised takes them
+                m_eff = col.mask & ym
+                tot = np.asarray(code_counts(col.data, m_eff, vsize))[:vsize]
+                evs = np.asarray(code_label_counts(col.data, m_eff, y, vsize))[:vsize]
                 with np.errstate(invalid="ignore", divide="ignore"):
                     rate = np.where(tot > 0, evs / np.maximum(tot, 1), 0.0)
                 _emit(

@@ -11,6 +11,7 @@ kernel over the λ grid.
 
 from __future__ import annotations
 
+import contextlib
 import logging
 import functools
 import math
@@ -43,11 +44,13 @@ logger = logging.getLogger(__name__)
 # affine scale, elementwise math + finite-mask, per-column impute fills —
 # lowered as ONE program over the padded (rows, k_pad) block.
 # ---------------------------------------------------------------------------
-@jax.jit
-def _bin_apply_program(X, edges):
-    """digitize + the 1-based int cast in one program: (bins0, bins1)."""
-    bins0 = digitize(X, edges)
-    return bins0, (bins0 + 1).astype(jnp.int32)
+@functools.partial(jax.jit, static_argnames=("scope",))
+def _bin_apply_program(X, edges, scope: Optional[str] = None):
+    """digitize + the 1-based int cast in one program: (bins0, bins1), under
+    the ``jax.named_scope`` the caller names, if it names one."""
+    with jax.named_scope(scope) if scope else contextlib.nullcontext():
+        bins0 = digitize(X, edges)
+        return bins0, (bins0 + 1).astype(jnp.int32)
 
 
 @jax.jit
@@ -173,6 +176,47 @@ def _emit(idf: Table, new_cols: "OrderedDict[str, Column]", output_mode: str, po
 # ----------------------------------------------------------------------
 # binning
 # ----------------------------------------------------------------------
+def binning_cutoffs(X, M, k: int, method_type: str, bin_size: int, scope: Optional[str] = None) -> np.ndarray:
+    """(k, bin_size - 1) interior cut-offs of the ``k`` live lanes of a numeric
+    block, on the host; NaN for a lane without a value.  equal_frequency: the
+    lower order statistic at j / bin_size of the values present; equal_range:
+    min + j (max - min) / bin_size.  The block-level step of
+    ``attribute_binning``, which ``association_evaluator`` takes too, so that
+    a measure's bins are the transformer's (``scope``: see
+    ``masked_quantiles``; the sketch and equal_range's moments carry none)."""
+    if method_type not in ("equal_frequency", "equal_range"):
+        raise TypeError("Invalid input for method_type")
+    if method_type == "equal_frequency":
+        qs = jnp.array([j / bin_size for j in range(1, bin_size)], jnp.float32)
+        # exact sort quantiles up to ~64M cells; beyond that the sort's
+        # O(rows·k) temp buffers crowd HBM → histogram sketch (O(k·nbins)
+        # state, error ≤ range/2048 — the approxQuantile analogue)
+        if X.size > int(os.environ.get("ANOVOS_EXACT_QUANTILE_CELLS", 64_000_000)):
+            from anovos_tpu.ops.quantiles import histogram_quantiles
+
+            return np.asarray(histogram_quantiles(X, M, qs))[:, :k].T.astype(np.float64)
+        # (k, B-1) — sliced to the live k of the column-bucketed block
+        return np.asarray(masked_quantiles(X, M, qs, interpolation="lower", scope=scope))[:, :k].T
+    mom = masked_moments(X, M)
+    lo = np.asarray(mom["min"], dtype=np.float64)[:k]
+    hi = np.asarray(mom["max"], dtype=np.float64)[:k]
+    return lo[:, None] + np.arange(1, bin_size)[None, :] * ((hi - lo) / bin_size)[:, None]
+
+
+def bin_block(X, cutoffs: np.ndarray, scope: Optional[str] = None):
+    """(0-based, 1-based) bin of every cell of a numeric block whose live
+    lanes have these interior ``cutoffs``: the number of cut-offs below the
+    value (value <= cut-off stays under it)."""
+    # digitize expects (k, nb+1) edges with sentinels; interior cutoffs only
+    # matter.  Edges are padded to the bucketed lane count (dead-lane bins
+    # are never read — every consumer indexes the live lanes).
+    k = len(cutoffs)
+    edges = np.concatenate([np.full((k, 1), -np.inf), cutoffs, np.full((k, 1), np.inf)], axis=1)
+    # digitize (0-indexed) + 1-based cast in one program; the host edge
+    # array rides in through the jit boundary (no convert program)
+    return _bin_apply_program(X, pad_lane_params(edges, X.shape[1]).astype(np.float32), scope=scope)
+
+
 def attribute_binning(
     idf: Table,
     list_of_cols="all",
@@ -211,32 +255,13 @@ def attribute_binning(
         cutoffs = np.array([cut_map[c] for c in cols], dtype=np.float64)
     else:
         X, M = idf.numeric_block(cols)
-        if method_type == "equal_frequency":
-            qs = jnp.array([j / bin_size for j in range(1, bin_size)], jnp.float32)
-            # exact sort quantiles up to ~64M cells; beyond that the sort's
-            # O(rows·k) temp buffers crowd HBM → histogram sketch (O(k·nbins)
-            # state, error ≤ range/2048 — the approxQuantile analogue)
-            if X.size > int(os.environ.get("ANOVOS_EXACT_QUANTILE_CELLS", 64_000_000)):
-                from anovos_tpu.ops.quantiles import histogram_quantiles
-
-                cutoffs = np.asarray(histogram_quantiles(X, M, qs))[:, : len(cols)].T.astype(np.float64)
-            else:
-                # (k, B-1) — sliced to the live k of the column-bucketed block
-                cutoffs = np.asarray(
-                    masked_quantiles(X, M, qs, interpolation="lower")
-                )[:, : len(cols)].T
-        else:
-            mom = masked_moments(X, M)
-            lo = np.asarray(mom["min"], dtype=np.float64)[: len(cols)]
-            hi = np.asarray(mom["max"], dtype=np.float64)[: len(cols)]
-            keep = ~np.isnan(lo)
-            if not keep.all():
-                dropped = [c for c, k in zip(cols, keep) if not k]
-                warnings.warn("Columns contains too much null values. Dropping " + ", ".join(dropped))
-                cols = [c for c, k in zip(cols, keep) if k]
-                lo, hi = lo[keep], hi[keep]
-            width = (hi - lo) / bin_size
-            cutoffs = lo[:, None] + np.arange(1, bin_size)[None, :] * width[:, None]
+        cutoffs = binning_cutoffs(X, M, len(cols), method_type, bin_size)
+        keep = ~np.isnan(cutoffs[:, 0])
+        if method_type == "equal_range" and not keep.all():
+            dropped = [c for c, k in zip(cols, keep) if not k]
+            warnings.warn("Columns contains too much null values. Dropping " + ", ".join(dropped))
+            cols = [c for c, k in zip(cols, keep) if k]
+            cutoffs = cutoffs[keep]
         if model_path != "NA":
             save_model_df(
                 pd.DataFrame({"attribute": cols, "parameters": [list(map(float, c)) for c in cutoffs]}),
@@ -246,18 +271,9 @@ def attribute_binning(
     if not cols:
         return idf
 
-    X, M = idf.numeric_block(cols)
+    X, _ = idf.numeric_block(cols)
     nb = cutoffs.shape[1] + 1
-    # digitize expects (k, nb+1) edges with sentinels; interior cutoffs only
-    # matter.  Edges are padded to the bucketed lane count (dead-lane bins
-    # are never read — every consumer below indexes bins0[:, i] for live i).
-    edges = np.concatenate(
-        [np.full((len(cols), 1), -np.inf), cutoffs, np.full((len(cols), 1), np.inf)], axis=1
-    )
-    edges_p = pad_lane_params(edges, X.shape[1]).astype(np.float32)
-    # digitize (0-indexed) + 1-based cast in one program; the host edge
-    # array rides in through the jit boundary (no convert program)
-    bins0, bins1 = _bin_apply_program(X, edges_p)
+    bins0, bins1 = bin_block(X, cutoffs)
     new_cols: "OrderedDict[str, Column]" = OrderedDict()
     if bin_dtype == "numerical":
         for i, c in enumerate(cols):

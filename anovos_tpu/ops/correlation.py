@@ -55,7 +55,8 @@ def _masked_corr(X: jax.Array, M: jax.Array, bf16: bool = False) -> jax.Array:
     cov_n = n * Sxy - Sx * Sy
     var_a = n * Sxx - Sx * Sx
     var_b = n * Syy - Sy * Sy
-    denom = jnp.sqrt(jnp.maximum(var_a, 0.0) * jnp.maximum(var_b, 0.0))
+    # the roots apart: the product of two sums of squares of 10^6-sized values over 10^4 rows is past f32
+    denom = jnp.sqrt(jnp.maximum(var_a, 0.0)) * jnp.sqrt(jnp.maximum(var_b, 0.0))
     corr = jnp.where(denom > 0, cov_n / jnp.maximum(denom, 1e-30), jnp.nan)
     k = X.shape[1]
     return jnp.where(jnp.eye(k, dtype=bool), 1.0, corr)
@@ -84,21 +85,33 @@ def _masked_cov(X: jax.Array, M: jax.Array, bf16: bool = False) -> jax.Array:
     return jnp.where(n > 1, (Sxy - mean_prod) / jnp.maximum(n - 1.0, 1.0), jnp.nan)
 
 
-@timed("ops.masked_corr_cc")
-def masked_corr_cc(X: jax.Array, M: jax.Array, k_live: int) -> jax.Array:
-    """Complete-case Pearson correlation over the LIVE lanes of a
-    column-bucketed block, fused: the live-lane row count, complete-case
-    scalar compare and mask combine of the association_evaluator call
-    sites fold into the correlation program itself.  The live
-    count rides in as a device scalar so the program stays keyed on the
-    bucketed shape."""
-    import numpy as np
-
-    return _masked_corr_cc(X, M, np.int32(k_live), bf16=bf16_sweep())
-
-
 @functools.partial(jax.jit, static_argnames=("bf16",))
 def _masked_corr_cc(X: jax.Array, M: jax.Array, k_live: jax.Array,
                     bf16: bool = False) -> jax.Array:
+    """Complete-case Pearson correlation over the LIVE lanes of a
+    column-bucketed block (``k_live`` of them, a device scalar so that the
+    program stays keyed on the bucketed shape): every pair over the rows
+    valid in ALL live lanes.  Every pair has the same rows, so the columns
+    are centred and brought to unit length once and ONE product, of the
+    complete rows gathered first, gives the matrix: no sum of squares of a
+    raw magnitude is multiplied by another (two columns of 10^6 over 10^4
+    rows overflowed f32 in the pairwise form's denominator).  A column
+    constant over the complete rows has no correlation (NaN); the diagonal
+    is 1."""
+    dt = jnp.float32
     row_ok = (M.sum(axis=1) == k_live)[:, None]
-    return _masked_corr(X, M & row_ok, bf16=bf16)
+    Mc = M & row_ok
+    Xf = X.astype(dt)
+    Xc = jnp.where(Mc, Xf - masked_mean(Xf, Mc)[None, :], 0.0)
+    big = jnp.asarray(jnp.finfo(dt).max, dt)
+    varies = jnp.max(jnp.where(Mc, Xf, -big), axis=0) > jnp.min(jnp.where(Mc, Xf, big), axis=0)
+    Z = Xc * jax.lax.rsqrt(jnp.maximum((Xc * Xc).sum(axis=0), jnp.finfo(dt).tiny))[None, :]
+    # the complete rows first, the zero rows after them: the MXU adds the row tiles of a contraction into its
+    # f32 accumulator rounding down, so 10^4 live rows among 3 x 10^5 zero rows read 1e-5 low in their worst
+    # pair at any precision and the same rows side by side 1e-6 (measured on a v5e: PERF.md section 6, PR 46)
+    Z = jnp.take(Z, jnp.argsort(~row_ok[:, 0]), axis=0)
+    k = Z.shape[1]
+    G = mm(Z.T, Z, bf16)
+    d = jnp.sqrt(jnp.diagonal(G))  # 1 but for the rounding of the scaling, which this takes out again
+    corr = jnp.where(varies[:, None] & varies[None, :], G / jnp.maximum(d[:, None] * d[None, :], 1e-30), jnp.nan)
+    return jnp.where(jnp.eye(k, dtype=bool), 1.0, corr)

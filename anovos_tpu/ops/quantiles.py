@@ -11,8 +11,9 @@ with a psum-merged fixed-width histogram.
 
 from __future__ import annotations
 
+import contextlib
 import functools
-from typing import Sequence
+from typing import Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -24,7 +25,8 @@ from anovos_tpu.shared.runtime import column_parallel, wants_column_parallel
 
 @timed("ops.masked_quantiles")
 def masked_quantiles(
-    X: jax.Array, M: jax.Array, qs: jax.Array, interpolation: str = "linear"
+    X: jax.Array, M: jax.Array, qs: jax.Array, interpolation: str = "linear",
+    scope: Optional[str] = None,
 ) -> jax.Array:
     """Exact quantiles per column.
 
@@ -33,7 +35,9 @@ def masked_quantiles(
     by each column's true valid count.  ``interpolation``: 'linear' (numpy
     default) or 'lower' (Spark approxQuantile returns actual elements).
     On a multi-device mesh the sort runs column-parallel
-    (runtime.column_parallel).
+    (runtime.column_parallel).  ``scope``: the ``jax.named_scope`` a caller
+    wants the program's operations under in a device trace (a block that
+    reads its own seconds there: ``assoc/cutoffs``).
 
     The quantile-grid axis is deliberately NOT shape-bucketed: padding q
     would change the public (q, k) return shape, and the census shows only
@@ -41,30 +45,31 @@ def masked_quantiles(
     live.
     """
     return _masked_quantiles(
-        X, M, qs, interpolation=interpolation, cp=wants_column_parallel(X, M)
+        X, M, qs, interpolation=interpolation, cp=wants_column_parallel(X, M), scope=scope
     )
 
 
-@functools.partial(jax.jit, static_argnames=("interpolation", "cp"))
+@functools.partial(jax.jit, static_argnames=("interpolation", "cp", "scope"))
 def _masked_quantiles(
     X: jax.Array, M: jax.Array, qs: jax.Array,
-    interpolation: str = "linear", cp: bool = False,
+    interpolation: str = "linear", cp: bool = False, scope: Optional[str] = None,
 ) -> jax.Array:
-    dt = X.dtype if X.dtype in (jnp.float32, jnp.float64) else jnp.float32
-    big = jnp.asarray(jnp.finfo(dt).max, dt)
-    Xs = jnp.sort(column_parallel(jnp.where(M, X.astype(dt), big), cp), axis=0)  # (rows, k)
-    n = M.sum(axis=0)  # (k,)
-    pos = qs[:, None] * jnp.maximum(n[None, :] - 1, 0)  # (q, k)
-    lo = jnp.floor(pos).astype(jnp.int32)
-    hi = jnp.ceil(pos).astype(jnp.int32)
-    v_lo = jnp.take_along_axis(Xs, lo, axis=0)
-    if interpolation == "lower":
-        out = v_lo
-    else:
-        v_hi = jnp.take_along_axis(Xs, hi, axis=0)
-        frac = (pos - lo).astype(dt)
-        out = v_lo + frac * (v_hi - v_lo)
-    return jnp.where(n[None, :] > 0, out, jnp.nan)
+    with jax.named_scope(scope) if scope else contextlib.nullcontext():
+        dt = X.dtype if X.dtype in (jnp.float32, jnp.float64) else jnp.float32
+        big = jnp.asarray(jnp.finfo(dt).max, dt)
+        Xs = jnp.sort(column_parallel(jnp.where(M, X.astype(dt), big), cp), axis=0)  # (rows, k)
+        n = M.sum(axis=0)  # (k,)
+        pos = qs[:, None] * jnp.maximum(n[None, :] - 1, 0)  # (q, k)
+        lo = jnp.floor(pos).astype(jnp.int32)
+        hi = jnp.ceil(pos).astype(jnp.int32)
+        v_lo = jnp.take_along_axis(Xs, lo, axis=0)
+        if interpolation == "lower":
+            out = v_lo
+        else:
+            v_hi = jnp.take_along_axis(Xs, hi, axis=0)
+            frac = (pos - lo).astype(dt)
+            out = v_lo + frac * (v_hi - v_lo)
+        return jnp.where(n[None, :] > 0, out, jnp.nan)
 
 
 def masked_median(X: jax.Array, M: jax.Array) -> jax.Array:

@@ -180,6 +180,13 @@ def _contracts(p: int, lanes_max: int, codes) -> bool:
     return _dense_class(p, lanes_max) and on_one_device(codes)
 
 
+def count_route(vocab_size: int, codes) -> Tuple[int, bool]:
+    """(padded class, ``dense``) of a group count over ``codes``: the two
+    statics of the count programs."""
+    p = _bucket_segments(vocab_size)
+    return p, _contracts(p, _DENSE_COUNT_LANES_MAX, codes)
+
+
 def segment_routes(classes, kind: str, sharded: bool = False) -> dict:
     """What calls over these padded classes count on their stage row: for
     ``kind`` "counts" the group counts by contraction and by scatter-add, for
@@ -250,8 +257,8 @@ def code_counts(codes: jax.Array, M: jax.Array, vocab_size: int) -> jax.Array:
     one-hot contraction (``_dense_group_sum``), a wider one, or a column laid
     over a mesh, by scatter-add.  Both give the exact integer count up to
     2^24 rows a code, where f32 stops counting by ones on either route."""
-    p = _bucket_segments(vocab_size)
-    return _code_counts_p(codes, M, p, dense=_contracts(p, _DENSE_COUNT_LANES_MAX, codes))
+    p, dense = count_route(vocab_size, codes)
+    return _code_counts_p(codes, M, p, dense=dense)
 
 
 @functools.partial(jax.jit, static_argnames=("vocab_size", "dense"))
@@ -281,8 +288,21 @@ def code_label_counts(
     other weights the dense route's is an f32 sum in another order than the
     scatter-add's, and one non-finite weight on a counted row reaches every
     lane of its chunk (0 x inf) where the scatter-add keeps it to its own."""
-    p = _bucket_segments(vocab_size)
-    return _code_label_counts_p(codes, M, y, p, dense=_contracts(p, _DENSE_COUNT_LANES_MAX, codes))
+    p, dense = count_route(vocab_size, codes)
+    return _code_label_counts_p(codes, M, y, p, dense=dense)
+
+
+@functools.partial(jax.jit, static_argnames=("vocab_size", "dense"))
+def _block_label_counts_p(
+    codes: jax.Array, M: jax.Array, y: jax.Array, vocab_size: int, dense: bool = False
+) -> jax.Array:
+    """:func:`code_label_counts` of every column of a (rows, k) block of codes
+    against one weight ``y``, in ONE program: (k, vocab_size) sums.  The
+    statics are :func:`count_route`'s, of the block's widest column; a caller
+    inside a program of its own (association_evaluator's group counts, under
+    their scope) passes them through."""
+    return jax.vmap(lambda c, m: _code_label_counts_p(c, m, y, vocab_size, dense),
+                    in_axes=1)(codes, M)
 
 
 def _dense_gather(lut: jax.Array, codes: jax.Array) -> jax.Array:

@@ -700,6 +700,21 @@ def stack_masks_padded(masks, pad_cols: bool = True) -> jax.Array:
     return _stack_bool(tuple(masks))
 
 
+def counted_fetch(tree, idf: "Table", span=None):
+    """``jax.device_get``, counted on the open stage row ``span``: one
+    ``fetches`` a call and, as ``host_rows``, the rows of every fetched array
+    that is as long as the table.  A block that brings aggregates to the host
+    and never a column reads 0 (the time-series inspection of a table padded
+    to exactly ``CALENDAR_DAY_LANES`` rows reads its day lanes too: high,
+    never low)."""
+    out = jax.device_get(tree)
+    if span is not None:
+        span.add(fetches=1, host_rows=sum(
+            a.shape[0] for a in jax.tree_util.tree_leaves(out)
+            if np.ndim(a) and a.shape[0] == idf.padded_rows))
+    return out
+
+
 def pad_lane_params(arr: np.ndarray, k_pad: int, fill=0.0) -> np.ndarray:
     """Pad a host per-column parameter array (k, ...) to (k_pad, ...) along
     axis 0 so elementwise kernels broadcast against a column-bucketed block

@@ -995,12 +995,13 @@ def _main(all_configs: dict, run_type: str, auth_key_val: Optional[dict],
                             else:
                                 df_in = df
                             df_stats = getattr(association_evaluator, subkey)(df_in, **value, **extra_args)
-                            if report_input_path:
-                                save_stats(df_stats, report_input_path, subkey, run_type=run_type,
-                                           auth_key=auth_key, async_writer=writer, async_key=f"stats:{subkey}")
-                            else:
-                                save(df_stats, write_stats, "data_analyzer/association_evaluator/" + subkey,
-                                     reread=True, writer=writer, key=f"stats:{subkey}")
+                            with get_tracer().phase("assoc/write", cat="block", rows=len(df_stats)):
+                                if report_input_path:
+                                    save_stats(df_stats, report_input_path, subkey, run_type=run_type,
+                                               auth_key=auth_key, async_writer=writer, async_key=f"stats:{subkey}")
+                                else:
+                                    save(df_stats, write_stats, "data_analyzer/association_evaluator/" + subkey,
+                                         reread=True, writer=writer, key=f"stats:{subkey}")
                         assoc_slice = {subkey: value}
                         if subkey == "correlation_matrix":
                             assoc_slice["cat_to_num_transformer"] = all_configs.get(

@@ -295,7 +295,7 @@ def test_the_inspection_brings_no_column_to_the_host(tmp_path):
         def add(self, **kw):
             self.attrs.update(kw)
 
-    ta._fetch(idf.columns["fare"].data, idf, Row())
+    ta.counted_fetch(idf.columns["fare"].data, idf, Row())
     assert Row.attrs == {"fetches": 1, "host_rows": idf.padded_rows}
 
 
