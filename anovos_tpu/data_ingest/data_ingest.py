@@ -112,7 +112,8 @@ def shard_files_for_process(files: List[str]) -> List[str]:
 # see of a read's size before it has decoded it is its files' bytes: 8 MiB of
 # parquet is 1.4-2.2 x 10^5 rows of the benchmark's tables, about where
 # ``shared/table.py`` starts to run a frame's column units side by side
-# (``_POOLED_COLUMNS_MIN_ROWS``).
+# (``_POOLED_COLUMNS_MIN_ROWS``; a shorter frame's arrays go to the device
+# by typed block on the calling thread).
 _POOLED_DECODE_MIN_BYTES = 1 << 23
 
 

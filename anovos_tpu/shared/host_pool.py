@@ -141,13 +141,16 @@ def get_host_pool() -> HostPool:
         return _POOL
 
 
-def record_units(what: str, ran: UnitsRun) -> None:
+def record_units(what: str, ran: UnitsRun, **counts) -> None:
     """``<what>_workers`` and ``<what>_wall_s`` of one call's units on the
     row of the pass's tree that the units' spans are filed under (inside
     ``read_dataset``: ``io:read_dataset``), so that the sum of those spans
-    over the wall is the overlap the call got.  Outside a pass: nothing."""
+    over the wall is the overlap the call got, and beside them whatever else
+    the caller counted of the units, each as ``<what>_<count>``.  Outside a
+    pass: nothing."""
     from anovos_tpu.obs.tracing import get_tracer
 
     row = get_tracer().tree_row()
     if row is not None:
-        row.add(**{f"{what}_workers": ran.workers, f"{what}_wall_s": ran.wall_s})
+        row.add(**{f"{what}_workers": ran.workers, f"{what}_wall_s": ran.wall_s},
+                **{f"{what}_{name}": count for name, count in counts.items()})

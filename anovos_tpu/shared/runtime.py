@@ -114,6 +114,27 @@ class Runtime:
                                       label="runtime.shard_rows", shards=self.n_data):
             return jax.device_put(arr, NamedSharding(self.mesh, spec))
 
+    def shard_rows_of_many(self, arrs: Sequence[np.ndarray]) -> List[jax.Array]:
+        """:meth:`shard_rows` of every one of ``arrs`` (each ``(rows,)``) in
+        one ``device_put`` call and under one transfer bracket: the same
+        arrays on the same sharding, without a Python call an array."""
+        from anovos_tpu.obs import devprof
+
+        with devprof.transfer_bracket("h2d", sum(a.nbytes for a in arrs),
+                                      label="runtime.shard_rows_of_many", shards=self.n_data):
+            return jax.device_put(list(arrs), self.row_sharding())
+
+    def shard_rows_block(self, block: np.ndarray) -> jax.Array:
+        """Place a host block of ``(k, rows)``, one row-sharded array a row
+        of it, on device with its second axis over the data axis: every
+        device gets the shard of each of the ``k`` arrays that
+        :meth:`shard_rows` would have given it, in one transfer."""
+        from anovos_tpu.obs import devprof
+
+        with devprof.transfer_bracket("h2d", block.nbytes,
+                                      label="runtime.shard_rows_block", shards=self.n_data):
+            return jax.device_put(block, NamedSharding(self.mesh, P(None, self.data_axis)))
+
     def pad_rows(self, n: int) -> int:
         """Rows are padded to a multiple of the data-axis size so every
         shard has identical (static) shape — XLA requires static shapes.

@@ -251,11 +251,13 @@ class Table:
         entries of a ``np.ma.MaskedArray`` (integers with nulls: the values
         stay integers and exact, the mask is the column's).
 
-        A column is one unit of the host pool (:func:`_upload_columns`: the
-        encode where the array still needs one, the conversion to the device
-        dtypes, the padding, the ``device_put`` calls): side by side for
-        ``_POOLED_COLUMNS_MIN_ROWS`` rows or more, a loop on this thread
-        under it; the table is the same either way."""
+        :func:`_upload_columns` builds the columns: the encode where an array
+        still needs one, the conversion to the device dtypes, the padding and
+        the hand-over to the device.  For ``_POOLED_COLUMNS_MIN_ROWS`` rows or
+        more a column is one unit of the host pool and an array one
+        ``device_put``, side by side; under it the table's arrays go over on
+        this thread by typed block, a few transfers a dtype
+        (:func:`_upload_in_blocks`); the table is the same either way."""
         if not data:
             return Table(OrderedDict(), 0)
         n = nrows if nrows is not None else len(next(iter(data.values())))
@@ -271,9 +273,11 @@ class Table:
         numpy array (:func:`_frame_sources`).  The vocab of a cat column is
         in code-point order, which is ``np.unique``'s over Python ``str``;
         Arrow computes it over the UTF-8 bytes where the distinct values are
-        an Arrow string array.  A string column's encode and its upload are
-        one unit of :func:`_upload_columns`, so the other columns are on the
-        device while the longest encode still runs."""
+        an Arrow string array.  In a frame of ``_POOLED_COLUMNS_MIN_ROWS``
+        rows or more a string column's encode and its upload are one unit of
+        :func:`_upload_columns`, so the other columns are on the device while
+        the longest encode still runs; a shorter frame's strings are encoded
+        first and its arrays then go over together."""
         sources = _frame_sources(df)
         if not sources:
             return Table(OrderedDict(), 0)
@@ -780,10 +784,13 @@ _BUCKETED_ENCODE_MIN_ROWS = 1 << 20
 # device (``_upload_columns``: ``Table.from_numpy`` / ``from_pandas``), the
 # string columns of a frame that stays on the host (``_frame_arrays``), the
 # columns of a table being fetched and converted (``Table.to_pandas``); a
-# shorter one's in a loop (the stats tables, a node's small frames, a
-# 32,561-row dataset): thirteen columns of 65,536 rows are 3 ms each and
-# threads that wake for them gave nothing back in the median, at 131,072
-# rows they halved the wall (PERF.md section 3).
+# shorter one's on the calling thread (the stats tables, a node's small
+# frames, a 32,561-row dataset): thirteen columns of 65,536 rows are 3 ms
+# each and threads that wake for them gave nothing back in the median, at
+# 131,072 rows they halved the wall (PERF.md section 3).  Under it an array
+# is at most 0.5 MB and a call that hands one to the device costs more than
+# its copy, so a table being built sends its arrays by typed block
+# (``_upload_in_blocks``).
 _POOLED_COLUMNS_MIN_ROWS = 1 << 17
 # The row of a pass's tree under which ``Table.to_pandas`` files a row a
 # column: ``write_dataset`` opens it around the fetch of the table it writes.
@@ -958,35 +965,52 @@ class _UnencodedStrings(NamedTuple):
     series: "pd.Series"
 
 
-def _record_unit_times(what: str, stamps: List[Tuple[int, float, float]], side_by_side: bool) -> None:
+def _record_unit_times(what: str, stamps: List[Tuple[int, float, float]], side_by_side: bool, **counts) -> None:
     """What :func:`record_units` says of one kind of work inside a call's
     units, where a unit is two kinds (a string column's encode, then its
     upload), from the ``(thread, start, end)`` of each piece of it:
-    ``<what>_workers`` (threads that ran the work; 0 for the loop) and
-    ``<what>_wall_s`` (first start to last end), if any ran."""
+    ``<what>_workers`` (threads that ran the work; 0 for the calling thread
+    alone) and ``<what>_wall_s`` (first start to last end), if any ran, and
+    the caller's ``counts`` of that work."""
     if stamps:
         threads, starts, ends = zip(*stamps)
-        record_units(what, UnitsRun([], len(set(threads)) if side_by_side else 0, max(ends) - min(starts)))
+        record_units(what, UnitsRun([], len(set(threads)) if side_by_side else 0, max(ends) - min(starts)),
+                     **counts)
 
 
 def _upload_columns(sources: Dict[str, object], n: int) -> "OrderedDict[str, Column]":
     """The device columns of a table of ``n`` rows from what
-    :meth:`Table.from_numpy` takes or :func:`_frame_sources` gives, one unit
-    of the host pool a column: a string column's encode
-    (:func:`encode_strings`, its ``ingest/encode`` span), then
-    :func:`_upload_column` (its ``ingest/h2d`` span).  Side by side for
-    ``_POOLED_COLUMNS_MIN_ROWS`` rows or more, a loop on this thread under
-    it.  The columns that need an encode are claimed first: they are the long
-    units (one of mostly distinct values hands its own buckets to the same
-    pool), and every other column is on the device before the longest encode
-    has ended.  The columns come back in ``sources``' order, each what the
-    loop makes of it.  A unit that raises stops the units not yet started;
-    the error of the first of them in the units' order is raised and no table
-    is made.  On the row of the pass's tree the call runs under
-    (``io:read_dataset`` inside a read): ``h2d_workers`` / ``h2d_wall_s`` of
-    the uploads and, where a frame's string columns were encoded here,
-    ``encode_workers`` / ``encode_wall_s``, as :func:`record_units` files
-    them."""
+    :meth:`Table.from_numpy` takes or :func:`_frame_sources` gives.  A string
+    column is dictionary-encoded first (:func:`encode_strings`, its
+    ``ingest/encode`` span); every column is then converted to the device
+    dtypes (:func:`_plain_to_host`), each of its arrays padded to the row
+    bucket with the fill it documents, and handed to the device on the row
+    sharding.  How the arrays are handed over is read from ``n``:
+
+    - ``_POOLED_COLUMNS_MIN_ROWS`` rows or more: a column is one unit of the
+      host pool, its encode and then :func:`_upload_column` (one
+      ``ingest/h2d`` span and one ``device_put`` an array), side by side.
+      The columns that need an encode are claimed first: they are the long
+      units (one of mostly distinct values hands its own buckets to the same
+      pool), and every other column is on the device before the longest
+      encode has ended.
+    - under it an array is so small that the call handing it over costs more
+      than its copy (0.29 ms for 0.13 MB on the chip, PERF.md section 6,
+      PR 47), and a table may have thousands: on this thread, the encodes,
+      then :func:`_upload_in_blocks` (one ``ingest/h2d`` span for the table,
+      a few transfers a dtype).
+
+    Either way the columns come back in ``sources``' order, each what one
+    ``device_put`` an array makes of it.  A column that raises stops those
+    not yet started; the error of the first of them in the units' order is
+    raised and no table is made.  On the row of the pass's tree the call runs
+    under (``io:read_dataset`` inside a read): ``h2d_workers`` /
+    ``h2d_wall_s`` of the uploads and, where a frame's string columns were
+    encoded here, ``encode_workers`` / ``encode_wall_s``, as
+    :func:`record_units` files them (0 workers: this thread alone), and
+    beside them ``h2d_arrays`` (arrays that reached the device) and
+    ``h2d_transfers`` (``device_put`` calls that carried them: as many as
+    arrays where a column is a unit)."""
     rt = get_runtime()
     npad = rt.pad_rows(max(n, 1))
     sources = {name: src if isinstance(src, (NativeEncodedStrings, _UnencodedStrings))
@@ -999,7 +1023,7 @@ def _upload_columns(sources: Dict[str, object], n: int) -> "OrderedDict[str, Col
         return isinstance(src, _UnencodedStrings) or (
             not isinstance(src, NativeEncodedStrings) and src.dtype.kind in "OUS")
 
-    def unit(name) -> Column:
+    def encoded(name) -> Union[np.ndarray, NativeEncodedStrings]:
         src = sources[name]
         if isinstance(src, _UnencodedStrings):
             t0 = time.perf_counter()
@@ -1007,16 +1031,30 @@ def _upload_columns(sources: Dict[str, object], n: int) -> "OrderedDict[str, Col
             encodes.append((threading.get_ident(), t0, time.perf_counter()))
         elif needs_encode(name):
             src = encode_strings(src[:n])
+        return src
+
+    def unit(name) -> Column:
+        src = encoded(name)
         t0 = time.perf_counter()
         col = _upload_column(src, n, npad, rt)
         uploads.append((threading.get_ident(), t0, time.perf_counter()))
         return col
 
     order = sorted(sources, key=lambda name: not needs_encode(name))  # stable: a kind keeps the table's order
-    ran = get_host_pool().run(unit, order, side_by_side=n >= _POOLED_COLUMNS_MIN_ROWS)
-    _record_unit_times("encode", encodes, ran.workers > 0)
-    _record_unit_times("h2d", uploads, ran.workers > 0)
-    made = dict(zip(order, ran.results))
+    if n >= _POOLED_COLUMNS_MIN_ROWS:
+        ran = get_host_pool().run(unit, order)
+        columns, side_by_side = ran.results, ran.workers > 0
+        transfers = sum(len(col.device_arrays()) for col in columns)  # a ``device_put`` an array
+    else:
+        plain = [encoded(name) for name in order]
+        t0 = time.perf_counter()
+        columns, transfers = _upload_in_blocks(plain, n, npad, rt)
+        uploads.append((threading.get_ident(), t0, time.perf_counter()))
+        side_by_side = False
+    arrays = sum(len(col.device_arrays()) for col in columns)
+    _record_unit_times("encode", encodes, side_by_side)
+    _record_unit_times("h2d", uploads, side_by_side, arrays=arrays, transfers=transfers)
+    made = dict(zip(order, columns))
     return OrderedDict((name, made[name]) for name in sources)
 
 
@@ -1039,7 +1077,9 @@ def _host_to_column(arr: np.ndarray, n: int, npad: int, rt) -> Column:
 
 def _upload_column(arr: Union[np.ndarray, NativeEncodedStrings], n: int, npad: int, rt) -> Column:
     """One column that needs no dictionary-encoding, from the host to the
-    device, under one ``ingest/h2d`` span (a row of the pass's tree where
+    device by itself (a unit of :func:`_upload_columns` from
+    ``_POOLED_COLUMNS_MIN_ROWS`` rows up, and :func:`_host_to_column`'s one
+    column), under one ``ingest/h2d`` span (a row of the pass's tree where
     ingest calls this; on a pool thread it has the parent it would have had
     on the calling one): the conversion to the device dtypes
     (:func:`_plain_to_host`; ``convert_s``), then array by array the padding
@@ -1114,11 +1154,28 @@ def _plain_to_host(arr: np.ndarray, n: int) -> HostColumn:
     return HostColumn("num", host, ~isnull, dtype_name=dtn)
 
 
+def _host_arrays(hc: HostColumn) -> List[Tuple[str, np.ndarray, object]]:
+    """``(field, array, fill)`` of every array of ``hc`` that the device
+    holds, in :meth:`Column.device_arrays`' order; the fill is what a padding
+    row carries: mask=False, code −1 in a cat column, the wide pair
+    (0, −2^31), 0 elsewhere."""
+    arrays = [("data", hc.data, -1 if hc.kind == "cat" else 0), ("mask", hc.mask, False)]
+    if hc.wide_hi is not None:
+        arrays += [("wide_hi", hc.wide_hi, np.int32(0)), ("wide_lo", hc.wide_lo, np.int32(-(1 << 31)))]
+    return arrays
+
+
+def _device_column(hc: HostColumn, placed: Dict[str, jax.Array]) -> Column:
+    """``hc`` with its arrays on the device: ``placed`` by field."""
+    return Column(hc.kind, placed["data"], placed["mask"], vocab=hc.vocab, dtype_name=hc.dtype_name,
+                  wide_hi=placed.get("wide_hi"), wide_lo=placed.get("wide_lo"), wide_kind=hc.wide_kind)
+
+
 def _place_column(hc: HostColumn, npad: int, rt, span) -> Column:
-    """The device half: each array of ``hc`` padded to ``npad`` rows (a
-    padding row carries mask=False, code −1 in a cat column, the wide pair
-    (0, −2^31)) and put on ``rt``'s row sharding, one array after the other,
-    so that an array is on its way while the next is padded.  The padding's
+    """The device half where a column is a unit: each array of ``hc`` padded
+    to ``npad`` rows (:func:`_host_arrays`' fills) and put on ``rt``'s row
+    sharding by a ``device_put`` of its own, one array after the other, so
+    that an array is on its way while the next is padded.  The padding's
     seconds go on ``span`` as ``pad_s``."""
     def put(a, fill):
         t0 = time.perf_counter()
@@ -1126,17 +1183,93 @@ def _place_column(hc: HostColumn, npad: int, rt, span) -> Column:
         span.add(pad_s=time.perf_counter() - t0)
         return rt.shard_rows(padded)
 
-    wide = hc.wide_hi is not None
-    return Column(
-        hc.kind,
-        put(hc.data, -1 if hc.kind == "cat" else 0),
-        put(hc.mask, False),
-        vocab=hc.vocab,
-        dtype_name=hc.dtype_name,
-        wide_hi=put(hc.wide_hi, np.int32(0)) if wide else None,
-        wide_lo=put(hc.wide_lo, np.int32(-(1 << 31))) if wide else None,
-        wide_kind=hc.wide_kind,
-    )
+    return _device_column(hc, {field: put(a, fill) for field, a, fill in _host_arrays(hc)})
+
+
+# Arrays of one dtype that go to the device as one block and come apart
+# there (``_upload_in_blocks``).  The width of the split program, so one
+# program a dtype and row bucket whatever the table's width; chosen on the
+# chip (PERF.md section 6, PR 47).
+_BLOCK_ARRAYS = 128
+
+
+@functools.lru_cache(maxsize=16)
+def _split_rows_program(sharding: NamedSharding):
+    """The program that takes a ``(k, rows)`` block apart into its ``k``
+    arrays of ``(rows,)``, each on ``sharding``.  ``out_shardings`` is stated:
+    left to the compiler the arrays come back with another spec than
+    ``Runtime.shard_rows`` gives (``P()`` for ``P('data',)`` on one device),
+    and every program they then enter compiles a second time."""
+    def _split_rows(block):
+        return tuple(block[i] for i in range(block.shape[0]))
+
+    return jax.jit(_split_rows, out_shardings=sharding)
+
+
+def _upload_in_blocks(srcs: Sequence[Union[np.ndarray, NativeEncodedStrings]], n: int, npad: int,
+                      rt) -> Tuple[List[Column], int]:
+    """The columns of a short table (no array needs a dictionary-encoding any
+    more) from the host to the device on this thread, by typed block and not
+    by array, under ONE ``ingest/h2d`` span: column after column the
+    conversion to the device dtypes (:func:`_plain_to_host`; ``convert_s``);
+    the arrays wait by dtype (f32 data; int32 data, codes, timestamps and wide
+    halves; bool masks), and as soon as ``_BLOCK_ARRAYS`` of a dtype wait they
+    are copied into the rows of one ``(_BLOCK_ARRAYS, npad)`` host block, which
+    is their padding (``pad_s``), the block goes over in one ``device_put``
+    with its row axis on ``rt``'s data axis (``enqueue_s``, ``bytes``,
+    ``shards`` from ``Runtime``'s transfer bracket) and is taken apart on the
+    device by :func:`_split_rows_program` (``split_s``: the seconds to
+    dispatch it; the block is let go of there and freed when the split has
+    run, so the device never holds a second table).  What is left of a dtype
+    at the end, fewer arrays than a block has, is padded array by array and
+    goes in one ``device_put`` of the list: no bytes but the arrays' own are
+    moved, and no program is compiled for a width.  The counts are this
+    thread's seconds and sum to at most the span's wall.  Returns the
+    columns and how many ``device_put`` calls carried their arrays."""
+    from anovos_tpu.obs.tracing import get_tracer
+
+    made: List[Tuple[HostColumn, Dict[str, jax.Array]]] = []  # a column and its arrays on the device, by field
+    waiting: Dict[np.dtype, List[Tuple[Dict[str, jax.Array], str, np.ndarray, object]]] = {}
+    transfers = 0
+
+    def hand_over(group, sp) -> None:
+        nonlocal transfers
+        t0 = time.perf_counter()
+        if len(group) == _BLOCK_ARRAYS:
+            block = np.empty((len(group), npad), dtype=group[0][2].dtype)
+            for i, (_, _, a, fill) in enumerate(group):
+                block[i, :len(a)] = a
+                block[i, len(a):] = fill
+            t1 = time.perf_counter()
+            on_device = rt.shard_rows_block(block)
+            t2 = time.perf_counter()
+            parts = _split_rows_program(rt.row_sharding())(on_device)
+            sp.add(split_s=time.perf_counter() - t2)
+        else:
+            padded = [_pad_to(a, npad, fill) for _, _, a, fill in group]
+            t1 = time.perf_counter()
+            parts = rt.shard_rows_of_many(padded)
+        sp.add(pad_s=t1 - t0)
+        transfers += 1
+        for (placed, field, _, _), part in zip(group, parts):
+            placed[field] = part
+        group.clear()
+
+    with get_tracer().phase("ingest/h2d", cat="io") as sp:
+        for src in srcs:
+            t0 = time.perf_counter()
+            hc = _plain_to_host(src, n)
+            sp.add(convert_s=time.perf_counter() - t0)
+            made.append((hc, {}))
+            for field, a, fill in _host_arrays(hc):
+                group = waiting.setdefault(a.dtype, [])
+                group.append((made[-1][1], field, a, fill))
+                if len(group) == _BLOCK_ARRAYS:
+                    hand_over(group, sp)
+        for group in waiting.values():
+            if group:
+                hand_over(group, sp)
+    return [_device_column(hc, placed) for hc, placed in made], transfers
 
 
 def _host_column_to_pandas(hc: HostColumn):

@@ -175,3 +175,28 @@ def test_row_sharded_describe_and_histograms_compile_for_four_chips(topo):
     text = _compile(_masked_moments_xla, X, M).as_text()
     assert "all-reduce" in text  # per-shard partials meet in a psum
     _compile(_binned_histograms_xla, X, M, cuts, nbins=10)
+
+
+@pytest.mark.parametrize("chips", [1, 4])
+def test_the_uploads_split_compiles_to_slices_on_each_chip_and_states_the_row_sharding(topo, chips):
+    """``table._split_rows_program`` at the width the program has and the
+    epsilon table's row bucket: a block of f32 columns and one of masks, on
+    one described chip and with the rows over four.  Every output is on the
+    very sharding ``Runtime.shard_rows`` gives, and no chip waits for another."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from anovos_tpu.shared import table as table_mod
+
+    mesh = Mesh(np.array(topo.devices[:chips]).reshape(chips, 1), ("data", "model"))
+    rows = NamedSharding(mesh, P("data"))
+    for dtype in (jnp.float32, jnp.bool_):
+        block = jax.ShapeDtypeStruct((table_mod._BLOCK_ARRAYS, 32768), dtype, sharding=NamedSharding(mesh, P(None, "data")))
+        compiled = _compile(table_mod._split_rows_program(rows), block)
+        assert len(compiled.output_shardings) == table_mod._BLOCK_ARRAYS
+        assert all(s == rows for s in compiled.output_shardings)
+        text = compiled.as_text()
+        assert "all-" not in text and "collective-permute" not in text
+        assert compiled.memory_analysis().temp_size_in_bytes == 0  # the block and its arrays, nothing between

@@ -237,14 +237,20 @@ def test_rows_written_from_pool_threads_are_in_the_tree(pool, tmp_path, monkeypa
         wall = counts[f"{what}_wall_s"]
         assert 0.0 < wall <= read["end_s"] - read["start_s"]
         assert wall == pytest.approx(max(r["end_s"] for r in found) - min(r["start_s"] for r in found), abs=2e-3)
-    # the same rows from the loop
+    # the same rows from this thread alone, the uploads' apart: a column's row there, one row a table here
     _inline(monkeypatch)
     _, loop_rows = _read_in_a_pass(str(tmp_path / "d"))
 
     def tree(rs):
         return sorted((r["name"], r["parent"], r["counts"].get("rows"), r["counts"].get("distinct"),
-                       r["counts"].get("bytes")) for r in rs if r["name"].startswith(("ingest/", "io:")))
-    assert tree(rows) == tree(loop_rows)
+                       r["counts"].get("bytes")) for r in rs
+                      if r["name"].startswith(("ingest/", "io:")) and r["name"] != "ingest/h2d")
+
+    def h2d(rs):
+        found = [r for r in rs if r["name"] == "ingest/h2d"]
+        return {r["parent"] for r in found}, sum(r["counts"]["bytes"] for r in found)
+    assert tree(rows) == tree(loop_rows) and h2d(rows) == h2d(loop_rows)
+    assert len([r for r in rows if r["name"] == "ingest/h2d"]) == tbl.ncols
 
 
 def test_a_frame_under_the_threshold_opens_no_pool_task(tmp_path, monkeypatch):

@@ -1,11 +1,15 @@
-"""``Table.from_numpy`` / ``Table.from_pandas``: a column's encode, conversion,
-padding and ``device_put`` as one unit of the host pool
-(``table._upload_columns``), side by side from ``_POOLED_COLUMNS_MIN_ROWS``
-rows up and in a loop on the calling thread under it.  Either way the table is
-what one column after the other gives (the reference kept here: the host half,
-``np.concatenate``'s padding, one ``device_put`` an array), the pass's tree has
-one ``ingest/h2d`` row a column with its counts, an error of one unit leaves no
-table, and ``host_table_frame`` gives the frame it gave."""
+"""``Table.from_numpy`` / ``Table.from_pandas`` (``table._upload_columns``): from
+``_POOLED_COLUMNS_MIN_ROWS`` rows up a column's encode, conversion, padding and
+``device_put``s are one unit of the host pool, side by side; under it the
+table's arrays go over on the calling thread by typed block
+(``table._upload_in_blocks``: ``_BLOCK_ARRAYS`` arrays of a dtype in one
+``device_put`` and a split on the device, what does not fill a block in one
+``device_put`` of a list).  Either way the table is what one column after the
+other gives (the reference kept here: the host half, ``np.concatenate``'s
+padding, one ``device_put`` an array on the row sharding), the pass's tree has
+its ``ingest/h2d`` rows with their counts (one a column, or one a table), an
+error of one column leaves no table, and ``host_table_frame`` gives the frame
+it gave."""
 
 import os
 import threading
@@ -18,6 +22,7 @@ import pandas as pd
 import pytest
 
 from anovos_tpu import obs
+from anovos_tpu.obs import compile_census
 from anovos_tpu.data_ingest import data_ingest
 from anovos_tpu.shared import host_pool
 from anovos_tpu.shared import table as table_mod
@@ -27,6 +32,7 @@ from anovos_tpu.shared.table import Table
 
 THREADS = 4
 POOLED, LOOPED = 140_000, 2_000  # either side of the 131,072 rows the program has
+SMALL_BLOCK = 4  # arrays a block where a test wants blocks from a table of nine columns
 KINDS = ["double_f32_exact", "double_wide", "int64_narrow", "int64_wide", "flag", "ts_nat",
          "int_nullable", "string", "encoded"]
 
@@ -97,8 +103,48 @@ def _same_column(col, hc, want, sharding=None):
         if host is not None:
             assert dev.dtype == host.dtype and dev.shape == host.shape, name
             np.testing.assert_array_equal(np.asarray(dev), host, err_msg=name)
-            if sharding is not None:
-                assert dev.sharding.is_equivalent_to(sharding, dev.ndim), name
+            if sharding is not None:  # the very sharding, not an equivalent one: programs are keyed on it
+                assert dev.sharding == sharding and dev.committed, name
+
+
+def _loop_table(arrays, rows: int) -> Table:
+    """The table as the per-array loop built it: the reference's padded host
+    arrays, one ``device_put`` each through ``Runtime.shard_rows``."""
+    rt = get_runtime()
+    cols = OrderedDict()
+    for name, arr in arrays.items():
+        hc, want = _reference(arr, rows, rt.pad_rows(rows))
+        cols[name] = table_mod._device_column(hc, {f: rt.shard_rows(a) for f, a in want.items() if a is not None})
+    return Table(cols, rows)
+
+
+def _wide_arrays(rows: int, cols: int) -> "OrderedDict[str, object]":
+    """``cols`` columns that cycle through the kinds, each with values of its own."""
+    out = OrderedDict()
+    for i in range(-(-cols // len(KINDS))):
+        for kind, arr in _arrays(rows + i).items():
+            if len(out) < cols:
+                out[f"{kind}_{i}"] = arr if isinstance(arr, NativeEncodedStrings) else arr[:rows]
+    return out
+
+
+def _arrays_by_dtype(tbl: Table) -> dict:
+    by_dtype = {}
+    for col in tbl.columns.values():
+        for a in col.device_arrays():
+            by_dtype[str(a.dtype)] = by_dtype.get(str(a.dtype), 0) + 1
+    return by_dtype
+
+
+def _transfers(tbl: Table) -> int:
+    """How many ``device_put`` calls the block branch makes for ``tbl``: a
+    dtype's arrays in blocks and one call for what is left."""
+    return sum(-(-k // table_mod._BLOCK_ARRAYS) for k in _arrays_by_dtype(tbl).values())
+
+
+def _row_sharding(rt):
+    """``Runtime.shard_rows``' sharding, stated here and not asked of the program."""
+    return jax.sharding.NamedSharding(rt.mesh, jax.sharding.PartitionSpec(rt.data_axis))
 
 
 @pytest.fixture(scope="module")
@@ -134,7 +180,7 @@ def test_a_column_of_every_kind_is_what_the_loop_made(built, entry, kind):
         npad = rt.pad_rows(rows)
         assert tbl.nrows == rows and tbl.padded_rows == npad and tbl.col_names == KINDS
         hc, want = _reference(arrays[kind], rows, npad)
-        sharding = jax.sharding.NamedSharding(rt.mesh, jax.sharding.PartitionSpec(rt.data_axis))
+        sharding = _row_sharding(rt)
         _same_column(tbl.columns[kind], hc, want, sharding)
     assert (hc.wide_hi is not None) == (kind in ("double_wide", "int64_wide"))
     assert hc.kind == {"ts_nat": "ts", "string": "cat", "encoded": "cat"}.get(kind, "num")
@@ -148,7 +194,7 @@ def test_on_a_four_device_mesh_every_array_has_the_loops_row_sharding(pool):
         arrays = _arrays(POOLED)
         tbl = Table.from_numpy(arrays)
         npad = rt.pad_rows(POOLED)
-        sharding = jax.sharding.NamedSharding(rt.mesh, jax.sharding.PartitionSpec(rt.data_axis))
+        sharding = _row_sharding(rt)
         for kind in KINDS:
             hc, want = _reference(arrays[kind], POOLED, npad)
             _same_column(tbl.columns[kind], hc, want, sharding)
@@ -156,6 +202,86 @@ def test_on_a_four_device_mesh_every_array_has_the_loops_row_sharding(pool):
                 assert len(a.addressable_shards) == 4 and {s.data.shape[0] for s in a.addressable_shards} == {npad // 4}
     finally:
         init_runtime()  # the suite's 8-device mesh again
+
+
+@pytest.mark.parametrize("devices", [4, 8])
+def test_on_a_mesh_every_array_of_a_short_table_has_the_loops_row_sharding(pool, monkeypatch, devices):
+    """The block's row axis is the sharded one: every array that comes out of
+    a block, and every one of the rest, lies on the devices as the loop's."""
+    monkeypatch.setattr(table_mod, "_BLOCK_ARRAYS", SMALL_BLOCK)
+    init_runtime(devices=jax.devices()[:devices])
+    try:
+        rt = get_runtime()
+        assert rt.n_data == devices
+        arrays = _arrays(LOOPED)
+        tbl = Table.from_numpy(arrays)
+        npad = rt.pad_rows(LOOPED)
+        sharding = _row_sharding(rt)
+        for kind in KINDS:
+            hc, want = _reference(arrays[kind], LOOPED, npad)
+            _same_column(tbl.columns[kind], hc, want, sharding)
+            for a in tbl.columns[kind].device_arrays():
+                assert len(a.addressable_shards) == devices
+                assert {s.data.shape for s in a.addressable_shards} == {(npad // devices,)}
+                assert [s.index for s in a.addressable_shards] == [
+                    s.index for s in rt.shard_rows(np.zeros(npad, a.dtype)).addressable_shards]
+    finally:
+        init_runtime()  # the suite's 8-device mesh again
+
+
+def test_a_table_wider_than_a_block_with_a_ragged_last_one_is_what_the_loop_made(pool):
+    """The program's own block width: 400 columns are 133 f32 arrays (a block
+    and 5), 444 int32 (three and 60) and 400 masks (three and 16)."""
+    rt = get_runtime()
+    arrays = _wide_arrays(LOOPED, 400)
+    tbl = Table.from_numpy(arrays)
+    npad = rt.pad_rows(LOOPED)
+    assert tbl.col_names == list(arrays) and tbl.padded_rows == npad
+    sharding = _row_sharding(rt)
+    for name, arr in arrays.items():
+        hc, want = _reference(arr, LOOPED, npad)
+        _same_column(tbl.columns[name], hc, want, sharding)
+    counts, block = _arrays_by_dtype(tbl), table_mod._BLOCK_ARRAYS
+    assert all(k > block and k % block for k in counts.values()), counts  # every dtype: blocks and a ragged rest
+
+
+@pytest.mark.parametrize("block", [1, None])
+@pytest.mark.parametrize("kind", ["double_wide", "string", "flag"])
+def test_a_table_of_one_column_is_what_the_loop_made(pool, monkeypatch, kind, block):
+    """Every array a block of its own, then the program's width, where the
+    column's arrays are what is left of their dtypes."""
+    if block:
+        monkeypatch.setattr(table_mod, "_BLOCK_ARRAYS", block)
+    rt = get_runtime()
+    arr = _arrays(LOOPED)[kind]
+    tbl = Table.from_numpy({kind: arr})
+    assert tbl.col_names == [kind] and tbl.nrows == LOOPED
+    sharding = _row_sharding(rt)
+    _same_column(tbl.columns[kind], *_reference(arr, LOOPED, rt.pad_rows(LOOPED)), sharding)
+
+
+def test_a_program_warmed_by_the_loops_table_compiles_nothing_for_a_blocks_table(pool, monkeypatch):
+    """What a program is keyed on (shape, dtype, sharding, committed) is the
+    loop's on every array that came out of a block: a program that a table
+    built array by array has compiled is found again."""
+    monkeypatch.setattr(table_mod, "_BLOCK_ARRAYS", SMALL_BLOCK)
+    arrays = _arrays(LOOPED)
+    numeric = ["double_f32_exact", "double_wide", "int64_narrow", "int64_wide", "flag", "int_nullable"]
+
+    def work(tbl):
+        X, M = tbl.numeric_block(numeric)
+        moved = tbl.gather_rows(np.arange(tbl.nrows)[::-1])
+        return np.asarray(X).sum(where=np.asarray(M)), moved.to_pandas()
+
+    compile_census.install()
+    want_sum, want_frame = work(_loop_table(arrays, LOOPED))
+    tbl = Table.from_numpy(arrays)  # the split programs compile here
+    jax.block_until_ready([a for c in tbl.columns.values() for a in c.device_arrays()])
+    mark = compile_census.mark()
+    got_sum, got_frame = work(tbl)
+    assert compile_census.census(since=mark)["compiles_total"] == 0
+    assert got_sum == want_sum
+    pd.testing.assert_frame_equal(got_frame, want_frame)
 
 
 def test_a_table_of_no_columns_and_a_table_of_one_row_come_back(pool):
@@ -220,6 +346,9 @@ def test_a_long_read_has_one_h2d_row_a_column_and_says_how_many_threads_ran_them
         assert c["convert_s"] + c["pad_s"] + c["enqueue_s"] <= r["end_s"] - r["start_s"] + 1e-4
     counts = read["counts"]
     assert 2 <= counts["h2d_workers"] <= THREADS and counts["h2d_workers"] == len({r["thread"] for r in h2d})
+    # a column is a unit: one device_put an array (two a column, four where it carries a wide pair)
+    assert counts["h2d_transfers"] == counts["h2d_arrays"] == 22
+    assert counts["h2d_arrays"] == sum(len(c.device_arrays()) for c in tbl.columns.values())
     # first start to last end: a string column's upload follows its encode, so this is no sum of the rows
     assert 0.0 < counts["h2d_wall_s"] <= read["end_s"] - read["start_s"]
     assert counts["h2d_wall_s"] == pytest.approx(max(r["end_s"] for r in h2d) - min(r["start_s"] for r in h2d), abs=2e-2)
@@ -228,18 +357,39 @@ def test_a_long_read_has_one_h2d_row_a_column_and_says_how_many_threads_ran_them
         max(r["end_s"] for r in encode) - min(r["start_s"] for r in encode), abs=2e-2)
 
 
-def test_a_short_read_is_the_loop_and_says_so(pool, tmp_path, monkeypatch):
+@pytest.mark.parametrize("block", [SMALL_BLOCK, None])
+def test_a_short_read_goes_by_block_on_this_thread_and_says_so(pool, tmp_path, monkeypatch, block):
+    """Blocks of four arrays, then the program's own width, at which nine
+    columns fill none and every dtype's arrays go in one call."""
+    if block:
+        monkeypatch.setattr(table_mod, "_BLOCK_ARRAYS", block)
     submitted = []
     monkeypatch.setattr(pool._executor, "submit", lambda fn, *a: submitted.append(fn))
+    puts = []
+    real_put = jax.device_put
+    monkeypatch.setattr(jax, "device_put", lambda x, *a, **k: puts.append(x) or real_put(x, *a, **k))
     _write_parts(str(tmp_path / "d"), LOOPED)
     tbl, rows, moved = _read_in_a_pass(str(tmp_path / "d"))
     (read,) = [r for r in rows if r["name"] == "io:read_dataset"]
-    h2d = [r for r in rows if r["name"] == "ingest/h2d"]
-    assert tbl.nrows == LOOPED and len(h2d) == 9 and submitted == []
-    assert read["counts"]["h2d_workers"] == 0 and read["counts"]["encode_workers"] == 0
-    assert 0.0 < read["counts"]["h2d_wall_s"] <= read["end_s"] - read["start_s"]
-    assert {r["thread"] for r in h2d} == {threading.current_thread().name}
-    assert sum(r["counts"]["bytes"] for r in h2d) == moved
+    (h2d,) = [r for r in rows if r["name"] == "ingest/h2d"]  # one row a table
+    encode = [r for r in rows if r["name"] == "ingest/encode"]
+    assert tbl.nrows == LOOPED and tbl.ncols == 9 and len(encode) == 2 and submitted == []
+    counts = read["counts"]
+    assert counts["h2d_workers"] == 0 and counts["encode_workers"] == 0
+    assert 0.0 < counts["h2d_wall_s"] <= read["end_s"] - read["start_s"]
+    assert h2d["thread"] == threading.current_thread().name and h2d["parent"] == "io:read_dataset"
+    assert all(r["parent"] == "io:read_dataset" and r["end_s"] <= h2d["start_s"] for r in encode)  # no row inside another
+    # every byte handed to device_put is on the table's row, once, and the padded arrays are what was handed
+    assert h2d["counts"]["bytes"] == moved == sum(a.nbytes for c in tbl.columns.values() for a in c.device_arrays())
+    assert moved == sum(sum(a.nbytes for a in x) if isinstance(x, list) else x.nbytes for x in puts)
+    # fewer calls than arrays: 22 arrays (nine columns, two with a wide pair) of three dtypes
+    assert counts["h2d_arrays"] == 22 and counts["h2d_transfers"] == len(puts) == _transfers(tbl)
+    assert counts["h2d_transfers"] == (7 if block else 3) < counts["h2d_arrays"]
+    c = h2d["counts"]
+    assert set(c) == {"bytes", "shards", "enqueue_s", "convert_s", "pad_s"} | ({"split_s"} if block else set())
+    assert c["shards"] == counts["h2d_transfers"] * get_runtime().n_data
+    assert min(c["convert_s"], c["pad_s"], c["enqueue_s"]) > 0.0
+    assert c["convert_s"] + c["pad_s"] + c["enqueue_s"] + c.get("split_s", 0.0) <= h2d["end_s"] - h2d["start_s"] + 1e-4
 
 
 def test_the_other_columns_are_uploaded_while_the_longest_encode_runs(pool, monkeypatch):
