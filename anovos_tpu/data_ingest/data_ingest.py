@@ -20,7 +20,7 @@ import shutil
 import threading
 import warnings
 from collections import OrderedDict
-from typing import Dict, List, Optional, Sequence, Union
+from typing import Dict, List, Optional, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -425,6 +425,11 @@ def write_dataset(
     frame went through the device, and the write touches no device, books
     no transfer and opens no ``ingest/*`` span; it puts ``host_frame=1`` on
     the ``write:<key>`` span it runs under.
+
+    A parquet part is pyarrow's default file (snappy, one row group up to
+    1,048,576 rows) except for the page encoding of float columns whose
+    values hardly repeat: those are written plain, not through a dictionary
+    (``_dictionary_columns``); a reader gets the same values bit for bit.
     """
     cfg = dict(file_configs or {})
     mode = cfg.pop("mode", "error")
@@ -452,13 +457,16 @@ def write_dataset(
         with _write_phase(tracer, FETCH_PHASE, arrays=len(arrays), bytes=sum(a.nbytes for a in arrays)):
             df = idf.to_pandas()
     with _write_phase(tracer, "write/" + file_type, rows=len(df)) as write_files:
-        written = _write_parts(df, file_path, file_type, cfg, repartition)
+        written, plain_columns = _write_parts(df, file_path, file_type, cfg, repartition)
         try:
             n_bytes = sum(os.path.getsize(f) for f in written)
         except OSError:
             n_bytes = 0
         if write_files is not None:
             write_files.add(bytes=n_bytes)
+            if file_type == "parquet":
+                write_files.add(plain_columns=plain_columns,
+                                dict_columns=len(written) * df.shape[1] - plain_columns)
     # incremental-recompute capture: the pyarrow writers bypass the
     # builtins.open hook, so this choke point books every part explicitly
     # (a no-op unless a cache recorder is active on this thread)
@@ -471,6 +479,10 @@ def write_dataset(
     reg = get_metrics()
     reg.counter("bytes_written_total", "artifact bytes written to disk").inc(n_bytes)
     reg.counter("rows_written_total", "rows persisted by write_dataset").inc(len(df))
+    reg.counter(
+        "parquet_plain_columns_total",
+        "parquet columns (summed over part files) written PLAIN because a dictionary could not pay",
+    ).inc(plain_columns)
 
 
 def _write_phase(tracer, name: str, **counts):
@@ -480,13 +492,70 @@ def _write_phase(tracer, name: str, **counts):
     return tracer.phase(name, **counts) if tracer.in_pass() else contextlib.nullcontext()
 
 
+# The encoding plan of a parquet part.  A dictionary of doubles pays when the
+# distinct values are far fewer than the rows: then the pages hold short
+# indices.  Where nearly every value is distinct the dictionary page is the
+# column over again, the indices come on top, and every value is hashed into a
+# memo table on the way (1,000 latent columns of 25,000 rows: 2.0 s and 201 MB
+# with dictionaries, 0.9 s and 154 MB without; PERF.md section 6, PR 48).
+# Arrow drops a dictionary by itself only once its page passes
+# ``dictionary_pagesize_limit`` (1 MiB = 131,072 doubles), which a part under
+# 131,072 rows never reaches, so the choice is made here, per float column,
+# from a strided sample of at most ``_PLAN_SAMPLE_ROWS`` of the part's own
+# rows (1,024: of a dictionary that Arrow would keep, at most 131,072 values,
+# such a sample shows 1,024^2 / 2 / 131,072 = 4 repeats or more).  A column is
+# written plain only where at least ``_PLAIN_MIN_SAMPLE`` sampled values are
+# not null (below that a dictionary costs nothing either way, and the file
+# keeps the bytes it had) and the sample shows no more repeats than it would
+# of a column in which ``_PLAIN_MIN_DISTINCT_SHARE`` of the values are distinct.
+_PLAN_SAMPLE_ROWS = 1024
+_PLAIN_MIN_SAMPLE = 64
+_PLAIN_MIN_DISTINCT_SHARE = 0.9
+
+
+def _dictionary_columns(part: pd.DataFrame) -> Optional[List[str]]:
+    """The columns of ``part`` that keep pyarrow's dictionary encoding, in
+    the frame's order, or ``None`` where every column does (the frame then
+    goes through the default call).  Only numpy float columns are judged;
+    every other dtype keeps the default, as does a frame whose column names
+    are not unique strings (``use_dictionary`` names columns by path)."""
+    names = list(part.columns)
+    if len(set(names)) != len(names) or not all(isinstance(c, str) for c in names):
+        return None
+    floats = [c for c, t in zip(names, part.dtypes) if isinstance(t, np.dtype) and t.kind == "f"]
+    if not floats or len(part) < _PLAIN_MIN_SAMPLE:
+        return None
+    # the row slice first: a view of <= 1,024 rows, so no full-column pass
+    sample = part.iloc[:: -(-len(part) // _PLAN_SAMPLE_ROWS)]
+    block = np.sort(sample[floats].to_numpy(dtype=np.float64), axis=0)  # NaN sorts last
+    valid = ~np.isnan(block)
+    n_valid = valid.sum(axis=0)
+    distinct = ((block[1:] != block[:-1]) & valid[1:]).sum(axis=0) + (n_valid > 0)
+    # Repeats among s rows drawn from n grow with s * s / n, not with s: a
+    # column of 10,000 values looks all distinct to 1,024 rows of a million.
+    # So the bar is the distinct share that a sample of this fraction f shows
+    # of a column whose values each fill 1 / share rows: share itself where
+    # the sample is the whole part, and 1 - (1 / share - 1) * f / 2 as f -> 0.
+    f = len(sample) / len(part)
+    share = _PLAIN_MIN_DISTINCT_SHARE
+    bar = share / f * (1.0 - (1.0 - f) ** (1.0 / share))
+    plain = (n_valid >= _PLAIN_MIN_SAMPLE) & (distinct >= bar * n_valid)
+    if not plain.any():
+        return None
+    written_plain = {c for c, p in zip(floats, plain) if p}
+    return [c for c in names if c not in written_plain]
+
+
 def _write_parts(df: pd.DataFrame, file_path: str, file_type: str, cfg: dict,
-                 repartition: int) -> List[str]:
+                 repartition: int) -> Tuple[List[str], int]:
     """``df`` as ``repartition`` part files of ``file_type`` under
     ``file_path`` and the ``_SUCCESS`` marker after them; the part files
-    THIS call wrote (append mode must not re-book pre-existing parts)."""
+    THIS call wrote (append mode must not re-book pre-existing parts), and
+    how many columns of them, summed over the parquet parts,
+    ``_dictionary_columns`` had written plain."""
     parts = np.array_split(np.arange(len(df)), max(repartition, 1))
     written: List[str] = []
+    plain_columns = 0
     for i, part_idx in enumerate(parts):
         # single-part writes (the checkpoint default) skip the fancy-index
         # row copy — df.iloc[arange] materializes a full second frame
@@ -549,7 +618,12 @@ def _write_parts(df: pd.DataFrame, file_path: str, file_type: str, cfg: dict,
                 part.to_csv(stem + ".csv", index=False, header=header, sep=delim)
                 written.append(stem + ".csv")
         elif file_type == "parquet":
-            part.to_parquet(stem + ".parquet", index=False)
+            keep = _dictionary_columns(part)
+            if keep is None:
+                part.to_parquet(stem + ".parquet", index=False)
+            else:
+                part.to_parquet(stem + ".parquet", index=False, use_dictionary=keep)
+                plain_columns += part.shape[1] - len(keep)
             written.append(stem + ".parquet")
         elif file_type == "avro":
             avro_io.write_avro(part, stem + ".avro")
@@ -560,7 +634,7 @@ def _write_parts(df: pd.DataFrame, file_path: str, file_type: str, cfg: dict,
         else:
             raise ValueError(f"unsupported file_type: {file_type}")
     open(os.path.join(file_path, "_SUCCESS"), "w").close()
-    return written
+    return written, plain_columns
 
 
 # ----------------------------------------------------------------------
