@@ -226,7 +226,7 @@ def _ts_num_viz_all(idf: Table, ts_col: str, num_cols: List[str], cal: dict, spa
     rows).  Daily formatting goes through the aggregator's shared
     ``format_segment_aggregate`` so the frames match the per-grain path
     byte-for-byte."""
-    from anovos_tpu.data_transformer.datetime import format_segment_aggregate, median_routes
+    from anovos_tpu.data_transformer.datetime import aggregate_routes, format_segment_aggregate
     from anovos_tpu.ops.segment import segment_class
     from anovos_tpu.shared.runtime import wants_column_parallel
 
@@ -240,8 +240,9 @@ def _ts_num_viz_all(idf: Table, ts_col: str, num_cols: List[str], cal: dict, spa
     cp = wants_column_parallel(tcol.data, tcol.mask, V, Mv,
                                replicate=(tcol.data, tcol.mask))
     if span is not None:
-        # the bucket lanes of the call, for its roofline, and how its medians are taken
-        span.add(segments=nseg_d + nseg_h + nseg_w, **median_routes(len(num_cols), nseg_d, nseg_h, nseg_w))
+        # the bucket lanes of the call, for its roofline, how its medians are taken, and what of it is a wide class
+        span.add(segments=nseg_d + nseg_h + nseg_w,
+                 **aggregate_routes(idf.padded_rows, len(num_cols), nseg_d, nseg_h, nseg_w))
     agg_d, agg_h, agg_w = counted_fetch(_ts_num_viz_program(
         np.int32(lo), tcol.data, tcol.mask, V, Mv, nseg_d, nseg_h, nseg_w, cp), idf, span)
     dv = format_segment_aggregate(agg_d, num_cols, _TS_NUM_AGGS, ts_col,
@@ -364,7 +365,7 @@ def ts_viz_data(
     phase = get_tracer().phase
     out = ends_with(output_path)
     num_all, cat_all, _ = idf.attribute_type_segregation()
-    num_cols = [c for c in num_all][:20]
+    num_cols = list(num_all)  # every one, as the upstream's loop takes them
     cat_cols = [c for c in cat_all][:10]
 
     cal = _calendar if _calendar is not None else ts_calendar(idf, col)

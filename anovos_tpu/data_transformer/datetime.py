@@ -24,7 +24,7 @@ import numpy as np
 import pandas as pd
 
 from anovos_tpu.ops import datetime_kernels as dk
-from anovos_tpu.ops.segment import dense_chunks
+from anovos_tpu.ops.segment import bf16_parts, dense_block_sums, dense_chunks
 from anovos_tpu.shared.runtime import get_runtime
 from anovos_tpu.shared.table import Column, Table, _host_to_column
 
@@ -631,10 +631,12 @@ def _segment_aggregate(ids0: jax.Array, valid: jax.Array, V: jax.Array, Mv: jax.
 
     ids0: (rows,) int32 bucket ids already offset to [0, nseg); valid:
     (rows,) row validity; V: (rows, k) f32 values; Mv: (rows, k) value
-    validity.  One program, no host loop (``_segment_aggregate_jit``).  On a
-    multi-device mesh the block is re-laid column-parallel (each device
-    sorts whole columns locally; ids/validity replicate) — see
-    runtime.column_parallel.
+    validity.  One program, no host loop (``_segment_aggregate_jit``: a
+    class of at most ``_DENSE_SEGMENTS_MAX`` buckets by contraction, masked
+    reduce and counting selection, a wider one by contraction and one sort
+    a column; no scatter on either side).  On a multi-device mesh the block
+    is re-laid column-parallel (each device sorts whole columns locally;
+    ids/validity replicate) — see runtime.column_parallel.
 
     The static segment count is bucketed into 2^k classes (min 8 —
     ops/segment.py ``segment_class``: NOT the coarse vocab classes, the
@@ -663,11 +665,18 @@ def _segment_aggregate_jit_off(ids: jax.Array, off: jax.Array, valid: jax.Array,
     return _segment_aggregate_jit(ids - off, valid, V, Mv, nseg, cp=cp)
 
 
-# A segment class of at most this many buckets (dayparts, weekdays, the days
-# of a month or two) takes its count, sum and sum of squares from a one-hot
-# contraction on the MXU and its min and max from a masked reduce: no
-# scatter.  Wider classes (a daily grain over years) keep the scatters,
-# whose cost does not grow with the class.
+# The one rule of the per-bucket aggregate, by the static class.  A class of
+# at most this many buckets (dayparts, weekdays, the days of a month or two)
+# takes its count, sum and sum of squares from a one-hot contraction at
+# precision ``highest``, its min and max from a masked reduce (a broadcast
+# of (chunk, columns, buckets): what keeps the limit where it is) and its
+# medians from a selection by counting, whose passes grow with the class.  A
+# wider class (a daily grain over years, ``aggregator`` at a fine grain)
+# takes the three moments from a contraction too (``ops/segment.py``'s
+# ``dense_block_sums`` over the values' bfloat16 parts) and min, max and
+# median all from ONE two-key sort a column.  No class takes a scatter: an
+# f32 scatter-add, one update at a time, lost 1.6 % of a bucket of 10^6
+# values and cost 7.2 ns an update on the chip (PERF.md section 6, PR 39).
 _DENSE_SEGMENTS_MAX = 64
 # rows a step of the dense path's scan: bounds the one-hot and the stacked
 # operand of the contraction whatever the table's length
@@ -719,21 +728,28 @@ def _dense_moments(ids0, ok, V, nseg: int):
     return moments[:k], moments[k:2 * k], moments[2 * k:], mn, mx
 
 
-def _scatter_moments(ids0, valid, ok, V, nseg: int):
-    """(cnt, sm, sq, mn, mx), each (k, nseg), by segment scatters: three
-    adds, a min and a max per column, under ``vmap`` over the columns."""
-    seg = jnp.where(valid, ids0, nseg)
+def _is_wide(nseg: int) -> bool:
+    """The rule: whether a static segment class is on the wide side."""
+    return nseg > _DENSE_SEGMENTS_MAX
 
-    def per_col(v, o):
-        s = jnp.where(o, ids0, nseg)
-        cnt = jax.ops.segment_sum(jnp.where(o, 1.0, 0.0), seg, num_segments=nseg + 1)[:nseg]
-        sm = jax.ops.segment_sum(jnp.where(o, v, 0.0), seg, num_segments=nseg + 1)[:nseg]
-        sq = jax.ops.segment_sum(jnp.where(o, v * v, 0.0), seg, num_segments=nseg + 1)[:nseg]
-        mn = jax.ops.segment_min(jnp.where(o, v, jnp.inf), s, num_segments=nseg + 1)[:nseg]
-        mx = jax.ops.segment_max(jnp.where(o, v, -jnp.inf), s, num_segments=nseg + 1)[:nseg]
-        return cnt, sm, sq, mn, mx
 
-    return jax.vmap(per_col, in_axes=(1, 1), out_axes=0)(V, ok)
+def _wide_moments(ids0, ok, V, nseg: int):
+    """(cnt, sm, sq), each (k, nseg), for a wide segment class: the buckets'
+    one-hot contracted with the validity and with the three bfloat16 parts
+    of the values and of their squares (``bf16_parts``: they add up to the
+    f32 exactly, so every product is exact and the sums are f32 sums taken
+    chunk by chunk, ``dense_block_sums``).  The parts' sums are added
+    smallest first."""
+    k = V.shape[1]
+
+    def planes(ok_c, v_c):  # (chunk, 7k)
+        x = jnp.where(ok_c, v_c, 0.0)
+        return jnp.concatenate([ok_c.astype(jnp.bfloat16), *bf16_parts(x), *bf16_parts(x * x)], axis=1)
+
+    sums = dense_block_sums(ids0, (ok, V), planes, nseg)
+    hi, mid, lo = sums[k:4 * k].reshape(3, k, nseg)
+    hi2, mid2, lo2 = sums[4 * k:].reshape(3, k, nseg)
+    return sums[:k], (lo + mid) + hi, (lo2 + mid2) + hi2
 
 
 _NAN_KEY = int(np.float32(np.nan).view(np.int32))
@@ -819,12 +835,14 @@ def _select_medians(ids0, ok, V, cnt, nseg: int):
     return (lo + hi) / 2  # an empty bucket reads 0.0; no consumer reads it
 
 
-def _sort_medians(ids0, ok, V, cnt, nseg: int):
-    """(k, nseg) medians: each column sorted by (bucket, value), the middle
-    one or two of every bucket picked through the cumulative counts.  The
-    columns sort ``_SORT_BLOCK_CELLS // rows`` at a time (a ``lax.map`` over
-    column blocks inside the one program), so the sort's memory does not
-    grow with the number of columns."""
+def _sort_picks(ids0, ok, V, cnt, nseg: int):
+    """(mn, mx, med), each (k, nseg): each column sorted by (bucket, value),
+    the first, the last and the middle one or two of every bucket picked
+    through the cumulative counts (an empty bucket reads +inf, -inf and
+    whatever lies at its place: no consumer reads it).  The columns sort
+    ``_SORT_BLOCK_CELLS // rows`` at a time (a ``lax.map`` over column
+    blocks inside the one program), so the sort's memory does not grow with
+    the number of columns."""
     rows, k = V.shape
 
     def per_col(v, o, c):
@@ -834,9 +852,12 @@ def _sort_medians(ids0, ok, V, cnt, nseg: int):
         c = c.astype(jnp.int32)
         starts = jnp.cumsum(c) - c  # (nseg,)
         c_i = jnp.maximum(c - 1, 0)
-        lo_i = jnp.clip(starts + c_i // 2, 0, rows - 1)
-        hi_i = jnp.clip(starts + (c_i + 1) // 2, 0, rows - 1)
-        return (v_sorted[lo_i] + v_sorted[hi_i]) / 2
+
+        def at(i):  # the i-th of every bucket's run
+            return v_sorted[jnp.clip(starts + i, 0, rows - 1)]
+
+        return (jnp.where(c > 0, at(0), jnp.inf), jnp.where(c > 0, at(c_i), -jnp.inf),
+                (at(c_i // 2) + at((c_i + 1) // 2)) / 2)
 
     block = jax.vmap(per_col, in_axes=(1, 1, 0), out_axes=0)
     b = max(1, min(k, _SORT_BLOCK_CELLS // rows))
@@ -845,26 +866,23 @@ def _sort_medians(ids0, ok, V, cnt, nseg: int):
     if b == k:
         return block(V, ok, cnt)
     cut = jax.lax.dynamic_slice_in_dim
-    return jax.lax.map(
+    picks = jax.lax.map(
         lambda i: block(cut(V, i * b, b, 1), cut(ok, i * b, b, 1), cut(cnt, i * b, b, 0)),
-        jnp.arange(k // b)).reshape(k, nseg)
+        jnp.arange(k // b))
+    return tuple(p.reshape(k, nseg) for p in picks)
 
 
-def _segment_medians(ids0, ok, V, cnt, nseg: int):
-    """(k, nseg) medians, by the static class like the moments: a selection
-    by counting up to ``_DENSE_SEGMENTS_MAX`` buckets, a sort above."""
-    if nseg <= _DENSE_SEGMENTS_MAX:
-        return _select_medians(ids0, ok, V, cnt, nseg)
-    return _sort_medians(ids0, ok, V, cnt, nseg)
-
-
-def median_routes(k: int, *nsegs: int) -> dict:
-    """What a call of ``k`` columns over grains of these classes counts on
-    its stage row: the (column, grain) medians by selection and by sort, and
-    the counting passes a selecting grain makes."""
-    selects = k * sum(n <= _DENSE_SEGMENTS_MAX for n in nsegs)
-    return {"median_selects": selects, "median_sorts": k * len(nsegs) - selects,
-            "select_passes": _SELECT_PASSES if selects else 0}
+def aggregate_routes(rows: int, k: int, *nsegs: int) -> dict:
+    """What a call of ``k`` columns of ``rows`` padded rows over grains of
+    these classes counts on its stage row, by the one rule: the (column,
+    grain) medians by selection and by sort and the counting passes a
+    selecting grain makes; the buckets of the wide grains and the cells
+    (rows x columns, a wide grain) they aggregate."""
+    wide = [n for n in nsegs if _is_wide(n)]
+    selects = k * (len(nsegs) - len(wide))
+    return {"median_selects": selects, "median_sorts": k * len(wide),
+            "select_passes": _SELECT_PASSES if selects else 0,
+            "wide_segments": sum(wide), "wide_cells": rows * k * len(wide)}
 
 
 @_functools.partial(jax.jit, static_argnames=("nseg", "cp"))
@@ -873,18 +891,22 @@ def _segment_aggregate_jit(ids0: jax.Array, valid: jax.Array, V: jax.Array,
     """(cnt, sm, sq, mn, mx, med), each (k, nseg): the ONE per-bucket
     aggregate of ``aggregator``, the time-series inspection's fused
     three-grain program and its per-grain path.  The static ``nseg`` picks
-    how the moments and the median are taken (``_DENSE_SEGMENTS_MAX``)."""
+    how they are taken (``_DENSE_SEGMENTS_MAX``); a wide class has a scope
+    of its own under the aggregate's, for a trace to tell it by."""
     from anovos_tpu.shared.runtime import column_parallel, replicated
 
     with jax.named_scope("ts/segment_aggregate"):
         V, Mv = column_parallel(V, cp), column_parallel(Mv, cp)
         ids0, valid = replicated(ids0, cp), replicated(valid, cp)
         ok = Mv & valid[:, None]
-        if nseg <= _DENSE_SEGMENTS_MAX:
+        if not _is_wide(nseg):
             moments = _dense_moments(ids0, ok, V, nseg)
-        else:
-            moments = _scatter_moments(ids0, valid, ok, V, nseg)
-        return (*moments, _segment_medians(ids0, ok, V, moments[0], nseg))
+            return (*moments, _select_medians(ids0, ok, V, moments[0], nseg))
+        with jax.named_scope("wide"):
+            with jax.named_scope("moments"):
+                cnt, sm, sq = _wide_moments(ids0, ok, V, nseg)
+            with jax.named_scope("medians"):
+                return (cnt, sm, sq, *_sort_picks(ids0, ok, V, cnt, nseg))
 
 
 def aggregator(

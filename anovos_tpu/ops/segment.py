@@ -230,6 +230,69 @@ def _dense_group_sum(codes: jax.Array, w: jax.Array, p: int) -> jax.Array:
     return sums.reshape(p)
 
 
+def bf16_parts(x: jax.Array) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """An f32 array as three bfloat16 arrays that add up to it, to the bit:
+    eight bits of the mantissa each, the largest part first.  What a
+    contraction with an exact 0 / 1 operand needs of precision ``HIGHEST``,
+    in three bf16 products for its six.  Each part is rounded by
+    ``lax.reduce_precision``, not by a cast to bfloat16 and back: inside a
+    fusion the chip's compiler keeps such a round trip in f32 (excess
+    precision), the rest comes out 0 and the sums are sums of bfloat16
+    values, 2e-3 off (PERF.md section 6, PR 49, call a49).  A value that is
+    not finite, or above bfloat16's largest (3.39e38), has no such parts
+    (inf - inf): its sums come out NaN."""
+    def rounded(a):
+        return jax.lax.reduce_precision(a, exponent_bits=8, mantissa_bits=7)
+
+    hi = rounded(x)
+    rest = x - hi
+    mid = rounded(rest)
+    return hi.astype(jnp.bfloat16), mid.astype(jnp.bfloat16), (rest - mid).astype(jnp.bfloat16)
+
+
+def dense_block_sums(ids: jax.Array, blocks, planes_of, nseg: int) -> jax.Array:
+    """(m, nseg) f32 sums by bucket of the m bfloat16 columns that
+    ``planes_of(*chunk of each of blocks)`` makes of a chunk of rows, zero
+    where a row does not count; ``ids`` (rows,) in [0, nseg), any other id
+    counts nowhere; ``blocks``: arrays as long as the rows.  Per chunk the
+    buckets' one-hot (chunk, nseg) is contracted with the planes over the
+    rows: both sides exact in bf16, the products added in f32, one MXU
+    pass, and nothing as long as the rows is built but the inputs.  A
+    ``lax.scan`` over the chunks carries the sums with their rounding
+    (Neumaier's compensation: a bucket of 10^6 values of one sign keeps the
+    1e-7 of one chunk's sum, where a plain f32 carry over 1,500 chunks loses
+    1e-6 and a scatter-add, one update at a time, 1e-2).  The rows are padded
+    to whole chunks, so no one-hot is ever longer than a chunk."""
+    rows = ids.shape[0]
+    chunk = min(_DENSE_CHUNK_ROWS, rows)
+    pad = -rows % chunk
+    if pad:
+        ids = jnp.concatenate([ids, jnp.full((pad,), -1, ids.dtype)])
+        blocks = [jnp.concatenate([b, jnp.zeros((pad, *b.shape[1:]), b.dtype)]) for b in blocks]
+    lanes = jnp.arange(nseg, dtype=ids.dtype)
+
+    def one(ids_c, *blocks_c):
+        hot = (ids_c[:, None] == lanes).astype(jnp.bfloat16)
+        return jnp.einsum("rs,rm->ms", hot, planes_of(*blocks_c), preferred_element_type=jnp.float32)
+
+    n = (rows + pad) // chunk
+    if n == 1:
+        return one(ids, *blocks)
+
+    def step(carry, xs):
+        total, lost = carry
+        part = one(*xs)
+        new = total + part
+        # what the addition rounded away, kept beside the sum and added once at the end
+        lost = lost + jnp.where(jnp.abs(total) >= jnp.abs(part), (total - new) + part, (part - new) + total)
+        return (new, lost), None
+
+    xs = (ids.reshape(n, chunk), *(b.reshape(n, chunk, *b.shape[1:]) for b in blocks))
+    zero = jnp.zeros(jax.eval_shape(one, *(x[0] for x in xs)).shape, jnp.float32)
+    (total, lost), _ = jax.lax.scan(step, (zero, zero), xs)
+    return total + lost
+
+
 @functools.partial(jax.jit, static_argnames=("vocab_size", "dense"))
 def _code_counts_p(codes: jax.Array, M: jax.Array, vocab_size: int, dense: bool = False) -> jax.Array:
     valid = M & (codes >= 0)

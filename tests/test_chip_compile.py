@@ -138,6 +138,30 @@ def test_ts_num_viz_program_compiles_without_a_scatter_or_a_sort(topo, shapes):
     assert compiled.memory_analysis().temp_size_in_bytes < 1.1 * ROWS * K * 5 + (64 << 20)
 
 
+def test_wide_segment_aggregate_compiles_to_a_contraction_and_one_sort_without_a_scatter(topo, shapes):
+    """``expedia_hotel.ts_inspect``'s daily grain (class 1,024; here at 4,194,304 x 16): the moments by
+    contraction of the values' bfloat16 parts, which the compiled program must still round with
+    ``reduce-precision`` (a cast to bfloat16 and back is dropped inside a fusion: PERF.md section 6,
+    PR 49), min, max and median from one sort of two operands, no scatter, and the planes built a chunk
+    at a time: nothing as long as the rows but the sort's keys and the scan's copy of the block."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from anovos_tpu.data_transformer.datetime import _segment_aggregate_jit
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    ids = jax.ShapeDtypeStruct((ROWS,), jnp.int32, sharding=one_chip)
+    valid = jax.ShapeDtypeStruct((ROWS,), jnp.bool_, sharding=one_chip)
+    compiled = _compile(_segment_aggregate_jit, ids, valid, shapes["X"], shapes["M"], nseg=1024, cp=False)
+    text = compiled.as_text()
+    assert " scatter(" not in text and "reduce-precision(" in text and " convolution(" in text
+    sorts = [line for line in text.split("\n") if " sort(" in line]
+    assert len(sorts) == 1 and "ts/segment_aggregate/wide/medians" in sorts[0], sorts
+    assert "ts/segment_aggregate/wide/moments" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * ROWS * K * 5 + (256 << 20)
+
+
 def test_dense_binned_histograms_compiles(shapes, monkeypatch):
     """_flat_counts with the TPU-only dense budget (1 << 30): at 4 M x 16 x
     10 the compare-and-reduce branch is taken, which no CPU test reaches."""

@@ -61,7 +61,7 @@ def test_the_selection_picks_the_sorts_medians_bit_for_bit(nseg, rows):
     assert (cnt[live] % 2 == 0).any() and (cnt[live] % 2 == 1).any() and cnt.max() < rows
     args = (jnp.asarray(ids), jnp.asarray(ok), jnp.asarray(V), jnp.asarray(cnt))
     by_count = np.asarray(jax.jit(dtt._select_medians, static_argnums=4)(*args, nseg))
-    by_sort = np.asarray(jax.jit(dtt._sort_medians, static_argnums=4)(*args, nseg))
+    by_sort = np.asarray(jax.jit(dtt._sort_picks, static_argnums=4)(*args, nseg)[2])
     assert by_count.shape == (k, nseg) and not np.isnan(by_count[~live]).any()  # nothing for jax_debug_nans
     same = by_count.view(np.int32) == by_sort.view(np.int32)
     same |= (by_count == 0) & (by_sort == 0)  # a zero's sign: the unstable sort leaves it to chance
@@ -80,10 +80,11 @@ def test_a_small_class_lowers_without_a_sort_or_a_scatter(nseg, sorts):
     ids, valid, V, Mv = _hard_block(4096, 3, nseg, seed=nseg)
     text = dtt._segment_aggregate_jit.lower(
         jnp.asarray(ids), jnp.asarray(valid), jnp.asarray(V), jnp.asarray(Mv), nseg=nseg).as_text()
-    assert ("stablehlo.sort" in text) == sorts and ("scatter" in text) == sorts
-    assert dtt.median_routes(3, nseg) == {
+    assert ("stablehlo.sort" in text) == sorts and "scatter" not in text  # no class scatters (PR 49)
+    assert dtt.aggregate_routes(4096, 3, nseg) == {
         "median_selects": 0 if sorts else 3, "median_sorts": 3 if sorts else 0,
-        "select_passes": 0 if sorts else dtt._SELECT_PASSES}
+        "select_passes": 0 if sorts else dtt._SELECT_PASSES,
+        "wide_segments": nseg if sorts else 0, "wide_cells": 3 * 4096 if sorts else 0}
 
 
 def _days_of_trips(days: int, rows: int = 900) -> pd.DataFrame:
@@ -111,6 +112,9 @@ def test_the_numeric_stage_row_says_how_its_medians_were_taken(days, selects, so
     assert row.args["cols"] == 3
     assert (row.args["median_selects"], row.args["median_sorts"]) == (selects, sorts)
     assert row.args["select_passes"] == dtt._SELECT_PASSES == 9
+    # the daily grain of years is the one wide class: its buckets, and the cells (padded rows x columns) it aggregates
+    assert (row.args["wide_segments"], row.args["wide_cells"]) == ((2048, 3 * row.args["rows"]) if sorts else (0, 0))
+    assert row.args["host_rows"] == 0
     hourly = pd.read_csv(tmp_path / "ts_num_hourly_pickup.csv")
     part = ta._DAYPART_LUT[frame["pickup"].dt.hour.to_numpy()]
     for (label, attribute), got in hourly.set_index(["bucket", "attribute"])["median"].items():
