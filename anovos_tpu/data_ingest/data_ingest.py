@@ -32,8 +32,9 @@ import pyarrow.parquet as pq
 
 from anovos_tpu.data_ingest import avro_io
 from anovos_tpu.data_ingest import guard
-from anovos_tpu.shared.host_pool import get_host_pool, record_units
+from anovos_tpu.shared.host_pool import UnitsRun, get_host_pool, record_units
 from anovos_tpu.shared.runtime import get_runtime
+from anovos_tpu.shared import table as _table
 from anovos_tpu.shared.table import (
     FETCH_PHASE, Column, Table, _host_to_column, _pad_to, arrow_typed_kind, arrow_typed_to_numpy,
     host_table_frame)
@@ -113,7 +114,9 @@ def shard_files_for_process(files: List[str]) -> List[str]:
 # parquet is 1.4-2.2 x 10^5 rows of the benchmark's tables, about where
 # ``shared/table.py`` starts to run a frame's column units side by side
 # (``_POOLED_COLUMNS_MIN_ROWS``; a shorter frame's arrays go to the device
-# by typed block on the calling thread).
+# by typed block on the calling thread).  Once the parts are decoded their
+# rows are known: the columns of the frame assembled from them are units of
+# the pool by that rule of rows itself (``_assemble_frames``).
 _POOLED_DECODE_MIN_BYTES = 1 << 23
 
 
@@ -303,25 +306,6 @@ def _arrow_typed(df: pd.DataFrame) -> List[tuple]:
     return [(c, kind) for c in df.columns if (kind := arrow_typed_kind(df[c].dtype))]
 
 
-def _convert_arrow_typed(df: pd.DataFrame) -> pd.DataFrame:
-    """The columns :func:`_keep_arrow_typed` left in Arrow, converted once
-    for the whole table, in Arrow and numpy (``table.arrow_typed_to_numpy``):
-    a decimal to float64 (``num``; ``_plain_to_host`` adds the exact wide
-    pair where f32 does not hold the value), a date to ``datetime64[s]`` at
-    midnight (``ts``, an ``other`` column as in the upstream; null: NaT).
-    One ``ingest/convert`` span a column (``kind``, ``rows``)."""
-    from anovos_tpu.obs import get_tracer
-
-    converted = {}
-    for c, kind in _arrow_typed(df):
-        with get_tracer().phase("ingest/convert", cat="io", kind=kind, rows=len(df)):
-            converted[c] = arrow_typed_to_numpy(df[c])
-    if not converted:
-        return df
-    # a new frame over the same arrays: assigning into the old one copies a column a time
-    return pd.DataFrame({c: converted.get(c, df[c]) for c in df.columns}, copy=False)
-
-
 def read_host_frame(files: List[str], file_type: str, cfg: dict) -> pd.DataFrame:
     """Host pandas frame from part files (shared by the single-process and
     multi-host loaders) — GUARDED: each part decodes under the quarantine/
@@ -336,7 +320,9 @@ def read_host_frame(files: List[str], file_type: str, cfg: dict) -> pd.DataFrame
     been read by then, which a loop would not have started).
     ``decode_workers`` (threads that decoded a part; 0 for the loop) and
     ``decode_wall_s`` (first start to last end) go on the row of the pass's
-    tree the call runs under."""
+    tree the call runs under, and beside them ``assemble_workers``,
+    ``assemble_wall_s`` and ``assemble_columns`` of the columns' units inside
+    the one ``ingest/assemble`` span (``_assemble_frames``)."""
     if file_type not in ("csv", "parquet", "avro", "json"):
         raise ValueError(f"unsupported file_type: {file_type}")
     from anovos_tpu.obs import get_tracer
@@ -367,38 +353,107 @@ def read_host_frame(files: List[str], file_type: str, cfg: dict) -> pd.DataFrame
         # how many columns arrive in their Arrow type and are converted here:
         # 0 says that this program converts and had nothing to convert
         sp.add(arrow_typed=len(_arrow_typed(frames[0][1])))
-        return _assemble_frames(frames, cfg, pol)
+        df, units = _assemble_frames(frames, cfg, pol)
+    # after the span: the counts belong on the row the span is a child of
+    record_units("assemble", units, columns=df.shape[1])
+    return df
 
 
-def _assemble_frames(frames: List, cfg: dict, pol) -> pd.DataFrame:
-    """One frame from the decoded parts: schemas reconciled, parts
-    concatenated, ``inferSchema`` re-coercion, hostile values sanitized."""
+def _assemble_frames(frames: List, cfg: dict, pol) -> Tuple[pd.DataFrame, UnitsRun]:
+    """One frame from the decoded parts: schemas reconciled on this thread
+    (cheap where they agree, order-dependent where they do not), then the
+    frame a column at a time (:func:`_assemble_column`: no column's work
+    reads another column), and the frame built once over the finished
+    columns' arrays.  The columns of a frame of ``_POOLED_COLUMNS_MIN_ROWS``
+    rows or more (the rule of the uploads that follow) are units of the host
+    pool side by side, a shorter frame's run here one after the other: the
+    same function either way.  Also how the units ran, for the caller's
+    ``assemble_workers`` / ``assemble_wall_s``."""
     aligned = guard.reconcile_frames(frames, pol)
-    df = aligned[0] if len(aligned) == 1 else pd.concat(aligned, ignore_index=True)
-    df = _convert_arrow_typed(df)
-    if str(cfg.get("inferSchema", True)).lower() in ("true", "1", "none"):
+    infer = str(cfg.get("inferSchema", True)).lower() in ("true", "1", "none")
+    names = list(aligned[0].columns)
+    rows = sum(len(part) for part in aligned)
+    # the columns' views are taken here: a part frame is shared by every unit
+    parts = {c: [part[c] for part in aligned] for c in names}
+    ran = get_host_pool().run(
+        lambda c: _assemble_column(c, parts[c], infer, pol), names,
+        side_by_side=rows >= _table._POOLED_COLUMNS_MIN_ROWS)
+    index = aligned[0].index if len(aligned) == 1 else pd.RangeIndex(rows)
+    # an object column goes in as a Series that states its dtype: of a bare
+    # object array the constructor makes ``str`` where it holds only strings
+    columns = {c: pd.Series(v, index=index, dtype=object, copy=False) if v.dtype == object else v
+               for c, v in zip(names, ran.results)}
+    return pd.DataFrame(columns, index=index, copy=False), ran
+
+
+def _column_array(s: pd.Series):
+    """A Series' values as the frame is built over them: the ndarray itself
+    for a numpy dtype (the frame's constructor scans a wrapped one for
+    nulls), the extension array for any other."""
+    return np.asarray(s.array) if isinstance(s.dtype, np.dtype) else s.array
+
+
+def _join_parts(parts: List[pd.Series]):
+    """What ``pd.concat`` of the part frames makes of one column, as its
+    array (:func:`_column_array`): where the parts agree on the dtype (the
+    common case) by the call pandas itself ends in, without a Series, an
+    Index and a name's check a part on the way (0.3 ms a column, under the
+    GIL, which is the whole assembly of a short frame): a numpy column one
+    array of the final length, an extension column its type's own join (a
+    nullable integer its values and mask, an Arrow-backed one, strings or a
+    decimal or date not yet converted, its chunks).  Parts that drifted
+    apart take ``pd.concat`` and its promotion rules."""
+    dtype = parts[0].dtype
+    if len(parts) == 1:
+        return _column_array(parts[0])
+    if any(part.dtype != dtype for part in parts):
+        return _column_array(pd.concat(parts, ignore_index=True))
+    arrays = [_column_array(part) for part in parts]
+    if isinstance(dtype, np.dtype):
+        return np.concatenate(arrays)
+    return type(arrays[0])._concat_same_type(arrays)
+
+
+def _assemble_column(name, parts: List[pd.Series], infer: bool, pol):
+    """One column of the frame a read returns, from the column's part of
+    every part frame, as the array the frame is built over."""
+    values = _join_parts(parts)
+    kind = arrow_typed_kind(values.dtype)
+    if kind:
+        # what _keep_arrow_typed left in Arrow, converted once for the whole
+        # column, in Arrow and numpy: a decimal to float64 (``num``;
+        # ``_plain_to_host`` adds the exact wide pair where f32 does not hold
+        # the value), a date to ``datetime64[s]`` at midnight (``ts``, an
+        # ``other`` column as in the upstream; null: NaT)
+        from anovos_tpu.obs import get_tracer
+
+        with get_tracer().phase("ingest/convert", cat="io", kind=kind, rows=len(values)):
+            values = arrow_typed_to_numpy(pd.Series(values, copy=False))
+    if infer and (values.dtype == object or str(values.dtype) in ("string", "str")):
         # whole-dataset schema inference (Spark inferSchema parity): per-part
         # readers can disagree (an all-null part decodes as string/null), so
-        # re-coerce object columns that are numeric across ALL parts.
-        for c in df.columns:
-            if df[c].dtype == object or str(df[c].dtype) in ("string", "str"):
-                nonnull = df[c].notna()
-                if nonnull.any():
-                    # cheap pre-check: a genuinely-string column (the common
-                    # case) is rejected on a small head sample instead of
-                    # paying a full-column to_numeric per string column
-                    head = df[c][nonnull].iloc[:1024]
-                    if pd.to_numeric(head, errors="coerce").isna().any():
-                        continue
-                    coerced = pd.to_numeric(df[c], errors="coerce")
-                    if coerced[nonnull].notna().all():
-                        df[c] = coerced
-                else:
-                    # all-null column → numeric NaN column
-                    df[c] = pd.to_numeric(df[c], errors="coerce")
-    # hostile-value sanitization LAST (after inferSchema may have produced
-    # new float columns): downstream device kernels never see inf/overflow
-    return guard.sanitize_frame(df, pol)
+        # re-coerce an object column that is numeric across ALL parts
+        s = pd.Series(values, dtype=values.dtype, copy=False)
+        nonnull = s.notna()
+        if not nonnull.any():
+            values = _column_array(pd.to_numeric(s, errors="coerce"))  # all-null column → numeric NaN column
+        # cheap pre-check: a genuinely-string column (the common case) is
+        # rejected on a small head sample instead of paying a full-column
+        # to_numeric per string column
+        elif not pd.to_numeric(s[nonnull].iloc[:1024], errors="coerce").isna().any():
+            coerced = pd.to_numeric(s, errors="coerce")
+            if coerced[nonnull].notna().all():
+                values = _column_array(coerced)
+    # hostile-value sanitization LAST (after inferSchema may have produced a
+    # new float column): downstream device kernels never see inf/overflow
+    if values.dtype.kind == "f":
+        joined_as_they_are = all(part.dtype == values.dtype for part in parts)
+        fixed = guard.sanitize_values(
+            name, values if isinstance(values, np.ndarray) else pd.Series(values, copy=False).to_numpy(), pol,
+            gate_over=[part.to_numpy() for part in parts] if joined_as_they_are else None)
+        if fixed is not None:
+            return fixed
+    return values
 
 
 def write_dataset(

@@ -1,5 +1,7 @@
 """The one bounded pool of host threads that ingest's independent units run
-on: the part files of a read, the columns of a table being built (a string
+on: the part files of a read, the columns of the frame assembled from them
+(a column's parts joined, its Arrow-typed conversion, ``inferSchema``'s look
+at it, its sanitization), the columns of a table being built (a string
 column's encode, every column's conversion to the device dtypes, its padding
 and its ``device_put``), the buckets of one long column, the columns of a
 table being fetched.  Almost all of a unit's seconds are in Arrow's C kernels
