@@ -24,4 +24,11 @@ the same YAML top-level keys):
 - ``feature_recommender`` / ``feature_store``
 """
 
-from anovos_tpu.version import __version__  # noqa: F401
+import time as _time
+
+# the package's first statement: what a process did before it is the
+# manifest's ``process/interpreter``, what it imports from here to the end of
+# ``workflow``'s imports is ``process/import`` (obs.manifest.process_section)
+IMPORT_STARTED = _time.perf_counter()
+
+from anovos_tpu.version import __version__  # noqa: E402,F401

@@ -22,10 +22,11 @@ Three cooperating, stdlib-only pieces:
   executor mode, critical path, per-node spans, metrics snapshot);
   the benchmark's harness and the HTML report read it instead of
   re-deriving timings.
-* **Compile census** (``obs.compile_census``): a ``jax.monitoring``
-  listener counting every real XLA backend compile with per-program
-  attribution; the per-run delta lands in the manifest and
-  ``tools/compile_census.py`` renders / CI-gates it.
+* **Compile census** (``obs.compile_census``): ``jax.monitoring``
+  listeners following every program's way to the device (trace, lowering,
+  load from the persistent cache or build) with per-program attribution;
+  the per-run delta lands in the manifest, each stage is a row of the
+  pass's phase tree, and ``tools/compile_census.py`` renders / CI-gates it.
 * **Device-time attribution** (``obs.devprof``): per-scheduler-node
   split of wall into device / dispatch / transfer / host via boundary
   drain probes, ``timed()`` dispatch brackets, and transfer brackets at
@@ -54,6 +55,7 @@ from anovos_tpu.obs.manifest import (
     build_manifest,
     config_hash,
     load_manifest,
+    process_section,
     stable_view,
     write_manifest,
 )
@@ -91,6 +93,7 @@ __all__ = [
     "build_manifest",
     "config_hash",
     "load_manifest",
+    "process_section",
     "stable_view",
     "write_manifest",
     "Counter",

@@ -72,7 +72,6 @@ logger = logging.getLogger("anovos_tpu.obs.devprof")
 __all__ = [
     "enabled",
     "reset",
-    "current_node",
     "current_frame",
     "under",
     "node_bracket",
@@ -495,15 +494,6 @@ def under(frame):
         yield
     finally:
         _TL.frame = None
-
-
-def current_node() -> "Optional[str]":
-    """Name of the scheduler node executing on THIS thread (None outside a
-    node bracket, or when devprof is disabled).  The compile census stamps
-    each backend-compile event with it, so a fused block's programs are
-    attributable to the node that compiled them."""
-    fr = getattr(_TL, "frame", None)
-    return fr.name if fr is not None else None
 
 
 def results() -> Dict[str, dict]:

@@ -20,7 +20,8 @@ from __future__ import annotations
 import hashlib
 import json
 import os
-from typing import Optional
+import time
+from typing import Callable, Optional
 
 # 2: ``phases`` and ``clock``
 MANIFEST_VERSION = 2
@@ -33,6 +34,7 @@ __all__ = [
     "write_manifest",
     "load_manifest",
     "stable_view",
+    "process_section",
 ]
 
 
@@ -113,10 +115,11 @@ def build_manifest(
         "block_seconds": {k: round(v, 4) for k, v in sorted((block_times or {}).items())},
         "metrics": metrics_snapshot,
         # per-run XLA compile census (obs.compile_census delta): compile
-        # count, distinct program signatures, distinct kernels, and the
-        # top programs by compile wall — the record the benchmark's
-        # fresh_programs / window_compiles and the tools/compile_census.py
-        # gate read
+        # count, distinct program signatures, distinct kernels, the cache's
+        # hits against real builds, the seconds of each stage, and the
+        # top programs — the record the benchmark's fresh_programs /
+        # window_compiles / fresh_built_programs and the
+        # tools/compile_census.py gate read
         "compile_census": compile_census,
         # incremental-recompute record (anovos_tpu.cache): store root,
         # per-run hits/misses/restore wall, resumed frontier — present only
@@ -146,11 +149,54 @@ def build_manifest(
         # ``workflow`` fills both in just before it writes the file
         "phases": None,
         "clock": None,
+        # which pass of its process this was and, on the first, what the
+        # process did before it (``process_section``); filled in with the two
+        # above
+        "process": None,
         "trace_path": trace_path,
         "backend": backend,
         "generated_unix": round(
             _time.time() if generated_unix is None else generated_unix, 3),
     }
+
+
+def _process_born() -> Optional[float]:
+    """The process's start as a ``time.perf_counter()`` reading: field 22 of
+    ``/proc/self/stat`` (clock ticks since boot) against ``CLOCK_BOOTTIME``.
+    None where ``/proc`` cannot be read."""
+    try:
+        with open("/proc/self/stat") as f:
+            # the command's name (field 2) may hold blanks: count from its closing bracket
+            ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+        age = time.clock_gettime(time.CLOCK_BOOTTIME) - ticks / os.sysconf("SC_CLK_TCK")
+        return time.perf_counter() - age
+    except (OSError, ValueError, IndexError, AttributeError):
+        return None
+
+
+def process_section(pass_index: int, import_started: float, import_done: float,
+                    seconds_at: Callable[[float], Optional[float]]) -> dict:
+    """The manifest's ``process``: ``pass_index`` (0 for the first pass the
+    process opened) and, on that first pass, ``rows`` of the phases' shape
+    (``name``, ``start_s``, ``end_s``; seconds from the root span's start, so
+    negative) for what the process did before it: ``process/interpreter``
+    (its start to the package's first statement: the interpreter and whatever
+    the entry script imported first; left out where ``/proc`` cannot be
+    read), ``process/import`` (from there to the end of ``workflow``'s
+    imports) and ``process/caller`` (from there to the root span's start: the
+    caller's own work).  ``import_started`` and ``import_done`` are
+    ``time.perf_counter()`` readings, ``seconds_at`` brings one onto the
+    pass's clock (``Tracer.seconds_at``)."""
+    if pass_index:
+        return {"pass_index": pass_index}
+    born = _process_born()
+    edges = [("process/interpreter", born, import_started),
+             ("process/import", import_started, import_done),
+             ("process/caller", import_done, None)]
+    return {"pass_index": 0,
+            "rows": [{"name": name, "start_s": seconds_at(start),
+                      "end_s": 0.0 if end is None else seconds_at(end)}
+                     for name, start, end in edges if start is not None]}
 
 
 def write_manifest(manifest: dict, path: str) -> str:
@@ -219,6 +265,8 @@ _VOLATILE_TOP_FIELDS = (
     # seconds, thread names and a per-pass id
     "phases",
     "clock",
+    # the process's history, not the run's identity
+    "process",
 )
 
 

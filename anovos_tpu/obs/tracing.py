@@ -23,7 +23,8 @@ One pass of ``workflow.run`` is one ``run_pass()``: a fresh timeline, a
 ``phase()`` spans opened under it (``config``, ``ingest``, ``dag``, ...) form
 the pass's phase tree; the scheduler's node spans of the pass are rows of it
 too (under ``dag``, each on its worker's thread), and so is a ``phase()``
-opened inside a node (``place/d2d``).  The tree has two sinks besides the
+opened inside a node (``place/d2d``) and a span reported once it was over
+(``finished()``: a compile stage).  The tree has two sinks besides the
 Chrome trace: the
 run manifest's ``phases`` (``Tracer.phases()``), and, while a profiler
 session is on (``annotate_with``), a ``jax.profiler.TraceAnnotation`` per
@@ -114,13 +115,14 @@ class OpenSpan:
     ``Tracer.current()`` returns, so that counts can be put on the span at
     the boundary where the work happens."""
 
-    __slots__ = ("name", "cat", "attrs", "tree")
+    __slots__ = ("name", "cat", "attrs", "tree", "start_ns")
 
     def __init__(self, name: str, cat: str, attrs: dict, tree: bool = False):
         self.name = name
         self.cat = cat
         self.attrs = attrs
         self.tree = tree  # a row of the pass's phase tree (Tracer.phases)
+        self.start_ns = 0  # perf_counter_ns at its start, once ``span()`` has taken it
 
     def add(self, **counts) -> None:
         """Add each count to the span's attribute of that name."""
@@ -210,7 +212,7 @@ class Tracer:
         usage0 = (resource.getrusage(resource.RUSAGE_SELF)
                   if cat == "phase" and attrs.get("parent") in (None, ROOT_PHASE) else None)
         cpu0 = time.thread_time_ns() if tree else 0
-        t0 = time.perf_counter_ns()
+        t0 = stack[-1].start_ns = time.perf_counter_ns()
         try:
             yield stack[-1]
         except BaseException as e:
@@ -247,6 +249,24 @@ class Tracer:
             if row is not self._stack()[-1]:
                 attrs["parent"] = row.name
         return self.span(name, cat=cat, **attrs)
+
+    def finished(self, name: str, seconds: float, cat: str = "anovos", **attrs) -> None:
+        """Record a span that ended just now on THIS thread and took
+        ``seconds``: what is learned only after the fact (a listener told how
+        long a compile stage took).  Filed as ``phase()`` would have filed it:
+        a row of the pass's tree under the innermost row open here (never
+        starting before that row), an ordinary span anywhere else.  It
+        carries no usage and no ``TraceAnnotation``."""
+        end = time.perf_counter_ns()
+        start = end - max(int(seconds * 1e9), 0)
+        row = self.tree_row()
+        if row is not None:
+            cat, attrs["parent"], start = "phase", row.name, max(start, row.start_ns)
+        elif self._stack():
+            attrs.setdefault("parent", self._stack()[-1].name)
+        th = threading.current_thread()
+        self._record(Span(name, cat, start - self._epoch_ns, end - start,
+                          th.name, th.ident or 0, attrs, self.run_id), row is not None)
 
     def tree_row(self) -> Optional[OpenSpan]:
         """The innermost span open on THIS thread that is a row of the pass's
@@ -401,14 +421,17 @@ class Tracer:
         rows.sort(key=lambda r: (r["start_s"], -r["end_s"]))
         return rows
 
-    def seconds_at(self, monotonic: float) -> Optional[float]:
-        """A ``time.monotonic()`` reading (the scheduler's stamps) in seconds
-        from the root span's start, as ``phases()`` counts them."""
+    def seconds_at(self, reading: float, perf_counter: bool = False) -> Optional[float]:
+        """A ``time.monotonic()`` reading (the scheduler's stamps), or with
+        ``perf_counter`` one of ``time.perf_counter()`` (the spans' own clock:
+        what a process noted before its first pass), in seconds from the root
+        span's start, as ``phases()`` counts them."""
         with self._lock:
             root = self._root
             if root is None:
                 return None
-            return round(monotonic - self._epoch_monotonic - root.start_ns / 1e9, 6)
+            epoch = self._epoch_ns / 1e9 if perf_counter else self._epoch_monotonic
+            return round(reading - epoch - root.start_ns / 1e9, 6)
 
     def drain(self) -> List[Span]:
         """Atomically copy-and-clear the ring WITHOUT re-basing the epoch

@@ -196,29 +196,32 @@ DEFAULT_COMPILE_CACHE_DIR = os.path.join(
     ".jax_cache")
 
 
-def _stamp_unstamped_cache_entries() -> None:
+def _stamp_unstamped_cache_entries() -> int:
     """Where the compile cache has a size limit (``jax_compilation_cache_max_size``;
     the chip machine sets ``JAX_COMPILATION_CACHE_MAX_SIZE``) JAX keeps an
     ``-atime`` file beside every ``-cache`` entry and reads them all before
     each write.  An entry without one, left by a process that used the
     directory without the limit, makes every write fail, and every later
     process compiles everything again (PERF.md, PR 24 and PR 25).  Such
-    entries get the stamp of now and age out like the rest."""
+    entries get the stamp of now and age out like the rest.  Returns the
+    ``-cache`` entries it listed (0 where it lists none)."""
     cache_dir = jax.config.jax_compilation_cache_dir
     if not cache_dir or "://" in cache_dir or jax.config.jax_compilation_cache_max_size == -1:
-        return
+        return 0
     try:
         names = set(os.listdir(cache_dir))
     except OSError:
-        return  # not there yet: JAX makes it
+        return 0  # not there yet: JAX makes it
     stamp = time.time_ns().to_bytes(8, "little")
-    for name in names:
-        if name.endswith("-cache") and name[: -len("cache")] + "atime" not in names:
+    entries = [name for name in names if name.endswith("-cache")]
+    for name in entries:
+        if name[: -len("cache")] + "atime" not in names:
             try:
                 with open(os.path.join(cache_dir, name[: -len("cache")] + "atime"), "wb") as f:
                     f.write(stamp)
             except OSError:
                 pass
+    return len(entries)
 
 
 @contextmanager
@@ -413,47 +416,53 @@ def init_runtime(
     axis.  ``distributed=True`` calls ``jax.distributed.initialize()`` first
     (multi-host over DCN; env-driven coordinator discovery).
     """
-    global _RUNTIME
-    # compile census from the first device touch: every XLA backend compile
-    # in this process is counted with per-program attribution (obs
-    # subsystem; the run manifest embeds the per-run delta)
-    try:
-        from anovos_tpu.obs.compile_census import install as _install_census
+    global _RUNTIME, _RUNTIME_GEN
+    from anovos_tpu.obs.tracing import get_tracer
 
-        _install_census()
-    except Exception:
-        pass
-    # TPU MXU's default f32 matmul precision is bf16 inputs — catastrophic
-    # for the quadratic-expansion distance/covariance kernels (squared lat/lon
-    # magnitudes produced within-eps errors ~800x eps^2).  A stats framework
-    # needs true-f32 matmuls; ANOVOS_MATMUL_PRECISION overrides (e.g. to
-    # "default" for throughput-over-accuracy experiments).
-    jax.config.update(
-        "jax_default_matmul_precision", os.environ.get("ANOVOS_MATMUL_PRECISION", "highest")
-    )
-    # persistent XLA compilation cache, on by default: pipeline stages
-    # produce many distinct table shapes, and compilation dominates
-    # cold-run wall time.  Where JAX_COMPILATION_CACHE_DIR is set JAX has
-    # already read it and no directory is set in code.
-    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
-        jax.config.update("jax_compilation_cache_dir", DEFAULT_COMPILE_CACHE_DIR)
-    # The pipeline is ~200 SMALL programs, so the threshold must sit well
-    # below jax's 1s default.
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.02)
-    _stamp_unstamped_cache_entries()
-    if distributed and jax.process_count() == 1 and "JAX_COORDINATOR_ADDRESS" in os.environ:
-        jax.distributed.initialize()
-    devs = list(devices if devices is not None else jax.devices())
-    if mesh_shape is None:
-        mesh_shape = (len(devs), 1)
-    n_data, n_model = mesh_shape
-    if n_data * n_model != len(devs):
-        raise ValueError(f"mesh_shape {mesh_shape} != device count {len(devs)}")
-    dev_grid = np.array(devs).reshape(n_data, n_model)
-    mesh = Mesh(dev_grid, (DATA_AXIS, MODEL_AXIS))
-    global _RUNTIME_GEN
-    _RUNTIME_GEN += 1
-    _RUNTIME = Runtime(mesh=mesh)
+    # a phase of the pass that first needs the runtime (an ordinary span
+    # outside any): the backend's start where nothing touched it before, the
+    # listing of the cache directory, the mesh
+    with get_tracer().phase("runtime/init", cat="runtime") as span:
+        # compile census from the first device touch: every XLA backend compile
+        # in this process is counted with per-program attribution (obs
+        # subsystem; the run manifest embeds the per-run delta)
+        try:
+            from anovos_tpu.obs.compile_census import install as _install_census
+
+            _install_census()
+        except Exception:
+            pass
+        # TPU MXU's default f32 matmul precision is bf16 inputs — catastrophic
+        # for the quadratic-expansion distance/covariance kernels (squared lat/lon
+        # magnitudes produced within-eps errors ~800x eps^2).  A stats framework
+        # needs true-f32 matmuls; ANOVOS_MATMUL_PRECISION overrides (e.g. to
+        # "default" for throughput-over-accuracy experiments).
+        jax.config.update(
+            "jax_default_matmul_precision", os.environ.get("ANOVOS_MATMUL_PRECISION", "highest")
+        )
+        # persistent XLA compilation cache, on by default: pipeline stages
+        # produce many distinct table shapes, and compilation dominates
+        # cold-run wall time.  Where JAX_COMPILATION_CACHE_DIR is set JAX has
+        # already read it and no directory is set in code.
+        if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+            jax.config.update("jax_compilation_cache_dir", DEFAULT_COMPILE_CACHE_DIR)
+        # The pipeline is ~200 SMALL programs, so the threshold must sit well
+        # below jax's 1s default.
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.02)
+        span.add(cache_entries=_stamp_unstamped_cache_entries())
+        if distributed and jax.process_count() == 1 and "JAX_COORDINATOR_ADDRESS" in os.environ:
+            jax.distributed.initialize()
+        devs = list(devices if devices is not None else jax.devices())
+        span.add(devices=len(devs))
+        if mesh_shape is None:
+            mesh_shape = (len(devs), 1)
+        n_data, n_model = mesh_shape
+        if n_data * n_model != len(devs):
+            raise ValueError(f"mesh_shape {mesh_shape} != device count {len(devs)}")
+        dev_grid = np.array(devs).reshape(n_data, n_model)
+        mesh = Mesh(dev_grid, (DATA_AXIS, MODEL_AXIS))
+        _RUNTIME_GEN += 1
+        _RUNTIME = Runtime(mesh=mesh)
     return _RUNTIME
 
 

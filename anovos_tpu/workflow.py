@@ -44,6 +44,7 @@ from typing import Optional
 import pandas as pd
 import yaml
 
+from anovos_tpu import IMPORT_STARTED
 from anovos_tpu.data_ingest import data_ingest
 from anovos_tpu.data_ingest import guard as ingest_guard
 from anovos_tpu.data_ingest.ts_auto_detection import ts_preprocess
@@ -79,6 +80,7 @@ from anovos_tpu.obs import (
     get_metrics,
     get_tracer,
     maybe_rotator,
+    process_section,
     record_cache_stats,
     record_device_memory,
     telemetry,
@@ -92,6 +94,12 @@ from anovos_tpu.resilience import failover as res_failover
 from anovos_tpu.resilience import policy as res_policy
 from anovos_tpu.shared.artifact_store import AsyncArtifactWriter
 from anovos_tpu.shared.table import Table
+
+# the imports are done: the end of the manifest's ``process/import``
+_IMPORTS_DONE = time.perf_counter()
+# passes this process has opened; the first one's manifest tells what the
+# process did before it (``process``)
+_PASSES = 0
 
 logger = logging.getLogger("anovos_tpu.workflow")
 
@@ -612,13 +620,15 @@ def _pass():
     pull and a profiler's start and export lie inside; ``main()`` called
     directly opens its own.  Once the root has ended the run manifest is
     written, last of all, with the pass's phases and the scheduler's origin
-    on their clock (so the file's own write is on no span)."""
-    global LAST_MANIFEST_PATH, _PASS_MANIFEST
+    on their clock (so the file's own write is on no span), and with what the
+    process did before its first pass (``process``)."""
+    global LAST_MANIFEST_PATH, _PASS_MANIFEST, _PASSES
     tracer = get_tracer()
     if tracer.in_pass():  # main() under run(): the root is run()'s
         yield
         return
     _PASS_MANIFEST = None
+    pass_index, _PASSES = _PASSES, _PASSES + 1
     try:
         with tracer.run_pass():
             yield
@@ -631,6 +641,9 @@ def _pass():
                 "run_id": tracer.run_id,
                 "scheduler_origin_s": None if origin is None else tracer.seconds_at(origin),
             }
+            manifest["process"] = process_section(
+                pass_index, IMPORT_STARTED, _IMPORTS_DONE,
+                lambda t: tracer.seconds_at(t, perf_counter=True))
             write_manifest(manifest, path)
             LAST_MANIFEST_PATH = path
             try:  # remote run_types publish the manifest next to the staged stats

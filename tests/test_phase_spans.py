@@ -7,6 +7,7 @@ import gc
 import glob
 import json
 import os
+import subprocess
 import sys
 import threading
 import time
@@ -110,6 +111,8 @@ def test_manifest_holds_every_phase_of_the_pass(stats_pass):
     for r in man["phases"]:
         assert set(r) == {"name", "parent", "start_s", "end_s", "thread", "counts", "usage"}
         assert 0.0 <= r["start_s"] <= r["end_s"]
+        if r["name"].startswith("compile/"):
+            continue  # a program's stage: on the thread of whichever row waited for it (the fresh process, below)
         # the scheduler's nodes are rows too, each on its worker's thread under ``dag``,
         # and so is what a node opens: the describe under the node that computes it
         in_node = r["parent"] == "dag" or r["parent"] in man["scheduler"]["nodes"] or r["parent"] == "describe"
@@ -244,6 +247,22 @@ def test_stable_view_drops_phases_and_clock(stats_pass):
     assert view["manifest_version"] == 2 and "scheduler" in view
 
 
+def test_only_the_first_pass_of_a_process_tells_what_came_before_it(stats_pass, config_path, work):
+    """The suite's worker may have run passes before this module's: a pass
+    says which of its process it was, and the first alone has rows."""
+    process = stats_pass["manifest"]["process"]
+    assert set(process) == ({"pass_index", "rows"} if process["pass_index"] == 0 else {"pass_index"})
+    assert "process" not in obs.stable_view(stats_pass["manifest"])
+    cwd = os.getcwd()
+    os.chdir(work)
+    try:
+        workflow.run(config_path, "local")
+    finally:
+        os.chdir(cwd)
+    later = obs.load_manifest(workflow.LAST_MANIFEST_PATH)["process"]
+    assert set(later) == {"pass_index"} and later["pass_index"] > process["pass_index"]
+
+
 def test_main_called_directly_is_a_pass_of_its_own(stats_pass, config_path, work):
     with open(config_path) as f:
         cfg = yaml.safe_load(f)
@@ -277,6 +296,99 @@ def test_a_finished_pass_keeps_no_device_array(stats_pass, config_path, work):
         gc.enable()
         os.chdir(cwd)
     assert not left, [(a.shape, a.dtype) for a in left]
+
+
+# -------------------------------------------------------- a fresh process ----
+_TWO_PASSES = """
+import os, shutil, sys
+from anovos_tpu import workflow
+os.chdir(sys.argv[2])
+for i in range(2):
+    workflow.run(sys.argv[1], "local")
+    shutil.copy(workflow.LAST_MANIFEST_PATH, os.path.join(sys.argv[2], f"manifest_{i}.json"))
+"""
+COMPILE_STAGES = {"compile/trace", "compile/lower", "compile/load", "compile/build"}
+
+
+@pytest.fixture(scope="module")
+def fresh_process(config_path, work):
+    """Two passes of the 2,000-row ``stats`` pipeline in a process of their
+    own, with a compile cache of its own and nothing in it: their manifests."""
+    out = work / "fresh_process"
+    os.makedirs(out)
+    env = {**os.environ, "JAX_PLATFORMS": "cpu", "PYTHONPATH": ROOT,
+           "XLA_FLAGS": "--xla_force_host_platform_device_count=8",
+           "JAX_COMPILATION_CACHE_DIR": str(out / "jax_cache")}
+    done = subprocess.run([sys.executable, "-c", _TWO_PASSES, config_path, str(out)], env=env,
+                          text=True, capture_output=True, timeout=600)
+    assert done.returncode == 0, done.stderr[-3000:]
+    return [obs.load_manifest(str(out / f"manifest_{i}.json")) for i in range(2)]
+
+
+def _holder(rows, r):
+    """The row of the tree that ``r`` names as its parent: of that name, around it in time."""
+    found = [p for p in rows if p["name"] == r["parent"] and p is not r
+             and p["start_s"] <= r["start_s"] and r["end_s"] <= p["end_s"]]
+    return found[0] if found else None
+
+
+def test_a_first_pass_names_the_stages_of_its_programs_under_the_rows_that_waited(fresh_process):
+    first = fresh_process[0]
+    rows = first["phases"]
+    stages = [r for r in rows if r["name"] in COMPILE_STAGES]
+    assert {r["name"] for r in stages} == {"compile/trace", "compile/lower", "compile/build"}  # an empty cache
+    assert not [r for r in rows if r["name"].startswith("compile/") and r["name"] not in COMPILE_STAGES]
+    for r in stages:
+        assert set(r) == {"name", "parent", "start_s", "end_s", "thread", "counts", "usage"}
+        assert r["counts"] == {} and r["usage"] == {}  # which program: the census; a finished span used nothing
+        holder = _holder(rows, r)
+        assert holder is not None, f"{r['name']} at {r['start_s']} lies in no {r['parent']!r}"
+        assert holder["thread"] == r["thread"] and not holder["name"].startswith("compile/")
+    census = first["compile_census"]
+    assert len([r for r in stages if r["name"] == "compile/build"]) == census["built_programs"] \
+        == census["compiles_total"] > 5
+    assert census["cache_hits"] == 0 and census["cache_requests"] == census["compiles_total"]
+    for stage in ("trace", "lower", "build"):  # the rows carry the seconds the census sums
+        seconds = sum(r["end_s"] - r["start_s"] for r in stages if r["name"] == "compile/" + stage)
+        assert seconds == pytest.approx(census[f"{stage}_seconds_total"], abs=0.01 + 1e-5 * len(stages))
+    # most of them under the node that computed the describe, and the node's name is in the census's table
+    nodes = {n for p in census["programs"] for n in p["nodes"]}
+    assert nodes and nodes <= set(first["scheduler"]["nodes"])
+    assert any(r["parent"].startswith("describe/") for r in stages)
+
+
+def test_a_first_pass_holds_the_runtimes_start(fresh_process):
+    (init,) = [r for r in fresh_process[0]["phases"] if r["name"] == "runtime/init"]
+    assert init["counts"] == {"devices": 8, "cache_entries": 0}  # no size limit: nothing is listed
+    assert init["thread"] == "MainThread" and _holder(fresh_process[0]["phases"], init) is not None
+    assert not [r for r in fresh_process[1]["phases"] if r["name"] == "runtime/init"]
+
+
+def test_a_first_pass_tells_what_the_process_did_before_it(fresh_process):
+    process = fresh_process[0]["process"]
+    assert set(process) == {"pass_index", "rows"} and process["pass_index"] == 0
+    rows = process["rows"]
+    assert [r["name"] for r in rows] == ["process/interpreter", "process/import", "process/caller"]
+    assert all(set(r) == {"name", "start_s", "end_s"} for r in rows)
+    assert all(a["end_s"] == b["start_s"] for a, b in zip(rows, rows[1:])) and rows[-1]["end_s"] == 0.0
+    interpreter, imported, caller = (r["end_s"] - r["start_s"] for r in rows)
+    assert imported > 0.5  # jax, pandas and the package
+    assert 0.0 <= caller < imported and 0.0 <= interpreter < 5.0  # a script of four lines; clock ticks of 10 ms
+
+
+def test_the_second_pass_of_the_process_is_no_first_pass(fresh_process):
+    second = fresh_process[1]
+    assert second["process"] == {"pass_index": 1}
+    assert second["clock"]["run_id"] != fresh_process[0]["clock"]["run_id"]
+    rows, census = second["phases"], second["compile_census"]
+    # on the CPU the ``stats`` race of PERF.md section 7 item 14 may compile; whatever
+    # does lies inside a node that the census names too
+    nodes = {n for p in census["programs"] for n in p["nodes"]}
+    for r in (r for r in rows if r["name"] in COMPILE_STAGES):
+        assert any(p["name"] in nodes and p["thread"] == r["thread"]
+                   and p["start_s"] <= r["start_s"] and r["end_s"] <= p["end_s"] for p in rows), r
+    assert len([r for r in rows if r["name"] in ("compile/load", "compile/build")]) == census["compiles_total"]
+    assert census["compiles_total"] < fresh_process[0]["compile_census"]["compiles_total"]
 
 
 # ---------------------------------------------------------------- tracer ----
@@ -329,6 +441,46 @@ def test_phases_survive_a_drained_ring():
             pass
         assert [sp.name for sp in tr.drain()] == ["ingest"]
     assert [r["name"] for r in tr.phases()] == ["run", "ingest"]
+
+
+def test_a_finished_span_is_filed_as_a_phase_would_have_been():
+    """What a listener learns after the fact: a row under the innermost row
+    open on the thread, starting no earlier than it; an ordinary span anywhere else."""
+    tr = obs.Tracer(buffer=100)
+    tr.finished("compile/build", 0.002, cat="compile")  # outside any pass
+    assert [(sp.name, sp.cat, sp.args) for sp in tr.snapshot()] == [("compile/build", "compile", {})]
+    with tr.run_pass():
+        with tr.span("a_node", cat="node"):
+            time.sleep(0.003)
+            with tr.span("ops.table_describe", cat="op"):  # no row: passed over
+                tr.finished("compile/trace", 0.001, cat="compile")
+            tr.finished("compile/build", 3600.0, cat="compile")  # longer than its row has been open
+        seen = []
+        worker = threading.Thread(target=lambda: (tr.finished("compile/load", 0.001, cat="compile"),
+                                                  seen.extend(tr.snapshot())))
+        worker.start()  # a thread with no row open: not of the tree
+        worker.join()
+    rows = {r["name"]: r for r in tr.phases()}
+    assert sorted(rows) == ["a_node", "compile/build", "compile/trace", "run"]
+    node, trace, build = rows["a_node"], rows["compile/trace"], rows["compile/build"]
+    assert trace["parent"] == build["parent"] == "a_node" and trace["thread"] == node["thread"]
+    assert trace["end_s"] - trace["start_s"] == pytest.approx(0.001, abs=2e-6)
+    assert node["start_s"] == build["start_s"] <= trace["start_s"] and build["end_s"] <= node["end_s"]
+    assert trace["counts"] == build["counts"] == trace["usage"] == build["usage"] == {}
+    assert [(sp.cat, sp.args) for sp in seen if sp.name == "compile/load"] == [("compile", {})]
+    cats = {sp.name: sp.cat for sp in tr.snapshot()}
+    assert cats["compile/trace"] == cats["compile/build"] == "phase" and cats["compile/load"] == "compile"
+
+
+def test_a_perf_counter_reading_lands_on_the_passes_clock():
+    tr = obs.Tracer(buffer=10)
+    before = time.perf_counter()
+    with tr.run_pass():
+        inside = time.perf_counter()
+    (root,) = tr.phases()
+    assert tr.seconds_at(before, perf_counter=True) <= 0.0 <= tr.seconds_at(inside, perf_counter=True) <= root["end_s"]
+    assert tr.seconds_at(time.perf_counter(), perf_counter=True) == pytest.approx(
+        tr.seconds_at(time.monotonic()), abs=0.01)
 
 
 def test_transfer_outside_a_node_is_booked_on_the_open_h2d_span(monkeypatch):
@@ -458,6 +610,9 @@ def test_every_row_carries_cpu_s_and_only_the_top_rows_the_process_counts(stats_
     rows = stats_pass["manifest"]["phases"]
     for r in rows:
         top = r["parent"] in (None, "run")
+        if r["name"].startswith("compile/"):  # a finished span: it was told its seconds, and measured nothing
+            assert r["usage"] == {}, r
+            continue
         assert set(r["usage"]) == (USAGE if top else {"cpu_s"}), r
         assert not USAGE & set(r["counts"]), r  # kept apart from the counts of the work
         # a thread's own clock: never more than the row's wall (and a tick of either clock)
@@ -756,7 +911,7 @@ def test_the_critical_path_is_named_by_stage(full_pass):
         mine = {r["name"] for r in rows if r["parent"] == name}
         if name in known:
             assert mine - {"io:read_dataset", "artifact:wait", "lane/wait"} - {
-                n for n in mine if n.startswith(("ingest/", "transform/", "describe", "place/"))} <= known[name]
+                n for n in mine if n.startswith(("ingest/", "transform/", "describe", "place/", "compile/"))} <= known[name]
             assert mine, name
             # loose (the smallest nodes are milliseconds long): a node whose stages went missing reads 0
             assert _covered(nodes[name], [r for r in rows if r["parent"] == name]) >= 0.3, name
@@ -767,7 +922,8 @@ def test_the_critical_path_is_named_by_stage(full_pass):
     assert unnamed == pytest.approx(by_hand, abs=1e-6) and 0.0 <= unnamed <= sched["critical_path_s"] + 0.01
     assert load_module("layer_metrics", "dag_cpu_s").read(run) > 0
     assert load_module("layer_metrics", "ingest_cpu_s").read(run) > 0
-    assert len(rows) <= 600  # a few hundred rows a pass, not thousands
+    # a few hundred rows a pass, not thousands (and three more for every program this one was the first to run)
+    assert len([r for r in rows if not r["name"].startswith("compile/")]) <= 600
 
 
 # ---------------------------------------------- the write of a device table ----

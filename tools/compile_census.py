@@ -1,9 +1,11 @@
 """Render / gate the XLA compile census of a run manifest.
 
 Reads the ``compile_census`` section ``workflow.main`` embeds in
-``obs/run_manifest.json`` (obs.compile_census: every real backend compile,
-attributed per program) and prints the top-N programs by compile wall —
-the cold-run tail the column/row shape bucketing exists to keep short.
+``obs/run_manifest.json`` (obs.compile_census: every program that reached
+the backend, attributed per program, with what its trace, its lowering and
+its load from the persistent cache or its build took) and prints the top-N
+programs — the cold-run tail the column/row shape bucketing exists to keep
+short, and on a warm start the programs the cache did not hold.
 
 CI gate: ``--assert-max-programs N`` (and ``--assert-max-compiles N``)
 exits non-zero when the run compiled more distinct program signatures
@@ -38,22 +40,38 @@ def load_census(manifest_path: str) -> dict:
     return census
 
 
+_STAGE_COLUMNS = ("trace_s", "lower_s", "load_s", "build_s")
+
+
 def format_census(census: dict, top: int = 15) -> str:
     lines = [
         "compiles_total={compiles_total}  distinct_programs={distinct_programs}  "
         "distinct_kernels={distinct_kernels}  compile_wall_s={compile_seconds_total}".format(**census),
-        f"{'seconds':>9}  {'count':>5}  program",
     ]
+    # the stages of a program's way to the device and the cache's answers
+    # (absent on older manifests): a slow start is builds where loads were
+    # expected, or loads that are slow
+    staged = "built_programs" in census
+    if staged:
+        lines.append(
+            "cache_requests={cache_requests}  cache_hits={cache_hits}  cache_writes={cache_writes}  "
+            "built_programs={built_programs}  trace_s={trace_seconds_total}  lower_s={lower_seconds_total}  "
+            "load_s={load_seconds_total}  build_s={build_seconds_total}  self_s={self_seconds_total}"
+            .format(**census))
+    stage_head = "".join(f"  {c:>8}" for c in _STAGE_COLUMNS) + f"  {'hits':>5}" if staged else ""
+    lines.append(f"{'seconds':>9}  {'count':>5}{stage_head}  program")
     for row in census.get("programs", [])[: top or None]:
-        # node attribution (census events are stamped with the devprof node
-        # bracket active at compile time — fused-block programs then name
-        # the scheduler node that owns them; absent on older manifests)
+        # node attribution (census events are stamped with the scheduler node
+        # open on the dispatching thread at compile time — fused-block programs
+        # then name the node that owns them; absent on older manifests)
         nodes = row.get("nodes") or []
         node_s = ""
         if nodes:
             shown = ", ".join(nodes[:3]) + (f", +{len(nodes) - 3}" if len(nodes) > 3 else "")
             node_s = f"  [{shown}]"
-        lines.append(f"{row['seconds']:9.3f}  {row['count']:5d}  {row['program']}{node_s}")
+        stages = ("".join(f"  {row.get(c, 0.0):8.3f}" for c in _STAGE_COLUMNS) + f"  {row.get('hits', 0):5d}"
+                  if staged else "")
+        lines.append(f"{row['seconds']:9.3f}  {row['count']:5d}{stages}  {row['program']}{node_s}")
     return "\n".join(lines)
 
 
