@@ -16,7 +16,7 @@ itself must live host-side.
 from __future__ import annotations
 
 import warnings
-from typing import List, Optional, Union
+from typing import List, NamedTuple, Optional, Union
 
 import jax
 import jax.numpy as jnp
@@ -633,10 +633,12 @@ def _segment_aggregate(ids0: jax.Array, valid: jax.Array, V: jax.Array, Mv: jax.
     (rows,) row validity; V: (rows, k) f32 values; Mv: (rows, k) value
     validity.  One program, no host loop (``_segment_aggregate_jit``: a
     class of at most ``_DENSE_SEGMENTS_MAX`` buckets by contraction, masked
-    reduce and counting selection, a wider one by contraction and one sort
-    a column; no scatter on either side).  On a multi-device mesh the block
-    is re-laid column-parallel (each device sorts whole columns locally;
-    ids/validity replicate) — see runtime.column_parallel.
+    reduce and counting selection, a wider one by contraction and the same
+    selection over rows that one sort has grouped, or one sort a column
+    where its rows are few a bucket; no scatter on any side).  On a
+    multi-device mesh the block is re-laid column-parallel (each device
+    sorts whole columns locally; ids/validity replicate, and so does the
+    order that groups the rows) — see runtime.column_parallel.
 
     The static segment count is bucketed into 2^k classes (min 8 —
     ops/segment.py ``segment_class``: NOT the coarse vocab classes, the
@@ -674,14 +676,25 @@ def _segment_aggregate_jit_off(ids: jax.Array, off: jax.Array, valid: jax.Array,
 # wider class (a daily grain over years, ``aggregator`` at a fine grain)
 # takes the three moments from a contraction too (``ops/segment.py``'s
 # ``dense_block_sums`` over the values' bfloat16 parts) and min, max and
-# median all from ONE two-key sort a column.  No class takes a scatter: an
-# f32 scatter-add, one update at a time, lost 1.6 % of a bucket of 10^6
-# values and cost 7.2 ns an update on the chip (PERF.md section 6, PR 39).
+# median from the same selection by counting, made narrow: ONE sort a grain
+# puts the rows in bucket order, every column's keys are gathered behind
+# them, a chunk of grouped rows then holds a short range of buckets, and the
+# selection's one-hot is a window of ``_WINDOW_LANES`` lanes that slides
+# along the chunk's own buckets (``_grouped_picks``; PERF.md section 6,
+# PR 52: 21 sorts a grain became one, 0.80 s became 0.30).  Inside the wide
+# side one more rule, on what a call can see, (rows, class): the windows a
+# pass crosses into are the class over ``_WINDOW_LANES`` whatever the rows,
+# so a class with few rows a bucket (``_groups_rows``: fewer chunks than
+# windows; a daily grain over 32,768 rows, a grain of minutes) keeps ONE
+# two-key sort a column (``_sort_picks``: 2.3 ms there, where the windows'
+# walk takes 5 to 12).  No class takes a scatter: an f32 scatter-add, one
+# update at a time, lost 1.6 % of a bucket of 10^6 values and cost 7.2 ns an
+# update on the chip (PERF.md section 6, PR 39).
 _DENSE_SEGMENTS_MAX = 64
 # rows a step of the dense path's scan: bounds the one-hot and the stacked
 # operand of the contraction whatever the table's length
 _DENSE_CHUNK_ROWS = 1 << 15
-# cells (rows x columns) the median's sort takes at once: the sort holds its
+# cells (rows x columns) the sort of a column takes at once: the sort holds its
 # keys twice, so a block of 2^25 cells is about 0.5 GB of the chip's memory
 _SORT_BLOCK_CELLS = 1 << 25
 # bits of the 32-bit key that one counting pass of a small class's selection
@@ -689,6 +702,8 @@ _SORT_BLOCK_CELLS = 1 << 25
 # passes over the rows a grain makes: one a digit, and one for the upper middle
 _SELECT_BITS = 4
 _SELECT_PASSES = 32 // _SELECT_BITS + 1
+# lanes of the window that a grouped class's selection slides along a chunk's own buckets
+_WINDOW_LANES = 64
 
 
 def _dense_moments(ids0, ok, V, nseg: int):
@@ -836,7 +851,9 @@ def _select_medians(ids0, ok, V, cnt, nseg: int):
 
 
 def _sort_picks(ids0, ok, V, cnt, nseg: int):
-    """(mn, mx, med), each (k, nseg): each column sorted by (bucket, value),
+    """(mn, mx, med), each (k, nseg), of a wide class whose rows are few a
+    bucket (``_groups_rows``), and the oracle the grouped selection is held
+    to: each column sorted by (bucket, value),
     the first, the last and the middle one or two of every bucket picked
     through the cumulative counts (an empty bucket reads +inf, -inf and
     whatever lies at its place: no consumer reads it).  The columns sort
@@ -872,17 +889,181 @@ def _sort_picks(ids0, ok, V, cnt, nseg: int):
     return tuple(p.reshape(k, nseg) for p in picks)
 
 
+def _groups_rows(rows: int, nseg: int) -> bool:
+    """The rule inside the wide side: whether a wide class groups its rows
+    for a selection (the windows a pass crosses into are then no more than
+    the chunks it walks) or sorts every column (``_sort_picks``)."""
+    return rows // _DENSE_CHUNK_ROWS >= -(-nseg // _WINDOW_LANES)
+
+
+class _GroupLayout(NamedTuple):
+    """A grouped class's rows padded to whole chunks, the chunk, the blocks of
+    ``_WINDOW_LANES`` buckets, and the static bound of (chunk, window) steps
+    a pass makes."""
+    padded: int
+    chunk: int
+    blocks: int
+    steps: int
+
+
+def _group_layout(rows: int, nseg: int) -> _GroupLayout:
+    """The chunks partition the sorted buckets, so a pass walks every chunk
+    once and crosses into every window block at most once, whatever the
+    skew: ``steps`` = chunks + blocks."""
+    chunk = min(_DENSE_CHUNK_ROWS, rows)
+    padded = rows + -rows % chunk
+    blocks = -(-nseg // _WINDOW_LANES)
+    return _GroupLayout(padded, chunk, blocks, padded // chunk + blocks)
+
+
+def _group_keys(ids0, valid, ok, V, nseg: int):
+    """(s, keys): the rows' buckets in order, (padded,), a row with no time
+    or a bucket outside ``[0, nseg)`` as ``nseg`` at the end; and every
+    column's int32 keys behind them, (k, padded), a value that does not
+    count as ``_I32_BIG``.  ONE sort whatever the number of columns: of the
+    buckets alone, carrying the row index (a rank-1 sort of two operands:
+    12 ms at 6,291,456 rows on the chip, where a column's two-key sort under
+    ``vmap`` takes 38), and the key block gathered by it a row at a time
+    (0.19 s there: 1.4 ns a cell; the keys as operands of the sort took 0.25
+    and compiled for 10 s an operand; PERF.md section 6, PR 52).  Within a
+    bucket the rows come in no order, and need none."""
+    rows, k = V.shape
+    padded = _group_layout(rows, nseg).padded
+    s = jnp.where(valid & (ids0 >= 0) & (ids0 < nseg), ids0, nseg).astype(jnp.int32)
+    keys = jnp.where(ok, _sort_keys(V), _I32_BIG)  # (rows, k)
+    if padded > rows:  # whole chunks: the rows added hold no time
+        s = jnp.concatenate([s, jnp.full((padded - rows,), nseg, jnp.int32)])
+        keys = jnp.concatenate([keys, jnp.full((padded - rows, k), _I32_BIG, jnp.int32)])
+    s, order = jax.lax.sort((s, jnp.arange(padded, dtype=jnp.int32)), num_keys=1, is_stable=False)
+    return s, keys[order].T
+
+
+def _window_steps(s, nseg: int):
+    """(chunk of every step, window block of every step, live steps) of a
+    pass over grouped rows: a chunk's rows hold the buckets ``[first, last]``
+    and take one step a block of ``_WINDOW_LANES`` buckets in that range (a
+    chunk of rows with no time takes none); the chunks in order and each
+    chunk's blocks in order, built by a cumulative sum, the live steps first
+    in a list of the static bound's length."""
+    padded, chunk, nblk, steps = _group_layout(s.shape[0], nseg)
+    n = padded // chunk
+    first = s[::chunk]
+    last = jnp.where(s < nseg, s, -1).reshape(n, chunk).max(axis=1)  # the rows with no time lie at the end: no bucket
+    windows = jnp.where(last >= 0, last // _WINDOW_LANES - first // _WINDOW_LANES + 1, 0)
+    ends = jnp.cumsum(windows)
+    t = jnp.arange(steps, dtype=jnp.int32)
+    c = jnp.minimum((ends[None, :] <= t[:, None]).sum(axis=1), n - 1).astype(jnp.int32)
+    blk = first[c] // _WINDOW_LANES + (t - (ends[c] - windows[c]))
+    return c, jnp.clip(blk, 0, nblk - 1).astype(jnp.int32), ends[-1]
+
+
+def _grouped_picks(ids0, valid, ok, V, cnt, nseg: int):
+    """(mn, mx, med), each (k, nseg), of a wide class whose rows are many a
+    bucket: the stored values ``_sort_picks`` picks, to the bit but for a
+    zero's sign (+0.0 here; a denormal, which the device's compare flushes,
+    reads as one), by ``_select_medians``' counting over rows that lie in
+    bucket order.  No scatter, and one sort a grain."""
+    return _windowed_picks(*_group_keys(ids0, valid, ok, V, nseg), cnt, nseg)
+
+
+def _windowed_picks(s, keys, cnt, nseg: int):
+    """``_grouped_picks`` of rows already grouped (``_group_keys``' pair).
+    A chunk of grouped rows holds a contiguous range of buckets, so its
+    one-hot is ``_WINDOW_LANES`` wide whatever the class: a pass walks the
+    (chunk, window) steps of ``_window_steps``, the prefix planes and the
+    counts being the step's own block of the (blocks, k, lanes) state.  The
+    last pass takes, beside the upper middle, the least and the greatest key
+    of every bucket."""
+    k = keys.shape[0]
+    _, chunk, nblk, _ = _group_layout(s.shape[0], nseg)
+    step_chunk, step_blk, live_steps = _window_steps(s, nseg)
+    s, keys = s.reshape(-1, chunk), keys.reshape(k, -1, chunk)
+    lanes = jnp.arange(_WINDOW_LANES, dtype=jnp.int32)
+    sign = jnp.uint32(1 << 31)
+    digits = jnp.arange(1, 1 << _SELECT_BITS, dtype=jnp.uint32)
+    at = _functools.partial(jax.lax.dynamic_index_in_dim, keepdims=False)
+
+    def blocks(a):  # (k, nseg) -> (blocks, k, lanes)
+        return jnp.pad(a, ((0, 0), (0, nblk * _WINDOW_LANES - nseg))).reshape(k, nblk, _WINDOW_LANES).transpose(1, 0, 2)
+
+    def buckets(a):  # and back
+        return a.transpose(1, 0, 2).reshape(k, nblk * _WINDOW_LANES)[:, :nseg]
+
+    def merged(f, state, blk, part):  # state[blk] = f(state[blk], part): a slice updated in place, no scatter
+        return jax.lax.dynamic_update_index_in_dim(state, f(at(state, blk, 0), part), blk, 0)
+
+    def over_steps(one, init):
+        def step(t, carry):
+            c, blk = step_chunk[t], step_blk[t]
+            hot = at(s, c, 0)[:, None] == blk * _WINDOW_LANES + lanes  # (chunk, lanes)
+            return one(carry, blk, hot, at(keys, c, 1))  # keys (k, chunk)
+
+        return jax.lax.fori_loop(0, live_steps, step, init)
+
+    c = blocks(cnt.astype(jnp.int32))
+    live = c > 0
+    lo_rank, hi_rank = jnp.maximum(c - 1, 0) // 2, c // 2
+
+    def digit(i, prefix):  # as _select_medians', the rows on the minor axis
+        shift = (32 - _SELECT_BITS * (i + 1)).astype(jnp.uint32)
+        planes = jnp.stack([((prefix ^ sign) >> sh) & 255 for sh in (0, 8, 16, 24)], axis=1).astype(jnp.bfloat16)
+
+        def below(counts, blk, hot, keys_c):
+            hot = hot.astype(jnp.bfloat16)
+            own = jnp.einsum("rs,bks->bkr", hot, at(planes, blk, 0),
+                             preferred_element_type=jnp.float32).astype(jnp.uint32)
+            own = own[0] | (own[1] << 8) | (own[2] << 16) | (own[3] << 24)  # (k, chunk)
+            cands = jax.lax.bitcast_convert_type(own[None] ^ (digits[:, None, None] << shift), jnp.int32)
+            under = (keys_c[None] < cands).astype(jnp.bfloat16)  # (15, k, chunk)
+            part = jnp.einsum("rs,jkr->jks", hot, under, preferred_element_type=jnp.float32)
+            return merged(jnp.add, counts, blk, part.astype(jnp.int32))
+
+        counts = over_steps(below, jnp.zeros((nblk, digits.size, k, _WINDOW_LANES), jnp.int32))
+        return prefix | ((counts <= lo_rank[:, None]).sum(axis=1).astype(jnp.uint32) << shift)
+
+    prefix = jax.lax.fori_loop(0, 32 // _SELECT_BITS, digit, jnp.zeros((nblk, k, _WINDOW_LANES), jnp.uint32))
+    lo = jnp.where(live, jax.lax.bitcast_convert_type(prefix ^ sign, jnp.int32), 0)
+
+    def around(carry, blk, hot, keys_c):
+        hot, keys_c = hot.T[None], keys_c[:, None, :]  # (k, lanes, chunk) by broadcast
+        lo_b = at(lo, blk, 0)[:, :, None]
+        parts = ((hot & (keys_c <= lo_b)).sum(axis=2, dtype=jnp.int32),
+                 jnp.where(hot & (keys_c > lo_b), keys_c, _I32_BIG).min(axis=2),
+                 jnp.where(hot, keys_c, _I32_BIG).min(axis=2),
+                 jnp.where(hot & (keys_c < _I32_BIG), keys_c, -_I32_BIG - 1).max(axis=2))
+        return tuple(merged(f, a, blk, p) for f, a, p in
+                     zip((jnp.add, jnp.minimum, jnp.minimum, jnp.maximum), carry, parts))
+
+    big = jnp.full((nblk, k, _WINDOW_LANES), _I32_BIG, jnp.int32)
+    upto, above, least, greatest = over_steps(around, (jnp.zeros_like(big), big, big, -big - 1))
+    hi = jnp.where(live & (upto <= hi_rank), above, lo)
+
+    def value(key):
+        return jax.lax.bitcast_convert_type(_flip_negative(key), jnp.float32)
+
+    return (buckets(jnp.where(live, value(least), jnp.inf)), buckets(jnp.where(live, value(greatest), -jnp.inf)),
+            buckets((value(lo) + value(hi)) / 2))  # an empty bucket reads +inf, -inf, 0.0; no consumer reads it
+
+
 def aggregate_routes(rows: int, k: int, *nsegs: int) -> dict:
     """What a call of ``k`` columns of ``rows`` padded rows over grains of
     these classes counts on its stage row, by the one rule: the (column,
-    grain) medians by selection and by sort and the counting passes a
-    selecting grain makes; the buckets of the wide grains and the cells
-    (rows x columns, a wide grain) they aggregate."""
+    grain) medians of the narrow classes (``median_selects``, with the
+    counting passes such a grain makes) and of the wide ones
+    (``median_sorts``: medians of a class whose rows a sort orders, be it one
+    sort a column or one that groups the grain's rows for a selection); the
+    buckets of the wide grains and the cells (rows x columns, a wide grain)
+    they aggregate; the sorts the call makes for its wide grains (one for a
+    grouped grain, one a column of the others) and the static bound of
+    (chunk, window) steps a pass over its grouped grains walks."""
     wide = [n for n in nsegs if _is_wide(n)]
+    grouped = [n for n in wide if _groups_rows(rows, n)]
     selects = k * (len(nsegs) - len(wide))
     return {"median_selects": selects, "median_sorts": k * len(wide),
             "select_passes": _SELECT_PASSES if selects else 0,
-            "wide_segments": sum(wide), "wide_cells": rows * k * len(wide)}
+            "wide_segments": sum(wide), "wide_cells": rows * k * len(wide),
+            "wide_sorts": len(grouped) + k * (len(wide) - len(grouped)),
+            "wide_select_steps": sum(_group_layout(rows, n).steps for n in grouped)}
 
 
 @_functools.partial(jax.jit, static_argnames=("nseg", "cp"))
@@ -906,6 +1087,8 @@ def _segment_aggregate_jit(ids0: jax.Array, valid: jax.Array, V: jax.Array,
             with jax.named_scope("moments"):
                 cnt, sm, sq = _wide_moments(ids0, ok, V, nseg)
             with jax.named_scope("medians"):
+                if _groups_rows(V.shape[0], nseg):
+                    return (cnt, sm, sq, *_grouped_picks(ids0, valid, ok, V, cnt, nseg))
                 return (cnt, sm, sq, *_sort_picks(ids0, ok, V, cnt, nseg))
 
 

@@ -142,24 +142,30 @@ def test_wide_segment_aggregate_compiles_to_a_contraction_and_one_sort_without_a
     """``expedia_hotel.ts_inspect``'s daily grain (class 1,024; here at 4,194,304 x 16): the moments by
     contraction of the values' bfloat16 parts, which the compiled program must still round with
     ``reduce-precision`` (a cast to bfloat16 and back is dropped inside a fusion: PERF.md section 6,
-    PR 49), min, max and median from one sort of two operands, no scatter, and the planes built a chunk
-    at a time: nothing as long as the rows but the sort's keys and the scan's copy of the block."""
+    PR 49); min, max and median by a selection over rows that ONE sort has grouped (PR 52: of rank 1,
+    the buckets and the row index, whatever the columns), the keys gathered behind it; no scatter, and
+    nothing as long as the rows but the keys (as built, gathered and turned), the sort's operands and
+    the scan's copy of the block."""
+    import re
+
     import jax
     import jax.numpy as jnp
     from jax.sharding import SingleDeviceSharding
 
-    from anovos_tpu.data_transformer.datetime import _segment_aggregate_jit
+    from anovos_tpu.data_transformer import datetime as dtt
 
     one_chip = SingleDeviceSharding(topo.devices[0])
     ids = jax.ShapeDtypeStruct((ROWS,), jnp.int32, sharding=one_chip)
     valid = jax.ShapeDtypeStruct((ROWS,), jnp.bool_, sharding=one_chip)
-    compiled = _compile(_segment_aggregate_jit, ids, valid, shapes["X"], shapes["M"], nseg=1024, cp=False)
+    assert dtt._groups_rows(ROWS, 1024) and dtt.aggregate_routes(ROWS, K, 1024)["wide_sorts"] == 1
+    compiled = _compile(dtt._segment_aggregate_jit, ids, valid, shapes["X"], shapes["M"], nseg=1024, cp=False)
     text = compiled.as_text()
     assert " scatter(" not in text and "reduce-precision(" in text and " convolution(" in text
     sorts = [line for line in text.split("\n") if " sort(" in line]
     assert len(sorts) == 1 and "ts/segment_aggregate/wide/medians" in sorts[0], sorts
+    assert len(re.findall(r" sort\(([^)]*)\)", sorts[0])[0].split(",")) == 2 and f"s32[{ROWS}]" in sorts[0], sorts[0]
     assert "ts/segment_aggregate/wide/moments" in text
-    assert compiled.memory_analysis().temp_size_in_bytes < 2 * ROWS * K * 5 + (256 << 20)
+    assert compiled.memory_analysis().temp_size_in_bytes < 3 * ROWS * K * 4 + (256 << 20)
 
 
 def test_dense_binned_histograms_compiles(shapes, monkeypatch):

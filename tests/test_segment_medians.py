@@ -75,16 +75,130 @@ def test_the_selection_picks_the_sorts_medians_bit_for_bit(nseg, rows):
     assert np.allclose(by_count[finite], want[finite], rtol=1e-6, atol=1e-30)
 
 
-@pytest.mark.parametrize("nseg,sorts", [(8, False), (32, False), (64, False), (128, True), (4096, True)])
-def test_a_small_class_lowers_without_a_sort_or_a_scatter(nseg, sorts):
-    ids, valid, V, Mv = _hard_block(4096, 3, nseg, seed=nseg)
+def _same_picks(got, want, live):
+    """Bit for bit where a bucket is live, but for what the device's compare cannot tell apart: a zero's
+    sign (the unstable sort leaves it to chance, the selection reads +0.0), a denormal (flushed: a zero
+    to both), and the NaN that the mean of -inf and +inf is."""
+    got, want = np.asarray(got), np.asarray(want)
+    tiny = np.finfo(np.float32).tiny
+    same = got.view(np.int32) == want.view(np.int32)
+    same |= (np.abs(got) < tiny) & (np.abs(want) < tiny)
+    same |= np.isnan(got) & np.isnan(want)
+    return same[live].all(), (got[live & ~same][:5], want[live & ~same][:5])
+
+
+def _skewed_block(rows, nseg, seed):
+    """``_hard_block``'s eight kinds on the edges a grouped selection has: one bucket holds half the rows
+    (so it is cut by every chunk's edge it meets); the last quarter of the class is a tail of one-row
+    buckets with an empty one after each (so the chunk that holds the tail spans more than
+    ``_WINDOW_LANES`` buckets from 512 buckets up); column 3 has no valid value; and twelve rows with a
+    time carry a bucket outside ``[0, nseg)``: no bucket's rows."""
+    ids, valid, V, Mv = _hard_block(rows, _KINDS, nseg, seed)
+    g = np.random.default_rng(seed + 1)
+    body = 3 * nseg // 4
+    ids %= body
+    ids[g.random(rows) < 0.5] = nseg // 3
+    tail = np.arange(body, nseg - 2, 2, dtype=np.int32)
+    picked = g.choice(rows, tail.size + 12, replace=False)
+    ids[picked] = np.concatenate([tail, np.array([-1, -5, nseg, nseg + 7] * 3, np.int32)])
+    valid[picked], Mv[picked] = True, True
+    Mv[:, 3] = False
+    return ids, valid, V, Mv, tail
+
+
+@pytest.mark.parametrize("length", ["one_chunk", "three_chunks_and_100"])
+@pytest.mark.parametrize("nseg", [128, 1024, 4096])
+def test_the_grouped_selection_picks_the_sorts_values_bit_for_bit(nseg, length, monkeypatch):
+    """min, max and median of a wide class from grouped rows (``_grouped_picks``) against one two-key sort
+    a column (``_sort_picks``, the oracle), at chunks of 2,048 rows."""
+    monkeypatch.setattr(dtt, "_DENSE_CHUNK_ROWS", 2048)
+    rows = 2048 if length == "one_chunk" else 3 * 2048 + 100
+    ids, valid, V, Mv, tail = _skewed_block(rows, nseg, seed=nseg + rows)
+    inside = (ids >= 0) & (ids < nseg)
+    ok = Mv & valid[:, None]
+    counted = ok & inside[:, None]
+    cnt = np.stack([np.bincount(ids[counted[:, j]], minlength=nseg) for j in range(_KINDS)]).astype(np.float32)
+    live = cnt > 0
+    assert cnt[:, nseg // 3].max() > rows // 4 and not live[3].any() and (~inside & valid).sum() == 12
+    assert (cnt[:3, tail] == 1).all() and not live[:, tail + 1].any()  # one-row buckets, an empty one after each
+    padded, chunk, nblk, steps = dtt._group_layout(rows, nseg)
+    assert (padded, chunk, nblk, steps) == (rows + -rows % 2048, 2048, nseg // dtt._WINDOW_LANES, padded // 2048 + nblk)
+    s_sorted = np.asarray(jax.jit(dtt._group_keys, static_argnums=4)(
+        jnp.asarray(ids), jnp.asarray(valid), jnp.asarray(ok), jnp.asarray(V), nseg)[0]).reshape(-1, chunk)
+    spans = np.minimum(s_sorted[:, -1], nseg - 1) - s_sorted[:, 0] + 1
+    assert spans.max() > dtt._WINDOW_LANES  # a chunk walks several windows
+    walked = int(dtt._window_steps(jnp.asarray(s_sorted.reshape(-1)), nseg)[2])
+    assert padded // chunk <= walked + (s_sorted[:, 0] == nseg).sum() and walked <= steps  # the bound holds under this skew
+    got = jax.jit(dtt._grouped_picks, static_argnums=5)(
+        jnp.asarray(ids), jnp.asarray(valid), jnp.asarray(ok), jnp.asarray(V), jnp.asarray(cnt), nseg)
+    want = jax.jit(dtt._sort_picks, static_argnums=4)(
+        jnp.asarray(np.where(inside, ids, 0)), jnp.asarray(counted), jnp.asarray(V), jnp.asarray(cnt), nseg)
+    for name, a, b in zip(("min", "max", "median"), got, want):
+        same, differ = _same_picks(a, b, live)
+        assert np.asarray(a).shape == (_KINDS, nseg) and same, (name, differ)
+    mn, mx, med = (np.asarray(a) for a in got)
+    assert np.isposinf(mn[~live]).all() and np.isneginf(mx[~live]).all() and not np.isnan(med[~live]).any()
+    # and min and max are numpy's own
+    j, b = (int(x[0]) for x in np.nonzero(cnt == cnt[:3].max()))
+    own = V[counted[:, j] & (ids == b), j]
+    assert mn[j, b] == own.min() and mx[j, b] == own.max()
+
+
+def _routes(selects=0, sorts=0, wide=(), cells=0, wide_sorts=0, steps=0):
+    return {"median_selects": selects, "median_sorts": sorts, "select_passes": dtt._SELECT_PASSES if selects else 0,
+            "wide_segments": sum(wide), "wide_cells": cells, "wide_sorts": wide_sorts, "wide_select_steps": steps}
+
+
+@pytest.mark.parametrize("nseg,rows,sorts,whiles", [
+    (8, 4096, 0, 3), (32, 4096, 0, 3), (64, 4096, 0, 3), (128, 4096, 3, 0), (4096, 4096, 3, 0),
+    (128, 3 * dtt._DENSE_CHUNK_ROWS, 1, None)])
+def test_a_small_class_lowers_without_a_sort_or_a_scatter(nseg, rows, sorts, whiles):
+    """... and a wide class with one sort: a column where its rows are few a bucket (one batched sort under
+    ``vmap``, no loop), one for the whole grain, of rank 1, where the rows are grouped first."""
+    ids, valid, V, Mv = _hard_block(rows, 3, nseg, seed=nseg)
     text = dtt._segment_aggregate_jit.lower(
         jnp.asarray(ids), jnp.asarray(valid), jnp.asarray(V), jnp.asarray(Mv), nseg=nseg).as_text()
-    assert ("stablehlo.sort" in text) == sorts and "scatter" not in text  # no class scatters (PR 49)
-    assert dtt.aggregate_routes(4096, 3, nseg) == {
-        "median_selects": 0 if sorts else 3, "median_sorts": 3 if sorts else 0,
-        "select_passes": 0 if sorts else dtt._SELECT_PASSES,
-        "wide_segments": nseg if sorts else 0, "wide_cells": 3 * 4096 if sorts else 0}
+    assert text.count("stablehlo.sort") == (1 if sorts else 0) and "scatter" not in text  # no class scatters (PR 49)
+    assert whiles is None or text.count("stablehlo.while") == whiles
+    grouped = dtt._groups_rows(rows, nseg)
+    assert grouped == (sorts == 1)
+    if grouped:  # the grain's one sort: the buckets its one key, the row index behind them
+        (line,) = [ln for ln in text.split("\n") if "stablehlo.sort" in ln]
+        assert line.split("(")[1].count("%") == 2 and "is_stable = false" in line and "tensor<%dxi32>" % rows in text
+    steps = dtt._group_layout(rows, nseg).steps if grouped else 0
+    assert dtt.aggregate_routes(rows, 3, nseg) == (
+        _routes(selects=3) if not sorts else
+        _routes(sorts=3, wide=(nseg,), cells=3 * rows, wide_sorts=sorts, steps=steps))
+
+
+# sha256 (first 16 hex digits) of the StableHLO text the narrow side lowers to at commit 44bb041, the parent of
+# PR 52 (jax 0.9.0, three f32 columns, the suite's default matmul precision ``highest``): a change of the narrow side's program changes these with it, and says so
+_PARENT_NARROW_TEXT = {
+    (4096, 8): "4496a80455782e8e", (4096, 32): "f75790bbae6b74f8", (4096, 64): "8d3e027b34e128ca",
+    (3 * 32768, 8): "cc82e4f04c2bbdb9", (3 * 32768, 32): "3e429e6ccebef98c", (3 * 32768, 64): "15f41f030131a1a0",
+}
+
+
+def _sha(text):
+    import hashlib
+
+    return hashlib.sha256(text.encode()).hexdigest()[:16]
+
+
+def _shapes(rows, k=3):
+    sd = jax.ShapeDtypeStruct
+    return sd((rows,), jnp.int32), sd((rows,), jnp.bool_), sd((rows, k), jnp.float32), sd((rows, k), jnp.bool_)
+
+
+@pytest.mark.parametrize("rows,nseg", sorted(_PARENT_NARROW_TEXT))
+def test_the_narrow_side_lowers_to_the_parents_text(rows, nseg):
+    """The grouped selection is the wide side's: a class of at most 64 buckets (``nyc_taxi.ts_inspect``'s
+    32 / 8 / 8, whose fused program is three calls of this one) lowers to the very text it had before PR 52,
+    with no sort, no scatter and no loop but the scans' and the digits'."""
+    with jax.default_matmul_precision("highest"):  # what the suite runs under; stated, so that the text is one text
+        text = dtt._segment_aggregate_jit.lower(*_shapes(rows), nseg=nseg).as_text()
+    assert "sort" not in text and "scatter" not in text and text.count("stablehlo.while") == (3 if rows == 4096 else 4)
+    assert _sha(text) == _PARENT_NARROW_TEXT[rows, nseg]
 
 
 def _days_of_trips(days: int, rows: int = 900) -> pd.DataFrame:

@@ -195,3 +195,46 @@ def test_aggregator_takes_a_daily_grain_over_a_year_by_the_wide_side():
             assert np.allclose(got[f"{c}_{ours}"].to_numpy()[some], want[c][theirs].to_numpy()[some], rtol=rtol), (c, ours)
         two = want[c]["count"].to_numpy() > 1
         assert np.allclose(got[f"{c}_stddev"].to_numpy()[two], want[c]["std"].to_numpy()[two], rtol=1e-3)
+
+
+def test_a_grouped_class_on_the_mesh_gives_the_one_device_results():
+    """On a mesh the block is column-parallel and the ids and the validity replicated: the grouped
+    selection (many rows a bucket: 3 chunks at class 128) gives the one device's counts, minima,
+    maxima and medians to the bit and its sums to the limit."""
+    from anovos_tpu.shared.runtime import get_runtime, wants_column_parallel
+
+    rt = get_runtime()
+    rows, nseg = 3 * dtt._DENSE_CHUNK_ROWS, 128
+    assert dtt._groups_rows(rows, nseg) and rows % rt.mesh.size == 0
+    ids, valid, V, Mv = _block(rows, 8, nseg, seed=52)
+    on_mesh = [rt.shard_rows(a) for a in (ids, valid, V, Mv)]
+    assert wants_column_parallel(*on_mesh, replicate=on_mesh[:2]) == (rt.mesh.size > 1)
+    got = [np.asarray(a) for a in dtt._segment_aggregate(*on_mesh, nseg)]
+    want = _aggregate(ids, valid, V, Mv, nseg)
+    live = want[0] > 0
+    for i in (0, 3, 4, 5):
+        assert (got[i][live] == want[i][live]).all(), i
+    for i in (1, 2):
+        assert _worst(got[i], want[i].astype(np.float64), live) < LIMIT
+
+
+def test_the_hand_timing_runs_the_programs_own_functions_at_a_small_shape(tmp_path, capsys):
+    """``tools/probes/wide_select_probe.py`` (PERF.md section 6, PR 52) on the CPU: a rehearsal of the
+    chip call at 3 chunks x 4 columns, class 128.  Every variant gets a row, the grouped route's picks
+    are the sort's, and the probe times ``datetime.py``'s functions, not copies of them."""
+    import json
+
+    from tools.probes import wide_select_probe as probe
+
+    rows = 3 * dtt._DENSE_CHUNK_ROWS
+    (found,) = probe.main(["--shape", f"{rows},4,128", "--reps", "1", "--out", str(tmp_path)])
+    table = found["table"]
+    assert set(table) == {"sort_picks", "group_keys", "windowed_picks", "grouped_picks", "group_index",
+                          "gather_rows", "gather_rows_32", "gather_cols"}  # operands_3: no divisor of four columns
+    assert table["grouped_picks"]["same"] and table["windowed_picks"]["same"] and found["groups_rows"]
+    assert 3 <= table["windowed_picks"]["steps"] <= table["windowed_picks"]["bound"] == dtt._group_layout(rows, 128).steps
+    assert all(r["s"] > 0 and r["first_s"] > 0 for r in table.values())
+    assert json.loads((tmp_path / "wide_select_probe.jsonl").read_text())["table"].keys() == table.keys()
+    assert "same as sort_picks" in capsys.readouterr().out
+    source = open(probe.__file__).read()
+    assert "def _sort_picks" not in source and "def _group_keys" not in source and "dtt._windowed_picks" in source

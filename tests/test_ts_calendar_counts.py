@@ -264,17 +264,24 @@ def test_wide_class_takes_no_scatter_and_keeps_the_scatters_results():
     assert np.allclose(med[n_cnt > 0], n_med[n_cnt > 0], rtol=1e-6, atol=1e-6)
 
 
-def test_the_median_sorts_in_column_blocks_with_the_same_result(monkeypatch):
-    """At a class that still sorts (128: above ``_DENSE_SEGMENTS_MAX``)."""
-    ids, valid, V, Mv = _block(4096, 6, 128, seed=3)
+@pytest.mark.parametrize("rows", [4096, 3 * dtt._DENSE_CHUNK_ROWS], ids=["a_sort_a_column", "grouped_rows"])
+def test_the_median_sorts_in_column_blocks_with_the_same_result(rows, monkeypatch):
+    """At a class that still sorts (128: above ``_DENSE_SEGMENTS_MAX``), on both sides of the wide side's rule:
+    few rows a bucket (one two-key sort a column, ``_SORT_BLOCK_CELLS // rows`` columns at a time) and many (ONE
+    sort of the buckets whatever the columns: the block size is nothing to it)."""
+    ids, valid, V, Mv = _block(rows, 6, 128, seed=3)
     args = (jnp.asarray(ids), jnp.asarray(valid), jnp.asarray(V), jnp.asarray(Mv))
-    assert "while" not in dtt._segment_aggregate_jit.lower(*args, nseg=128).as_text()
+    grouped = dtt._groups_rows(rows, 128)
+    assert grouped == (rows > 4096)
+    loops = dtt._segment_aggregate_jit.lower(*args, nseg=128).as_text().count("stablehlo.while")
+    assert loops == (4 if grouped else 0)  # grouped: the moments' scan, the digits, a digit's steps, the last pass's
     whole = [np.asarray(a) for a in dtt._segment_aggregate_jit(*args, nseg=128)]
-    monkeypatch.setattr(dtt, "_SORT_BLOCK_CELLS", 2 * 4096)  # two columns at a time: a lax.map of three steps
+    assert dtt.aggregate_routes(rows, 6, 128)["wide_sorts"] == (1 if grouped else 6)
+    monkeypatch.setattr(dtt, "_SORT_BLOCK_CELLS", 2 * rows)  # two columns at a time: a lax.map of three steps
     dtt._segment_aggregate_jit.clear_cache()
     try:
         text = dtt._segment_aggregate_jit.lower(*args, nseg=128).as_text()
-        assert "while" in text and "stablehlo.sort" in text
+        assert text.count("stablehlo.while") == loops + (0 if grouped else 1) and text.count("stablehlo.sort") == 1
         blocked = [np.asarray(a) for a in dtt._segment_aggregate_jit(*args, nseg=128)]
     finally:
         dtt._segment_aggregate_jit.clear_cache()
