@@ -287,7 +287,7 @@ def _fit_drift_model(cfg: ContinuumConfig, state: ContinuumState,
 
     from anovos_tpu.data_transformer.model_io import save_model_df
     from anovos_tpu.drift_stability.drift_detector import _drop_allnan_cutoffs
-    from anovos_tpu.ops.drift_kernels import binned_histograms, cutoffs_from_bounds
+    from anovos_tpu.ops.drift_kernels import binned_histograms, cutoffs_from_bounds, device_cutoffs
 
     spec = ctx.drift
     if spec is None or not spec.baseline:
@@ -303,10 +303,8 @@ def _fit_drift_model(cfg: ContinuumConfig, state: ContinuumState,
     num_cols = _cols_of(mom)
     cut_rows: List[Tuple[str, np.ndarray]] = []
     if num_cols:
-        cuts = np.asarray(cutoffs_from_bounds(
-            jnp.asarray(mom["min"], jnp.float32),
-            jnp.asarray(mom["max"], jnp.float32),
-            jnp.asarray(mom["n"], jnp.float32), spec.bin_size))
+        cuts = cutoffs_from_bounds(np.asarray(mom["min"], np.float32), np.asarray(mom["max"], np.float32),
+                                   mom["n"], spec.bin_size)
         cuts64, kept_cols, _ = _drop_allnan_cutoffs(cuts[: len(num_cols)], num_cols)
         cut_rows = list(zip(kept_cols, cuts64))
     cut_map = {c: np.asarray(v, np.float64) for c, v in cut_rows}
@@ -330,7 +328,7 @@ def _fit_drift_model(cfg: ContinuumConfig, state: ContinuumState,
             cuts_pad = np.full((k_pad, spec.bin_size - 1), np.nan, np.float32)
             for j, c in enumerate(part.num_cols):
                 if c in cut_map:
-                    cuts_pad[j] = np.asarray(cut_map[c], np.float32)
+                    cuts_pad[j] = device_cutoffs(cut_map[c])
             hist = np.asarray(binned_histograms(
                 v, m, jnp.asarray(cuts_pad), spec.bin_size))
             for c in cols:

@@ -127,6 +127,12 @@ def statistics(
         idf_target.col_names,
         drop_cols,
     )
+    if idf_source is not None and not pre_existing_source:
+        # a string column without a value in one dataset: ingest types an all-null
+        # column numeric whatever its file said, and the other side has its values
+        kinds = {c: (idf_target.columns[c].kind, idf_source.columns[c].kind) for c in cols}
+        idf_target = _empty_as_strings(idf_target, [c for c, k in kinds.items() if k == ("num", "cat")])
+        idf_source = _empty_as_strings(idf_source, [c for c, k in kinds.items() if k == ("cat", "num")])
     num_cols = [c for c in cols if idf_target.columns[c].kind == "num"]
     cat_cols = [c for c in cols if idf_target.columns[c].kind == "cat"]
     if source_path == "NA":
@@ -149,14 +155,18 @@ def statistics(
 
     count_target = idf_target.nrows
     from anovos_tpu.data_transformer.model_io import load_model_df, save_model_df
-    from anovos_tpu.ops.drift_kernels import drift_side_full
+    from anovos_tpu.ops.drift_kernels import cutoffs_from_bounds, device_cutoffs, drift_side_full, fit_bounds
+    from anovos_tpu.ops.segment import bucket_segments_pow2
     from anovos_tpu.shared.runtime import get_runtime
 
     # single-device meshes have no collectives, so the cutoff-fit and both
     # side programs can be pipelined on device with ONE host sync at the end;
     # multi-device stays strictly sequential (two collective programs in
     # flight can interleave their rendezvous — see Table.gather_rows)
-    pipeline_ok = bool(get_runtime().n_devices == 1 and not pre_existing_source and num_cols)
+    one_device = get_runtime().n_devices == 1
+    # equal_range cut-offs are float64 arithmetic on the host over the source's fetched bounds
+    # (cutoffs_from_bounds); equal_frequency ones are values of the column and stay on the device
+    pipeline_ok = bool(one_device and not pre_existing_source and num_cols and bin_method != "equal_range")
 
     # the cutoffs fitted on the source (dispatched; fetched with the sides where one chip
     # pipelines them) or the saved model read back, and the union vocabularies on the host
@@ -172,6 +182,11 @@ def statistics(
                 cut_map = {r["attribute"]: list(r["parameters"]) for _, r in dfm.iterrows()}
                 num_cols_eff = [c for c in num_cols if c in cut_map]
                 cutoffs = np.array([cut_map[c] for c in num_cols_eff], dtype=np.float64)
+            elif bin_method == "equal_range":
+                lo, hi, n = jax.device_get(fit_bounds(*_padded_col_tuples(idf_source, num_cols)))
+                k = len(num_cols)  # the column bucket's dead lanes are no dropped columns
+                cutoffs, num_cols_eff, _ = _drop_allnan_cutoffs(
+                    cutoffs_from_bounds(lo[:k], hi[:k], n[:k], bin_size), num_cols)
             else:
                 cuts_d = _fit_cutoffs_dev(idf_source, num_cols, bin_size, bin_method)
                 if not pipeline_ok:
@@ -201,32 +216,46 @@ def statistics(
                     freq_p[c] = np.array([smap.get(str(v), 0.0) for v in uni])
                 # numeric columns absent from the binning model are skipped
             cat_cols = [c for c in cat_cols if c in union_vocabs]
-        else:
+
+    # a ``str`` and a set entry a value of either side's vocabulary, then the union sorted
+    if not pre_existing_source:
+        with phase("drift/union", cat="block", cols=len(cat_cols)) as sp:
             union_vocabs = _union_vocabs_for(idf_source, idf_target, cat_cols)
+            sp.add(values=sum(len(idf.columns[c].vocab) for idf in (idf_source, idf_target) for c in cat_cols))
 
     # ---- ONE fused program per dataset side --------------------------------
-    n_union = max((len(union_vocabs[c]) for c in cat_cols), default=1)
+    # the lanes of the union histograms are a power of two, as the LUT's are: two seeds'
+    # unions differ by a few values, and an exact size made each compile its own side program
+    n_union = bucket_segments_pow2(max((len(union_vocabs[c]) for c in cat_cols), default=1))
+    # a dict entry a value of the union and a look-up a local value, once a side
+    sides = [idf_target] if pre_existing_source else [idf_target, idf_source]
+    with phase("drift/lut", cat="block", cols=len(cat_cols), sides=len(sides)) as sp:
+        luts = [_lut_for(idf, cat_cols, union_vocabs) for idf in sides]
+        sp.add(values=sum(len(union_vocabs[c]) + len(idf.columns[c].vocab) for idf in sides for c in cat_cols))
     if pipeline_ok:
         cuts_dev = cuts_d  # stays on device; NaN rows dropped post-hoc
         num_cols_eff = list(num_cols)
     else:
-        cuts_dev = jnp.asarray(cutoffs, jnp.float32) if num_cols_eff else jnp.zeros((0, bin_size - 1))
+        cuts_dev = jnp.asarray(device_cutoffs(cutoffs)) if num_cols_eff else jnp.zeros((0, bin_size - 1))
 
     # both sides' histograms: two programs, one fetch where the fit stayed on the device
-    with phase("drift/sides", cat="block", rows=idf_target.padded_rows, cols=len(num_cols_eff) + len(cat_cols)):
-        def side(idf: Table, sync: bool = True):
+    live = len(num_cols_eff) + len(cat_cols)
+    with phase("drift/sides", cat="block", rows=idf_target.padded_rows, cols=live,
+               # what no implementation of the histograms can avoid moving (the benchmark's drift_hist_hbm_pct):
+               # every live column's cell once a side, the cut-offs, the counts at their own sizes
+               cells=sum(idf.padded_rows for idf in sides) * live,
+               cutoffs=len(sides) * len(num_cols_eff) * (bin_size - 1),
+               hist_lanes=len(sides) * (len(num_cols_eff) * bin_size + sum(len(union_vocabs[c]) for c in cat_cols))):
+        def side(i: int, sync: bool = True):
             out = drift_side_full(
-                *_side_args(
-                    idf, num_cols_eff, cat_cols, cuts_dev,
-                    _lut_for(idf, cat_cols, union_vocabs), bin_size, n_union,
-                )
+                *_side_args(sides[i], num_cols_eff, cat_cols, cuts_dev, luts[i], bin_size, n_union)
             )
             return jax.device_get(out) if sync else out
 
         if pipeline_ok:
             # async dispatch of all three programs, one host sync
-            tgt_pair = side(idf_target, sync=False)
-            src_pair = side(idf_source, sync=False)
+            tgt_pair = side(0, sync=False)
+            src_pair = side(1, sync=False)
             cutoffs, (tgt_num, tgt_cat), (src_num, src_cat) = jax.device_get(
                 (cuts_dev, tgt_pair, src_pair)
             )
@@ -236,13 +265,16 @@ def statistics(
             cutoffs, num_cols_eff, keep = _drop_allnan_cutoffs(cutoffs[:k_live], num_cols_eff)
             tgt_num = tgt_num[:k_live][keep]
             src_num = src_num[:k_live][keep]
+        elif one_device and not pre_existing_source:
+            # no collectives on one device: both programs dispatched, one host sync
+            (tgt_num, tgt_cat), (src_num, src_cat) = jax.device_get((side(0, sync=False), side(1, sync=False)))
         else:
-            tgt_num, tgt_cat = side(idf_target)
+            tgt_num, tgt_cat = side(0)
             if not pre_existing_source:
-                src_num, src_cat = side(idf_source)
+                src_num, src_cat = side(1)
 
     # the binning model and the source frequencies, a file a column
-    with phase("drift/model", cat="block", cols=len(num_cols_eff) + len(cat_cols)):
+    with phase("drift/model", cat="block", cols=len(num_cols_eff) + len(cat_cols)) as sp_model:
         if not pre_existing_source and cutoffs is not None:
             save_model_df(
                 pd.DataFrame(
@@ -264,11 +296,14 @@ def statistics(
             for j, c in enumerate(cat_cols):
                 freq_p[c] = src_cat[j][: len(union_vocabs[c])] / max(idf_source.nrows, 1)
             if source_save:
+                values = 0
                 for c in num_cols_eff + cat_cols:
                     keys = (
                         list(range(1, bin_size + 1)) if c in num_cols_eff else list(union_vocabs[c])
                     )
                     save_frequency_map(model_dir, c, keys, freq_p[c])
+                    values += len(keys)
+                sp_model.add(values=values)  # a line of a column's CSV each
 
     with phase("drift/frame", cat="block", cols=len(cols)):
         odf = _metrics_frame(freq_p, freq_q, cols, methods, threshold)
@@ -303,6 +338,24 @@ def _metrics_frame(freq_p: Dict[str, np.ndarray], freq_q: Dict[str, np.ndarray],
         odf[m] = np.round(mets[m], 4)
     odf["flagged"] = (odf[methods] > threshold).any(axis=1).astype(int)
     return odf
+
+
+def _empty_as_strings(idf: Table, names: List[str]) -> Table:
+    """``idf`` with each of ``names``, numeric there, as a string column of no
+    values.  Ingest types a column without a value numeric (all NaN) whatever
+    its file declared, so a string column that is empty in one dataset and
+    filled in the other arrives as two kinds; with a value on the numeric side
+    the two datasets disagree on the type and there is nothing to compare."""
+    from anovos_tpu.shared.table import Column
+
+    swaps = []
+    for c in names:
+        col = idf.columns[c]
+        if bool(jnp.any(col.mask)):
+            raise TypeError(f"drift statistics: {c} is numeric in one dataset and categorical in the other")
+        swaps.append((c, Column("cat", jnp.where(col.mask, 0, -1).astype(jnp.int32), col.mask,
+                                vocab=np.array([], dtype=object), dtype_name="string")))
+    return idf.with_columns(swaps) if swaps else idf
 
 
 def _padded_col_tuples(idf: Table, cols: List[str]):
@@ -416,7 +469,7 @@ def _side_args(
 # ---------------------------------------------------------------------------
 # out-of-core streaming drift (round 12): the two-pass histogram machinery
 # applied chunkwise over the prefetch iterator — source cutoffs fitted from
-# streamed global bounds (bit-identical to fit_cutoffs' equal_range tail),
+# streamed global bounds (cutoffs_from_bounds, the in-memory fit's own arithmetic),
 # per-chunk binned counts summed exactly, categorical counts tallied
 # host-side — so a dataset that never fits in memory produces the SAME
 # drift frame and the SAME persisted binning/frequency model, byte for
@@ -493,7 +546,7 @@ def statistics_streaming(
     from anovos_tpu.data_ingest.prefetch import StreamController, StreamStats
     from anovos_tpu.data_transformer.model_io import load_model_df, save_model_df
     from anovos_tpu.ops import streaming as st
-    from anovos_tpu.ops.drift_kernels import binned_histograms, cutoffs_from_bounds
+    from anovos_tpu.ops.drift_kernels import binned_histograms, cutoffs_from_bounds, device_cutoffs
     from anovos_tpu.shared.runtime import get_runtime
     from anovos_tpu.shared.utils import parse_cols as _parse
 
@@ -574,10 +627,8 @@ def statistics_streaming(
         src_rows, src_counters = _merge_side_parts(parts1, cat_cols)
         if num_cols:
             agg = st._pairwise_merge([parts1[i] for i in sorted(parts1)])
-            cuts_full = np.asarray(cutoffs_from_bounds(
-                jnp.asarray(agg["min"], jnp.float32),
-                jnp.asarray(agg["max"], jnp.float32),
-                jnp.asarray(agg["n"], jnp.float32), bin_size))
+            cuts_full = cutoffs_from_bounds(
+                np.asarray(agg["min"], np.float32), np.asarray(agg["max"], np.float32), agg["n"], bin_size)
             cutoffs, num_cols_eff, _ = _drop_allnan_cutoffs(
                 cuts_full[: len(num_cols)], num_cols)
         else:
@@ -599,7 +650,7 @@ def statistics_streaming(
     if num_cols_eff:
         k_pad = get_runtime().pad_cols(len(num_cols_eff))
         cuts_pad = np.full((k_pad, bin_size - 1), np.nan, np.float32)
-        cuts_pad[: len(num_cols_eff)] = np.asarray(cutoffs, np.float32)
+        cuts_pad[: len(num_cols_eff)] = device_cutoffs(cutoffs)
 
     def _hist_dispatch(v, m):
         return {"hist": binned_histograms(

@@ -20,6 +20,7 @@ import os
 import warnings
 from typing import Dict, List, Optional, Union
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pandas as pd
@@ -31,11 +32,39 @@ from anovos_tpu.drift_stability.validations import (
     compute_si,
 )
 from anovos_tpu.obs import get_tracer
-from anovos_tpu.ops.reductions import masked_moments
+from anovos_tpu.ops.reductions import _masked_moments_xla, masked_moments
 from anovos_tpu.shared.table import Table
 from anovos_tpu.shared.utils import parse_cols
 
 logger = logging.getLogger(__name__)
+
+
+def _period_moments(X, M):
+    """Mean, sample stddev and excess kurtosis of a period's numeric block:
+    ``masked_moments`` under the scope ``stability/moments``, so that a device
+    trace tells the block's seconds from those of every other caller."""
+    from anovos_tpu.ops.pallas_kernels import use_pallas
+
+    if use_pallas():  # the hand-scheduled kernel is chosen outside any jit, call by call
+        return _without_spread(masked_moments(X, M))
+    return _period_moments_xla(X, M)
+
+
+@jax.jit
+def _period_moments_xla(X, M):
+    with jax.named_scope("stability/moments"):
+        return _without_spread(_masked_moments_xla(X, M))
+
+
+def _without_spread(mom):
+    """(mean, stddev, kurtosis) with a column of ONE value stated as what it
+    is: stddev 0 and no kurtosis.  The chip's division leaves the mean of a
+    constant a unit of the last place off it, and the centred sums then read a
+    spread of 2e-7 and a kurtosis of exactly 1 (``policy_code``, 1.0 in every
+    row: PERF.md section 6, PR 53) where the CPU's read 0 and none."""
+    flat = mom["min"] == mom["max"]
+    return (mom["mean"], jnp.where(flat & (mom["count"] > 1), 0.0, mom["stddev"]),
+            jnp.where(flat, jnp.nan, mom["kurtosis"]))
 
 
 def stability_index_computation(
@@ -61,6 +90,10 @@ def stability_index_computation(
     if isinstance(binary_cols, str):
         binary_cols = [x.strip() for x in binary_cols.split("|") if x.strip()]
     num_all, _, _ = idfs[0].attribute_type_segregation()
+    if list_of_cols == "all":
+        # a string column without a value in the first period is numeric there (ingest types
+        # an all-null column numeric whatever its file said): "all" is what is numeric in every period
+        num_all = [c for c in num_all if all(idf.columns[c].kind == "num" for idf in idfs[1:] if c in idf.columns)]
     cols = parse_cols(list_of_cols if list_of_cols != "all" else num_all, idfs[0].col_names, drop_cols)
     bad = [c for c in cols if c not in num_all]
     if bad or not cols:
@@ -81,11 +114,8 @@ def stability_index_computation(
     for di, idf in enumerate(idfs):
         # a stage a dataset: one program, its three moments fetched
         with phase("stability/moments", cat="block", rows=idf.padded_rows, cols=len(cols), fetches=3):
-            X, M = idf.numeric_block(cols)
-            mom = masked_moments(X, M)
-            mean = np.asarray(mom["mean"], np.float64)
-            std = np.asarray(mom["stddev"], np.float64)
-            kurt = np.asarray(mom["kurtosis"], np.float64) + 3.0  # reference adds 3 (:243)
+            mean, std, kurt = (np.asarray(a, np.float64) for a in _period_moments(*idf.numeric_block(cols)))
+            kurt = kurt + 3.0  # reference adds 3 (:243)
         for i, c in enumerate(cols):
             hist_rows.append(
                 {

@@ -16,6 +16,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from anovos_tpu.obs import timed
 
 
@@ -130,6 +131,11 @@ def drift_side_full(
     """ONE program for a whole dataset side, straight from raw column arrays:
     stack+cast numeric, stack+vocab-remap categorical, both histogram
     families.  Exactly one device dispatch per side."""
+    with jax.named_scope("drift/side_histograms"):
+        return _side_histograms(num_datas, num_masks, cutoffs, cat_datas, cat_masks, lut, nbins, n_cat_bins)
+
+
+def _side_histograms(num_datas, num_masks, cutoffs, cat_datas, cat_masks, lut, nbins: int, n_cat_bins: int):
     if num_datas:
         X = jnp.stack([d.astype(jnp.float32) for d in num_datas], axis=1)
         Mx = jnp.stack(num_masks, axis=1)
@@ -164,6 +170,11 @@ def fit_cutoffs(
     method: str = "equal_range",
 ) -> jax.Array:
     """Interior bin cutoffs (k, nbins-1) fitted in one program."""
+    with jax.named_scope("drift/fit_cutoffs"):
+        return _fit_cutoffs(num_datas, num_masks, nbins, method)
+
+
+def _fit_cutoffs(num_datas, num_masks, nbins: int, method: str) -> jax.Array:
     X = jnp.stack([d.astype(jnp.float32) for d in num_datas], axis=1)
     M = jnp.stack(num_masks, axis=1)
     if method == "equal_frequency":
@@ -171,31 +182,53 @@ def fit_cutoffs(
 
         qs = jnp.array([j / nbins for j in range(1, nbins)], jnp.float32)
         return masked_quantiles(X, M, qs, interpolation="lower").T
+    return _equal_range_cuts(*_bounds(X, M), nbins)
+
+
+def _bounds(X: jax.Array, M: jax.Array):
+    """Smallest value, largest value and count of every column of a masked block."""
     big = jnp.asarray(jnp.finfo(jnp.float32).max, jnp.float32)
-    lo = jnp.where(M, X, big).min(axis=0)
-    hi = jnp.where(M, X, -big).max(axis=0)
-    n = M.sum(axis=0)
-    return _equal_range_cuts(lo, hi, n, nbins)
+    return jnp.where(M, X, big).min(axis=0), jnp.where(M, X, -big).max(axis=0), M.sum(axis=0)
+
+
+@jax.jit
+def fit_bounds(num_datas: Tuple[jax.Array, ...], num_masks: Tuple[jax.Array, ...]):
+    """Smallest value, largest value and count of every column, one program:
+    what the equal_range cutoffs are made of (:func:`cutoffs_from_bounds`)."""
+    with jax.named_scope("drift/fit_cutoffs"):
+        return _bounds(jnp.stack([d.astype(jnp.float32) for d in num_datas], axis=1), jnp.stack(num_masks, axis=1))
 
 
 def _equal_range_cuts(lo: jax.Array, hi: jax.Array, n: jax.Array,
                       nbins: int) -> jax.Array:
-    """The equal_range cutoff arithmetic, shared so the streaming fit
-    (global min/max merged across chunks — exact, order-independent)
-    reproduces ``fit_cutoffs`` bit-for-bit."""
+    """The equal_range cutoff arithmetic of ``fit_cutoffs`` in f32 on the
+    device (the report's charts bin with it; the drift model takes
+    :func:`cutoffs_from_bounds`)."""
     width = (hi - lo) / nbins
     cuts = lo[:, None] + jnp.arange(1, nbins, dtype=jnp.float32)[None, :] * width[:, None]
     return jnp.where(n[:, None] > 0, cuts, jnp.nan)
 
 
-@functools.partial(jax.jit, static_argnames=("nbins",))
-def cutoffs_from_bounds(lo: jax.Array, hi: jax.Array, n: jax.Array,
-                        nbins: int) -> jax.Array:
-    """Interior equal_range cutoffs from already-reduced per-column
-    bounds: the out-of-core fit.  ``lo``/``hi`` are the streamed global
-    f32 min/max (identical values to the in-memory reduction — min/max
-    are exact under any merge order), ``n`` the valid counts; the cut
-    arithmetic is the exact ``fit_cutoffs`` tail, so a streaming drift
-    run persists byte-identical binning models."""
-    return _equal_range_cuts(lo.astype(jnp.float32), hi.astype(jnp.float32),
-                             n, nbins)
+def cutoffs_from_bounds(lo, hi, n, nbins: int) -> np.ndarray:
+    """Interior equal_range cutoffs ``(k, nbins-1)`` from per-column bounds,
+    in float64 on the host as the upstream computes them (``min + j * ((max -
+    min) / bins)``; NaN where ``n`` is 0): ``lo`` / ``hi`` are a side's f32
+    smallest and largest value (exact under any merge order), so the
+    in-memory fit, the streamed fit and the continuum's land on the same
+    model to the bit.  The f32 arithmetic this replaces put a cut-off a unit
+    of the last place to either side of a value that lies ON it (a whole
+    number, a rate to two decimals), and a bin's worth of rows with it."""
+    lo, hi = np.asarray(lo, np.float64), np.asarray(hi, np.float64)
+    width = (hi - lo) / nbins
+    cuts = lo[:, None] + np.arange(1, nbins, dtype=np.float64)[None, :] * width[:, None]
+    return np.where(np.asarray(n)[:, None] > 0, cuts, np.nan)
+
+
+def device_cutoffs(cuts) -> np.ndarray:
+    """float64 cutoffs as the f32 the device compares with: each rounded
+    DOWN, so that ``x > cut`` decides for every f32 ``x`` what it decides in
+    float64 (no f32 lies between a cut-off and the f32 under it)."""
+    cuts = np.asarray(cuts, np.float64)
+    near = cuts.astype(np.float32)
+    with np.errstate(invalid="ignore"):
+        return np.where(near.astype(np.float64) > cuts, np.nextafter(near, np.float32(-np.inf)), near).astype(np.float32)

@@ -171,6 +171,23 @@ def ETL(args: dict) -> Table:
     return df
 
 
+def _read_inside(name: str, spec: dict) -> Table:
+    """:func:`ETL` of a table that a node reads for itself (the drift source, a
+    stability period) as a stage row ``name`` of that node: the read's own
+    ``io:read_dataset`` and ``ingest/*`` rows under it, as the pass's
+    ``ingest`` has them, and the table's rows, columns and file bytes on it."""
+    with get_tracer().phase(name, cat="io") as sp:
+        df = ETL(spec)
+        rd = spec.get("read_dataset") or {}
+        try:
+            files = data_ingest._resolve_files(rd.get("file_path"), rd.get("file_type"))
+            nbytes = sum(os.path.getsize(f) for f in files)
+        except (OSError, TypeError, ValueError):
+            nbytes = 0
+        sp.add(rows=df.nrows, columns=df.ncols, bytes=nbytes)
+    return df
+
+
 def save(
     data,
     write_configs: Optional[dict],
@@ -1051,7 +1068,7 @@ def _main(all_configs: dict, run_type: str, auth_key_val: Optional[dict],
                                     ):
                                         source = base_df
                                     else:
-                                        source = ETL(src_spec)
+                                        source = _read_inside("drift/read", src_spec)
                                 # statistics() also persists the drift frequency
                                 # model (the charts node's drift tab reads it)
                                 df_stats = ddetector.statistics(df, source, **value["configs"])
@@ -1075,7 +1092,7 @@ def _main(all_configs: dict, run_type: str, auth_key_val: Optional[dict],
                                             value))
                         else:
                             def _stability(df, value=value):
-                                idfs = [ETL(value[k]) for k in value if k != "configs"]
+                                idfs = [_read_inside("stability/read", value[k]) for k in value if k != "configs"]
                                 df_stats = dstability.stability_index_computation(*idfs, **value["configs"])
                                 if report_input_path:
                                     save_stats(df_stats, report_input_path, "stability_index",

@@ -814,8 +814,9 @@ MORE_STAGES = {
     "quality_checker/biasedness_detection": {"biasedness/stats"},
     "quality_checker/outlier_detection": {"outlier/bounds", "outlier/flags", "outlier/treat"},
     "quality_checker/nullColumns_detection": {"nullcols/stats", "nullcols/treat"},
-    "drift_detector/drift_statistics": {"drift/fit", "drift/sides", "drift/model", "drift/frame"},
-    "drift_detector/stability_index": {"stability/moments", "stability/frame"},
+    "drift_detector/drift_statistics": {"drift/read", "drift/fit", "drift/union", "drift/lut", "drift/sides", "drift/model",
+                                        "drift/frame"},
+    "drift_detector/stability_index": {"stability/read", "stability/moments", "stability/frame"},
     "report_preprocessing/charts_to_objects": {"charts/read", "charts/num", "charts/cat", "charts/write"},
 }
 COLUMNS, TABS = 24, 11  # of the income table; of the report
